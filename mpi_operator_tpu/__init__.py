@@ -7,7 +7,7 @@ delegates to."""
 
 __version__ = "0.1.0"
 
-# importing the package applies the jax/flax API shims (utils/compat.py)
-# before any model code runs — e.g. the flax duplicate-logical-axis-name
-# patch that MaskedLM's ("embed", "embed") mlm_dense kernel needs
-from .utils import compat as _compat  # noqa: E402,F401
+# Importing the package loads nothing else: the control plane (controller/,
+# cluster/, api/, bootstrap.launch) must stay importable without jax
+# (Dockerfile build check, tests/test_import_hygiene.py). The data-plane
+# subpackages import utils/compat.py themselves.

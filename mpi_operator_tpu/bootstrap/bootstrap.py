@@ -385,12 +385,6 @@ def initialize(env: Optional[Mapping[str, str]] = None,
                     num_processes=info.num_processes)
     if not info.is_launcher and info.num_processes > 1:
         _initialize_distributed(info, resolved_env, events=events)
-    elif not info.is_launcher:
-        # a launch wrapper may have set cpu-collectives=gloo before the
-        # gang size was known; with no distributed client this jaxlib
-        # can't build the CPU backend at all (utils/compat.py)
-        from ..utils.compat import cpu_collectives_solo_fallback
-        cpu_collectives_solo_fallback()
     gated = (ENV_READY_FILE in resolved_env
              or ENV_EXPECTED_CHIPS in resolved_env)
     if not info.is_launcher and (gated or info.num_processes > 1):

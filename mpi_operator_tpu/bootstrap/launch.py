@@ -14,23 +14,46 @@ global rank `ordinal*slots + local`), waits for all, and exits with the
 first non-zero status — the same all-or-nothing semantics mpirun gave.
 
 The usual TPU case is slots=1 (one process drives all local chips) and this
-module is not needed at all.
+module is not needed at all. On a host that has TPU chips, slots>1 is
+refused: every forked process would open ALL local chips, a chip belongs
+to one process at a time, so the second process fails or hangs at backend
+init. Giving each process its own chips (libtpu's visibility environment)
+is not implemented; until it is, the launcher says so instead of hanging.
+This module never imports jax — it holds no chip itself.
 """
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import subprocess
 import sys
 from typing import List, Optional
 
-from .bootstrap import ENV_LOCAL_RANK, ENV_SLOTS
+from .bootstrap import ENV_LOCAL_RANK, ENV_SLOTS, BootstrapError
+
+#: device nodes a TPU VM exposes, one per chip (the accel driver, or vfio
+#: on newer images) — how a process that must not load libtpu can tell
+#: that the host has chips
+TPU_DEVICE_GLOBS = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
+
+
+def local_tpu_chips() -> List[str]:
+    return sorted(p for pat in TPU_DEVICE_GLOBS for p in glob.glob(pat))
 
 
 def launch(command: List[str], slots: Optional[int] = None) -> int:
     slots = slots or int(os.environ.get(ENV_SLOTS, "1"))
     if slots == 1:
         return subprocess.call(command)
+    chips = local_tpu_chips()
+    if chips:
+        raise BootstrapError(
+            f"slotsPerWorker={slots} on a host with {len(chips)} TPU "
+            f"chip(s) ({chips[0]}…): each of the {slots} processes would "
+            f"open every local chip, and a chip belongs to one process at "
+            f"a time — the second process fails or hangs at backend init. "
+            f"Use slotsPerWorker=1 (one process drives all local chips).")
 
     procs: List[subprocess.Popen] = []
     for local_rank in range(slots):
@@ -69,7 +92,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         print("usage: python -m mpi_operator_tpu.bootstrap.launch -- "
               "<command> [args...]", file=sys.stderr)
         return 2
-    return launch(argv)
+    try:
+        return launch(argv)
+    except BootstrapError as exc:
+        print(f"bootstrap.launch: {exc}", file=sys.stderr)
+        return 1
 
 
 if __name__ == "__main__":

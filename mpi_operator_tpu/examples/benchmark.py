@@ -21,7 +21,10 @@ import os
 import sys
 from typing import Callable, Dict, Optional, Tuple
 
+from ._report import with_run_report
 
+
+@with_run_report
 def run_benchmark(
     model_name: str = "resnet101",
     batch_per_device: int = 64,
@@ -136,6 +139,8 @@ def main(argv=None) -> int:
         print("launcher: waiting on rank-0 status channel", flush=True)
         return launcher_wait(info)
 
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     status = StatusServer() if info.is_coordinator else None
     exit_code = 1
     try:

@@ -1,0 +1,225 @@
+"""Pallas attention kernels against their dense references, on the chip.
+
+The CPU tests run these kernels in interpret mode; whether Mosaic compiles
+them, and whether the compiled kernels agree with the dense path, can only
+be learnt on a TPU. This module is that check, at the shapes the two
+GPT-2-medium legs of `chip_smoke.py` use:
+
+  flash forward and gradients (dq, dk, dv)   vs `dense_attention`
+  decode_attention, per-row cursor vector     vs the dense branch of
+  paged_decode_attention                         `Attention._decode_attend`
+  the int8-cache variants of both decode kernels
+
+The decode cases go through the `Attention` module itself — one set of
+weights, one prefilled cache, the single-token step run once with
+`decode_kernel=True` and once with `False` — so the reference is the
+repo's own dense branch, not a copy of it.
+
+Closeness is measured at the output level, `max|a-b| / max|b|`, against a
+stated bf16 tolerance: the MXU does not sum in the interpreter's order,
+and the two paths round p·v at different points, so bit or token equality
+is not the claim.
+
+    python -m mpi_operator_tpu.examples.kernel_parity
+
+fails at once, before building anything, when the backend is not a TPU or
+its `device_kind` is not in the peaks table (utils/flops.py).
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import sys
+from typing import Dict, List, Optional
+
+#: max|kernel - dense| / max|dense| allowed for bf16 operands. bf16 keeps
+#: 8 significant bits (relative step 2^-8 = 0.0039); kernel and reference
+#: each round scores, probabilities and the p·v product at different
+#: points, so a few steps of disagreement on the largest element is the
+#: expected floor. An indexing bug (a wrong page, a read past a cursor, a
+#: mis-masked block) moves whole rows by O(1) and lands far above it.
+BF16_TOL = 2e-2
+
+#: GPT-2-medium attention geometry and the two legs' shapes
+GPT2_MEDIUM = dict(heads=16, head_dim=64)
+TRAIN_SHAPE = dict(batch=16, seq=512)
+SERVE_SHAPE = dict(slots=8, max_len=256, page_size=64, prefilled=200)
+
+
+def _rel_err(got, ref) -> float:
+    import jax.numpy as jnp
+
+    got = jnp.asarray(got, jnp.float32)
+    ref = jnp.asarray(ref, jnp.float32)
+    return float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+
+
+def _assert_mosaic(jitted, *args) -> None:
+    """The lowered program must carry the kernel as a Mosaic custom call;
+    an interpreted kernel lowers to plain HLO loops instead."""
+    import jax
+
+    if jax.default_backend() == "tpu" and \
+            "tpu_custom_call" not in jitted.lower(*args).as_text():
+        raise AssertionError("kernel did not lower to a tpu_custom_call")
+
+
+def flash_cases(batch: int, seq: int, heads: int, head_dim: int
+                ) -> List[Dict[str, object]]:
+    """Flash forward + (dq, dk, dv) vs dense_attention at one shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..models.transformer import dense_attention
+    from ..ops.attention import flash_attention
+
+    shape = (batch, seq, heads, head_dim)
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (jax.random.normal(key, shape, jnp.bfloat16)
+                  for key in (kq, kk, kv, kw))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, causal=True, dtype=q.dtype)
+
+    def fwd_and_grads(fn):
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32)
+                           * w.astype(jnp.float32))
+        return jax.jit(lambda q, k, v: (fn(q, k, v),
+                                        *jax.grad(loss, (0, 1, 2))(q, k, v)))
+
+    kernel = fwd_and_grads(flash)
+    _assert_mosaic(kernel, q, k, v)
+    got = kernel(q, k, v)
+    ref = fwd_and_grads(dense)(q, k, v)
+    return [{"kernel": f"flash_{name}", "shape": list(shape),
+             "max_rel_err": _rel_err(g, r)}
+            for name, g, r in zip(("fwd", "dq", "dk", "dv"), got, ref)]
+
+
+def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
+                page_size: int, prefilled: int, heads: int, head_dim: int
+                ) -> Dict[str, object]:
+    """One single-token step through `Attention`, kernel vs dense branch,
+    on one prefilled cache with every row at its own cursor."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..models.transformer import Attention, TransformerConfig
+    from ..ops.attention import record_traced
+
+    nblk = max_len // page_size
+    cfg = TransformerConfig(
+        num_heads=heads, embed_dim=heads * head_dim, max_len=max_len,
+        dtype=jnp.bfloat16, decode=True, decode_slots=True,
+        kv_cache_dtype="int8" if int8 else None,
+        decode_page_size=page_size if paged else None,
+        decode_num_pages=slots * nblk + 1 if paged else 0)
+    dense = Attention(dataclasses.replace(cfg, decode_kernel=False))
+    kernel = Attention(dataclasses.replace(cfg, decode_kernel=True))
+
+    kw = {}
+    if paged:
+        # every row's logical blocks scattered over the pool (page 0 is
+        # the reserved trash page), so a kernel that ignored the table
+        # would read another row's pages
+        ids = np.random.RandomState(0).permutation(slots * nblk) + 1
+        kw["pages"] = jnp.asarray(ids.reshape(slots, nblk), jnp.int32)
+    kp, kx, ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    E = cfg.embed_dim
+    x_fill = jax.random.normal(kx, (slots, prefilled, E), jnp.bfloat16)
+    x_step = jax.random.normal(ks, (slots, 1, E), jnp.bfloat16)
+    fill_pos = jnp.broadcast_to(jnp.arange(prefilled)[None],
+                                (slots, prefilled))
+    # cursors on and around the block boundaries of both kernels
+    # (k-tile 128, page 64), first and last filled position included
+    marks = [0, page_size - 1, page_size, 2 * page_size - 1,
+             2 * page_size, prefilled - 1, 5, prefilled - 9]
+    cur = jnp.asarray([min(max(m, 0), prefilled - 1)
+                       for m in (marks * slots)[:slots]], jnp.int32)
+
+    params = dense.init(kp, x_step, positions=cur[:, None], **kw)["params"]
+    # the multi-token call is the dense branch under either setting
+    _, filled = jax.jit(lambda p: dense.apply(
+        {"params": p}, x_fill, positions=fill_pos, mutable=["cache"],
+        **kw))(params)
+
+    def step(module):
+        return jax.jit(lambda p, c: module.apply(
+            {"params": p, "cache": c}, x_step, positions=cur[:, None],
+            mutable=["cache"], **kw)[0])
+
+    with record_traced() as traced:
+        _assert_mosaic(step(kernel), params, filled["cache"])
+        got = step(kernel)(params, filled["cache"])
+    want = "pallas_paged" if paged else "pallas"
+    if traced["decode"] != {want}:
+        raise AssertionError(f"decode step traced {traced['decode']}, "
+                             f"expected {want!r}")
+    ref = step(dense)(params, filled["cache"])
+    return {"kernel": ("paged_decode_attention" if paged
+                       else "decode_attention") + ("_int8" if int8 else ""),
+            "shape": {"slots": slots, "heads": heads, "head_dim": head_dim,
+                      "max_len": max_len,
+                      **({"page_size": page_size} if paged else {})},
+            "cursors": [int(c) for c in cur],
+            "max_rel_err": _rel_err(got, ref)}
+
+
+def run_kernel_parity(train_shape: Optional[dict] = None,
+                      serve_shape: Optional[dict] = None,
+                      model: Optional[dict] = None,
+                      tol: float = BF16_TOL) -> List[Dict[str, object]]:
+    """Every kernel the two legs use, at their shapes; one record each
+    with its measured error and `ok`. Off TPU the kernels interpret (the
+    tier-1 test runs tiny shapes that way)."""
+    train_shape = train_shape or TRAIN_SHAPE
+    serve_shape = serve_shape or SERVE_SHAPE
+    model = model or GPT2_MEDIUM
+    records = flash_cases(train_shape["batch"], train_shape["seq"],
+                          model["heads"], model["head_dim"])
+    for paged in (False, True):
+        for int8 in (False, True):
+            records.append(decode_case(paged, int8, **serve_shape, **model))
+    for rec in records:
+        rec["tol"] = tol
+        rec["ok"] = bool(rec["max_rel_err"] <= tol)
+    return records
+
+
+def main(argv=None) -> int:
+    del argv
+    import jax
+
+    from ..utils import flops
+    from ..utils.compile_cache import enable_compile_cache
+    from ._report import device_record
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"kernel_parity: needs a TPU backend to compile the kernels "
+              f"with Mosaic; jax found platform {backend!r}",
+              file=sys.stderr)
+        return 2
+    flops.device_peaks()            # an unknown device_kind raises here
+    cache_dir = enable_compile_cache()
+    device = device_record()
+    records = run_kernel_parity()
+    for rec in records:
+        print(json.dumps({**rec, **device}))
+    ok = all(rec["ok"] for rec in records)
+    print(json.dumps({"metric": "kernel_parity", "ok": ok,
+                      "kernels": len(records),
+                      "worst_max_rel_err": max(r["max_rel_err"]
+                                               for r in records),
+                      "tol": BF16_TOL, **device,
+                      "compile_cache_dir": cache_dir}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -23,6 +23,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
+from ._report import device_ids, with_run_report
+
 
 def _percentiles(xs, ps=(50, 99)):
     import numpy as np
@@ -48,6 +50,7 @@ def _latency_fields(results, prefix="serving"):
             f"{prefix}_tpot_p99_ms": ms(tpot[99], 3)}
 
 
+@with_run_report
 def run_serving_benchmark(
     size: Optional[str] = None,
     family: str = "gpt2",
@@ -132,7 +135,8 @@ def run_serving_benchmark(
     if decode_kernel is None:
         # same auto policy as run_generate_benchmark: Pallas fast path on
         # TPU, dense oracle elsewhere (interpret-mode pallas inside the
-        # step would simulate, not measure)
+        # step would simulate, not measure). The record's `decode_impl`
+        # is what the step traced, whatever was asked for here.
         decode_kernel = jax.default_backend() == "tpu"
     # cache length: fits the longest request, rounded up so the decode
     # kernel's k-tile divides it (decode_block_k caps at max_len, so any
@@ -172,6 +176,7 @@ def run_serving_benchmark(
     # in-memory ring only (no sink file): the per-hop breakdown and the
     # completeness gate read the ring after the measured run
     tracer = Tracer(sample=1.0)
+    warm_t0 = time.perf_counter()
     engine = ServingEngine(model, params, EngineConfig(
         slots=slots, chunk_buckets=tuple(chunk_buckets),
         decode_kernel=decode_kernel, rng_seed=seed,
@@ -189,6 +194,14 @@ def run_serving_benchmark(
             for j, p in enumerate(sorted(set(int(r) for r in prompt_grid)))]
     engine.run(warm)
     engine.reset()
+    # engine construction + warm-up: every program the measured trace
+    # uses compiles in here, none after (serving_no_recompile)
+    warmup_seconds = time.perf_counter() - warm_t0
+    warm_counts = engine.compile_counts()
+    param_ids = device_ids(engine.params)
+    cache_ids = device_ids(engine.cache)
+    log(f"serving placement: params on devices {param_ids}, KV cache on "
+        f"devices {cache_ids} of {jax.device_count()} visible")
 
     profiler = WindowProfiler(profile_dir, log)
     profiler.start()
@@ -209,6 +222,11 @@ def run_serving_benchmark(
     # program per bucket; anything beyond that is a recompile leak
     no_recompile = (counts["step"] <= 3
                     and counts["prefill"] <= len(chunk_buckets))
+    # every request came back with the token count it asked for (the
+    # trace sets no eos_id, so "length" is the only way to finish)
+    complete = all(r.id in results
+                   and len(results[r.id].tokens) == r.max_new_tokens
+                   for r in trace)
     # host_gap percentiles BEFORE any sync rerun below touches the same
     # histogram: these must describe the measured (async) trace only
     gap50_ms, gap99_ms = None, None
@@ -235,6 +253,7 @@ def run_serving_benchmark(
     out: Dict[str, object] = {
         "serving_tokens_per_sec": round(tps, 1),
         "serving_requests": num_requests,
+        "serving_requests_complete": bool(complete),
         "serving_slots": slots,
         "serving_total_new_tokens": total_new,
         "serving_wall_seconds": round(wall, 3),
@@ -248,8 +267,14 @@ def run_serving_benchmark(
         "serving_step_compiles": counts["step"],
         "serving_prefill_compiles": counts["prefill"],
         "serving_no_recompile": bool(no_recompile),
+        "serving_compiles_after_warmup": (
+            sum(counts.values()) - sum(warm_counts.values())),
+        "serving_warmup_seconds": round(warmup_seconds, 3),
+        "serving_param_device_ids": param_ids,
+        "serving_cache_device_ids": cache_ids,
         "serving_decode_kernel": bool(decode_kernel),
         "serving_async_decode": bool(engine.config.async_decode),
+        "serving_cache_donated": engine.donates_cache,
         "serving_paged": bool(paged),
     }
     if speculative is not None:
@@ -414,11 +439,11 @@ def run_serving_benchmark(
             shapes[(len(r.prompt), r.max_new_tokens,
                     r.temperature > 0)] = r
         for r in shapes.values():
-            int(run_one(r).tokens[0, -1])       # compile + true barrier
+            int(run_one(r).tokens[0, -1])       # compile + wait
         t0 = time.perf_counter()
         for r in trace:
             o = run_one(r)
-        int(o.tokens[0, -1])                    # host read = barrier
+        int(o.tokens[0, -1])                    # waits for the last call
         base_wall = time.perf_counter() - t0
         base_total = sum(r.max_new_tokens for r in trace)
         base_tps = base_total / base_wall
@@ -434,6 +459,7 @@ def run_serving_benchmark(
     return out
 
 
+@with_run_report
 def run_disagg_benchmark(
     size: Optional[str] = None,
     family: str = "gpt2",
@@ -617,6 +643,7 @@ def run_disagg_benchmark(
     return out
 
 
+@with_run_report
 def run_router_benchmark(
     size: Optional[str] = None,
     family: str = "gpt2",
@@ -874,6 +901,7 @@ def run_router_benchmark(
     return out
 
 
+@with_run_report
 def run_livescale_benchmark(
     size: Optional[str] = None,
     family: str = "gpt2",
@@ -1238,6 +1266,18 @@ def main(argv=None) -> int:
                         help="serve live engine telemetry at "
                              "/metrics on this port (0 = any free port)")
     args = parser.parse_args(argv)
+    from ..utils.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+
+    def headline(name, metrics) -> int:
+        # the run_* functions' metrics already name the device and the
+        # traced implementations (with_run_report)
+        print(json.dumps({"metric": f"{name}_tokens_per_sec",
+                          "value": metrics[f"{name}_tokens_per_sec"],
+                          "unit": "tokens/sec", **metrics,
+                          "compile_cache_dir": cache_dir}))
+        return 0
+
     if args.livescale:
         metrics = run_livescale_benchmark(
             size=args.size, family=args.family, replicas=args.replicas,
@@ -1248,10 +1288,7 @@ def main(argv=None) -> int:
             max_inflight=args.max_inflight,
             scale_up_at=args.scale_up_at,
             scale_down_at=args.scale_down_at, seed=args.seed)
-        print(json.dumps({"metric": "livescale_tokens_per_sec",
-                          "value": metrics["livescale_tokens_per_sec"],
-                          "unit": "tokens/sec", **metrics}))
-        return 0
+        return headline("livescale", metrics)
     if args.router:
         metrics = run_router_benchmark(
             size=args.size, family=args.family, replicas=args.replicas,
@@ -1260,10 +1297,7 @@ def main(argv=None) -> int:
             num_pages=args.num_pages,
             shared_prefix_len=args.shared_prefix_len or 32,
             max_inflight=args.max_inflight, seed=args.seed)
-        print(json.dumps({"metric": "router_tokens_per_sec",
-                          "value": metrics["router_tokens_per_sec"],
-                          "unit": "tokens/sec", **metrics}))
-        return 0
+        return headline("router", metrics)
     if args.disagg:
         metrics = run_disagg_benchmark(
             size=args.size, family=args.family, slots=args.slots,
@@ -1271,10 +1305,7 @@ def main(argv=None) -> int:
             kv_cache_dtype=args.kv_cache_dtype,
             page_size=args.page_size, num_pages=args.num_pages,
             seed=args.seed)
-        print(json.dumps({"metric": "disagg_tokens_per_sec",
-                          "value": metrics["disagg_tokens_per_sec"],
-                          "unit": "tokens/sec", **metrics}))
-        return 0
+        return headline("disagg", metrics)
     metrics = run_serving_benchmark(
         size=args.size, family=args.family, slots=args.slots,
         num_requests=args.num_requests, dtype_name=args.dtype,
@@ -1286,10 +1317,7 @@ def main(argv=None) -> int:
         baseline=not args.no_baseline, compare_sync=args.compare_sync,
         compare_spec=args.compare_spec, seed=args.seed,
         profile_dir=args.profile_dir, metrics_port=args.metrics_port)
-    print(json.dumps({"metric": "serving_tokens_per_sec",
-                      "value": metrics["serving_tokens_per_sec"],
-                      "unit": "tokens/sec", **metrics}))
-    return 0
+    return headline("serving", metrics)
 
 
 if __name__ == "__main__":

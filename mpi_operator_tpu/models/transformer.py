@@ -32,6 +32,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+# importing compat applies the flax duplicate-logical-name patch that
+# MaskedLM's ("embed", "embed") mlm_dense kernel needs
+from ..utils.compat import axis_bound as _axis_bound, shard_map
+
 Dtype = Any
 
 kernel_init = nn.initializers.normal(stddev=0.02)   # GPT-2/BERT init
@@ -84,8 +88,9 @@ class TransformerConfig:
     # kernel (ops/attention.decode_attention) — GQA-native (no repeated-KV
     # transient), length-aware cache reads (only the filled prefix
     # streams), int8 dequant fused into the cache read. False keeps the
-    # dense einsum path, the CPU/correctness oracle. Prefill and tile-
-    # unaligned cache lengths always use the dense path.
+    # dense einsum path, the CPU/correctness oracle. Prefill always uses
+    # the dense path; a cache shape the kernel cannot tile is an error on
+    # TPU and takes the dense path elsewhere (Attention._decode_attend).
     decode_kernel: bool = False
     # decode-kernel k-tile (None = ops.attention.decode_block_k default)
     decode_block_k: Optional[int] = None
@@ -347,7 +352,6 @@ class Attention(nn.Module):
         from ..parallel.collectives import allgather_matmul
         from ..parallel.sharding import (tp_manual_spec,
                                          tp_overlap_activation_spec)
-        from ..utils.compat import shard_map
         cfg = self.config
         H, D, KV = cfg.num_heads, cfg.head_dim, cfg.kv_heads
         E = x.shape[-1]
@@ -400,7 +404,6 @@ class Attention(nn.Module):
         from ..parallel.collectives import matmul_reducescatter
         from ..parallel.sharding import (tp_manual_spec,
                                          tp_overlap_activation_spec)
-        from ..utils.compat import shard_map
         cfg = self.config
         H, D, E = cfg.num_heads, cfg.head_dim, cfg.embed_dim
         wo, bo = _ProjParams((H, D, E), (E,), ("heads", "kv", "embed"),
@@ -451,8 +454,8 @@ class Attention(nn.Module):
         single-token steps run ops.attention.decode_attention, which is
         GQA-native AND length-aware (only the filled prefix streams, int8
         dequant fused into the read) — the dense path below stays the
-        correctness oracle and handles prefill + unaligned cache
-        lengths.
+        correctness oracle and handles prefill (and, off TPU, cache
+        shapes the kernel cannot tile).
 
         With cfg.decode_page_size the slot rows stop owning contiguous
         cache: the cache variables become a POOL of pages
@@ -620,28 +623,50 @@ class Attention(nn.Module):
             cv.value = constrain(upd4(cv.value, v_t))
             bump()
 
+        from ..ops.attention import note_traced
         if cfg.decode_kernel and S == 1:
+            # A requested kernel runs, or says why it cannot. On TPU a
+            # shape the kernel cannot tile is an error naming the shape
+            # (falling through would quietly stream the whole cache
+            # where a kernel was asked for); off TPU the dense oracle
+            # below takes it, which is what the CPU tests compare the
+            # kernel against.
             if paged:
                 from ..ops.attention import paged_decode_attention
                 # Mosaic second-minor tiling for the (ps, D) page block:
-                # int8 needs 32, bf16 16, f32 8 — pages below that fall
-                # back to the dense gather oracle
+                # int8 needs 32, bf16 16, f32 8
                 need = (32 if ck.value.dtype == jnp.int8
                         else 16 if ck.value.dtype == jnp.bfloat16 else 8)
                 if ps % need == 0:
+                    note_traced("decode", "pallas_paged")
                     out = paged_decode_attention(
                         q[:, 0], ck.value, cv.value, cur, pt,
                         k_scale=k_scale, v_scale=v_scale)
                     return out[:, None]
+                untileable = (f"decode_page_size={ps} is not a multiple "
+                              f"of {need}, the second-minor tile of a "
+                              f"{ck.value.dtype.name} page block "
+                              f"[{ps}, {D}]")
             else:
                 from ..ops.attention import (decode_attention,
                                              decode_block_k)
-                if L % decode_block_k(L, cfg.decode_block_k) == 0:
+                bk = decode_block_k(L, cfg.decode_block_k)
+                if L % bk == 0:
+                    note_traced("decode", "pallas")
                     out = decode_attention(
                         q[:, 0], ck.value, cv.value, cur,
                         k_scale=k_scale, v_scale=v_scale,
                         block_k=cfg.decode_block_k)
                     return out[:, None]
+                untileable = (f"cache length max_len={L} does not tile "
+                              f"by the decode k-tile {bk}")
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    f"decode_kernel=True but the Pallas decode kernel "
+                    f"cannot take this shape: {untileable}. Resize the "
+                    f"cache, or set decode_kernel=False to ask for the "
+                    f"dense path.")
+        note_traced("decode" if S == 1 else "prefill", "dense")
         # dense oracle path (prefill, CPU correctness, unaligned shapes).
         # Paged caches gather the page table back into the logical
         # [B, KV, L, D] layout first — trash/junk entries land at
@@ -677,13 +702,6 @@ class Attention(nn.Module):
         return jnp.einsum("bhqk,bhkd->bqhd", probs, values)
 
 
-def _axis_bound(name: str) -> bool:
-    """True when `name` is a live collective axis (we're tracing inside
-    shard_map/pmap over it)."""
-    from ..utils.compat import axis_bound
-    return axis_bound(name)
-
-
 def _attend(q, k, v, mask, cfg: TransformerConfig):
     """Dispatch to the configured attention implementation.
     q/k/v: [B, S, H, D]; returns [B, S, H, D].
@@ -692,6 +710,7 @@ def _attend(q, k, v, mask, cfg: TransformerConfig):
     kernel (ops/attention.py); the ring schedule doesn't implement it, so
     masked ring requests fall back to dense rather than silently attending
     to padding."""
+    from ..ops.attention import flash_attention, note_traced
     impl = cfg.attention
     if impl == "auto":
         # flash kernel only on TPU; dense elsewhere (CPU tests/simulation)
@@ -699,7 +718,7 @@ def _attend(q, k, v, mask, cfg: TransformerConfig):
     if mask is not None and impl == "ring":
         impl = "dense"
     if impl == "flash":
-        from ..ops.attention import flash_attention
+        # flash_attention reports "flash" or, for an untileable S, "dense"
         kw = {}
         if cfg.flash_block_q:
             kw["block_q"] = cfg.flash_block_q
@@ -710,6 +729,7 @@ def _attend(q, k, v, mask, cfg: TransformerConfig):
         from ..parallel.ring_attention import (ring_attention,
                                                ring_attention_inner)
         from ..parallel.sharding import current_mesh
+        note_traced("attention", "ring")
         if _axis_bound("sp"):
             # already inside shard_map/pmap over sp: the seq dim is the
             # local shard, run the ring body directly
@@ -728,6 +748,7 @@ def _attend(q, k, v, mask, cfg: TransformerConfig):
             "(train under LMTrainer on a MeshConfig(sp=N) mesh; a "
             "degenerate 1-device ring would deliver no context parallelism"
             "); for direct use call parallel.ring_attention(q, k, v, mesh)")
+    note_traced("attention", "dense")
     return dense_attention(q, k, v, mask=mask, causal=cfg.causal,
                            dtype=cfg.dtype)
 
@@ -826,7 +847,6 @@ class Mlp(nn.Module):
                                             matmul_reducescatter)
         from ..parallel.sharding import (tp_manual_spec,
                                          tp_overlap_activation_spec)
-        from ..utils.compat import shard_map
         cfg = self.config
         E, M = cfg.embed_dim, cfg.mlp_dim
         if M % tp:

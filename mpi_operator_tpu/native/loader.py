@@ -2,13 +2,18 @@
 
 The shared library is built on first use with the system g++ (no pybind11
 in the image — the C ABI + ctypes is the sanctioned binding path) and
-cached next to the source. Everything degrades gracefully: if no compiler
+cached next to the source, with the SHA-256 of the source it was built
+from beside it; a library that is absent, or was not built from the
+npy_loader.cc now on disk, is rebuilt (file times say nothing after a
+checkout or a tree copy). Neither file is tracked by git. Everything
+degrades gracefully: if no compiler
 is available, `native_available()` is False and data/imagefolder.py keeps
 its pure-Python feeder.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,17 +24,22 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "npy_loader.cc")
 _SO = os.path.join(_HERE, "libnpyloader.so")
+_STAMP = _SO + ".sha256"
 _lock = threading.Lock()
 _lib = None
 _build_error: Optional[str] = None
 
 
 def _build() -> Optional[str]:
-    """Compile the .so if stale/missing; returns an error string or None."""
+    """Compile the .so unless one built from the current source is there;
+    returns an error string or None."""
     try:
-        if (os.path.exists(_SO)
-                and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return None
+        with open(_SRC, "rb") as fh:
+            digest = hashlib.sha256(fh.read()).hexdigest()
+        if os.path.exists(_SO) and os.path.exists(_STAMP):
+            with open(_STAMP) as fh:
+                if fh.read().strip() == digest:
+                    return None
         proc = subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-pthread", _SRC, "-o",
              _SO + ".tmp"],
@@ -37,6 +47,9 @@ def _build() -> Optional[str]:
         if proc.returncode != 0:
             return f"g++ failed: {proc.stderr[-500:]}"
         os.replace(_SO + ".tmp", _SO)
+        with open(_STAMP + ".tmp", "w") as fh:
+            fh.write(digest + "\n")
+        os.replace(_STAMP + ".tmp", _STAMP)
         return None
     except FileNotFoundError:
         return "g++ not found"

@@ -29,23 +29,156 @@ Key-padding masks are first-class: `kv_mask` [B, S] (True = real token)
 masks score columns in all three kernels, so padded BERT batches keep the
 flash path instead of falling back to dense O(S²) (the round-1 gap).
 
-On CPU (tests, simulation) the identical kernels run in interpret mode.
+On CPU (tests, simulation) the identical kernels run in interpret mode;
+on TPU they are always compiled by Mosaic (`_resolve_interpret`).
+
+Every dispatch site that chooses between a kernel and the dense path
+(here, and `models/transformer.py` `_attend` / `_decode_attend`) reports
+what it chose through `note_traced`, so an entry point can print the
+implementation that was actually traced (`record_traced`) instead of the
+flag it was asked for.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
-from typing import Optional
+from typing import Dict, Iterator, Optional, Set
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 LANES = 128        # minor-dim width for row-statistic tensors
 
 
 from ..utils.compat import out_struct as _out_struct  # noqa: E402
+
+
+# ---------------------------------------------------------------------------
+# Which implementation was traced
+# ---------------------------------------------------------------------------
+
+_TRACED: contextvars.ContextVar = contextvars.ContextVar(
+    "traced_attention", default=None)
+
+
+@contextlib.contextmanager
+def record_traced() -> Iterator[Dict[str, Set[str]]]:
+    """Collect the attention implementations traced while the block runs.
+
+    Yields a dict the dispatch sites fill at TRACE time:
+      "attention" — full-sequence attention: "flash" | "dense" | "ring"
+      "decode"    — single-token KV-cache steps: "pallas" | "pallas_paged"
+                    | "dense"
+      "prefill"   — multi-token KV-cache calls (always "dense" today)
+    A jitted function traces once, so wrap the whole run (first call
+    included), not a later window."""
+    rec: Dict[str, Set[str]] = {"attention": set(), "decode": set(),
+                                "prefill": set()}
+    token = _TRACED.set(rec)
+    try:
+        yield rec
+    finally:
+        _TRACED.reset(token)
+
+
+def note_traced(kind: str, impl: str) -> None:
+    """Called by a dispatch site when it traces `impl`; no-op outside
+    `record_traced`."""
+    rec = _TRACED.get()
+    if rec is not None:
+        rec[kind].add(impl)
+
+
+def traced_name(impls: Set[str]) -> Optional[str]:
+    """One printable name for a set of traced implementations: the name
+    itself when there is one, "a+b" when a run traced several, None when
+    nothing of that kind was traced."""
+    return "+".join(sorted(impls)) if impls else None
+
+
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Interpret mode is the CPU stand-in for Mosaic; on TPU a kernel is
+    compiled or it is an error — never quietly interpreted."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU backend: Pallas kernels "
+                         "are compiled by Mosaic there")
+    return interpret
+
+
+# ---------------------------------------------------------------------------
+# Kernels on a multi-device mesh
+# ---------------------------------------------------------------------------
+# GSPMD cannot partition a Mosaic kernel: inside a plain jit whose program
+# spans more than one device, lowering a pallas_call raises "Mosaic kernels
+# cannot be automatically partitioned. Please wrap the call in a
+# shard_map" (first seen on the four-chip v5e host; one chip never hits
+# it). Attention is independent per (row, head), so the public kernels
+# below wrap themselves: rows split over the data axes, heads over tp —
+# the layout the trainer's activations and the tp-sharded projections
+# already have, so the wrap moves no data — and each device runs the
+# kernel on its own block. A dim the axes do not divide stays whole
+# (every device computes all of it, which is what GSPMD would have done
+# with an opaque call on replicated operands).
+
+def _kernel_mesh(x):
+    """The mesh a kernel called on `x` must be shard_mapped over, or None
+    when the call can go straight through: `x` is not typed on a mesh
+    (plain single-device jit), the mesh is one device, or we are already
+    inside a manual region (ring attention, pipeline stages — Mosaic wants
+    every axis manual, and those callers own their layout)."""
+    mesh = jax.typeof(x).sharding.mesh
+    if mesh.empty or mesh.size == 1 or any(
+            t == jax.sharding.AxisType.Manual for t in mesh.axis_types):
+        return None
+    return mesh
+
+
+def _per_device(fn, mesh, rows: int, heads: int, args, layouts, out_layout):
+    """Run `fn(*args)` on each device's block of a multi-device mesh.
+
+    A layout names, per dim of its array, what the dim holds: "rows"
+    (batch rows / slots, `rows` of them — split over the data axes),
+    "heads" (`heads` of them — split over tp) or None (kept whole). A
+    split the axes do not divide is dropped. None args (absent mask /
+    scales) pass through."""
+    from ..parallel.mesh import BATCH_AXES
+    from ..utils.compat import shard_map
+
+    def axes_dividing(dim, axes):
+        names = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+        n = 1
+        for a in names:
+            n *= mesh.shape[a]
+        return names if names and dim % n == 0 else None
+
+    split = {"rows": axes_dividing(rows, BATCH_AXES),
+             "heads": axes_dividing(heads, ("tp",)), None: None}
+
+    def spec(layout):
+        return P(*(split[role] for role in layout))
+
+    present = [i for i, a in enumerate(args) if a is not None]
+
+    def body(*given):
+        full = [None] * len(args)
+        for i, g in zip(present, given):
+            full[i] = g
+        return fn(*full)
+
+    return shard_map(
+        body, mesh=mesh, in_specs=tuple(spec(layouts[i]) for i in present),
+        out_specs=spec(out_layout),
+        # the Pallas interpreter (CPU) trips the VMA checker; the specs
+        # here name every split explicitly and claim no replication
+        check_vma=False)(*(args[i] for i in present))
 
 
 # ---------------------------------------------------------------------------
@@ -413,7 +546,8 @@ def flash_attention(q, k, v, causal: bool = True,
     """Flash attention over [B, S, H, D] tensors (layout matches
     models.transformer). `mask`: optional [B, S] valid-key mask (True =
     attend), the BERT padding mask. Falls back to dense attention when S
-    doesn't tile into Mosaic-legal blocks.
+    doesn't tile into Mosaic-legal blocks (ViT's S=197); either way the
+    choice is reported through `note_traced("attention", ...)`.
 
     block_q/block_k default to a per-seq-len policy measured on v5e
     (gpt2-medium train step): 512 tiles up to seq 1024; 1024 tiles from
@@ -422,8 +556,7 @@ def flash_attention(q, k, v, causal: bool = True,
     2048-wide q tiles overflow VMEM; don't.
     """
     B, S, H, D = q.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     # 1024 tiles only when they tile S exactly — a 512-multiple like 2560
     # must keep 512 tiles (flash), never fall through to the dense path
     auto = 1024 if S >= 2048 and S % 1024 == 0 else 512
@@ -433,8 +566,21 @@ def flash_attention(q, k, v, causal: bool = True,
                  or (not interpret and (block_q % 8 or block_k % 8)))
     if unaligned:
         from ..models.transformer import dense_attention
+        note_traced("attention", "dense")
         return dense_attention(q, k, v, mask=mask, causal=causal,
                                dtype=q.dtype)
+    note_traced("attention", "flash")
+    mesh = _kernel_mesh(q)
+    if mesh is not None:
+        # each device re-enters with its own rows / heads (inside the
+        # manual region _kernel_mesh is None and the kernel below runs)
+        qkv = ("rows", None, "heads", None)
+        return _per_device(
+            lambda q, k, v, mask: flash_attention(
+                q, k, v, causal=causal, mask=mask, block_q=block_q,
+                block_k=block_k, interpret=interpret),
+            mesh, B, H, (q, k, v, mask), (qkv, qkv, qkv, ("rows", None)),
+            qkv)
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
@@ -558,8 +704,7 @@ def decode_attention(q, k_cache, v_cache, cache_index,
     if L % bk:
         raise ValueError(f"cache len {L} does not tile by block_k={bk}; "
                          f"use the dense decode path")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     nk = L // bk
     quantized = k_scale is not None
     cur = jnp.asarray(cache_index, jnp.int32)
@@ -568,6 +713,16 @@ def decode_attention(q, k_cache, v_cache, cache_index,
     elif cur.shape != (B,):
         raise ValueError(f"cache_index must be scalar or [B]={B}, "
                          f"got shape {cur.shape}")
+    mesh = _kernel_mesh(q)
+    if mesh is not None:
+        # per device, as in flash_attention; H follows KV (query head h
+        # belongs to kv head h // G, both split in contiguous chunks)
+        cache, scale = ("rows", "heads", None, None), ("rows", "heads", None)
+        return _per_device(
+            functools.partial(decode_attention, block_k=block_k,
+                              interpret=interpret),
+            mesh, B, KV, (q, k_cache, v_cache, cur, k_scale, v_scale),
+            (scale, cache, cache, ("rows",), scale, scale), scale)
 
     def last_blk(cur_ref, b):
         return jnp.minimum(cur_ref[b] // bk, nk - 1)
@@ -664,14 +819,24 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
         raise ValueError(f"page_table must be [B={B}, nblk], got shape "
                          f"{page_table.shape}")
     nblk = page_table.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     quantized = k_scale is not None
     cur = jnp.asarray(cache_index, jnp.int32)
     if cur.shape != (B,):
         raise ValueError(f"cache_index must be [B]={B} per-row cursors, "
                          f"got shape {cur.shape}")
     pt = jnp.asarray(page_table, jnp.int32)
+    mesh = _kernel_mesh(q)
+    if mesh is not None:
+        # rows and kv heads split as in decode_attention; the page POOL is
+        # global state every row may point into, so it is split over kv
+        # heads only
+        pool, scale = (None, "heads", None, None), (None, "heads", None)
+        out = ("rows", "heads", None)
+        return _per_device(
+            functools.partial(paged_decode_attention, interpret=interpret),
+            mesh, B, KV, (q, k_pages, v_pages, cur, pt, k_scale, v_scale),
+            (out, pool, pool, ("rows",), ("rows", None), scale, scale), out)
 
     def page_of(b, ki, cur_ref, pt_ref):
         # physical page for logical block ki, clamped to the row's
@@ -731,4 +896,5 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
 
 
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
-           "paged_decode_attention"]
+           "paged_decode_attention", "record_traced", "note_traced",
+           "traced_name"]

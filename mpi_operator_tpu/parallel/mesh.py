@@ -18,7 +18,6 @@ Axis vocabulary (scaling-book conventions):
 """
 from __future__ import annotations
 
-import logging
 import math
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple
@@ -94,14 +93,16 @@ def make_mesh(config: MeshConfig,
     else:
         try:
             dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except (ValueError, AssertionError):
+        except (ValueError, AssertionError) as exc:
             if devices[0].platform == "tpu":
-                # on real hardware this loses ICI-adjacency-aware placement —
-                # collectives may cross non-neighbor links; say so loudly
-                logging.getLogger(__name__).warning(
-                    "create_device_mesh failed for shape %s on TPU; falling "
-                    "back to enumeration-order layout (topology-unaware — "
-                    "collective performance may degrade)", dict(sizes))
+                # enumeration order is topology-blind: collectives would
+                # cross non-neighbour ICI links and every timing taken on
+                # that mesh would be of a layout nobody meant to run
+                raise ValueError(
+                    f"mesh_utils.create_device_mesh cannot lay "
+                    f"{dict(sizes)} over {len(devices)} "
+                    f"{devices[0].device_kind} devices: {exc}") from exc
+            # virtual CPU devices have no topology to respect
             dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

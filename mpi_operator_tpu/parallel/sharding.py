@@ -34,6 +34,10 @@ from flax import linen as nn
 from flax.core import meta
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# the rule table's duplicate-name stance (logical_to_spec below) needs
+# flax's checker patched to match; importing compat applies it
+from ..utils import compat as _compat  # noqa: F401
+
 # logical name -> mesh axis (or None = replicate). A name absent from the
 # table replicates. Tuple values shard one dim over several mesh axes.
 DEFAULT_RULES: Tuple[Tuple[str, Any], ...] = (

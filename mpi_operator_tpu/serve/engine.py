@@ -383,20 +383,37 @@ class ServingEngine:
         # HBM-bound; see generate.cast_params for the barrier story)
         self._cast = jax.jit(lambda p: cast_params(p, dt))
         self.params = self._cast(params)
-        # the device the engine's params are COMMITTED to, or None when
-        # they are uncommitted/sharded (the colocated default — jit
-        # places everything on the default device). A disaggregated
-        # pool's params arrive committed to its pool device, which
-        # makes every jit output committed too; the persistent
-        # host-born operand (_prev_tok) must then match, or the first
-        # decode step (uncommitted chain) and every later one
-        # (committed chain) would key two compiled programs
+        # where the persistent host-born operand (_prev_tok) must live so
+        # that the FIRST decode step keys the same compiled program as
+        # every later one, whose prev_tok is the previous step's output:
+        #   params on a mesh (benchmarks' shard_init) — step outputs carry
+        #     that mesh in their abstract type (jax types name the mesh),
+        #     so the chain starts replicated on the same mesh;
+        #   params committed to one device (a disaggregated pool) — every
+        #     jit output is committed there too, so the chain starts there;
+        #   params uncommitted (the colocated default) — None, jit places
+        #     everything on the default device.
+        # Get this wrong and the step program compiles twice. On a mesh
+        # the step also PINS its token output there (pin_tok): left to
+        # GSPMD, a kernel that splits rows over dp hands back a
+        # dp-sharded token vector, and the second step would again see
+        # an input unlike the first's.
         leaves = jax.tree.leaves(self.params)
-        self.device = None
-        if leaves and getattr(leaves[0], "committed", False):
+        self._tok_sharding = None
+        if leaves and isinstance(leaves[0].sharding,
+                                 jax.sharding.NamedSharding):
+            self._tok_sharding = jax.sharding.NamedSharding(
+                leaves[0].sharding.mesh, jax.sharding.PartitionSpec())
+        elif leaves and getattr(leaves[0], "committed", False):
             devs = leaves[0].devices()
             if len(devs) == 1:
-                self.device = next(iter(devs))
+                self._tok_sharding = next(iter(devs))
+        tok_sharding = self._tok_sharding
+
+        def pin_tok(tok):
+            if isinstance(tok_sharding, jax.sharding.NamedSharding):
+                return lax.with_sharding_constraint(tok, tok_sharding)
+            return tok
 
         nblk = mcfg.max_len // ps if cfg.paged else 0
         self._nblk = nblk
@@ -461,7 +478,7 @@ class ServingEngine:
             logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
             tok, logp = sample_slots(logits, rng, temperature, top_k,
                                      top_p, mode=mode)
-            return vars_["cache"], tok, logp
+            return vars_["cache"], pin_tok(tok), logp
 
         def step_paged(params, cache, prev_tok, host_toks, use_prev,
                        positions, rng, temperature, top_k, top_p, pages,
@@ -478,7 +495,7 @@ class ServingEngine:
             logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
             tok, logp = sample_slots(logits, rng, temperature, top_k,
                                      top_p, mode=mode)
-            return vars_["cache"], tok, logp
+            return vars_["cache"], pin_tok(tok), logp
 
         def _verify_targets(h, params, rng, temperature, top_k, top_p,
                             mode):
@@ -543,6 +560,7 @@ class ServingEngine:
         # per program.) prev_tok is NOT donated: the pending sync still
         # reads its buffer after the next step consumed it.
         donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()
+        self.donates_cache = bool(donate)
         self._init_cache = jax.jit(init_cache)
         if cfg.paged:
             self._prefill = jax.jit(prefill_paged, donate_argnums=donate)
@@ -584,11 +602,13 @@ class ServingEngine:
     # -- bookkeeping ------------------------------------------------------
 
     def _zeros_tok(self, n: int):
-        """The device-side token chain's initial value, committed to the
-        engine's device (see __init__) — step N's out_tok is committed
-        there too, so step 1 and step N hit the same compiled program."""
+        """The device-side token chain's initial value, placed where the
+        step's own outputs land (see __init__) so step 1 and step N hit
+        the same compiled program."""
         z = jnp.zeros((n,), jnp.int32)
-        return z if self.device is None else jax.device_put(z, self.device)
+        if self._tok_sharding is None:
+            return z
+        return jax.device_put(z, self._tok_sharding)
 
     def reset(self) -> None:
         """Clear all serving state (queue, slots, cache contents, page

@@ -351,10 +351,18 @@ class LMTrainer:
         opt_sh = _opt_shardings(opt_abstract, params, param_sh,
                                 self.replicated)
         opt_state = jax.jit(init_opt, out_shardings=opt_sh)(params)
-        state = LMTrainState(step=jnp.zeros((), jnp.int32), params=params,
+
+        # the counters are born ON the mesh like every other leaf: an
+        # array made outside it has a different abstract type (jax types
+        # carry the mesh), so the state the step RETURNS would not match
+        # the state it was first called with and the whole train step
+        # would trace and compile a second time on its second call
+        def counter():
+            return jax.device_put(jnp.zeros((), jnp.int32), self.replicated)
+        state = LMTrainState(step=counter(), params=params,
                              opt_state=opt_state, tx=self.tx,
                              apply_fn=self.model.apply,
-                             nonfinite_streak=jnp.zeros((), jnp.int32))
+                             nonfinite_streak=counter())
         self._state_shardings = LMTrainState(
             step=self.replicated, params=param_sh, opt_state=opt_sh,
             tx=self.tx, apply_fn=self.model.apply,
@@ -575,7 +583,12 @@ class LMTrainer:
             resilience.telemetry = tel    # rollback accounting → goodput
         it = iter(dataset)
         probe = next(it)
+        c0 = time.perf_counter()
         state, metrics = self.train_step(state, *probe)   # compiles
+        # trace + compile + the first step, reported apart from step time
+        # (a persistent compile cache shows up here and nowhere else)
+        jax.block_until_ready(metrics["loss"])
+        compile_seconds = time.perf_counter() - c0
         flops_per_step = self._step_flops(state, probe)
         for _ in range(max(0, warmup_steps - 1)):
             batch = next(it)
@@ -648,6 +661,10 @@ class LMTrainer:
             "tokens_per_sec": tps,
             "tokens_per_sec_per_device": tps / n,
             "wall_seconds": time.perf_counter() - wall0,
+            "compile_seconds": compile_seconds,
+            # one program for the whole run; 2 means the state the step
+            # returns does not match the state it was first given
+            "step_compiles": self._step._cache_size(),
             "final_loss": float(metrics["loss"]),
             "step_time_p50_ms": p50_ms,
             "step_time_p99_ms": p99_ms,

@@ -196,10 +196,11 @@ class Trainer:
         at divergence_k.
 
         Synchronization note: each window is closed by FETCHING the loss
-        scalar to the host, not by `block_until_ready` — on remote-relay
-        device transports (e.g. tunneled TPUs) only a real host read is a
-        true barrier. The fetch itself happens OUTSIDE the timed window, so
-        reported images/sec is pure step throughput. The headline number is
+        scalar to the host — the log line needs the value anyway, and a
+        host read of the last step's output waits for every step before
+        it, so it is the barrier `block_until_ready` would be. The fetch
+        itself happens OUTSIDE the timed window, so reported images/sec
+        is pure step throughput. The headline number is
         the mean over steady-state windows (first window dropped — it
         absorbs pipeline fill), matching how tf_cnn_benchmarks averages
         per-step rates after warmup (ref README.md:113-131).

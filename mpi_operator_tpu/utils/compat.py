@@ -1,165 +1,79 @@
-"""jax version compatibility — ONE place that knows which API vintage is
-installed.
+"""The jax/flax surface the data plane is written against, in one place.
 
-The codebase is written against the current jax surface (`jax.shard_map`,
-`jax.typeof(...).vma`, `jax.lax.axis_size`); the container may carry an
-older release (0.4.x) where shard_map still lives in jax.experimental with
-the (check_rep, auto) parameter spelling. Every module imports the
-new-style names from here instead of sniffing versions locally, so the
-whole repo flips vintage in one file.
+The repo targets the one installation `requirements.txt` pins (jax 0.9.0,
+flax 0.12.3). What lives here is what callers import instead of spelling
+out themselves, plus the one flax patch the models still need:
 
-Exports:
   shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
             check_vma=None)
-      — the modern keyword surface. On legacy jax, `axis_names` (the
-      MANUAL axes) is translated to `auto` (its complement over
-      mesh.axis_names) and `check_vma` to `check_rep`.
+      — `jax.shard_map` with None-valued keywords dropped, so callers can
+      pass through "not specified" without knowing jax's defaults.
   out_struct(shape, dtype, *like)
       — jax.ShapeDtypeStruct carrying the union of the `like` operands'
-      varying-manual-axes when the installed jax tracks VMA; a plain
-      struct otherwise (legacy jax has no vma typing to satisfy).
+      varying-manual-axes, so Pallas kernels type-check under shard_map's
+      VMA checker (ring attention launches them inside a manual region).
+  axis_size(name)
+      — static size of a bound collective axis.
   axis_bound(name)
       — True when `name` is a live collective axis at trace time.
+
+Importing this module applies `_patch_flax_duplicate_logical_names`. The
+data-plane modules that build or shard models import it
+(models/transformer.py, parallel/sharding.py); the package root does not,
+so control-plane processes never load jax.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    from jax import shard_map as _native_shard_map
-except ImportError:                                   # jax < 0.6
-    _native_shard_map = None
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
 
-try:
-    HAS_VMA = hasattr(jax.typeof(0.0), "vma")
-except AttributeError:                                # jax < 0.6
-    HAS_VMA = False
-
-
-if _native_shard_map is not None:
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return _native_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-else:
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None):
-        kw = {}
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kw["auto"] = auto
-                # legacy partial-auto shard_map can't infer replication
-                # through auto-axis regions; rep checking must be off
-                # unless the caller explicitly asked for it
-                if check_vma is None:
-                    check_vma = False
-        if check_vma is not None:
-            kw["check_rep"] = bool(check_vma)
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
+              check_vma=None):
+    kw = {}
+    if axis_names is not None:
+        kw["axis_names"] = axis_names
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def out_struct(shape, dtype, *like):
-    """Pallas out_shape carrying the varying-manual-axes of its inputs, so
-    kernels type-check under shard_map's default VMA checker (ring
-    attention launches them inside a manual region). Plain struct on
-    legacy jax (no vma typing there to satisfy)."""
-    if HAS_VMA:
-        vma = frozenset().union(*(jax.typeof(x).vma for x in like))
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    """Pallas out_shape carrying the varying-manual-axes of its inputs."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def axis_size(name) -> int:
-    """Static size of a bound collective axis — `jax.lax.axis_size` where
-    it exists; `lax.psum(1, name)` (which constant-folds to a Python int
-    at trace time) on legacy jax."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+    """Static size of a bound collective axis."""
+    return jax.lax.axis_size(name)
 
 
 def axis_bound(name: str) -> bool:
     """True when `name` is a live collective axis (tracing inside
     shard_map/pmap over it)."""
     try:
-        if hasattr(jax.lax, "axis_size"):
-            jax.lax.axis_size(name)
-        else:                                         # jax < 0.5
-            jax.lax.axis_index(name)
+        jax.lax.axis_size(name)
         return True
     except NameError:
         return False
 
 
-def _patch_threefry_partitionable() -> None:
-    """Modern jax defaults `jax_threefry_partitionable` to True; 0.4.x
-    ships it False, where a jit with sharded out_shardings can produce
-    DIFFERENT random bits than the same program unsharded. The repo's
-    shard_init contract (parallel/sharding.py) — and every
-    sharded-vs-replicated parity test — assumes the modern semantics:
-    identical values regardless of layout. Flip the flag to the modern
-    default; explicit user overrides (env/flag already set) are kept."""
-    try:
-        if not jax.config.jax_threefry_partitionable:
-            import os
-            if "JAX_THREEFRY_PARTITIONABLE" not in os.environ:
-                jax.config.update("jax_threefry_partitionable", True)
-    except AttributeError:      # flag removed once partitionable-only
-        pass
-
-
-_patch_threefry_partitionable()
-
-
-def cpu_collectives_solo_fallback() -> None:
-    """Make single-process CPU backend init survive a blanket
-    `jax_cpu_collectives_implementation=gloo`.
-
-    Multi-host launch wrappers set the gloo flag before the gang size is
-    known (cross-process CPU collectives need it), but this jaxlib
-    vintage's binding requires a live DistributedRuntimeClient —
-    `make_gloo_tcp_collectives(distributed_client=None)` is a TypeError,
-    so a process that (correctly) skipped jax.distributed.initialize
-    because num_processes == 1 can't even build its CPU backend. Newer
-    jaxlib accepts None. Called from bootstrap.initialize on the
-    single-process path: with no distributed client connected, drop back
-    to the in-process default before the backend first initializes."""
-    try:
-        from jax._src import distributed
-        from jax._src import xla_bridge as _xb
-        if distributed.global_state.client is not None:
-            return                      # real gang: gloo is wanted
-        # a flag, not a config-state attribute — read the holder directly
-        if _xb.CPU_COLLECTIVES_IMPLEMENTATION.value == "gloo":
-            jax.config.update("jax_cpu_collectives_implementation", "none")
-    except (ImportError, AttributeError):
-        pass                            # modern jaxlib: None is accepted
-
-
 def _patch_flax_duplicate_logical_names() -> None:
-    """flax >= 0.8 hard-errors when a parameter's logical axis names repeat
+    """flax hard-errors when a parameter's logical axis names repeat
     (`flax/linen/spmd.py:_logical_to_mesh_axes` raises "Dimensions (...)
-    occur more than once"). The repo's rule table takes the opposite,
-    well-defined stance (parallel/sharding.logical_to_spec): a mesh axis
-    shards at most one dim, so later duplicates REPLICATE — an
-    ("embed", "embed") square kernel (MaskedLM's mlm_dense) shards its
-    first dim and replicates the second. Rewrite duplicates to None before
-    flax's checker sees them; first occurrence keeps its rule, which is
-    exactly the layout logical_to_spec computes for the same names."""
-    try:
-        from flax.linen import spmd as _spmd
-    except ImportError:
-        return
-    orig = getattr(_spmd, "_logical_to_mesh_axes", None)
-    if orig is None or getattr(orig, "_dedup_wrapped", False):
+    occur more than once"; still true in 0.12.3). The repo's rule table
+    takes the opposite, well-defined stance
+    (parallel/sharding.logical_to_spec): a mesh axis shards at most one
+    dim, so later duplicates REPLICATE — an ("embed", "embed") square
+    kernel (MaskedLM's mlm_dense) shards its first dim and replicates the
+    second. Rewrite duplicates to None before flax's checker sees them;
+    first occurrence keeps its rule, which is exactly the layout
+    logical_to_spec computes for the same names."""
+    from flax.linen import spmd as _spmd
+
+    orig = _spmd._logical_to_mesh_axes
+    if getattr(orig, "_dedup_wrapped", False):
         return
 
     def dedup(array_dim_names, rules=None):
@@ -180,5 +94,4 @@ def _patch_flax_duplicate_logical_names() -> None:
 _patch_flax_duplicate_logical_names()
 
 
-__all__ = ["shard_map", "out_struct", "axis_size", "axis_bound",
-           "HAS_VMA"]
+__all__ = ["shard_map", "out_struct", "axis_size", "axis_bound"]

@@ -21,77 +21,57 @@ per-step counts; MFU is flops_per_step * steps_per_sec / (n_devices * peak).
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
-# bf16 peak dense matmul FLOP/s per chip, by device_kind substring.
-# (public figures: v2 45T, v3 123T, v4 275T, v5e 197T, v5p 459T, v6e 918T)
-_PEAK_TABLE = (
-    ("v6e", 918e12), ("v6 lite", 918e12), ("trillium", 918e12),
-    ("v5p", 459e12),
-    ("v5 lite", 197e12), ("v5e", 197e12), ("v5litepod", 197e12),
-    ("v5", 459e12),              # plain "TPU v5" = v5p (observed kind)
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# Peaks of one chip, keyed by the exact `device_kind` jax reports:
+# (bf16 dense matmul FLOP/s, HBM bytes/s). The spellings are jax's own
+# (jax/_src/pallas/mosaic/tpu_info.py, 0.9.0); the figures are the
+# per-chip numbers of Google Cloud's TPU documentation for each version
+# ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s). Only "TPU v5 lite" has been
+# read off hardware by this repo (chip_smoke.py, PERF.md); a kind that is
+# not listed is an error, never a default — a guessed peak makes every
+# MFU/MBU computed from it wrong without saying so.
+_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),      # v5e
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),          # v5p
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),     # v6e (Trillium)
+    "TPU v6e": (918e12, 1640e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v2": (45e12, 700e9),
+}
+
+
+def device_peaks(device=None) -> Optional[Tuple[float, float]]:
+    """(peak bf16 FLOP/s, peak HBM bytes/s) of one device. None off-TPU
+    (CPU tests have no peak to divide by); a TPU whose `device_kind` is
+    not in the table raises."""
+    import jax
+
+    device = device or jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind
+    if kind not in _PEAKS:
+        raise ValueError(
+            f"device_kind {kind!r} is not in the peaks table "
+            f"(utils/flops.py); add its published bf16 FLOP/s and HBM "
+            f"bytes/s with their source. Known: {sorted(_PEAKS)}")
+    return _PEAKS[kind]
 
 
 def device_peak_flops(device=None) -> Optional[float]:
-    """Peak bf16 FLOP/s for one device; None when unknown (CPU/GPU)."""
-    import jax
-
-    device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    if "tpu" not in kind and device.platform != "tpu":
-        return None
-    return _lookup(kind, _PEAK_TABLE)
-
-
-# HBM bandwidth per chip (bytes/s), by device_kind substring — the decode
-# roofline's denominator. Public figures: v2 700GB/s, v3 900, v4 1228,
-# v5e 819, v5p 2765, v6e (Trillium) 1640.
-_HBM_TABLE = (
-    ("v6e", 1640e9), ("v6 lite", 1640e9), ("trillium", 1640e9),
-    ("v5p", 2765e9),
-    ("v5 lite", 819e9), ("v5e", 819e9), ("v5litepod", 819e9),
-    ("v5", 2765e9),              # plain "TPU v5" = v5p (observed kind)
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
-
-# The bare "v5" rows above are a last-resort fallback: real v5p chips
-# report device_kind "TPU v5" verbatim, so dropping the rows would
-# silently lose every mfu/mbu field on v5p. But an UNEXPECTED v5e kind
-# spelling landing on them would overstate peak bandwidth ~3.4x and
-# silently understate MBU — so any bare-marker match is logged loudly
-# (the advisor-r04 visibility remedy).
-_BARE_FALLBACK_WARNED = set()
-
-
-def _lookup(kind: str, table) -> Optional[float]:
-    for marker, val in table:
-        if marker in kind:
-            if marker == "v5" and kind not in _BARE_FALLBACK_WARNED:
-                _BARE_FALLBACK_WARNED.add(kind)
-                import sys
-                print(f"# flops: device_kind {kind!r} matched only the "
-                      f"bare 'v5' marker — assuming v5p peak figures; "
-                      f"if this is a v5e spelling, MFU/MBU are wrong",
-                      file=sys.stderr)
-            return val
-    return None
+    """Peak bf16 FLOP/s for one device; None off-TPU."""
+    peaks = device_peaks(device)
+    return peaks[0] if peaks else None
 
 
 def device_hbm_bandwidth(device=None) -> Optional[float]:
-    """Peak HBM bytes/s for one device; None when unknown (CPU/GPU)."""
-    import jax
-
-    device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    if "tpu" not in kind and device.platform != "tpu":
-        return None
-    return _lookup(kind, _HBM_TABLE)
+    """Peak HBM bytes/s for one device; None off-TPU."""
+    peaks = device_peaks(device)
+    return peaks[1] if peaks else None
 
 
 def decode_bytes_per_step(num_params: int, num_layers: int,
@@ -115,8 +95,8 @@ def decode_bytes_per_step(num_params: int, num_layers: int,
 
 def mbu(bytes_per_step: float, steps_per_sec: float,
         device=None) -> Optional[float]:
-    """Achieved fraction of peak HBM bandwidth (single device). None when
-    the device's bandwidth is unknown."""
+    """Achieved fraction of peak HBM bandwidth (single device). None
+    off-TPU."""
     bw = device_hbm_bandwidth(device)
     if not bw or not bytes_per_step:
         return None
@@ -213,7 +193,8 @@ def throughput_stats(flops_per_step: Optional[float], steps_per_sec: float,
     }
 
 
-__all__ = ["device_peak_flops", "device_hbm_bandwidth", "compiled_flops",
+__all__ = ["device_peaks", "device_peak_flops", "device_hbm_bandwidth",
+           "compiled_flops",
            "resnet_train_flops_per_image",
            "transformer_train_flops_per_token", "param_count", "mfu",
            "mbu", "decode_bytes_per_step", "throughput_stats"]

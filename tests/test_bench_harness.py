@@ -3,7 +3,7 @@ signal-flush path.
 
 Every measured leg appends one fsync'd {"leg": ...} record to --jsonl
 BEFORE the ladder moves on, so a bench process killed mid-ladder (the
-driver timeout, an OOM kill, a lost tunnel) still leaves the finished
+driver timeout, an OOM kill, a lost machine) still leaves the finished
 legs parseable on disk — and an EXTERNAL timeout (SIGTERM, `timeout`'s
 default) additionally gets a flushed summary line built from the
 completed legs. Both tests run a real bench.py subprocess on a SHRUNKEN

@@ -464,9 +464,12 @@ class TestWireFormat:
         StartRecordingToSink, mpi_job_controller.go:165-172; Synced event
         :518): after a reconcile the scripted server must hold a POSTed
         Event manifest with the exact wire fields kubectl consumes."""
-        events = reconciled.objects_of("events")
-        synced = [e for e in events if e.get("reason") == "Synced"]
-        assert synced, f"no Synced event posted; got {events}"
+        # the fixture returns once the worker StatefulSet exists, but the
+        # Synced event is the LAST write of that same sync — wait for it
+        synced = wait_for(
+            lambda: [e for e in reconciled.objects_of("events")
+                     if e.get("reason") == "Synced"],
+            "Synced event posted")
         ev = synced[0]
         assert ev["apiVersion"] == "v1"
         assert ev["kind"] == "Event"

@@ -18,21 +18,6 @@ from mpi_operator_tpu.models.transformer import (
 from mpi_operator_tpu.parallel import (
     MeshConfig, MoeMlp, make_mesh, pipeline_apply, ring_attention,
     shard_init, stack_stage_params)
-from mpi_operator_tpu.utils.compat import HAS_VMA
-
-# The pipeline's partial-manual shard_map (pp manual, tp/ep auto) +
-# lax.axis_index lowers to a PartitionId instruction that this jax
-# vintage's SPMD partitioner rejects outright ("UNIMPLEMENTED:
-# PartitionId instruction is not supported for SPMD partitioning") —
-# seed-era failures, triaged in ROADMAP "Open items". The probe is the
-# same one utils/compat.py keys its shims on: the modern (vma-style)
-# shard_map partitions these fine, so a jax upgrade re-enables them
-# automatically instead of leaving a stale skip behind.
-needs_partial_manual_spmd = pytest.mark.skipif(
-    not HAS_VMA,
-    reason="partial-manual shard_map + lax.axis_index lowers to a "
-           "PartitionId instruction this XLA's SPMD partitioner rejects "
-           "(ROADMAP Open items)")
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +522,6 @@ class TestPipelineLM:
         return (cfg, model, vs, pp_params, tk, tg, M, oracle,
                 pipeline_lm_loss, stack_lm_params)
 
-    @needs_partial_manual_spmd
     @pytest.mark.parametrize("dropless", [False, True])
     def test_pp_moe_matches_microbatched_unpiped(self, dropless):
         """pp×ep MoE (VERDICT r04 next #2): stage bodies scan (dense, MoE)
@@ -577,7 +561,6 @@ class TestPipelineLM:
                 np.asarray(a), np.asarray(b), atol=3e-4,
                 err_msg=jax.tree_util.keystr(path))
 
-    @needs_partial_manual_spmd
     def test_pp_moe_dp_sharded_runs(self):
         """pp×dp×ep MoE: with the microbatch dim manually dp-sharded each
         dp rank routes its own token slice (per-shard capacity budgets —
@@ -595,7 +578,6 @@ class TestPipelineLM:
         assert all(np.all(np.isfinite(np.asarray(x)))
                    for x in jax.tree.leaves(g))
 
-    @needs_partial_manual_spmd
     def test_pp_moe_trainer_end_to_end(self):
         """PipelineLMTrainer with a MoE config: init → train steps →
         loss decreases trend not required, but steps run, the drop rate
@@ -785,11 +767,9 @@ class TestPipelineTrainer:
                 err_msg=jax.tree_util.keystr(path))
         return init_state
 
-    @needs_partial_manual_spmd
     def test_one_step_matches_unpiped_trainer(self):
         self._assert_matches_unpiped(MeshConfig(pp=2, dp=4))
 
-    @needs_partial_manual_spmd
     def test_pp_tp_composes_with_megatron_shardings(self):
         """pp×tp×dp: block params placed with Megatron tp shardings
         (lm_stage_tp_specs) while pipeline_lm_loss runs tp as a GSPMD auto
