@@ -14,6 +14,8 @@ import threading
 from queue import Full, Queue
 from typing import Iterator
 
+from ..telemetry import span
+
 
 #: end-of-stream marker the feeder enqueues when `_produce()` returns;
 #: `__next__` re-enqueues it so exhaustion is sticky (every subsequent
@@ -62,7 +64,10 @@ class PrefetchDataset:
         return self
 
     def __next__(self):
-        item = self._queue.get()
+        # the consumer's wait, where it happens: depth 0 on entry means
+        # the feeder was behind and this step waits for it
+        with span("data.next", depth=self._queue.qsize()):
+            item = self._queue.get()
         if item is _DONE:
             # just freed a queue slot, so this put never blocks
             self._queue.put(_DONE)

@@ -281,6 +281,17 @@ def propose_ngram(history: Sequence[int], k: int,
     return out
 
 
+def _sample_mode(consumers) -> str:
+    """The cheapest step variant the rows of this step allow (the host
+    knows the sampling params exactly; see sample_slots)."""
+    sampling = [st.req for st in consumers if st.req.temperature > 0.0]
+    if not sampling:
+        return "greedy"
+    if all(1 <= r.top_k <= SAMPLE_POOL for r in sampling):
+        return "bounded"
+    return "full"
+
+
 class ServingEngine:
     """Continuous-batching inference over a trained CausalLM.
 
@@ -320,286 +331,299 @@ class ServingEngine:
         bookkeeping: no device operand, no rng fold, no compiled
         program changes — greedy tokens and compile pins are bitwise
         identical with tracing on or off."""
-        cfg = config or EngineConfig()
-        mcfg = model.config
-        if not mcfg.causal:
-            raise ValueError("serving needs a causal LM")
-        for b in cfg.chunk_buckets:
-            if b > mcfg.max_len:
-                raise ValueError(f"chunk bucket {b} exceeds "
-                                 f"max_len={mcfg.max_len}")
-        if cfg.speculative not in (None, "ngram", "draft"):
-            raise ValueError(f"speculative={cfg.speculative!r}: expected "
-                             f"None, 'ngram' or 'draft'")
-        if cfg.speculative is not None and cfg.draft_k < 1:
-            raise ValueError(f"draft_k={cfg.draft_k}: speculation needs "
-                             f"at least one proposed token")
-        if cfg.speculative == "draft" and drafter is None:
-            raise ValueError("speculative='draft' needs a drafter "
-                             "callable (history, k) -> tokens")
-        self._drafter = drafter
-        # ≤2 compiled verify widths: a narrow one for single-token
-        # proposals plus the full draft_k+1 (compile_counts pins this)
-        self._verify_buckets = tuple(sorted({min(2, cfg.draft_k + 1),
-                                             cfg.draft_k + 1}))
-        self.config = cfg
-        self.model_config = mcfg
-        ps = cfg.page_size
-        if cfg.paged:
-            if ps < 1 or mcfg.max_len % ps:
-                raise ValueError(f"page_size={ps} must be >= 1 and divide "
-                                 f"max_len={mcfg.max_len}")
-            NP = cfg.num_pages
-            if NP is None:
-                # contiguous layout's byte budget, plus the trash page
-                NP = cfg.slots * (mcfg.max_len // ps) + 1
-            self.page_allocator: Optional[PageAllocator] = \
-                PageAllocator(NP, ps)
-        else:
-            NP = 0
-            self.page_allocator = None
-        self.dmodel = decode_model(model, cfg.decode_kernel, slots=True,
-                                   page_size=ps if cfg.paged else None,
-                                   num_pages=NP)
-        self._base_rng = jax.random.PRNGKey(cfg.rng_seed)
-        self._steps_dispatched = 0
-        self.telemetry = telemetry
-        self.events = events
-        self.tracer = tracer
-        # session clock for trace hops — set while a session (or the
-        # disaggregated run loop) is live; tracing is inert without it
-        self._trace_now: Optional[Callable[[], float]] = None
-        self._session_span = None
-        if telemetry is not None:
-            telemetry.slots.set(cfg.slots)
+        with span("serve.engine_init"):
+            cfg = config or EngineConfig()
+            mcfg = model.config
+            if not mcfg.causal:
+                raise ValueError("serving needs a causal LM")
+            for b in cfg.chunk_buckets:
+                if b > mcfg.max_len:
+                    raise ValueError(f"chunk bucket {b} exceeds "
+                                     f"max_len={mcfg.max_len}")
+            if cfg.speculative not in (None, "ngram", "draft"):
+                raise ValueError(f"speculative={cfg.speculative!r}: expected "
+                                 f"None, 'ngram' or 'draft'")
+            if cfg.speculative is not None and cfg.draft_k < 1:
+                raise ValueError(f"draft_k={cfg.draft_k}: speculation needs "
+                                 f"at least one proposed token")
+            if cfg.speculative == "draft" and drafter is None:
+                raise ValueError("speculative='draft' needs a drafter "
+                                 "callable (history, k) -> tokens")
+            self._drafter = drafter
+            # ≤2 compiled verify widths: a narrow one for single-token
+            # proposals plus the full draft_k+1 (compile_counts pins this)
+            self._verify_buckets = tuple(sorted({min(2, cfg.draft_k + 1),
+                                                 cfg.draft_k + 1}))
+            self.config = cfg
+            self.model_config = mcfg
+            ps = cfg.page_size
             if cfg.paged:
-                telemetry.pages_total.set(self.page_allocator.usable)
+                if ps < 1 or mcfg.max_len % ps:
+                    raise ValueError(f"page_size={ps} must be >= 1 and divide "
+                                     f"max_len={mcfg.max_len}")
+                NP = cfg.num_pages
+                if NP is None:
+                    # contiguous layout's byte budget, plus the trash page
+                    NP = cfg.slots * (mcfg.max_len // ps) + 1
+                self.page_allocator: Optional[PageAllocator] = \
+                    PageAllocator(NP, ps)
+            else:
+                NP = 0
+                self.page_allocator = None
+            self.dmodel = decode_model(model, cfg.decode_kernel, slots=True,
+                                       page_size=ps if cfg.paged else None,
+                                       num_pages=NP)
+            self._base_rng = jax.random.PRNGKey(cfg.rng_seed)
+            self._steps_dispatched = 0
+            self.telemetry = telemetry
+            self.events = events
+            self.tracer = tracer
+            # session clock for trace hops — set while a session (or the
+            # disaggregated run loop) is live; tracing is inert without it
+            self._trace_now: Optional[Callable[[], float]] = None
+            self._session_span = None
+            if telemetry is not None:
+                telemetry.slots.set(cfg.slots)
+                if cfg.paged:
+                    telemetry.pages_total.set(self.page_allocator.usable)
 
-        dmodel = self.dmodel
-        dt = dmodel.config.dtype
-        S = cfg.slots
+            dmodel = self.dmodel
+            dt = dmodel.config.dtype
+            S = cfg.slots
 
-        # params cast once, device-resident across every step (decode is
-        # HBM-bound; see generate.cast_params for the barrier story)
-        self._cast = jax.jit(lambda p: cast_params(p, dt))
-        self.params = self._cast(params)
-        # where the persistent host-born operand (_prev_tok) must live so
-        # that the FIRST decode step keys the same compiled program as
-        # every later one, whose prev_tok is the previous step's output:
-        #   params on a mesh (benchmarks' shard_init) — step outputs carry
-        #     that mesh in their abstract type (jax types name the mesh),
-        #     so the chain starts replicated on the same mesh;
-        #   params committed to one device (a disaggregated pool) — every
-        #     jit output is committed there too, so the chain starts there;
-        #   params uncommitted (the colocated default) — None, jit places
-        #     everything on the default device.
-        # Get this wrong and the step program compiles twice. On a mesh
-        # the step also PINS its token output there (pin_tok): left to
-        # GSPMD, a kernel that splits rows over dp hands back a
-        # dp-sharded token vector, and the second step would again see
-        # an input unlike the first's.
-        leaves = jax.tree.leaves(self.params)
-        self._tok_sharding = None
-        if leaves and isinstance(leaves[0].sharding,
-                                 jax.sharding.NamedSharding):
-            self._tok_sharding = jax.sharding.NamedSharding(
-                leaves[0].sharding.mesh, jax.sharding.PartitionSpec())
-        elif leaves and getattr(leaves[0], "committed", False):
-            devs = leaves[0].devices()
-            if len(devs) == 1:
-                self._tok_sharding = next(iter(devs))
-        tok_sharding = self._tok_sharding
+            # params cast once, device-resident across every step (decode is
+            # HBM-bound; see generate.cast_params for the barrier story)
+            self._cast = jax.jit(lambda p: cast_params(p, dt))
+            # not synced: host-born weights may copy to the device while the
+            # cache program below is traced; serve.init_cache waits for both
+            with span("serve.cast_params"):
+                self.params = self._cast(params)
+            # where the persistent host-born operand (_prev_tok) must live so
+            # that the FIRST decode step keys the same compiled program as
+            # every later one, whose prev_tok is the previous step's output:
+            #   params on a mesh (benchmarks' shard_init) — step outputs
+            #     carry that mesh in their abstract type (jax types name it),
+            #     so the chain starts replicated on the same mesh;
+            #   params committed to one device (a disaggregated pool) — every
+            #     jit output is committed there too, so the chain starts there;
+            #   params uncommitted (the colocated default) — None, jit places
+            #     everything on the default device.
+            # Get this wrong and the step program compiles twice. On a mesh
+            # the step also PINS its token output there (pin_tok): left to
+            # GSPMD, a kernel that splits rows over dp hands back a
+            # dp-sharded token vector, and the second step would again see
+            # an input unlike the first's.
+            leaves = jax.tree.leaves(self.params)
+            self._tok_sharding = None
+            if leaves and isinstance(leaves[0].sharding,
+                                     jax.sharding.NamedSharding):
+                self._tok_sharding = jax.sharding.NamedSharding(
+                    leaves[0].sharding.mesh, jax.sharding.PartitionSpec())
+            elif leaves and getattr(leaves[0], "committed", False):
+                devs = leaves[0].devices()
+                if len(devs) == 1:
+                    self._tok_sharding = next(iter(devs))
+            tok_sharding = self._tok_sharding
 
-        def pin_tok(tok):
-            if isinstance(tok_sharding, jax.sharding.NamedSharding):
-                return lax.with_sharding_constraint(tok, tok_sharding)
-            return tok
+            def pin_tok(tok):
+                if isinstance(tok_sharding, jax.sharding.NamedSharding):
+                    return lax.with_sharding_constraint(tok, tok_sharding)
+                return tok
 
-        nblk = mcfg.max_len // ps if cfg.paged else 0
-        self._nblk = nblk
+            nblk = mcfg.max_len // ps if cfg.paged else 0
+            self._nblk = nblk
 
-        def init_cache(params):
-            # a zero-token step apply materializes the cache collection
-            # at its serving shape; the hidden-state output is discarded
-            z = jnp.zeros((S, 1), jnp.int32)
-            kw = ({"pages": jnp.zeros((S, nblk), jnp.int32)}
-                  if cfg.paged else {})
-            _, vars_ = dmodel.apply({"params": params}, z, positions=z,
-                                    with_head=False, mutable=["cache"],
-                                    **kw)
-            return vars_["cache"]
+            def init_cache(params):
+                # a zero-token step apply materializes the cache collection
+                # at its serving shape; the hidden-state output is discarded
+                z = jnp.zeros((S, 1), jnp.int32)
+                kw = ({"pages": jnp.zeros((S, nblk), jnp.int32)}
+                      if cfg.paged else {})
+                _, vars_ = dmodel.apply({"params": params}, z, positions=z,
+                                        with_head=False, mutable=["cache"],
+                                        **kw)
+                return vars_["cache"]
 
-        def prefill(params, cache, slot, tokens, start):
-            # one chunk for one slot: slice the row out, run the
-            # backbone headless over [1, C] tokens at absolute
-            # positions start..start+C, splice the row back. `slot` and
-            # `start` are traced operands — one compile per bucket C.
-            row = jax.tree.map(
-                lambda x: lax.dynamic_slice_in_dim(x, slot, 1, 0), cache)
-            positions = (start + jnp.arange(tokens.shape[0]))[None]
-            _, vars_ = dmodel.apply(
-                {"params": params, "cache": row}, tokens[None],
-                positions=positions, with_head=False, mutable=["cache"])
-            return jax.tree.map(
-                lambda full, r: lax.dynamic_update_slice_in_dim(
-                    full, r, slot, 0),
-                cache, vars_["cache"])
+            def prefill(params, cache, slot, tokens, start):
+                # one chunk for one slot: slice the row out, run the
+                # backbone headless over [1, C] tokens at absolute
+                # positions start..start+C, splice the row back. `slot` and
+                # `start` are traced operands — one compile per bucket C.
+                row = jax.tree.map(
+                    lambda x: lax.dynamic_slice_in_dim(x, slot, 1, 0), cache)
+                positions = (start + jnp.arange(tokens.shape[0]))[None]
+                _, vars_ = dmodel.apply(
+                    {"params": params, "cache": row}, tokens[None],
+                    positions=positions, with_head=False, mutable=["cache"])
+                return jax.tree.map(
+                    lambda full, r: lax.dynamic_update_slice_in_dim(
+                        full, r, slot, 0),
+                    cache, vars_["cache"])
 
-        def prefill_paged(params, cache, tokens, starts, pages):
-            # BATCHED chunk over the page pool: [S, C] tokens, one row
-            # per slot, writes routed through the page tables — the pool
-            # is shared so there is no row to slice out, and every
-            # waiting slot whose next chunk shares this bucket advances
-            # in the same program. Non-member rows carry zero tokens at
-            # their OWN cursor: their junk K/V lands exactly where their
-            # next real write (chunk or decode step) overwrites it, the
-            # same argument as the fixed-shape decode step's masked rows
-            # (free rows' tables are all trash-page entries).
-            positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
-            _, vars_ = dmodel.apply(
-                {"params": params, "cache": cache}, tokens,
-                positions=positions, with_head=False, mutable=["cache"],
-                pages=pages)
-            return vars_["cache"]
+            def prefill_paged(params, cache, tokens, starts, pages):
+                # BATCHED chunk over the page pool: [S, C] tokens, one row
+                # per slot, writes routed through the page tables — the pool
+                # is shared so there is no row to slice out, and every
+                # waiting slot whose next chunk shares this bucket advances
+                # in the same program. Non-member rows carry zero tokens at
+                # their OWN cursor: their junk K/V lands exactly where their
+                # next real write (chunk or decode step) overwrites it, the
+                # same argument as the fixed-shape decode step's masked rows
+                # (free rows' tables are all trash-page entries).
+                positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
+                _, vars_ = dmodel.apply(
+                    {"params": params, "cache": cache}, tokens,
+                    positions=positions, with_head=False, mutable=["cache"],
+                    pages=pages)
+                return vars_["cache"]
 
-        def step(params, cache, prev_tok, host_toks, use_prev, positions,
-                 rng, temperature, top_k, top_p, mode):
-            # ONE token for ALL slots: [S] tokens at [S] cursors. The
-            # input token per row comes from the DEVICE-side chain
-            # (prev_tok = last step's output, rows with use_prev) or from
-            # the host (bonus token after prefill) — the chain is what
-            # lets the host dispatch step N+1 without reading step N.
-            from ..models.transformer import _head_matmul
-            tokens = jnp.where(use_prev, prev_tok, host_toks)
-            h, vars_ = dmodel.apply(
-                {"params": params, "cache": cache}, tokens[:, None],
-                positions=positions[:, None], with_head=False,
-                mutable=["cache"])
-            logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
-            tok, logp = sample_slots(logits, rng, temperature, top_k,
-                                     top_p, mode=mode)
-            return vars_["cache"], pin_tok(tok), logp
+            def step(params, cache, prev_tok, host_toks, use_prev, positions,
+                     rng, temperature, top_k, top_p, mode):
+                # ONE token for ALL slots: [S] tokens at [S] cursors. The
+                # input token per row comes from the DEVICE-side chain
+                # (prev_tok = last step's output, rows with use_prev) or from
+                # the host (bonus token after prefill) — the chain is what
+                # lets the host dispatch step N+1 without reading step N.
+                from ..models.transformer import _head_matmul
+                tokens = jnp.where(use_prev, prev_tok, host_toks)
+                h, vars_ = dmodel.apply(
+                    {"params": params, "cache": cache}, tokens[:, None],
+                    positions=positions[:, None], with_head=False,
+                    mutable=["cache"])
+                logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
+                tok, logp = sample_slots(logits, rng, temperature, top_k,
+                                         top_p, mode=mode)
+                return vars_["cache"], pin_tok(tok), logp
 
-        def step_paged(params, cache, prev_tok, host_toks, use_prev,
-                       positions, rng, temperature, top_k, top_p, pages,
-                       mode):
-            # the decode step with the per-slot page tables as one extra
-            # [S, nblk] operand — table churn (admit/retire) never
-            # recompiles, exactly like cursor churn
-            from ..models.transformer import _head_matmul
-            tokens = jnp.where(use_prev, prev_tok, host_toks)
-            h, vars_ = dmodel.apply(
-                {"params": params, "cache": cache}, tokens[:, None],
-                positions=positions[:, None], with_head=False,
-                mutable=["cache"], pages=pages)
-            logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
-            tok, logp = sample_slots(logits, rng, temperature, top_k,
-                                     top_p, mode=mode)
-            return vars_["cache"], pin_tok(tok), logp
+            def step_paged(params, cache, prev_tok, host_toks, use_prev,
+                           positions, rng, temperature, top_k, top_p, pages,
+                           mode):
+                # the decode step with the per-slot page tables as one extra
+                # [S, nblk] operand — table churn (admit/retire) never
+                # recompiles, exactly like cursor churn
+                from ..models.transformer import _head_matmul
+                tokens = jnp.where(use_prev, prev_tok, host_toks)
+                h, vars_ = dmodel.apply(
+                    {"params": params, "cache": cache}, tokens[:, None],
+                    positions=positions[:, None], with_head=False,
+                    mutable=["cache"], pages=pages)
+                logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
+                tok, logp = sample_slots(logits, rng, temperature, top_k,
+                                         top_p, mode=mode)
+                return vars_["cache"], pin_tok(tok), logp
 
-        def _verify_targets(h, params, rng, temperature, top_k, top_p,
-                            mode):
-            # shared verify tail: [S, W] hidden states → per-position
-            # target tokens + logprobs. Column 0 is the plain decode
-            # step's sample (same sample_slots, so sampling rows in a
-            # mixed batch still draw correctly); columns 1.. are the
-            # greedy targets the drafts are checked against — argmax in
-            # float32, bitwise the same reduction sample_slots runs for
-            # a temperature-0 row, which is the token-exactness hinge.
-            from ..models.transformer import _head_matmul
-            Sv, W, E = h.shape
-            logits = _head_matmul(h.reshape(Sv * W, E),
-                                  params["wte"]["embedding"])
-            logits = logits.reshape(Sv, W, -1)
-            tok0, lp0 = sample_slots(logits[:, 0], rng, temperature,
-                                     top_k, top_p, mode=mode)
-            f32 = logits.astype(jnp.float32)
-            logp = jax.nn.log_softmax(f32)
-            greedy = jnp.argmax(f32, axis=-1)
-            glp = jnp.take_along_axis(logp, greedy[..., None],
-                                      axis=-1)[..., 0]
-            targets = greedy.at[:, 0].set(tok0)
-            return targets, glp.at[:, 0].set(lp0)
+            def _verify_targets(h, params, rng, temperature, top_k, top_p,
+                                mode):
+                # shared verify tail: [S, W] hidden states → per-position
+                # target tokens + logprobs. Column 0 is the plain decode
+                # step's sample (same sample_slots, so sampling rows in a
+                # mixed batch still draw correctly); columns 1.. are the
+                # greedy targets the drafts are checked against — argmax in
+                # float32, bitwise the same reduction sample_slots runs for
+                # a temperature-0 row, which is the token-exactness hinge.
+                from ..models.transformer import _head_matmul
+                Sv, W, E = h.shape
+                logits = _head_matmul(h.reshape(Sv * W, E),
+                                      params["wte"]["embedding"])
+                logits = logits.reshape(Sv, W, -1)
+                tok0, lp0 = sample_slots(logits[:, 0], rng, temperature,
+                                         top_k, top_p, mode=mode)
+                f32 = logits.astype(jnp.float32)
+                logp = jax.nn.log_softmax(f32)
+                greedy = jnp.argmax(f32, axis=-1)
+                glp = jnp.take_along_axis(logp, greedy[..., None],
+                                          axis=-1)[..., 0]
+                targets = greedy.at[:, 0].set(tok0)
+                return targets, glp.at[:, 0].set(lp0)
 
-        def verify(params, cache, toks, positions, rng, temperature,
-                   top_k, top_p, mode):
-            # ONE batched pass over [S, W] proposed tokens at explicit
-            # per-position cursors — a chunked-prefill-shaped step with
-            # right-aligned ragged rows. Row layout (host-built): column
-            # 0 = the row's real next input, columns 1..k = drafts,
-            # padded tail positions = max_len (out-of-bounds, so their
-            # K/V writes DROP — transformer.py's multi-token scatter).
-            # K/V for every column is written BEFORE attention reads it,
-            # and each query position attends only <= itself, so a
-            # row's rejected tail never contaminates an accepted
-            # position; the cursor rewind makes it invisible to every
-            # later step too.
-            h, vars_ = dmodel.apply(
-                {"params": params, "cache": cache}, toks,
-                positions=positions, with_head=False, mutable=["cache"])
-            targets, tlp = _verify_targets(h, params, rng, temperature,
-                                           top_k, top_p, mode)
-            return vars_["cache"], targets, tlp
+            def verify(params, cache, toks, positions, rng, temperature,
+                       top_k, top_p, mode):
+                # ONE batched pass over [S, W] proposed tokens at explicit
+                # per-position cursors — a chunked-prefill-shaped step with
+                # right-aligned ragged rows. Row layout (host-built): column
+                # 0 = the row's real next input, columns 1..k = drafts,
+                # padded tail positions = max_len (out-of-bounds, so their
+                # K/V writes DROP — transformer.py's multi-token scatter).
+                # K/V for every column is written BEFORE attention reads it,
+                # and each query position attends only <= itself, so a
+                # row's rejected tail never contaminates an accepted
+                # position; the cursor rewind makes it invisible to every
+                # later step too.
+                h, vars_ = dmodel.apply(
+                    {"params": params, "cache": cache}, toks,
+                    positions=positions, with_head=False, mutable=["cache"])
+                targets, tlp = _verify_targets(h, params, rng, temperature,
+                                               top_k, top_p, mode)
+                return vars_["cache"], targets, tlp
 
-        def verify_paged(params, cache, toks, positions, rng, temperature,
-                         top_k, top_p, pages, mode):
-            # padded tail positions hit the trash-page guard instead of
-            # the scatter bound — same dropped-write semantics
-            h, vars_ = dmodel.apply(
-                {"params": params, "cache": cache}, toks,
-                positions=positions, with_head=False, mutable=["cache"],
-                pages=pages)
-            targets, tlp = _verify_targets(h, params, rng, temperature,
-                                           top_k, top_p, mode)
-            return vars_["cache"], targets, tlp
+            def verify_paged(params, cache, toks, positions, rng, temperature,
+                             top_k, top_p, pages, mode):
+                # padded tail positions hit the trash-page guard instead of
+                # the scatter bound — same dropped-write semantics
+                h, vars_ = dmodel.apply(
+                    {"params": params, "cache": cache}, toks,
+                    positions=positions, with_head=False, mutable=["cache"],
+                    pages=pages)
+                targets, tlp = _verify_targets(h, params, rng, temperature,
+                                               top_k, top_p, mode)
+                return vars_["cache"], targets, tlp
 
-        # cache buffers are donated — the engine holds the only live
-        # reference, and the cache ([SLOTS, KV, L, D] per layer, or the
-        # page pool) is the biggest allocation here; donation keeps it
-        # single-buffered. (CPU has no donation support and would warn
-        # per program.) prev_tok is NOT donated: the pending sync still
-        # reads its buffer after the next step consumed it.
-        donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()
-        self.donates_cache = bool(donate)
-        self._init_cache = jax.jit(init_cache)
-        if cfg.paged:
-            self._prefill = jax.jit(prefill_paged, donate_argnums=donate)
-            self._step = jax.jit(step_paged, donate_argnums=donate,
-                                 static_argnums=(11,))
-            self._verify = jax.jit(verify_paged, donate_argnums=donate,
-                                   static_argnums=(9,))
-        else:
-            self._prefill = jax.jit(prefill, donate_argnums=donate)
-            self._step = jax.jit(step, donate_argnums=donate,
-                                 static_argnums=(10,))
-            self._verify = jax.jit(verify, donate_argnums=donate,
-                                   static_argnums=(8,))
+            # cache buffers are donated — the engine holds the only live
+            # reference, and the cache ([SLOTS, KV, L, D] per layer, or the
+            # page pool) is the biggest allocation here; donation keeps it
+            # single-buffered. (CPU has no donation support and would warn
+            # per program.) prev_tok is NOT donated: the pending sync still
+            # reads its buffer after the next step consumed it.
+            donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()
+            self.donates_cache = bool(donate)
+            self._init_cache = jax.jit(init_cache)
+            if cfg.paged:
+                self._prefill = jax.jit(prefill_paged, donate_argnums=donate)
+                self._step = jax.jit(step_paged, donate_argnums=donate,
+                                     static_argnums=(11,))
+                self._verify = jax.jit(verify_paged, donate_argnums=donate,
+                                       static_argnums=(9,))
+            else:
+                self._prefill = jax.jit(prefill, donate_argnums=donate)
+                self._step = jax.jit(step, donate_argnums=donate,
+                                     static_argnums=(10,))
+                self._verify = jax.jit(verify, donate_argnums=donate,
+                                       static_argnums=(8,))
 
-        self.scheduler = Scheduler(cfg.chunk_buckets, mcfg.max_len,
-                                   admit_lookahead=cfg.admit_lookahead,
-                                   reserve=self.RESERVE)
-        self.slots = SlotManager(S)
-        self.cache = self._init_cache(self.params)
-        self._prev_tok = self._zeros_tok(S)
-        self._session = None   # open steppable session (start()/finish())
-        # push-based load reporting (set_heartbeat): (hook, interval)
-        self._heartbeat = None
-        self._heartbeat_last: Optional[float] = None
-        # high-water marks over a run(): the capacity story in one pair
-        # of numbers (paged mode sustains more slots than contiguous at
-        # equal cache bytes exactly when pages_in_use_peak stays under
-        # the pool while occupancy_peak exceeds the contiguous slot cap)
-        self.occupancy_peak = 0
-        self.pages_in_use_peak = 0
-        # speculation run counters (host truth the bench reads;
-        # spec_stats() derives acceptance_rate / effective tokens/step)
-        self.spec_proposed = 0       # draft tokens sent to verify
-        self.spec_accepted = 0       # draft tokens that matched argmax
-        self.spec_steps = 0          # verify steps run
-        self.spec_rows = 0           # consumer rows across verify steps
-        self.spec_tokens = 0         # tokens emitted by verify steps
+            self.scheduler = Scheduler(cfg.chunk_buckets, mcfg.max_len,
+                                       admit_lookahead=cfg.admit_lookahead,
+                                       reserve=self.RESERVE)
+            self.slots = SlotManager(S)
+            # closes on a device sync, so the engine's set-up span holds the
+            # weights' copy and both programs' time; tick() never blocks for
+            # a span
+            with span("serve.init_cache"):
+                self.cache = jax.block_until_ready(
+                    self._init_cache(self.params))
+            self._prev_tok = self._zeros_tok(S)
+            # (rows, widest bucket) of the prefill calls dispatched since the
+            # last decode dispatch: the device runs them BEFORE that step, so
+            # the step's span carries them and its sync is the one that waits
+            self._prefill_queued = (0, 0)
+            self._session = None   # open steppable session (start()/finish())
+            # push-based load reporting (set_heartbeat): (hook, interval)
+            self._heartbeat = None
+            self._heartbeat_last: Optional[float] = None
+            # high-water marks over a run(): the capacity story in one pair
+            # of numbers (paged mode sustains more slots than contiguous at
+            # equal cache bytes exactly when pages_in_use_peak stays under
+            # the pool while occupancy_peak exceeds the contiguous slot cap)
+            self.occupancy_peak = 0
+            self.pages_in_use_peak = 0
+            # speculation run counters (host truth the bench reads;
+            # spec_stats() derives acceptance_rate / effective tokens/step)
+            self.spec_proposed = 0       # draft tokens sent to verify
+            self.spec_accepted = 0       # draft tokens that matched argmax
+            self.spec_steps = 0          # verify steps run
+            self.spec_rows = 0           # consumer rows across verify steps
+            self.spec_tokens = 0         # tokens emitted by verify steps
 
-    # -- bookkeeping ------------------------------------------------------
+        # -- bookkeeping ------------------------------------------------------
 
     def _zeros_tok(self, n: int):
         """The device-side token chain's initial value, placed where the
@@ -635,6 +659,7 @@ class ServingEngine:
             self.page_allocator.reset()
         self.cache = self._init_cache(self.params)
         self._prev_tok = self._zeros_tok(self.config.slots)
+        self._prefill_queued = (0, 0)
         # the per-step rng folds in this counter — rewind it so a reset
         # engine replays a trace with identical draws
         self._steps_dispatched = 0
@@ -707,22 +732,29 @@ class ServingEngine:
     def _run_prefill_chunk(self, st: RequestState) -> None:
         w, size = st.chunks.pop(0)
         p1 = len(st.req.prompt) - 1
-        window = list(st.req.prompt[w:min(w + size, p1)])
-        window += [0] * (size - len(window))     # right-pad short prompts
-        t0 = time.perf_counter()
         with span("serve.prefill"):
+            window = list(st.req.prompt[w:min(w + size, p1)])
+            window += [0] * (size - len(window))  # right-pad short prompts
+            t0 = time.perf_counter()
             self.cache = self._prefill(
                 self.params, self.cache, jnp.int32(st.slot),
                 jnp.asarray(window, jnp.int32), jnp.int32(w))
+        self._note_prefill_queued(1, size)
         if self.telemetry is not None:
             # async dispatch: host wall time, not device time — the next
-            # decode step's sync absorbs any queued prefill work
+            # decode step's sync absorbs any queued prefill work (that
+            # step's serve.decode_step span carries prefill_rows, and its
+            # serve.sync is the wait)
             self.telemetry.prefill_seconds.observe(time.perf_counter() - t0)
         st.pos = min(p1, w + size)
         if not st.chunks:
             rt = self._trace(st.req.id)
             if rt is not None:
                 rt.begin_hop(self.POST_PREFILL_HOP, self._trace_now())
+
+    def _note_prefill_queued(self, rows: int, bucket: int) -> None:
+        r, b = self._prefill_queued
+        self._prefill_queued = (r + rows, max(b, bucket))
 
     def _page_table_array(self) -> np.ndarray:
         """[S, nblk] physical-page tables for every slot row; free rows
@@ -740,27 +772,28 @@ class ServingEngine:
         chunk per loop iteration. Bound non-member rows run zero tokens
         at their own cursor (junk lands at their next write offset)."""
         size = lead.chunks[0][1]
-        batch = [st for st in self.scheduler.active
-                 if st.prefilling and st.chunks[0][1] == size]
-        toks = np.zeros((self.config.slots, size), np.int32)
-        starts = np.zeros((self.config.slots,), np.int32)
-        for st in self.slots.states:
-            if st is not None:
-                starts[st.slot] = st.pos
-        done = []
-        for st in batch:
-            w, _ = st.chunks.pop(0)
-            p1 = len(st.req.prompt) - 1
-            window = list(st.req.prompt[w:min(w + size, p1)])
-            window += [0] * (size - len(window))
-            toks[st.slot] = window
-            starts[st.slot] = w
-            done.append((st, w, p1))
-        t0 = time.perf_counter()
         with span("serve.prefill"):
+            batch = [st for st in self.scheduler.active
+                     if st.prefilling and st.chunks[0][1] == size]
+            toks = np.zeros((self.config.slots, size), np.int32)
+            starts = np.zeros((self.config.slots,), np.int32)
+            for st in self.slots.states:
+                if st is not None:
+                    starts[st.slot] = st.pos
+            done = []
+            for st in batch:
+                w, _ = st.chunks.pop(0)
+                p1 = len(st.req.prompt) - 1
+                window = list(st.req.prompt[w:min(w + size, p1)])
+                window += [0] * (size - len(window))
+                toks[st.slot] = window
+                starts[st.slot] = w
+                done.append((st, w, p1))
+            t0 = time.perf_counter()
             self.cache = self._prefill(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(starts), jnp.asarray(self._page_table_array()))
+        self._note_prefill_queued(len(batch), size)
         if self.telemetry is not None:
             self.telemetry.prefill_seconds.observe(time.perf_counter() - t0)
         for st, w, p1 in done:
@@ -799,29 +832,27 @@ class ServingEngine:
     def _dispatch_decode_step(self):
         """Build the step arrays and dispatch ONE decode step without
         waiting for its result. Returns the pending sync handle
-        (device token/logprob refs + the consumers at dispatch time),
-        or None when no state is eligible to consume a step. Cursors
-        and dispatch counts advance HERE — they are deterministic, so
-        the host's view stays exact while the tokens are in flight."""
-        toks, pos, use_prev, temps, top_ks, top_ps, consumers = \
-            self.slots.step_arrays()
-        if not consumers:
-            return None
-        # pick the cheapest step variant the active rows allow (the host
-        # knows the sampling params exactly; see sample_slots)
-        sampling = [st.req for st in consumers if st.req.temperature > 0.0]
-        if not sampling:
-            mode = "greedy"
-        elif all(1 <= r.top_k <= SAMPLE_POOL for r in sampling):
-            mode = "bounded"
-        else:
-            mode = "full"
-        rng = jax.random.fold_in(self._base_rng, self._steps_dispatched)
-        self._steps_dispatched += 1
-        step_t0 = time.perf_counter()
-        extra = ((jnp.asarray(self._page_table_array()),)
-                 if self.config.paged else ())
-        with span("serve.decode_step"):
+        (device token/logprob refs, the consumers at dispatch time, the
+        dispatch time and the id of the dispatch's span, which the sync
+        names as its cause), or None when no state is eligible to
+        consume a step. Cursors and dispatch counts advance HERE — they
+        are deterministic, so the host's view stays exact while the
+        tokens are in flight."""
+        with span("serve.decode_step") as sp:
+            toks, pos, use_prev, temps, top_ks, top_ps, consumers = \
+                self.slots.step_arrays()
+            if not consumers:
+                sp.drop()
+                return None
+            mode = _sample_mode(consumers)
+            prefill_rows, prefill_bucket = self._prefill_queued
+            self._prefill_queued = (0, 0)
+            sp.set(prefill_rows=prefill_rows, prefill_bucket=prefill_bucket)
+            rng = jax.random.fold_in(self._base_rng, self._steps_dispatched)
+            self._steps_dispatched += 1
+            step_t0 = time.perf_counter()
+            extra = ((jnp.asarray(self._page_table_array()),)
+                     if self.config.paged else ())
             self.cache, out_tok, out_logp = self._step(
                 self.params, self.cache, self._prev_tok,
                 jnp.asarray(toks), jnp.asarray(use_prev), jnp.asarray(pos),
@@ -841,7 +872,7 @@ class ServingEngine:
                 # land on top of (never under) this request's K/V.
                 self.slots.release(st)
                 st.slot_released = True
-        return out_tok, out_logp, consumers, step_t0
+        return out_tok, out_logp, consumers, step_t0, sp.id
 
     def _plan_drafts(self) -> Dict[int, List[int]]:
         """Host-side proposal pass: {slot: draft tokens} for every row
@@ -882,7 +913,7 @@ class ServingEngine:
         return planned
 
     def _spec_step(self, planned: Dict[int, List[int]], now_fn,
-                   on_token=None) -> List[RequestState]:
+                   on_token, results) -> List[RequestState]:
         """Dispatch ONE verify step over every decoding row and sync it:
         drafting rows carry [next_input, draft...] at consecutive
         cursors, plain rows ride along in column 0 (mixed batches cost
@@ -900,54 +931,53 @@ class ServingEngine:
         cfg = self.config
         Sn = cfg.slots
         L = self.model_config.max_len
-        max_k = max((len(d) for d in planned.values()), default=0)
-        W = next(b for b in self._verify_buckets if b >= max_k + 1)
-        toks = np.zeros((Sn, W), np.int32)
-        posn = np.full((Sn, W), L, np.int32)   # max_len = dropped write
-        temps = np.zeros((Sn,), np.float32)
-        top_ks = np.zeros((Sn,), np.int32)
-        top_ps = np.ones((Sn,), np.float32)
-        consumers: List[RequestState] = []
-        for st in self.slots.states:
-            if st is None or st.prefilling or st.done:
-                continue
-            if st.dispatched >= st.req.max_new_tokens:
-                continue                       # drained: final sync only
-            toks[st.slot, 0] = st.next_input
-            posn[st.slot, 0] = st.pos
-            temps[st.slot] = st.req.temperature
-            top_ks[st.slot] = st.req.top_k
-            top_ps[st.slot] = st.req.top_p
-            d = planned.get(st.slot, ())
-            if d:
-                toks[st.slot, 1:1 + len(d)] = d
-                posn[st.slot, 1:1 + len(d)] = \
-                    st.pos + 1 + np.arange(len(d))
-            consumers.append(st)
-        if not consumers:
-            return []
-        sampling = [st.req for st in consumers if st.req.temperature > 0.0]
-        if not sampling:
-            mode = "greedy"
-        elif all(1 <= r.top_k <= SAMPLE_POOL for r in sampling):
-            mode = "bounded"
-        else:
-            mode = "full"
-        rng = jax.random.fold_in(self._base_rng, self._steps_dispatched)
-        self._steps_dispatched += 1
-        step_t0 = time.perf_counter()
-        extra = ((jnp.asarray(self._page_table_array()),)
-                 if cfg.paged else ())
-        with span("serve.verify_step"):
+        with span("serve.verify_step") as sp:
+            max_k = max((len(d) for d in planned.values()), default=0)
+            W = next(b for b in self._verify_buckets if b >= max_k + 1)
+            toks = np.zeros((Sn, W), np.int32)
+            posn = np.full((Sn, W), L, np.int32)   # max_len = dropped write
+            temps = np.zeros((Sn,), np.float32)
+            top_ks = np.zeros((Sn,), np.int32)
+            top_ps = np.ones((Sn,), np.float32)
+            consumers: List[RequestState] = []
+            for st in self.slots.states:
+                if st is None or st.prefilling or st.done:
+                    continue
+                if st.dispatched >= st.req.max_new_tokens:
+                    continue                       # drained: final sync only
+                toks[st.slot, 0] = st.next_input
+                posn[st.slot, 0] = st.pos
+                temps[st.slot] = st.req.temperature
+                top_ks[st.slot] = st.req.top_k
+                top_ps[st.slot] = st.req.top_p
+                d = planned.get(st.slot, ())
+                if d:
+                    toks[st.slot, 1:1 + len(d)] = d
+                    posn[st.slot, 1:1 + len(d)] = \
+                        st.pos + 1 + np.arange(len(d))
+                consumers.append(st)
+            if not consumers:
+                sp.drop()
+                return []
+            mode = _sample_mode(consumers)
+            prefill_rows, prefill_bucket = self._prefill_queued
+            self._prefill_queued = (0, 0)
+            sp.set(prefill_rows=prefill_rows, prefill_bucket=prefill_bucket)
+            rng = jax.random.fold_in(self._base_rng, self._steps_dispatched)
+            self._steps_dispatched += 1
+            step_t0 = time.perf_counter()
+            extra = ((jnp.asarray(self._page_table_array()),)
+                     if cfg.paged else ())
             self.cache, dev_tg, dev_lp = self._verify(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(posn), rng, jnp.asarray(temps),
                 jnp.asarray(top_ks), jnp.asarray(top_ps), *extra, mode)
         tel = self.telemetry
-        gap_t0 = time.perf_counter()
-        tg = np.asarray(dev_tg)
-        lp = np.asarray(dev_lp)
-        t_sync = time.perf_counter()
+        with span("serve.sync", caused_by=sp.id):
+            gap_t0 = time.perf_counter()
+            tg = np.asarray(dev_tg)
+            lp = np.asarray(dev_lp)
+            t_sync = time.perf_counter()
         if tel is not None:
             tel.host_gap_seconds.observe(t_sync - gap_t0)
             tel.decode_step_seconds.observe(t_sync - step_t0)
@@ -956,57 +986,61 @@ class ServingEngine:
         finished: List[RequestState] = []
         self.spec_steps += 1
         spec_p0, spec_a0 = self.spec_proposed, self.spec_accepted
-        for st in consumers:
-            d = planned.get(st.slot, [])
-            row_t, row_l = tg[st.slot], lp[st.slot]
-            accepted = 0
-            while accepted < len(d) and d[accepted] == int(row_t[accepted]):
-                accepted += 1
-            emit = accepted + 1          # the model's own token is free
-            eos = st.req.eos_id
-            if eos is not None:
-                for j in range(emit):    # nothing streams past an EOS
-                    if int(row_t[j]) == eos:
-                        emit = j + 1
-                        break
-            written = len(d) + 1         # columns this row really wrote
-            st.pos += written
-            if written > emit:
-                self.slots.rewind(st.slot, written - emit, page_size=ps)
-            st.dispatched += emit
-            if d:
-                self.spec_proposed += len(d)
-                self.spec_accepted += accepted
+        with span("serve.retire"):
+            for st in consumers:
+                d = planned.get(st.slot, [])
+                row_t, row_l = tg[st.slot], lp[st.slot]
+                accepted = 0
+                while (accepted < len(d)
+                       and d[accepted] == int(row_t[accepted])):
+                    accepted += 1
+                emit = accepted + 1          # the model's own token is free
+                eos = st.req.eos_id
+                if eos is not None:
+                    for j in range(emit):    # nothing streams past an EOS
+                        if int(row_t[j]) == eos:
+                            emit = j + 1
+                            break
+                written = len(d) + 1         # columns this row really wrote
+                st.pos += written
+                if written > emit:
+                    self.slots.rewind(st.slot, written - emit, page_size=ps)
+                st.dispatched += emit
+                if d:
+                    self.spec_proposed += len(d)
+                    self.spec_accepted += accepted
+                    if tel is not None:
+                        tel.spec_proposed_total.inc(len(d))
+                        tel.spec_accepted_total.inc(accepted)
+                        tel.spec_acceptance_ratio.observe(accepted / len(d))
+                self.spec_rows += 1
+                self.spec_tokens += emit
                 if tel is not None:
-                    tel.spec_proposed_total.inc(len(d))
-                    tel.spec_accepted_total.inc(accepted)
-                    tel.spec_acceptance_ratio.observe(accepted / len(d))
-            self.spec_rows += 1
-            self.spec_tokens += emit
-            if tel is not None:
-                tel.spec_tokens_per_step.observe(emit)
-            for j in range(emit):
-                t = int(row_t[j])
-                if tel is not None:
-                    if st.token_times:
-                        tel.tpot_seconds.observe(now - st.token_times[-1])
-                    else:
-                        tel.ttft_seconds.observe(now - st.req.arrival)
-                    tel.tokens_total.inc()
-                st.generated.append(t)
-                st.logprobs.append(float(row_l[j]))
-                st.token_times.append(now)
-                if on_token is not None:
-                    on_token(st.req, t)
-            st.next_input = int(row_t[emit - 1])
-            st.host_next = True          # device chain token is stale
-            if (eos is not None and st.generated
-                    and st.generated[-1] == eos):
-                st.finish_reason = "eos"
-            elif len(st.generated) >= st.req.max_new_tokens:
-                st.finish_reason = "length"
-            if st.done:
-                finished.append(st)
+                    tel.spec_tokens_per_step.observe(emit)
+                for j in range(emit):
+                    t = int(row_t[j])
+                    if tel is not None:
+                        if st.token_times:
+                            tel.tpot_seconds.observe(now - st.token_times[-1])
+                        else:
+                            tel.ttft_seconds.observe(now - st.req.arrival)
+                        tel.tokens_total.inc()
+                    st.generated.append(t)
+                    st.logprobs.append(float(row_l[j]))
+                    st.token_times.append(now)
+                    if on_token is not None:
+                        on_token(st.req, t)
+                st.next_input = int(row_t[emit - 1])
+                st.host_next = True          # device chain token is stale
+                if (eos is not None and st.generated
+                        and st.generated[-1] == eos):
+                    st.finish_reason = "eos"
+                elif len(st.generated) >= st.req.max_new_tokens:
+                    st.finish_reason = "length"
+                if st.done:
+                    finished.append(st)
+            for st in finished:
+                self._retire_state(st, results)
         if self._session_span is not None:
             # batch-level verify span under the session root, stamped
             # at sync on the session clock; acceptance counts ride as
@@ -1020,19 +1054,22 @@ class ServingEngine:
                 accepted=self.spec_accepted - spec_a0)
         return finished
 
-    def _sync_decode_step(self, pending, now_fn, on_token=None) \
+    def _sync_decode_step(self, pending, now_fn, on_token, results) \
             -> List[RequestState]:
         """Host-sync a previously dispatched step: fetch its tokens
         (the only blocking device read in the loop — host_gap_seconds
-        is exactly this wait), stream them, and mark EOS/length
-        retirements. A consumer already done at sync time took its
-        one post-EOS junk step; its junk token is discarded here."""
-        dev_tok, dev_logp, consumers, step_t0 = pending
+        is exactly this wait, and the `serve.sync` span names the
+        dispatch it waited on), stream them, and retire what finished
+        by EOS or length into `results`. A consumer already done at
+        sync time took its one post-EOS junk step; its junk token is
+        discarded here."""
+        dev_tok, dev_logp, consumers, step_t0, dispatch_id = pending
         tel = self.telemetry
-        gap_t0 = time.perf_counter()
-        out_tok = np.asarray(dev_tok)            # host sync: stream point
-        out_logp = np.asarray(dev_logp)
-        t_sync = time.perf_counter()
+        with span("serve.sync", caused_by=dispatch_id):
+            gap_t0 = time.perf_counter()
+            out_tok = np.asarray(dev_tok)        # host sync: stream point
+            out_logp = np.asarray(dev_logp)
+            t_sync = time.perf_counter()
         if tel is not None:
             # how long the host was BLOCKED on the device — near zero
             # when the dispatched work fully hides under host scheduling
@@ -1046,28 +1083,31 @@ class ServingEngine:
             self._session_span.child("serve.decode_step", now - dur, dur,
                                      batch=len(consumers))
         finished = []
-        for st in consumers:
-            if st.done:
-                continue
-            t = int(out_tok[st.slot])
-            if tel is not None:
-                if st.token_times:
-                    tel.tpot_seconds.observe(now - st.token_times[-1])
-                else:
-                    tel.ttft_seconds.observe(now - st.req.arrival)
-                tel.tokens_total.inc()
-            st.next_input = t
-            st.generated.append(t)
-            st.logprobs.append(float(out_logp[st.slot]))
-            st.token_times.append(now)
-            if on_token is not None:
-                on_token(st.req, t)
-            if st.req.eos_id is not None and t == st.req.eos_id:
-                st.finish_reason = "eos"
-            elif len(st.generated) >= st.req.max_new_tokens:
-                st.finish_reason = "length"
-            if st.done:
-                finished.append(st)
+        with span("serve.retire"):
+            for st in consumers:
+                if st.done:
+                    continue
+                t = int(out_tok[st.slot])
+                if tel is not None:
+                    if st.token_times:
+                        tel.tpot_seconds.observe(now - st.token_times[-1])
+                    else:
+                        tel.ttft_seconds.observe(now - st.req.arrival)
+                    tel.tokens_total.inc()
+                st.next_input = t
+                st.generated.append(t)
+                st.logprobs.append(float(out_logp[st.slot]))
+                st.token_times.append(now)
+                if on_token is not None:
+                    on_token(st.req, t)
+                if st.req.eos_id is not None and t == st.req.eos_id:
+                    st.finish_reason = "eos"
+                elif len(st.generated) >= st.req.max_new_tokens:
+                    st.finish_reason = "length"
+                if st.done:
+                    finished.append(st)
+            for st in finished:
+                self._retire_state(st, results)
         return finished
 
     def _note_admissions(self, admitted: List[RequestState]) -> None:
@@ -1273,82 +1313,79 @@ class ServingEngine:
             raise RuntimeError("tick() outside a session (call start())")
         if not self.active:
             return False
-        alloc = self.page_allocator
-        tel = self.telemetry
-        now_fn = sess["now_fn"]
-        on_token = sess["on_token"]
-        results = sess["results"]
-
-        def retire(finished: List[RequestState]) -> None:
-            for st in finished:
-                self._retire_state(st, results)
-
-        now = now_fn()
-        # deadline sweep FIRST: a wedged head-of-queue request frees
-        # its slot before this iteration's admission fills the rows
-        self._sweep_timeouts(now, results)
-        with span("serve.schedule"):
-            self._note_admissions(
-                self.scheduler.admit(self.slots.free, now,
-                                     allocator=alloc))
-        self.occupancy_peak = max(self.occupancy_peak,
-                                  self.slots.occupied)
-        if alloc is not None:
-            self.pages_in_use_peak = max(self.pages_in_use_peak,
-                                         alloc.in_use)
-        if tel is not None:
-            tel.queue_depth.set(len(self.scheduler.queue))
-            tel.slot_occupancy.set(self.slots.occupied)
+        with span("serve.tick") as tick_span:
+            alloc = self.page_allocator
+            tel = self.telemetry
+            now_fn = sess["now_fn"]
+            on_token = sess["on_token"]
+            results = sess["results"]
+            now = now_fn()
+            with span("serve.schedule"):
+                # deadline sweep FIRST: a wedged head-of-queue request frees
+                # its slot before this iteration's admission fills the rows
+                self._sweep_timeouts(now, results)
+                self._note_admissions(
+                    self.scheduler.admit(self.slots.free, now,
+                                         allocator=alloc))
+            self.occupancy_peak = max(self.occupancy_peak,
+                                      self.slots.occupied)
             if alloc is not None:
-                tel.pages_in_use.set(alloc.in_use)
-                tel.pages_cached.set(alloc.cached_pages)
-        # heartbeat AFTER admission: the published queue depth is what
-        # is still waiting behind the slots, not this instant's intake
-        self._maybe_heartbeat(now)
-        # nothing resident yet and the next arrival is in the future:
-        # nothing to advance — report it instead of spinning
-        pending = sess["pending"]
-        if self.slots.occupied == 0 and pending is None:
-            nxt = self.scheduler.next_arrival()
-            if nxt is not None and nxt > now_fn():
-                return False
-        st = self.scheduler.next_prefill()
-        if st is not None:
-            if self.config.paged:
-                self._run_prefill_batched(st)
+                self.pages_in_use_peak = max(self.pages_in_use_peak,
+                                             alloc.in_use)
+            if tel is not None:
+                tel.queue_depth.set(len(self.scheduler.queue))
+                tel.slot_occupancy.set(self.slots.occupied)
+                if alloc is not None:
+                    tel.pages_in_use.set(alloc.in_use)
+                    tel.pages_cached.set(alloc.cached_pages)
+            # heartbeat AFTER admission: the published queue depth is what
+            # is still waiting behind the slots, not this instant's intake
+            self._maybe_heartbeat(now)
+            # nothing resident yet and the next arrival is in the future:
+            # nothing to advance — report it instead of spinning
+            pending = sess["pending"]
+            if self.slots.occupied == 0 and pending is None:
+                nxt = self.scheduler.next_arrival()
+                if nxt is not None and nxt > now_fn():
+                    tick_span.drop()
+                    return False
+            st = self.scheduler.next_prefill()
+            if st is not None:
+                if self.config.paged:
+                    self._run_prefill_batched(st)
+                else:
+                    self._run_prefill_chunk(st)
+            planned = {}
+            if (self.config.speculative is not None
+                    and self.scheduler.decoding()):
+                # drafting reads host-known history, and acceptance
+                # decides the next step's inputs — drain the in-flight
+                # step first (speculative steps are synchronous; the
+                # multi-token payoff replaces the dispatch overlap)
+                if pending is not None:
+                    self._sync_decode_step(pending, now_fn, on_token,
+                                           results)
+                    pending = None
+                planned = self._plan_drafts()
+            if planned:
+                self._spec_step(planned, now_fn, on_token, results)
+                new_pending = None
             else:
-                self._run_prefill_chunk(st)
-        planned = {}
-        if (self.config.speculative is not None
-                and self.scheduler.decoding()):
-            # drafting reads host-known history, and acceptance
-            # decides the next step's inputs — drain the in-flight
-            # step first (speculative steps are synchronous; the
-            # multi-token payoff replaces the dispatch overlap)
+                # no row drafted this step (novel text, sampling rows,
+                # exhausted budgets): plain decode, async overlap intact
+                new_pending = (self._dispatch_decode_step()
+                               if self.scheduler.decoding() else None)
             if pending is not None:
-                retire(self._sync_decode_step(pending, now_fn,
-                                              on_token))
+                self._sync_decode_step(pending, now_fn, on_token, results)
                 pending = None
-            planned = self._plan_drafts()
-        if planned:
-            retire(self._spec_step(planned, now_fn, on_token))
-            new_pending = None
-        else:
-            # no row drafted this step (novel text, sampling rows,
-            # exhausted budgets): plain decode, async overlap intact
-            new_pending = (self._dispatch_decode_step()
-                           if self.scheduler.decoding() else None)
-        if pending is not None:
-            retire(self._sync_decode_step(pending, now_fn, on_token))
-            pending = None
-        if self.config.async_decode:
-            pending = new_pending
-        elif new_pending is not None:
-            # sync mode: same compiled step, fetched immediately
-            retire(self._sync_decode_step(new_pending, now_fn,
-                                          on_token))
-        sess["pending"] = pending
-        return True
+            if self.config.async_decode:
+                pending = new_pending
+            elif new_pending is not None:
+                # sync mode: same compiled step, fetched immediately
+                self._sync_decode_step(new_pending, now_fn, on_token,
+                                       results)
+            sess["pending"] = pending
+            return True
 
     def session_results(self) -> Dict[int, RequestResult]:
         """The open session's retired results so far (live view) — the
@@ -1837,29 +1874,24 @@ class DisaggEngine:
                 # the disaggregated split composes with speculation with
                 # no extra machinery (see ServingEngine.run)
                 if pending is not None:
-                    for fin in dec._sync_decode_step(pending, now_fn,
-                                                     on_token):
-                        dec._retire_state(fin, results)
+                    dec._sync_decode_step(pending, now_fn, on_token,
+                                          results)
                     pending = None
                 planned = dec._plan_drafts()
             if planned:
-                for fin in dec._spec_step(planned, now_fn, on_token):
-                    dec._retire_state(fin, results)
+                dec._spec_step(planned, now_fn, on_token, results)
                 new_pending = None
             else:
                 new_pending = (dec._dispatch_decode_step()
                                if dec.scheduler.decoding() else None)
             if pending is not None:
-                for fin in dec._sync_decode_step(pending, now_fn,
-                                                 on_token):
-                    dec._retire_state(fin, results)
+                dec._sync_decode_step(pending, now_fn, on_token, results)
                 pending = None
             if self.config.async_decode:
                 pending = new_pending
             elif new_pending is not None:
-                for fin in dec._sync_decode_step(new_pending, now_fn,
-                                                 on_token):
-                    dec._retire_state(fin, results)
+                dec._sync_decode_step(new_pending, now_fn, on_token,
+                                      results)
         for eng in (pre, dec):
             if eng.telemetry is not None:
                 counts = eng.compile_counts()

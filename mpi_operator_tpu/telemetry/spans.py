@@ -1,44 +1,229 @@
-"""Host-side span annotations that land in XProf traces.
+"""Host spans of the program: one call, two places they land.
 
-`jax.profiler.TraceAnnotation` names a host-thread region in the
-profiler timeline, so "schedule", "prefill", "decode_step", and
-"checkpoint.save" show up NEXT TO the device ops they caused — the view
-that makes a host-bound serving loop or a synchronous checkpoint stall
-obvious in one screenshot.
+`span(name, **attrs)` names a host-thread region. It opens the
+`jax.profiler.TraceAnnotation` it has always opened, so an XProf capture
+shows `serve.tick`, `serve.prefill`, `serve.sync`, `train.init_state`,
+`checkpoint.save` NEXT TO the device operations they caused — and when the
+region closes it appends one record to a bounded in-memory log of this
+process, so that the same names can be read without a capture: which sync
+waited on which dispatch, what the host did in a tick besides waiting, which
+program's trace, lowering, compile or cache load set-up was spent on.
 
-Outside an active capture the annotation is close to free (TraceMe's
-fast path is a disabled-flag check), so call sites keep their spans
-unconditionally. If this jax build lacks the API the helper degrades to
-a nullcontext rather than gating every caller.
+The log has no switch. It is a `deque` of `LOG_BOUND` records (30-50 MB
+when full, measured): a server that runs for a month keeps the last hours,
+a benchmark run keeps everything (set-up alone is 20-30 thousand records,
+nearly all of them JAX's nested traces). A span costs under 2 us; outside
+a capture the annotation itself is a disabled-flag check. Nothing is
+written to disk here — writing out is the reader's business (`records()`).
+
+A record (`Span`):
+
+  id          unique in the process, in order of opening
+  parent      id of the innermost span open on the same thread, or None
+  caused_by   id of the span named as the cause, or None — how a
+              `serve.sync` names the `serve.decode_step` whose output it
+              fetches, a tick later
+  name, attrs attributes may be set until the span closes (`set`)
+  start_ns, end_ns   on `time.perf_counter_ns()`
+  in_capture  whether a profiler capture was running when it opened
+  thread      `threading.get_ident()` of the thread that opened it
+
+The trees the program records:
+
+  a serving tick (serve/engine.py `tick()`; one `serve.tick` a worked tick)
+    serve.schedule     timeouts, admission
+    serve.prefill      one prefill call's dispatch, arrays built
+    serve.decode_step  one decode dispatch (serve.verify_step when
+                       speculating)      prefill_rows, prefill_bucket: the
+                       prefill calls queued ahead of it on the device
+    serve.sync         the blocking token fetch   caused_by = the dispatch
+    serve.retire       tokens streamed, requests retired
+
+  An attribute is kept only where something reads it (PERF.md section 3
+  names the reader of each); the counts a tick could carry are
+  `ServeTelemetry`'s gauges already.
+
+  set-up
+    serve.engine_init > serve.cast_params, serve.init_cache
+    train.trainer_init; train.init_state > train.shard_init,
+                                           train.optimizer_init
+    each with JAX's own work under it, by `fun_name`:
+    jax.trace, jax.lower, jax.compile (backend compile) or jax.cache_load
+    (the same program found in the persistent cache). One of these under
+    a `serve.tick` after warm-up IS the recompile, by name.
+
+  input (data/prefetch.py)
+    data.next          the consumer's wait on the queue   depth on entry
 """
 from __future__ import annotations
 
-from contextlib import nullcontext
+import collections
+import itertools
+import sys
+import threading
+import time
+from typing import Any, Dict, List, Optional
 
-# resolved on first span() call, not at import: the telemetry package is
-# shared with the CONTROL plane (controller/metrics.py reuses the
-# histogram/text-format code), which must stay importable without jax
+#: records kept; the oldest fall out
+LOG_BOUND = 100_000
+
+_LOG: "collections.deque[Span]" = collections.deque(maxlen=LOG_BOUND)
+_ids = itertools.count(1)
+_open = threading.local()          # .stack: the thread's open spans
+
+# resolved on the first span() — or at import when jax is already loaded —
+# and not by importing jax here: the telemetry package is shared with the
+# CONTROL plane (controller/metrics.py reuses the histogram/text-format
+# code), which must stay importable without jax
 _TraceAnnotation = None
-_resolved = False
+_resolve_lock = threading.Lock()
+
+#: JAX's own phases, reported through jax.monitoring with their duration
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
-def _resolve():
-    global _TraceAnnotation, _resolved
+def _stack() -> list:
     try:
-        from jax.profiler import TraceAnnotation
-        _TraceAnnotation = TraceAnnotation
-    except ImportError:                                # pragma: no cover
-        _TraceAnnotation = None
-    _resolved = True
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
 
 
-def span(name: str):
-    """Context manager marking a named host region in XProf traces."""
-    if not _resolved:
+class Span:
+    """One named region: a context manager while open, a record after."""
+
+    __slots__ = ("id", "parent", "caused_by", "name", "attrs", "start_ns",
+                 "end_ns", "in_capture", "thread", "_annotation", "_dropped")
+
+    def __init__(self, name: str, caused_by: Optional[int],
+                 attrs: Dict[str, Any]):
+        self.id = next(_ids)
+        self.parent: Optional[int] = None
+        self.caused_by = caused_by
+        self.name = name
+        self.attrs = attrs
+        self.start_ns = self.end_ns = 0
+        self.in_capture = False
+        self.thread = 0
+        self._annotation = None
+        self._dropped = False
+
+    def set(self, **attrs) -> None:
+        """Attributes known only later (a tick's counts at its end)."""
+        self.attrs.update(attrs)
+
+    def drop(self) -> None:
+        """Keep no record of this span nor of what closed under it: the
+        region turned out to be no work (a tick that found nothing to do,
+        whose `serve.schedule` would otherwise stay behind without a
+        parent and, from a server that polls, fill the log)."""
+        self._dropped = True
+
+    @property
+    def duration_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+    def __enter__(self) -> "Span":
+        stack = _stack()
+        self.parent = stack[-1].id if stack else None
+        self.thread = threading.get_ident()
+        self.in_capture = _TraceAnnotation.is_enabled()
+        self._annotation = _TraceAnnotation(self.name)
+        stack.append(self)
+        self._annotation.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        _stack().pop()
+        if not self._dropped:
+            _LOG.append(self)
+            return
+        # what closed on this thread since this span opened lies under it,
+        # at the log's end; another thread's records go back as they were
+        others = []
+        while _LOG and _LOG[-1].end_ns >= self.start_ns:
+            rec = _LOG.pop()
+            if rec.thread != self.thread:
+                others.append(rec)
+        _LOG.extend(reversed(others))
+
+    def __repr__(self) -> str:
+        return (f"Span({self.id} {self.name!r} parent={self.parent} "
+                f"caused_by={self.caused_by} "
+                f"{self.duration_ns / 1e6:.3f} ms {self.attrs})")
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    """jax.monitoring listener: a phase of JAX's that just ended becomes a
+    closed span under whatever program span is open on this thread. A
+    persistent-cache hit reports its retrieval and THEN the backend
+    compile that enclosed it; the two become one `jax.cache_load` that
+    carries the program's name."""
+    if event == _CACHE_LOAD_EVENT:
+        _open.cache_hit = True
+        return
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    attrs = {k: v for k, v in kw.items() if k == "fun_name"}
+    if name == "jax.compile" and getattr(_open, "cache_hit", False):
+        _open.cache_hit = False
+        name = "jax.cache_load"
+    rec = Span(name, None, attrs)
+    stack = _stack()
+    rec.parent = stack[-1].id if stack else None
+    rec.thread = threading.get_ident()
+    rec.in_capture = _TraceAnnotation.is_enabled()
+    rec.end_ns = time.perf_counter_ns()
+    rec.start_ns = rec.end_ns - int(duration * 1e9)
+    _LOG.append(rec)
+
+
+def _resolve() -> None:
+    """Meet JAX: take its TraceAnnotation and listen, once, to the
+    durations it reports."""
+    global _TraceAnnotation
+    import jax.monitoring
+    from jax.profiler import TraceAnnotation
+    with _resolve_lock:
+        if _TraceAnnotation is None:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _TraceAnnotation = TraceAnnotation
+
+
+def span(name: str, caused_by: Optional[int] = None, **attrs) -> Span:
+    """Context manager naming a host region: in XProf captures, and in
+    this process's span log when it closes. `caused_by` names another
+    span's id as the cause where that is not the enclosing span."""
+    if _TraceAnnotation is None:
         _resolve()
-    if _TraceAnnotation is None:                       # pragma: no cover
-        return nullcontext()
-    return _TraceAnnotation(name)
+    return Span(name, caused_by, attrs)
 
 
-__all__ = ["span"]
+def records() -> List[Span]:
+    """A snapshot of the closed spans, oldest first."""
+    return list(_LOG.copy())      # copy() is atomic; iterating _LOG is not
+
+
+def clear() -> None:
+    """Forget every record (for the tests)."""
+    _LOG.clear()
+
+
+if sys.modules.get("jax") is not None:
+    # jax's own phases count from the first program that is built, which
+    # may be before the program's first span
+    _resolve()
+
+__all__ = ["LOG_BOUND", "Span", "clear", "records", "span"]

@@ -37,8 +37,13 @@ Train series (LMTrainer / Trainer / PipelineLMTrainer benchmark loops):
 Serve series (ServingEngine):
   ttft_seconds            histogram — request arrival → first token
   tpot_seconds            histogram — inter-token gap per slot
-  prefill_seconds         histogram — prefill chunk dispatch (async: host
-                                      wall time, not device time)
+  prefill_seconds         histogram — prefill chunk DISPATCH (async: host
+                                      wall time of the enqueue; by its
+                                      name it should be the call's time
+                                      and is not — the device time lands
+                                      in the next decode step's sync; see
+                                      the serve.prefill / serve.sync
+                                      spans, telemetry/spans.py)
   decode_step_seconds     histogram — decode step dispatch → token sync
                                       (async: spans the loop iteration
                                       that hid under it)
@@ -288,7 +293,8 @@ class ServeTelemetry:
             "tpu_worker_tpot_seconds", "inter-token gap per slot")
         self.prefill_seconds = hist(
             "tpu_worker_prefill_seconds",
-            "prefill chunk host dispatch time (async)")
+            "prefill chunk host DISPATCH time (async enqueue; not the "
+            "call's device time, which the next decode sync absorbs)")
         self.decode_step_seconds = hist(
             "tpu_worker_decode_step_seconds",
             "decode step wall time, dispatch to token sync")

@@ -301,73 +301,82 @@ class LMTrainer:
     def __init__(self, model, mesh: Mesh,
                  config: Optional[LMTrainerConfig] = None,
                  tx: Optional[optax.GradientTransformation] = None):
-        self.model = model
-        self.mesh = mesh
-        self.config = config or LMTrainerConfig()
-        self.tx = tx or make_adamw(self.config)
-        # [B, S] batches: batch over the data axes, seq over sp (context
-        # parallelism — attention="ring" rings the K/V shards; everything
-        # else in the model is position-wise so GSPMD shards it over seq
-        # for free). sp=1 meshes get the same spec, trivially.
-        sp = dict(mesh.shape).get("sp", 1)
-        if self.config.seq_len % max(sp, 1):
-            raise ValueError(
-                f"seq_len={self.config.seq_len} not divisible by the mesh's "
-                f"sp={sp}; context parallelism shards the sequence axis")
-        self.batch_sharding = NamedSharding(mesh, batch_spec(("sp",)))
-        A = self.config.accum_steps
-        nb = math.prod(mesh.shape[a] for a in BATCH_AXES)
-        if A < 1:
-            raise ValueError(f"accum_steps={A} must be >= 1")
-        if A > 1 and self.config.global_batch_size % (A * nb):
-            raise ValueError(
-                f"global_batch_size={self.config.global_batch_size} must "
-                f"split into accum_steps={A} microbatches of whole "
-                f"per-device shards (data-parallel degree {nb})")
-        self.replicated = NamedSharding(mesh, P())
-        self._step = None
-        self._eval = None
-        self._state_shardings = None
+        with span("train.trainer_init"):
+            self.model = model
+            self.mesh = mesh
+            self.config = config or LMTrainerConfig()
+            self.tx = tx or make_adamw(self.config)
+            # [B, S] batches: batch over the data axes, seq over sp
+            # (context parallelism — attention="ring" rings the K/V
+            # shards; everything else in the model is position-wise so
+            # GSPMD shards it over seq for free). sp=1 meshes get the same
+            # spec, trivially.
+            sp = dict(mesh.shape).get("sp", 1)
+            if self.config.seq_len % max(sp, 1):
+                raise ValueError(
+                    f"seq_len={self.config.seq_len} not divisible by the "
+                    f"mesh's sp={sp}; context parallelism shards the "
+                    f"sequence axis")
+            self.batch_sharding = NamedSharding(mesh, batch_spec(("sp",)))
+            A = self.config.accum_steps
+            nb = math.prod(mesh.shape[a] for a in BATCH_AXES)
+            if A < 1:
+                raise ValueError(f"accum_steps={A} must be >= 1")
+            if A > 1 and self.config.global_batch_size % (A * nb):
+                raise ValueError(
+                    f"global_batch_size={self.config.global_batch_size} "
+                    f"must split into accum_steps={A} microbatches of "
+                    f"whole per-device shards (data-parallel degree {nb})")
+            self.replicated = NamedSharding(mesh, P())
+            self._step = None
+            self._eval = None
+            self._state_shardings = None
 
     def init_state(self, rng: jax.Array) -> LMTrainState:
-        cfg = self.config
-        # batch dim sized to the data-axes product: the nested ring
-        # shard_map (attention="ring") needs every global dim divisible by
-        # its mapped mesh axes, init included
-        nb = math.prod(self.mesh.shape[a] for a in BATCH_AXES)
-        dummy = jnp.zeros((max(2, nb), cfg.seq_len), jnp.int32)
-        # under the scope so attention="ring" can resolve the ambient mesh
-        # while tracing init (same context the step runs in)
-        with activation_rules_scope(self.mesh):
-            variables, shardings = shard_init(self.model, self.mesh, rng,
-                                              dummy)
-        params = variables["params"]
-        param_sh = shardings["params"]
+        with span("train.init_state"):
+            cfg = self.config
+            # batch dim sized to the data-axes product: the nested ring
+            # shard_map (attention="ring") needs every global dim divisible by
+            # its mapped mesh axes, init included
+            nb = math.prod(self.mesh.shape[a] for a in BATCH_AXES)
+            dummy = jnp.zeros((max(2, nb), cfg.seq_len), jnp.int32)
+            # under the scope so attention="ring" can resolve the ambient mesh
+            # while tracing init (same context the step runs in). Each set-up
+            # span closes on a device sync, so it holds its own program's time
+            with span("train.shard_init"), activation_rules_scope(self.mesh):
+                variables, shardings = shard_init(self.model, self.mesh, rng,
+                                                  dummy)
+                jax.block_until_ready(variables)
+            params = variables["params"]
+            param_sh = shardings["params"]
 
-        def init_opt(p):
-            return self.tx.init(p)
-        # optimizer state shardings mirror the params they track
-        opt_abstract = jax.eval_shape(init_opt, params)
-        opt_sh = _opt_shardings(opt_abstract, params, param_sh,
-                                self.replicated)
-        opt_state = jax.jit(init_opt, out_shardings=opt_sh)(params)
+            def init_opt(p):
+                return self.tx.init(p)
+            with span("train.optimizer_init"):
+                # optimizer state shardings mirror the params they track
+                opt_abstract = jax.eval_shape(init_opt, params)
+                opt_sh = _opt_shardings(opt_abstract, params, param_sh,
+                                        self.replicated)
+                opt_state = jax.block_until_ready(
+                    jax.jit(init_opt, out_shardings=opt_sh)(params))
 
-        # the counters are born ON the mesh like every other leaf: an
-        # array made outside it has a different abstract type (jax types
-        # carry the mesh), so the state the step RETURNS would not match
-        # the state it was first called with and the whole train step
-        # would trace and compile a second time on its second call
-        def counter():
-            return jax.device_put(jnp.zeros((), jnp.int32), self.replicated)
-        state = LMTrainState(step=counter(), params=params,
-                             opt_state=opt_state, tx=self.tx,
-                             apply_fn=self.model.apply,
-                             nonfinite_streak=counter())
-        self._state_shardings = LMTrainState(
-            step=self.replicated, params=param_sh, opt_state=opt_sh,
-            tx=self.tx, apply_fn=self.model.apply,
-            nonfinite_streak=self.replicated)
-        return state
+            # the counters are born ON the mesh like every other leaf: an
+            # array made outside it has a different abstract type (jax types
+            # carry the mesh), so the state the step RETURNS would not match
+            # the state it was first called with and the whole train step
+            # would trace and compile a second time on its second call
+            def counter():
+                return jax.device_put(jnp.zeros((), jnp.int32),
+                                      self.replicated)
+            state = LMTrainState(step=counter(), params=params,
+                                 opt_state=opt_state, tx=self.tx,
+                                 apply_fn=self.model.apply,
+                                 nonfinite_streak=counter())
+            self._state_shardings = LMTrainState(
+                step=self.replicated, params=param_sh, opt_state=opt_sh,
+                tx=self.tx, apply_fn=self.model.apply,
+                nonfinite_streak=self.replicated)
+            return state
 
     def _use_fused(self):
         mcfg = getattr(self.model, "config", None)
