@@ -176,6 +176,7 @@ def check_kernels(head, device) -> dict:
              f"platform {head.get('platform')!r}, not 'tpu'")
     _require(head.get("ok") is True, f"kernel parity failed: {head}")
     return {"kernels": head["kernels"],
+            "decode_traced": head["decode_traced"],
             "worst_max_rel_err": head["worst_max_rel_err"],
             "tol": head["tol"]}
 
@@ -208,7 +209,8 @@ def check_trainer(head, device) -> dict:
 
 def check_server(head, device) -> dict:
     _same_device(head, device)
-    _require(head.get("decode_impl") == "pallas_paged",
+    # the kernel's name carries the kv heads a grid step took
+    _require(str(head.get("decode_impl")).startswith("pallas_paged[hb="),
              f"decode step traced {head.get('decode_impl')!r}, not the "
              f"paged Pallas kernel")
     _require(head.get("serving_requests_complete") is True,

@@ -3,7 +3,8 @@
 The CPU tests run these kernels in interpret mode; whether Mosaic compiles
 them, and whether the compiled kernels agree with the dense path, can only
 be learnt on a TPU. This module is that check, at the shapes the two
-GPT-2-medium legs of `chip_smoke.py` use:
+GPT-2-medium legs of `chip_smoke.py` use, the decode kernels also with
+GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
 
   flash forward and gradients (dq, dk, dv)   vs `dense_attention`
   decode_attention, per-row cursor vector     vs the dense branch of
@@ -40,8 +41,10 @@ from typing import Dict, List, Optional
 #: mis-masked block) moves whole rows by O(1) and lands far above it.
 BF16_TOL = 2e-2
 
-#: GPT-2-medium attention geometry and the two legs' shapes
+#: GPT-2-medium attention geometry and the two legs' shapes; GPT-2-XL's
+#: head count is one no power of two divides
 GPT2_MEDIUM = dict(heads=16, head_dim=64)
+GPT2_XL = dict(heads=25, head_dim=64)
 TRAIN_SHAPE = dict(batch=16, seq=512)
 SERVE_SHAPE = dict(slots=8, max_len=256, page_size=64, prefilled=200)
 
@@ -110,7 +113,7 @@ def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
     import numpy as np
 
     from ..models.transformer import Attention, TransformerConfig
-    from ..ops.attention import record_traced
+    from ..ops.attention import record_traced, traced_name
 
     nblk = max_len // page_size
     cfg = TransformerConfig(
@@ -156,13 +159,16 @@ def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
     with record_traced() as traced:
         _assert_mosaic(step(kernel), params, filled["cache"])
         got = step(kernel)(params, filled["cache"])
-    want = "pallas_paged" if paged else "pallas"
-    if traced["decode"] != {want}:
+    # the kernel names itself with the kv heads a grid step took
+    want = ("pallas_paged" if paged else "pallas") + "[hb="
+    name = traced_name(traced["decode"]) or ""
+    if not name.startswith(want) or "+" in name:
         raise AssertionError(f"decode step traced {traced['decode']}, "
-                             f"expected {want!r}")
+                             f"expected one {want}N]")
     ref = step(dense)(params, filled["cache"])
     return {"kernel": ("paged_decode_attention" if paged
                        else "decode_attention") + ("_int8" if int8 else ""),
+            "traced": name,
             "shape": {"slots": slots, "heads": heads, "head_dim": head_dim,
                       "max_len": max_len,
                       **({"page_size": page_size} if paged else {})},
@@ -173,18 +179,22 @@ def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
 def run_kernel_parity(train_shape: Optional[dict] = None,
                       serve_shape: Optional[dict] = None,
                       model: Optional[dict] = None,
+                      decode_models: Optional[List[dict]] = None,
                       tol: float = BF16_TOL) -> List[Dict[str, object]]:
     """Every kernel the two legs use, at their shapes; one record each
-    with its measured error and `ok`. Off TPU the kernels interpret (the
-    tier-1 test runs tiny shapes that way)."""
+    with its measured error and `ok`. The decode kernels run once per
+    entry of `decode_models` (default: `model` alone). Off TPU the
+    kernels interpret (the tier-1 test runs tiny shapes that way)."""
     train_shape = train_shape or TRAIN_SHAPE
     serve_shape = serve_shape or SERVE_SHAPE
     model = model or GPT2_MEDIUM
     records = flash_cases(train_shape["batch"], train_shape["seq"],
                           model["heads"], model["head_dim"])
-    for paged in (False, True):
-        for int8 in (False, True):
-            records.append(decode_case(paged, int8, **serve_shape, **model))
+    for geometry in decode_models or [model]:
+        for paged in (False, True):
+            for int8 in (False, True):
+                records.append(
+                    decode_case(paged, int8, **serve_shape, **geometry))
     for rec in records:
         rec["tol"] = tol
         rec["ok"] = bool(rec["max_rel_err"] <= tol)
@@ -208,7 +218,7 @@ def main(argv=None) -> int:
     flops.device_peaks()            # an unknown device_kind raises here
     cache_dir = enable_compile_cache()
     device = device_record()
-    records = run_kernel_parity()
+    records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL])
     for rec in records:
         print(json.dumps({**rec, **device}))
     ok = all(rec["ok"] for rec in records)
@@ -216,7 +226,10 @@ def main(argv=None) -> int:
                       "kernels": len(records),
                       "worst_max_rel_err": max(r["max_rel_err"]
                                                for r in records),
-                      "tol": BF16_TOL, **device,
+                      "tol": BF16_TOL,
+                      "decode_traced": sorted({r["traced"] for r in records
+                                               if "traced" in r}),
+                      **device,
                       "compile_cache_dir": cache_dir}))
     return 0 if ok else 1
 

@@ -625,8 +625,9 @@ class Attention(nn.Module):
 
         from ..ops.attention import note_traced
         if cfg.decode_kernel and S == 1:
-            # A requested kernel runs, or says why it cannot. On TPU a
-            # shape the kernel cannot tile is an error naming the shape
+            # A requested kernel runs (and notes itself as traced, with
+            # the heads a grid step took), or says why it cannot. On TPU
+            # a shape the kernel cannot tile is an error naming the shape
             # (falling through would quietly stream the whole cache
             # where a kernel was asked for); off TPU the dense oracle
             # below takes it, which is what the CPU tests compare the
@@ -638,7 +639,6 @@ class Attention(nn.Module):
                 need = (32 if ck.value.dtype == jnp.int8
                         else 16 if ck.value.dtype == jnp.bfloat16 else 8)
                 if ps % need == 0:
-                    note_traced("decode", "pallas_paged")
                     out = paged_decode_attention(
                         q[:, 0], ck.value, cv.value, cur, pt,
                         k_scale=k_scale, v_scale=v_scale)
@@ -652,7 +652,6 @@ class Attention(nn.Module):
                                              decode_block_k)
                 bk = decode_block_k(L, cfg.decode_block_k)
                 if L % bk == 0:
-                    note_traced("decode", "pallas")
                     out = decode_attention(
                         q[:, 0], ck.value, cv.value, cur,
                         k_scale=k_scale, v_scale=v_scale,

@@ -72,8 +72,9 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
 
     Yields a dict the dispatch sites fill at TRACE time:
       "attention" — full-sequence attention: "flash" | "dense" | "ring"
-      "decode"    — single-token KV-cache steps: "pallas" | "pallas_paged"
-                    | "dense"
+      "decode"    — single-token KV-cache steps: "pallas[hb=N]" |
+                    "pallas_paged[hb=N]" (N: kv heads a grid step of
+                    the kernel covers, `decode_head_block`) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
     A jitted function traces once, so wrap the whole run (first call
     included), not a later window."""
@@ -603,17 +604,26 @@ def flash_attention(q, k, v, causal: bool = True,
 
 def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, sm_scale, block_k):
-    """One decode step for one (batch, kv-head) pair: grid (B, KV, nk),
-    k innermost. q block [G, D] holds ALL query heads of the group (GQA
-    runs natively — no repeated-KV transient anywhere). Length-aware:
-    k blocks past the cache cursor are skipped (their index_map pins to
-    the boundary block, so the pipeline re-uses the already-resident
-    block instead of streaming dead cache), and the boundary block masks
-    columns beyond the cursor. int8 caches dequantize BLOCKWISE in VMEM
-    (ks/vs are the per-position scales) — the bf16 cache transient the
-    dense path materializes in HBM never exists here. The cursor vector
-    is per-row ([B]): row b attends positions <= cur_ref[b], which is
-    what lets the serving engine pack independent requests at unrelated
+    """One decode step for one (row, block of kv heads, k block): grid
+    (B, KV // hb, nk), k innermost. A grid step covers `hb` kv heads at
+    once — K/V blocks [hb, block_k, D], the q block [hb, G, D] with ALL
+    query heads of each group (GQA runs natively — no repeated-KV
+    transient anywhere) — because a step has a fixed cost of a few
+    tenths of a microsecond whatever it moves: with one head a step,
+    gpt2-xl's 25 heads x 16 pages x 64 rows were 25 600 steps a layer
+    at 0.22 us each, all of the kernel's 5.6 ms (ledger, PR 24), for
+    8 KB blocks. The heads of a block are independent: scores and p.v
+    are batched matmuls over the head dim, each head's products summed
+    in the order the one-head body summed them, statistics and
+    accumulator float32 per head. Length-aware: k blocks past the cache
+    cursor are skipped (their index_map pins to the boundary block, so
+    the pipeline re-uses the already-resident block instead of
+    streaming dead cache), and the boundary block masks columns beyond
+    the cursor. int8 caches dequantize BLOCKWISE in VMEM (ks/vs are the
+    per-position scales) — the bf16 cache transient the dense path
+    materializes in HBM never exists here. The cursor vector is per-row
+    ([B]): row b attends positions <= cur_ref[b], which is what lets
+    the serving engine pack independent requests at unrelated
     generation depths into one compiled step."""
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -627,38 +637,121 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
 
     @pl.when(ki * block_k <= cur)
     def _attend():
-        q = q_ref[0, 0]                           # [G, D]
-        k = k_ref[0, 0]                           # [block_k, D]
-        v = v_ref[0, 0]
+        q = q_ref[0]                              # [hb, G, D]
+        k = k_ref[0]                              # [hb, block_k, D]
+        v = v_ref[0]
         if ks_ref is not None:
             # fused dequant: int8 cache block × per-position f32 scale,
             # in the compute dtype (matches the dense oracle's
             # cast-then-scale arithmetic exactly)
-            k = k.astype(q.dtype) * ks_ref[0, 0].astype(q.dtype)
-            v = v.astype(q.dtype) * vs_ref[0, 0].astype(q.dtype)
+            k = k.astype(q.dtype) * ks_ref[0].astype(q.dtype)
+            v = v.astype(q.dtype) * vs_ref[0].astype(q.dtype)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [G, block_k]
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale  # [hb, G, block_k]
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(ki * block_k + cols <= cur, s, NEG_INF)
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
+        m_prev = m_ref[:, :, :1]
+        l_prev = l_ref[:, :, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new
-        l_ref[:, :1] = l_new
+        m_ref[:, :, :1] = m_new
+        l_ref[:, :, :1] = l_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+
+
+#: VMEM the double-buffered K and V blocks of one decode grid step may
+#: take. Mosaic's scoped limit on a v5e is 16 MiB; a quarter of it for
+#: the streamed blocks leaves the rest to the step's own temporaries
+#: (dequantized blocks, scores), which are of the same order.
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+def decode_head_block(kv_heads: int, block_k: int, head_dim: int,
+                      cache_dtype, vmem_budget: int) -> int:
+    """How many kv heads one grid step of the decode kernels covers: the
+    largest divisor of `kv_heads` (the per-device count) whose K and V
+    blocks [hb, block_k, head_dim], double-buffered by the pipeline, fit
+    `vmem_budget` bytes. A function of shapes and dtype alone — a block
+    is counted as VMEM holds it, the minor dim padded to whole 128-lane
+    tiles, and an int8 cache brings its two float32 scale blocks
+    [hb, block_k, 1], which pad to a lane tile a position. gpt2-xl's
+    page block (25 heads, 64 x 64 bfloat16) takes 1.6 MB: all 25 heads
+    in one step."""
+    dtype = jnp.dtype(cache_dtype)
+    per_head = block_k * -(-head_dim // LANES) * LANES * dtype.itemsize
+    if dtype == jnp.int8:
+        per_head += block_k * LANES * 4
+    fit = vmem_budget // (4 * per_head)          # K and V, two buffers each
+    return max(d for d in range(1, kv_heads + 1)
+               if kv_heads % d == 0 and d <= max(fit, 1))
+
+
+def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
+                 block_k, interpret):
+    """The pallas_call both decode kernels share. q4 is [B, KV, G, D];
+    `k`/`v` (and the int8 scales, given a trailing unit dim here) are
+    blocked (1, hb, block_k, ·) at `kv_index(b, h, ki, *prefetch_refs)` —
+    the contiguous cache and the page pool differ in that index map and
+    in what they prefetch (`prefetch[0]` is the [B] cursor vector),
+    nothing else. Reports `name[hb=..]` as the traced decode
+    implementation, so a headline says how many heads a grid step took."""
+    B, KV, G, D = q4.shape
+    hb = decode_head_block(KV, block_k, D, k.dtype, _KV_VMEM_BUDGET)
+    note_traced("decode", f"{name}[hb={hb}]")
+    quantized = k_scale is not None
+    n_pre = len(prefetch)
+
+    def kv_spec(minor):
+        return pl.BlockSpec((1, hb, block_k, minor), kv_index)
+
+    qo_spec = pl.BlockSpec((1, hb, G, D),
+                           lambda b, h, ki, *pre: (b, h, 0, 0))
+    in_specs = [qo_spec, kv_spec(D), kv_spec(D)]
+    args = [q4, k, v]
+    if quantized:
+        # [.., block_k] → [.., block_k, 1]: a trailing unit lane dim makes
+        # the scale block Mosaic-legal (last dim equal to the array dim)
+        in_specs += [kv_spec(1), kv_spec(1)]
+        args += [k_scale[..., None], v_scale[..., None]]
+
+    def kern(*refs):
+        # prefetch refs, q/k/v, the scales when quantized, out, scratch
+        q_ref, k_ref, v_ref, *rest = refs[n_pre:]
+        ks_ref, vs_ref = ((rest.pop(0), rest.pop(0)) if quantized
+                          else (None, None))
+        _decode_kernel(refs[0], q_ref, k_ref, v_ref, ks_ref, vs_ref, *rest,
+                       sm_scale=1.0 / (D ** 0.5), block_k=block_k)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pre,
+        grid=(B, KV // hb, nk),
+        in_specs=in_specs,
+        out_specs=qo_spec,
+        scratch_shapes=[
+            pltpu.VMEM((hb, G, D), jnp.float32),      # acc
+            pltpu.VMEM((hb, G, LANES), jnp.float32),  # running max m
+            pltpu.VMEM((hb, G, LANES), jnp.float32),  # running sum l
+        ],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=_out_struct((B, KV, G, D), q4.dtype, q4, k, v),
+        interpret=interpret,
+    )(*prefetch, *args)
+    return out.reshape(B, KV * G, D)
 
 
 def decode_block_k(max_len: int, block_k: Optional[int] = None) -> int:
@@ -693,20 +786,20 @@ def decode_attention(q, k_cache, v_cache, cache_index,
     whole query group from one cache block — the [B, H, L, D] repeated
     transient of the dense path never materializes. The cache length L
     must tile by `decode_block_k(L, block_k)`; callers fall back to the
-    dense oracle otherwise.
+    dense oracle otherwise. Grid (B, KV // hb, L // block_k): a step
+    takes `decode_head_block` kv heads of one row's k-tile, `hb` strided
+    chunks of the cache in one block.
     """
     B, H, D = q.shape
     _, KV, L, _ = k_cache.shape
     if H % KV:
         raise ValueError(f"H={H} must be a multiple of KV={KV}")
-    G = H // KV
     bk = decode_block_k(L, block_k)
     if L % bk:
         raise ValueError(f"cache len {L} does not tile by block_k={bk}; "
                          f"use the dense decode path")
     interpret = _resolve_interpret(interpret)
     nk = L // bk
-    quantized = k_scale is not None
     cur = jnp.asarray(cache_index, jnp.int32)
     if cur.ndim == 0:
         cur = jnp.broadcast_to(cur[None], (B,))
@@ -724,62 +817,15 @@ def decode_attention(q, k_cache, v_cache, cache_index,
             mesh, B, KV, (q, k_cache, v_cache, cur, k_scale, v_scale),
             (scale, cache, cache, ("rows",), scale, scale), scale)
 
-    def last_blk(cur_ref, b):
-        return jnp.minimum(cur_ref[b] // bk, nk - 1)
+    def kv_index(b, h, ki, cur_ref):
+        # k-tiles past the cursor re-use the boundary tile
+        return (b, h, jnp.minimum(ki, jnp.minimum(cur_ref[b] // bk, nk - 1)),
+                0)
 
-    q4 = q.reshape(B, KV, G, D)       # query head h ↔ kv head h // G,
-    #                                   matching jnp.repeat(kv, G, axis)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, h, ki, cur: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bk, D),
-                     lambda b, h, ki, cur: (b, h,
-                                            jnp.minimum(ki,
-                                                        last_blk(cur, b)),
-                                            0)),
-        pl.BlockSpec((1, 1, bk, D),
-                     lambda b, h, ki, cur: (b, h,
-                                            jnp.minimum(ki,
-                                                        last_blk(cur, b)),
-                                            0)),
-    ]
-    args = [q4, k_cache, v_cache]
-    kern = functools.partial(_decode_kernel, sm_scale=1.0 / (D ** 0.5),
-                             block_k=bk)
-    if quantized:
-        # [B, KV, L] → [B, KV, L, 1]: a trailing unit lane dim makes the
-        # scale block Mosaic-legal (last dim equal to the array dim)
-        scale_spec = pl.BlockSpec(
-            (1, 1, bk, 1),
-            lambda b, h, ki, cur: (b, h,
-                                   jnp.minimum(ki, last_blk(cur, b)), 0))
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scale[..., None], v_scale[..., None]]
-    else:
-        inner = kern
-
-        def kern(cur_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
-                 _inner=inner):
-            return _inner(cur_ref, q_ref, k_ref, v_ref, None, None, o_ref,
-                          *scratch)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KV, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, ki, cur: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),      # acc
-            pltpu.VMEM((G, LANES), jnp.float32),  # running max m
-            pltpu.VMEM((G, LANES), jnp.float32),  # running sum l
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=_out_struct((B, KV, G, D), q.dtype, q, k_cache, v_cache),
-        interpret=interpret,
-    )(cur, *args)
-    return out.reshape(B, H, D)
+    # query head h ↔ kv head h // G, matching jnp.repeat(kv, G, axis)
+    return _decode_call("pallas", q.reshape(B, KV, H // KV, D), k_cache,
+                        v_cache, k_scale, v_scale, (cur,), nk, kv_index, bk,
+                        interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
@@ -800,27 +846,31 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
                  column mask already excludes them)
     k_scale/v_scale [NP, KV, ps] f32  int8 per-(page-slot, head) scales
 
-    The kernel body is IDENTICAL to the contiguous one — block_k equals
-    the page size and logical block ki covers positions [ki*ps, ki*ps+ps),
-    so the cursor skip/mask arithmetic carries over unchanged. Only the
-    index maps differ: the second scalar-prefetch operand (the page
-    table) resolves which PHYSICAL page streams for logical block ki,
-    with past-the-cursor blocks pinned to the boundary block's page so
-    the pipeline re-reads a resident page instead of streaming dead pool.
-    That one extra prefetched operand is the whole cost of paging — the
-    MXU work per step is byte-for-byte the contiguous kernel's.
+    The kernel body is the contiguous one — block_k equals the page size
+    and logical block ki covers positions [ki*ps, ki*ps+ps), so the
+    cursor skip/mask arithmetic carries over unchanged. Only the index
+    map differs: the second scalar-prefetch operand (the page table)
+    resolves which PHYSICAL page streams for logical block ki, with
+    past-the-cursor blocks pinned to the boundary block's page so the
+    pipeline re-reads a resident page instead of streaming dead pool.
+
+    Grid (B, KV // hb, nblk): one step takes `hb` kv heads of one page
+    (`decode_head_block`; all of them when they fit VMEM, as gpt2-xl's 25
+    do), which sit next to each other in the pool — one contiguous read
+    of hb*ps*D elements for K and one for V. A step's fixed cost, not
+    its bytes, was the whole of this kernel with one head a step (0.22 us
+    x 25 600 steps a layer for gpt2-xl; ledger, PR 24), dead pages past a
+    row's cursor included: they move nothing and still cost a step.
     """
     B, H, D = q.shape
     NP, KV, ps, _ = k_pages.shape
     if H % KV:
         raise ValueError(f"H={H} must be a multiple of KV={KV}")
-    G = H // KV
     if page_table.ndim != 2 or page_table.shape[0] != B:
         raise ValueError(f"page_table must be [B={B}, nblk], got shape "
                          f"{page_table.shape}")
     nblk = page_table.shape[1]
     interpret = _resolve_interpret(interpret)
-    quantized = k_scale is not None
     cur = jnp.asarray(cache_index, jnp.int32)
     if cur.shape != (B,):
         raise ValueError(f"cache_index must be [B]={B} per-row cursors, "
@@ -838,63 +888,18 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
             mesh, B, KV, (q, k_pages, v_pages, cur, pt, k_scale, v_scale),
             (out, pool, pool, ("rows",), ("rows", None), scale, scale), out)
 
-    def page_of(b, ki, cur_ref, pt_ref):
+    def kv_index(b, h, ki, cur_ref, pt_ref):
         # physical page for logical block ki, clamped to the row's
         # boundary block (blocks past the cursor re-use its page — the
         # kernel skips their compute anyway)
         last = jnp.minimum(cur_ref[b] // ps, nblk - 1)
-        return pt_ref[b, jnp.minimum(ki, last)]
+        return (pt_ref[b, jnp.minimum(ki, last)], h, 0, 0)
 
-    q4 = q.reshape(B, KV, G, D)
-    kv_spec = pl.BlockSpec(
-        (1, 1, ps, D),
-        lambda b, h, ki, cur, pt_: (page_of(b, ki, cur, pt_), h, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D),
-                     lambda b, h, ki, cur, pt_: (b, h, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
-    args = [q4, k_pages, v_pages]
-    kern = functools.partial(_decode_kernel, sm_scale=1.0 / (D ** 0.5),
-                             block_k=ps)
-    if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, 1, ps, 1),
-            lambda b, h, ki, cur, pt_: (page_of(b, ki, cur, pt_), h, 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        args += [k_scale[..., None], v_scale[..., None]]
-
-        def kern2(cur_ref, pt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, *scratch, _inner=kern):
-            return _inner(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                          o_ref, *scratch)
-    else:
-        def kern2(cur_ref, pt_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
-                  _inner=kern):
-            return _inner(cur_ref, q_ref, k_ref, v_ref, None, None, o_ref,
-                          *scratch)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KV, nblk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, ki, cur, pt_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),      # acc
-            pltpu.VMEM((G, LANES), jnp.float32),  # running max m
-            pltpu.VMEM((G, LANES), jnp.float32),  # running sum l
-        ],
-    )
-    out = pl.pallas_call(
-        kern2,
-        grid_spec=grid_spec,
-        out_shape=_out_struct((B, KV, G, D), q.dtype, q, k_pages, v_pages),
-        interpret=interpret,
-    )(cur, pt, *args)
-    return out.reshape(B, H, D)
+    return _decode_call("pallas_paged", q.reshape(B, KV, H // KV, D),
+                        k_pages, v_pages, k_scale, v_scale, (cur, pt), nblk,
+                        kv_index, ps, interpret)
 
 
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
-           "paged_decode_attention", "record_traced", "note_traced",
-           "traced_name"]
+           "decode_head_block", "paged_decode_attention", "record_traced",
+           "note_traced", "traced_name"]

@@ -17,7 +17,11 @@ from flax.core import meta
 
 from mpi_operator_tpu.models import CausalLM, generate, gpt2_config
 from mpi_operator_tpu.models.transformer import llama_config
-from mpi_operator_tpu.ops.attention import decode_attention, decode_block_k
+from mpi_operator_tpu.ops import attention
+from mpi_operator_tpu.ops.attention import (
+    decode_attention, decode_block_k, decode_head_block,
+    paged_decode_attention, record_traced, traced_name,
+)
 
 POISON = 1e4          # beyond-cursor cache contents: loud if ever read
 
@@ -61,22 +65,28 @@ def _cache(B, H, KV, L, D, cur, quantized=False, seed=0):
             jnp.where(dead3, POISON, vscale))
 
 
-@pytest.mark.parametrize("H,KV", [(4, 4), (4, 2), (8, 1)])
+# the last three: head counts no power of two divides (5, and gpt2-xl's
+# 25 heads of 64) as MHA, and 5 kv heads as GQA (G = 2)
+@pytest.mark.parametrize("H,KV,D", [(4, 4, 16), (4, 2, 16), (8, 1, 16),
+                                    (5, 5, 64), (25, 25, 64), (10, 5, 16)])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_decode_kernel_matches_dense(H, KV, quantized):
+def test_decode_kernel_matches_dense(H, KV, D, quantized):
     """MHA (H==KV), GQA, and MQA (KV=1), each with and without the int8
     cache — cursor mid-block so both the block skip and the in-block
-    column mask are exercised."""
-    B, L, D, cur = 2, 64, 16, 37
+    column mask are exercised. One grid step takes all KV heads of a
+    k-tile (the default budget holds them)."""
+    B, L, cur = 2, 64, 37
     q, k, v, ks, vs = _cache(B, H, KV, L, D, cur, quantized)
-    if quantized:
-        ref = _dense_ref(q, k, v, cur, ks, vs)
-        out = decode_attention(q, k, v, cur, k_scale=ks, v_scale=vs,
-                               block_k=16, interpret=True)
-    else:
-        ref = _dense_ref(q, k, v, cur)
-        out = decode_attention(q, k, v, cur, block_k=16, interpret=True)
+    with record_traced() as traced:
+        if quantized:
+            ref = _dense_ref(q, k, v, cur, ks, vs)
+            out = decode_attention(q, k, v, cur, k_scale=ks, v_scale=vs,
+                                   block_k=16, interpret=True)
+        else:
+            ref = _dense_ref(q, k, v, cur)
+            out = decode_attention(q, k, v, cur, block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+    assert traced["decode"] == {f"pallas[hb={KV}]"}
 
 
 @pytest.mark.parametrize("cur", [0, 15, 16, 31, 63])
@@ -91,12 +101,26 @@ def test_decode_kernel_cursor_positions(cur):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
 
 
+def _head_bytes(block_k, quantized):
+    """What `decode_head_block` counts for ONE kv head of a float32 (or
+    int8) cache with D <= 128: K and V blocks padded to 128 lanes (int8:
+    plus a [block_k, 1] f32 scale block each), two pipeline buffers."""
+    return 4 * block_k * 128 * ((1 + 4) if quantized else 4)
+
+
+# fit: how many of the 6 kv heads the VMEM budget holds; None: the default
+@pytest.mark.parametrize("fit,hb", [(None, 6), (4, 3), (1, 1), (0, 1)])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_decode_kernel_per_row_cursors(quantized):
+def test_decode_kernel_per_row_cursors(monkeypatch, fit, hb, quantized):
     """[B] cursor vector (the serving engine's slot mode): each row reads
     exactly its own filled prefix — per-row poison past each cursor makes
-    any cross-row or beyond-cursor read loud."""
-    B, H, KV, L, D = 4, 4, 2, 64, 16
+    any cross-row or beyond-cursor read loud. GQA over 6 kv heads, in
+    6 // hb head blocks a row: all heads a step, three (a budget for 4
+    takes the largest divisor under it), one."""
+    B, H, KV, L, D = 4, 12, 6, 64, 16
+    if fit is not None:
+        monkeypatch.setattr(attention, "_KV_VMEM_BUDGET",
+                            fit * _head_bytes(16, quantized))
     curs = np.array([0, 17, 31, 63], np.int32)
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(keys[0], (B, H, D), jnp.float32)
@@ -122,9 +146,11 @@ def test_decode_kernel_per_row_cursors(quantized):
                    None if ks is None else ks[b:b + 1],
                    None if vs is None else vs[b:b + 1])
         for b in range(B)])
-    out = decode_attention(q, k, v, jnp.asarray(curs), k_scale=ks,
-                           v_scale=vs, block_k=16, interpret=True)
+    with record_traced() as traced:
+        out = decode_attention(q, k, v, jnp.asarray(curs), k_scale=ks,
+                               v_scale=vs, block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+    assert traced["decode"] == {f"pallas[hb={hb}]"}
 
 
 def test_decode_kernel_vector_cursor_matches_broadcast_scalar():
@@ -154,6 +180,57 @@ def test_decode_block_k_policy():
     assert decode_block_k(1024) == 128          # default tile
     assert decode_block_k(32) == 32             # short caches shrink
     assert decode_block_k(1024, 256) == 256     # explicit override
+
+
+GPT2_XL_PAGE = dict(kv_heads=25, block_k=64, head_dim=64,
+                    cache_dtype=jnp.bfloat16)       # 64 KiB a head
+
+
+@pytest.mark.parametrize("shape,budget,hb", [
+    (GPT2_XL_PAGE, 4 << 20, 25),         # the shipped budget: every head
+    (GPT2_XL_PAGE, 25 << 16, 25),        # exactly fits
+    (GPT2_XL_PAGE, (25 << 16) - 1, 5),   # one byte short: next divisor
+    (GPT2_XL_PAGE, 24 << 16, 5),         # 24 fit, 25 = 5 x 5
+    (GPT2_XL_PAGE, 4 << 16, 1),
+    (GPT2_XL_PAGE, 0, 1),                # nothing fits: one head anyway
+    # D = 128 fills its lanes; the 128-tile of the contiguous kernel
+    (dict(kv_heads=32, block_k=128, head_dim=128,
+          cache_dtype=jnp.bfloat16), 4 << 20, 32),
+    # int8: one byte an element, plus a [bk, 1] f32 scale block for K and
+    # for V that pads to a lane tile a position — 320 KiB a head, 12 fit
+    (dict(kv_heads=32, block_k=128, head_dim=128,
+          cache_dtype=jnp.int8), 4 << 20, 8),
+    (dict(kv_heads=8, block_k=128, head_dim=128,
+          cache_dtype="bfloat16"), 4 << 20, 8),
+    (dict(kv_heads=1, block_k=128, head_dim=64,
+          cache_dtype=np.float32), 4 << 20, 1),
+])
+def test_decode_head_block_is_a_function_of_shapes_and_dtype(shape, budget,
+                                                             hb):
+    """The largest divisor of the kv heads whose double-buffered K and V
+    blocks fit the budget; nothing else goes in."""
+    assert decode_head_block(vmem_budget=budget, **shape) == hb
+    assert shape["kv_heads"] % hb == 0
+
+
+def test_traced_decode_names_the_head_block(monkeypatch):
+    """What a run's headline prints (`traced_name` of `record_traced`'s
+    "decode") says which kernel ran and how many kv heads a grid step
+    took, also when the budget made it fall back to one."""
+    B, H, KV, L, D, cur = 2, 6, 3, 32, 16, 20
+    q, k, v, _, _ = _cache(B, H, KV, L, D, cur)
+    pool = jnp.zeros((5, KV, 16, D), jnp.float32)
+    curs = jnp.full((B,), cur, jnp.int32)
+    table = jnp.ones((B, 2), jnp.int32)
+    with record_traced() as traced:
+        decode_attention(q, k, v, cur, block_k=16, interpret=True)
+        paged_decode_attention(q, pool, pool, curs, table, interpret=True)
+    assert traced_name(traced["decode"]) == \
+        "pallas[hb=3]+pallas_paged[hb=3]"
+    monkeypatch.setattr(attention, "_KV_VMEM_BUDGET", 0)
+    with record_traced() as traced:
+        paged_decode_attention(q, pool, pool, curs, table, interpret=True)
+    assert traced_name(traced["decode"]) == "pallas_paged[hb=1]"
 
 
 def _e2e(cfg, new_tokens=8, seed=1):

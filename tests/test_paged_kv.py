@@ -24,7 +24,9 @@ import pytest
 from flax.core import meta
 
 from mpi_operator_tpu.models import CausalLM, gpt2_config
-from mpi_operator_tpu.ops.attention import paged_decode_attention
+from mpi_operator_tpu.ops import attention
+from mpi_operator_tpu.ops.attention import (paged_decode_attention,
+                                            record_traced)
 from mpi_operator_tpu.serve import (
     EngineConfig, PageAllocator, Request, Scheduler, ServingEngine,
     plan_chunks,
@@ -272,16 +274,15 @@ def _scatter_pages(contig, pt, NP, ps):
     return pool
 
 
-@pytest.mark.parametrize("H,KV", [(4, 4), (4, 2)])
-@pytest.mark.parametrize("quantized", [False, True])
-def test_paged_kernel_matches_dense(H, KV, quantized):
-    """Per-row cursors at block starts/interiors/ends over a shuffled
-    page table; beyond-cursor pool content is poisoned so a wrong page
-    resolution or missing mask shows up as a huge error."""
-    B, D, ps, nblk = 4, 16, 16, 4
-    L = ps * nblk
+def _paged_vs_dense(H, KV, D, quantized, curs, shared=(), ps=16, nblk=4):
+    """The paged kernel against the dense oracle on a shuffled page
+    table; beyond-cursor pool content is poisoned so a wrong page
+    resolution or missing mask shows up as a huge error. Rows in
+    `shared` read their first page through ONE physical page (the
+    prefix-cache layout); their cursors must sit past it."""
+    curs = np.asarray(curs, np.int32)
+    B, L = len(curs), ps * nblk
     NP = B * nblk + 2                        # trash + one never-mapped
-    curs = np.array([0, 17, 31, 63], np.int32)
     rs = np.random.RandomState(5)
     # distinct physical pages per logical block, shuffled across the pool
     perm = rs.permutation(np.arange(1, NP - 1)).reshape(B, nblk)
@@ -289,6 +290,11 @@ def test_paged_kernel_matches_dense(H, KV, quantized):
     q = jax.random.normal(jax.random.PRNGKey(0), (B, H, D), jnp.float32)
     k = rs.randn(B, KV, L, D).astype(np.float32)
     v = rs.randn(B, KV, L, D).astype(np.float32)
+    for b in shared[1:]:
+        assert curs[b] >= ps - 1 and curs[shared[0]] >= ps - 1
+        pt[b, 0] = pt[shared[0], 0]
+        k[b, :, :ps] = k[shared[0], :, :ps]
+        v[b, :, :ps] = v[shared[0], :, :ps]
     dead = np.arange(L)[None, None, :, None] > curs[:, None, None, None]
     ks = vs = ksp = vsp = None
     if quantized:
@@ -311,10 +317,41 @@ def test_paged_kernel_matches_dense(H, KV, quantized):
                      jnp.asarray(curs),
                      None if ks is None else jnp.asarray(ks),
                      None if vs is None else jnp.asarray(vs))
-    out = paged_decode_attention(q, kp, vp, jnp.asarray(curs),
-                                 jnp.asarray(pt), k_scale=ksp,
-                                 v_scale=vsp, interpret=True)
+    with record_traced() as traced:
+        out = paged_decode_attention(q, kp, vp, jnp.asarray(curs),
+                                     jnp.asarray(pt), k_scale=ksp,
+                                     v_scale=vsp, interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+    return traced["decode"]
+
+
+# MHA and GQA at the old shapes; then head counts no power of two divides
+# (5, and gpt2-xl's 25 heads of 64), as MHA and as GQA (G = 2 over 5)
+@pytest.mark.parametrize("H,KV,D", [(4, 4, 16), (4, 2, 16), (5, 5, 64),
+                                    (25, 25, 64), (10, 5, 16)])
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_kernel_matches_dense(H, KV, D, quantized):
+    """Per-row cursors at block starts/interiors/ends; every kv head of a
+    page in one grid step (the default budget holds them all)."""
+    traced = _paged_vs_dense(H, KV, D, quantized, [0, 17, 31, 63])
+    assert traced == {f"pallas_paged[hb={KV}]"}
+
+
+@pytest.mark.parametrize("fit,hb", [(6, 6), (4, 3), (1, 1), (0, 1)])
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_kernel_head_blocks(monkeypatch, fit, hb, quantized):
+    """A VMEM budget that holds `fit` of 6 kv heads (GQA, G = 2): the
+    grid runs 6 // hb head blocks a row and the result does not change.
+    Rows: cursor 0, a cursor on a page's last position, and two rows
+    sharing their prefix page; dead pages stay poisoned and unread."""
+    ps, D = 16, 16
+    # what decode_head_block counts for one head: K and V blocks padded to
+    # 128 lanes (int8: plus a [ps, 1] f32 scale block each), two buffers
+    per_head = 4 * (ps * 128 * (1 + 4) if quantized else ps * 128 * 4)
+    monkeypatch.setattr(attention, "_KV_VMEM_BUDGET", fit * per_head)
+    traced = _paged_vs_dense(12, 6, D, quantized, [0, ps - 1, 2 * ps + 3, 63],
+                             shared=(1, 2), ps=ps)
+    assert traced == {f"pallas_paged[hb={hb}]"}
 
 
 def test_paged_kernel_shared_pages_between_rows():
