@@ -10,6 +10,9 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   decode_attention, per-row cursor vector     vs the dense branch of
   paged_decode_attention                         `Attention._decode_attend`
   the int8-cache variants of both decode kernels
+  mla_paged_decode_attention (absorbed latent   vs `mla_paged_attend`, the
+  attention, LongCat-Flash's published widths)     dense form of
+                                                   `LatentAttention`
 
 The decode cases go through the `Attention` module itself — one set of
 weights, one prefilled cache, the single-token step run once with
@@ -47,6 +50,9 @@ GPT2_MEDIUM = dict(heads=16, head_dim=64)
 GPT2_XL = dict(heads=25, head_dim=64)
 TRAIN_SHAPE = dict(batch=16, seq=512)
 SERVE_SHAPE = dict(slots=8, max_len=256, page_size=64, prefilled=200)
+#: the latent decode kernel at LongCat-Flash's published widths (the
+#: module's defaults): 64 heads share rows of 512 + 64, padded to 640
+MLA_CASE = dict(slots=8, max_len=1280, page_size=64, prefilled=1000)
 
 
 def _rel_err(got, ref) -> float:
@@ -176,15 +182,78 @@ def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
             "max_rel_err": _rel_err(got, ref)}
 
 
+def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
+                    **widths) -> Dict[str, object]:
+    """One single-token step through `LatentAttention` (models/longcat.py)
+    over a prefilled latent page pool, every row at its own cursor: the
+    absorbed Pallas kernel against the module's dense form
+    (`ops.attention.mla_paged_attend`), same weights, same cache."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..models.longcat import LatentAttention, LongcatConfig
+    from ..ops.attention import record_traced, traced_name
+
+    nblk = max_len // page_size
+    cfg = LongcatConfig(max_len=max_len, dtype=jnp.bfloat16, decode=True,
+                        decode_slots=True, decode_page_size=page_size,
+                        decode_num_pages=slots * nblk + 1, **widths)
+    dense = LatentAttention(dataclasses.replace(cfg, decode_kernel=False))
+    kernel = LatentAttention(dataclasses.replace(cfg, decode_kernel=True))
+    ids = np.random.RandomState(0).permutation(slots * nblk) + 1
+    pages = jnp.asarray(ids.reshape(slots, nblk), jnp.int32)
+    kp, kx, ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    E = cfg.hidden_size
+    x_fill = jax.random.normal(kx, (slots, prefilled, E), jnp.bfloat16)
+    x_step = jax.random.normal(ks, (slots, 1, E), jnp.bfloat16)
+    fill_pos = jnp.broadcast_to(jnp.arange(prefilled)[None],
+                                (slots, prefilled))
+    # cursors on and around the page boundaries and the kernel's groups
+    # of pages, first and last filled position included
+    from ..ops.attention import mla_pages_per_step
+    group = mla_pages_per_step(nblk) * page_size
+    marks = [0, page_size - 1, page_size, group - 1, group, prefilled - 1,
+             5, prefilled - 9]
+    cur = jnp.asarray([min(max(m, 0), prefilled - 1)
+                       for m in (marks * slots)[:slots]], jnp.int32)
+    params = dense.init(kp, x_step, positions=cur[:, None],
+                        pages=pages)["params"]
+    _, filled = jax.jit(lambda p: dense.apply(
+        {"params": p}, x_fill, positions=fill_pos, pages=pages,
+        mutable=["cache"]))(params)
+
+    def step(module):
+        return jax.jit(lambda p, c: module.apply(
+            {"params": p, "cache": c}, x_step, positions=cur[:, None],
+            pages=pages, mutable=["cache"])[0])
+
+    with record_traced() as traced:
+        _assert_mosaic(step(kernel), params, filled["cache"])
+        got = step(kernel)(params, filled["cache"])
+    name = traced_name(traced["decode"]) or ""
+    if not name.startswith("pallas_mla_paged[pp=") or "+" in name:
+        raise AssertionError(f"decode step traced {traced['decode']}, "
+                             f"expected one pallas_mla_paged[pp=N]")
+    ref = step(dense)(params, filled["cache"])
+    return {"kernel": "mla_paged_decode_attention", "traced": name,
+            "shape": {"slots": slots, "max_len": max_len,
+                      "page_size": page_size, **widths},
+            "cursors": [int(c) for c in cur],
+            "max_rel_err": _rel_err(got, ref)}
+
+
 def run_kernel_parity(train_shape: Optional[dict] = None,
                       serve_shape: Optional[dict] = None,
                       model: Optional[dict] = None,
                       decode_models: Optional[List[dict]] = None,
+                      mla: Optional[dict] = None,
                       tol: float = BF16_TOL) -> List[Dict[str, object]]:
     """Every kernel the two legs use, at their shapes; one record each
     with its measured error and `ok`. The decode kernels run once per
-    entry of `decode_models` (default: `model` alone). Off TPU the
-    kernels interpret (the tier-1 test runs tiny shapes that way)."""
+    entry of `decode_models` (default: `model` alone); the latent decode
+    kernel where `mla` gives its case. Off TPU the kernels interpret (the
+    tier-1 test runs tiny shapes that way)."""
     train_shape = train_shape or TRAIN_SHAPE
     serve_shape = serve_shape or SERVE_SHAPE
     model = model or GPT2_MEDIUM
@@ -195,6 +264,8 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
             for int8 in (False, True):
                 records.append(
                     decode_case(paged, int8, **serve_shape, **geometry))
+    if mla:
+        records.append(mla_decode_case(**mla))
     for rec in records:
         rec["tol"] = tol
         rec["ok"] = bool(rec["max_rel_err"] <= tol)
@@ -218,7 +289,8 @@ def main(argv=None) -> int:
     flops.device_peaks()            # an unknown device_kind raises here
     cache_dir = enable_compile_cache()
     device = device_record()
-    records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL])
+    records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL],
+                                mla=MLA_CASE)
     for rec in records:
         print(json.dumps({**rec, **device}))
     ok = all(rec["ok"] for rec in records)

@@ -49,12 +49,16 @@ def decode_model(model, decode_kernel: Optional[bool] = None,
     `num_pages` switch the slot cache to the paged page-pool layout
     (transformer.py decode_page_size — requires slots=True)."""
     cfg = model.config
-    return type(model)(dataclasses.replace(
-        cfg, decode=True, attention="dense", remat=False,
-        decode_slots=slots,
+    changes = dict(
+        decode=True, attention="dense", remat=False, decode_slots=slots,
         decode_page_size=page_size, decode_num_pages=num_pages,
         decode_kernel=(cfg.decode_kernel if decode_kernel is None
-                       else decode_kernel)))
+                       else decode_kernel))
+    # a config that has no such knob (another family's: no choice of
+    # training attention, no remat) is not given it
+    known = {f.name for f in dataclasses.fields(cfg)}
+    return type(model)(dataclasses.replace(
+        cfg, **{k: v for k, v in changes.items() if k in known}))
 
 
 def cast_params(params, dtype):

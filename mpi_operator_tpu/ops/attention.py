@@ -900,6 +900,233 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
                         kv_index, ps, interpret)
 
 
+# ---------------------------------------------------------------------------
+# Latent (MLA) paged attention: one cached row a position, shared by all heads
+# ---------------------------------------------------------------------------
+# A latent page holds, per position, `c_kv` (after its norm and scale,
+# `rank` values) beside the rotated shared key part `k_pe`, the row padded
+# with zeros to whole 128-lane tiles: [NP, ps, W], W = `mla_row_width`.
+# With the key/value up-projection absorbed into the query and the
+# output, every head attends the SAME rows: an MQA of group H whose keys
+# are the whole row and whose values are its first `rank` columns — so a
+# page is read once, for scores and for p.v alike.
+
+def einsum_f32(spec: str, a, b):
+    """The float32 product of two operands in their own (bfloat16) type:
+    what the MXU gives with `preferred_element_type`. XLA's CPU backend
+    has no bf16 x bf16 = f32 dot, so there the operands are widened first
+    — the same products, summed in float32 either way."""
+    if jax.default_backend() == "cpu":
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def mla_row_width(rank: int, rope: int) -> int:
+    """Columns of a latent cache row: `rank + rope` rounded up to whole
+    128-lane tiles (576 -> 640). A row-major bfloat16 array on the chip
+    is tiled (8, 128)(2, 1): a 576-wide row occupies five lane tiles
+    whether the shape says so or not, and a shape that says 576 makes
+    the compiler keep the pool pages-minor instead — and copy all of it
+    into the kernel's layout and back, every step (what the per-head
+    pool of 64-wide rows pays, `PERF.md` section 5). The pad columns are
+    zeros in the cache and in the query, and add nothing to a score."""
+    return -(-(rank + rope) // LANES) * LANES
+
+
+def mla_paged_attend(q, pool, positions, page_table, rank, sm_scale):
+    """Absorbed latent attention over the page pool in plain jax: the
+    dense form the kernel is compared with, the path of every
+    multi-token call (prefill chunks) and of a decode step that asked
+    for no kernel.
+
+    q [B, S, H, W]: per head, q_nope through the absorbed key projection
+    (`rank` columns), the rotated q_pe, zeros up to W; pool [NP, ps, W];
+    positions [B, S] absolute (a position >= nblk * ps marks a query
+    whose result nobody reads); page_table [B, nblk]. Returns
+    u [B, S, H, rank] = softmax(q . row) . c_kv, before the absorbed
+    value projection.
+
+    Pages are walked in logical order with an online softmax, up to the
+    furthest block any live query reaches — a chunk of a 200-token
+    prompt walks four pages, not the table's hundred — so neither scores
+    nor gathered rows over the whole logical length ever exist."""
+    B, S, H, W = q.shape
+    NP, ps, _ = pool.shape
+    nblk = page_table.shape[1]
+    pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B, S))
+    blocks = jnp.max(jnp.where(pos < nblk * ps, pos // ps + 1, 0))
+    pt = jnp.asarray(page_table, jnp.int32)
+
+    def attend(q, qpos, pt):
+        """q [G, S*H, W], qpos [G, S*H], pt [G, nblk] -> [G, S*H, rank]."""
+        def body(j, carry):
+            m, l, acc = carry
+            page = pool[pt[:, j]]                             # [G, ps, W]
+            s = einsum_f32("bqw,bkw->bqk", q, page) * sm_scale
+            cols = j * ps + jnp.arange(ps, dtype=jnp.int32)
+            s = jnp.where(cols[None, None, :] <= qpos[:, :, None], s,
+                          NEG_INF)
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + p.sum(-1, keepdims=True)
+            acc = acc * alpha + einsum_f32(
+                "bqk,bkr->bqr", p.astype(pool.dtype), page[..., :rank])
+            return m_new, l, acc
+
+        G, Q = qpos.shape
+        init = (jnp.full((G, Q, 1), NEG_INF, jnp.float32),
+                jnp.zeros((G, Q, 1), jnp.float32),
+                jnp.zeros((G, Q, rank), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
+        return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+
+    q = q.reshape(B, S * H, W)
+    qpos = jnp.repeat(pos, H, axis=1)                         # [B, S*H]
+    # the float32 accumulator is [rows, S*H, rank]: a 128-token chunk of
+    # 64 rows and 64 heads would hold a gigabyte of it, so rows go through
+    # in groups of about `_MLA_QUERY_ROWS` queries
+    G = max(1, _MLA_QUERY_ROWS // (S * H))
+    if B > G and B % G == 0:
+        grouped = lambda x: x.reshape((B // G, G) + x.shape[1:])  # noqa: E731
+        u = jax.lax.map(lambda a: attend(*a),
+                        (grouped(q), grouped(qpos), grouped(pt)))
+    else:
+        u = attend(q, qpos, pt)
+    return u.reshape(B, S, H, rank)
+
+
+#: queries (rows x positions x heads) one pass of `mla_paged_attend` holds
+_MLA_QUERY_ROWS = 65536
+
+
+def _mla_decode_kernel(cur_ref, pt_ref, q_ref, *rest, sm_scale, ps, rank,
+                       pp):
+    """One decode step for one (row, group of `pp` pages): grid
+    (B, nblk // pp), pages innermost; the `pp` page blocks of a step are
+    the same pool under `pp` index maps. All H heads of the row share
+    each page: scores [H, ps] from the absorbed query against the whole
+    row, p.v against its first `rank` columns. Pages past the row's
+    cursor are skipped and their index map pins to the boundary page, as
+    in `_decode_kernel`."""
+    page_refs, (o_ref, acc_ref, m_ref, l_ref) = rest[:pp], rest[pp:]
+    g = pl.program_id(1)
+    cur = cur_ref[pl.program_id(0)]
+
+    @pl.when(g == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    for i, page_ref in enumerate(page_refs):
+        first = (g * pp + i) * ps
+
+        @pl.when(first <= cur)
+        def _attend(page_ref=page_ref, first=first):
+            page = page_ref[0]                                # [ps, W]
+            s = jax.lax.dot_general(
+                q_ref[0], page, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [H, ps]
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(first + cols <= cur, s, NEG_INF)
+            m_prev = m_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+                p.astype(page.dtype), page[:, :rank],
+                preferred_element_type=jnp.float32)
+            m_ref[:, :1] = m_new
+
+    @pl.when(g == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def mla_pages_per_step(nblk: int, most: int = 10) -> int:
+    """Pages one grid step of the latent decode kernel takes: the largest
+    divisor of the table's length up to `most`. A grid step costs 0.3-0.5
+    us whatever it moves, a dead one (past the row's cursor) too, and a
+    64-position bfloat16 page is 80 KB, a tenth of a microsecond of HBM
+    time: with four pages a step, 64 rows of a 100-page table were 12 800
+    steps a step of the model over 8 sublayers and 6.7 ms, most of it
+    dead steps (my chip run, PR 27); ten a step are 5 120. Ten
+    double-buffered pages are 1.6 MB of VMEM; a page past the cursor is
+    pinned to the boundary page and moves nothing."""
+    return max(d for d in range(1, most + 1) if nblk % d == 0)
+
+
+def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
+                               sm_scale: float,
+                               interpret: Optional[bool] = None):
+    """Single-step absorbed latent attention over the page pool — the
+    decode fast path of a latent cache.
+
+    q [B, H, W] (see `mla_paged_attend`), pool [NP, ps, W], cache_index
+    int32 [B] (row b attends positions <= cursor(b)), page_table int32
+    [B, nblk]. Returns u [B, H, rank]. Grid (B, nblk // pp), `pp` =
+    `mla_pages_per_step(nblk)`."""
+    B, H, W = q.shape
+    NP, ps, _ = pool.shape
+    if pool.shape[2] != W or rank > W:
+        raise ValueError(f"q rows of {W} and rank {rank} do not fit the "
+                         f"pool's rows of {pool.shape[2]}")
+    if page_table.ndim != 2 or page_table.shape[0] != B:
+        raise ValueError(f"page_table must be [B={B}, nblk], got shape "
+                         f"{page_table.shape}")
+    nblk = page_table.shape[1]
+    interpret = _resolve_interpret(interpret)
+    cur = jnp.asarray(cache_index, jnp.int32)
+    if cur.shape != (B,):
+        raise ValueError(f"cache_index must be [B]={B} per-row cursors, "
+                         f"got shape {cur.shape}")
+    pt = jnp.asarray(page_table, jnp.int32)
+    mesh = _kernel_mesh(q)
+    if mesh is not None:
+        rows = ("rows", None, None)
+        return _per_device(
+            functools.partial(mla_paged_decode_attention, rank=rank,
+                              sm_scale=sm_scale, interpret=interpret),
+            mesh, B, 1, (q, pool, cur, pt),
+            (rows, (None, None, None), ("rows",), ("rows", None)), rows)
+    pp = mla_pages_per_step(nblk)
+    note_traced("decode", f"pallas_mla_paged[pp={pp}]")
+
+    def page_spec(i):
+        def index(b, g, cur_ref, pt_ref):
+            last = jnp.minimum(cur_ref[b] // ps, nblk - 1)
+            return (pt_ref[b, jnp.minimum(g * pp + i, last)], 0, 0)
+        return pl.BlockSpec((1, ps, W), index)
+
+    def row_spec(minor):
+        return pl.BlockSpec((1, H, minor), lambda b, g, *pre: (b, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, nblk // pp),
+        in_specs=[row_spec(W)] + [page_spec(i) for i in range(pp)],
+        out_specs=row_spec(rank),
+        scratch_shapes=[
+            pltpu.VMEM((H, rank), jnp.float32),   # acc
+            pltpu.VMEM((H, LANES), jnp.float32),  # running max m
+            pltpu.VMEM((H, LANES), jnp.float32),  # running sum l
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, sm_scale=sm_scale, ps=ps,
+                          rank=rank, pp=pp),
+        grid_spec=grid_spec,
+        out_shape=_out_struct((B, H, rank), q.dtype, q, pool),
+        interpret=interpret,
+    )(cur, pt, q, *([pool] * pp))
+
+
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
-           "decode_head_block", "paged_decode_attention", "record_traced",
-           "note_traced", "traced_name"]
+           "decode_head_block", "paged_decode_attention",
+           "mla_paged_attend", "mla_paged_decode_attention",
+           "mla_pages_per_step", "mla_row_width", "einsum_f32",
+           "record_traced", "note_traced", "traced_name"]

@@ -1,0 +1,168 @@
+"""An expert layer that holds a share of the experts — the serving half of
+expert parallelism, on one chip.
+
+`MoeMlp` (moe.py) is the trainer's layer: every expert lives where the
+layer does (or GSPMD moves tokens over `ep`), and capacity dispatch may
+drop. A served model with hundreds of experts is spread over the chips
+that share a layer; each is told which experts it holds, routes over ALL
+the router's outputs, and computes the part of the result that its own
+experts give. This module is that part, with no stand-in for the other
+chips or for the exchange between them: what the absent experts would add
+is left out.
+
+Routing (`route`): softmax over every router output in float32; the
+picks are the top `top_k` of `p + bias` (the score-correction bias moves
+the CHOICE only); a pick's weight is `scale * p`, not renormalised over
+the picks. Outputs at or past `n_real` are identity ("zero-compute")
+experts: `E_i(y) = y`, so their whole contribution is `y` times the sum
+of their weights (`identity_weight`).
+
+Two forms of the held experts' part, equal in what they compute and
+neither dropping a token (`held_experts`):
+
+  masked   every held expert runs the call's whole row block and the gate
+           (zero for rows not routed to it) is applied in the combine. At
+           a decode step's rows an expert's three matmuls are bound by
+           reading its weights, so this costs what a grouped
+           form costs whenever the expert is hit — and its device time is
+           a function of SHAPES, not of the routing: a step takes as long
+           whatever the router picked.
+  grouped  the assignments to held experts are sorted by expert, each
+           expert's group padded to whole blocks of `block_rows`, and a
+           loop over the blocks that exist gathers a block's rows,
+           runs its expert and scatter-adds the weighted result. Static
+           shapes, a trip count that follows the routing: for prefill
+           chunks, where thousands of tokens give a held expert a small
+           share each and the masked form would multiply the FLOPs by the
+           experts held.
+
+Which runs is decided by the tokens in the call (`MASKED_MAX_TOKENS`),
+something the code can see, not by a flag.
+"""
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import einsum_f32
+
+#: up to this many tokens a call takes the masked form. An expert's SwiGLU
+#: is 6 FLOP a weight byte-pair and row; under about 240 rows (the chip's
+#: FLOP per byte) reading the weights bounds it, whatever rows are masked.
+MASKED_MAX_TOKENS = 256
+
+
+def route(logits, bias, top_k: int, scale: float):
+    """([T, k] picked output ids, [T, k] weights) from [T, n_out] router
+    logits (any float type; the softmax runs in float32)."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, idx = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)
+    return idx, scale * jnp.take_along_axis(p, idx, axis=-1)
+
+
+def held_gates(idx, w, first: int, count: int):
+    """[T, count] gate of each held expert for each token: the pick's
+    weight where output `first + e` was picked, else zero."""
+    hit = (idx - first)[..., None] == jnp.arange(count)       # [T, k, count]
+    return jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
+
+
+def identity_weight(idx, w, n_real: int):
+    """[T] summed weight of the picks that fell on identity experts."""
+    return jnp.sum(jnp.where(idx >= n_real, w, 0.0), axis=-1)
+
+
+def pick_counts(idx, first: int, count: int, n_real: int):
+    """(picks on held experts, picks on identity experts, the largest
+    held expert's load) of one call, int32 scalars."""
+    load = jnp.sum(((idx - first)[..., None] == jnp.arange(count))
+                   .astype(jnp.int32), axis=(0, 1))           # [count]
+    return (jnp.sum(load), jnp.sum((idx >= n_real).astype(jnp.int32)),
+            jnp.max(load))
+
+
+def _swiglu(x, gate, up, down):
+    h = jax.nn.silu(x @ gate) * (x @ up)
+    return h @ down
+
+
+def masked_experts(y, gates, gate, up, down):
+    """The masked form: y [T, H], gates [T, E] float32, stacked weights
+    gate/up [E, H, F], down [E, F, H]. Returns [T, H] float32."""
+    g = jnp.einsum("th,ehf->etf", y, gate)
+    u = jnp.einsum("th,ehf->etf", y, up)
+    o = einsum_f32("etf,efh->eth", jax.nn.silu(g) * u, down)
+    return jnp.einsum("eth,te->th", o, gates)
+
+
+def grouped_experts(y, idx, w, first: int, gate, up, down,
+                    block_rows: int = 256):
+    """The grouped form over the same operands as `held_experts`."""
+    T, H = y.shape
+    E = gate.shape[0]
+    k = idx.shape[1]
+    A = T * k
+    e = (idx - first).reshape(A)
+    key = jnp.where((e >= 0) & (e < E), e, E)         # not held: sorts last
+    order = jnp.argsort(key, stable=True)
+    token = order // k
+    weight = w.reshape(A)[order]
+    sizes = jnp.sum((key[:, None] == jnp.arange(E)).astype(jnp.int32), 0)
+    starts = jnp.cumsum(sizes) - sizes                # group starts, sorted
+    blocks = -(-sizes // block_rows)
+    block_ends = jnp.cumsum(blocks)                   # [E]
+    lane = jnp.arange(block_rows)
+
+    def body(b, out):
+        ex = jnp.searchsorted(block_ends, b, side="right")
+        within = b - (block_ends[ex] - blocks[ex])
+        row = starts[ex] + within * block_rows + lane
+        ok = row < starts[ex] + sizes[ex]
+        row = jnp.minimum(row, A - 1)
+        tok = token[row]
+        o = _swiglu(y[tok], gate[ex], up[ex], down[ex]).astype(jnp.float32)
+        o = o * jnp.where(ok, weight[row], 0.0)[:, None]
+        return out.at[tok].add(o)
+
+    return jax.lax.fori_loop(0, block_ends[-1], body,
+                             jnp.zeros((T, H), jnp.float32))
+
+
+def held_experts(y, idx, w, first: int, gate, up, down) -> jax.Array:
+    """sum_i w_i E_i(y) over the picks that fell on the held experts
+    `first .. first + E - 1`: [T, H] float32, dropless on either form."""
+    if y.shape[0] <= MASKED_MAX_TOKENS:
+        return masked_experts(y, held_gates(idx, w, first, gate.shape[0]),
+                              gate, up, down)
+    return grouped_experts(y, idx, w, first, gate, up, down)
+
+
+def shortcut_experts(y, router, bias, gate, up, down, *, held: Tuple[int, int],
+                     n_real: int, top_k: int, scale: float):
+    """The whole layer on this chip for y [T, H]: the held experts' part
+    plus the identity experts' (computed here in full; in the deployment
+    the token's home chip does). Returns ([T, H] in y's type, the
+    `pick_counts` of the call)."""
+    first, count = held
+    if gate.shape[0] != count:
+        raise ValueError(f"holds {gate.shape[0]} experts' weights but was "
+                         f"told held={held}")
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("th,hn->tn", y.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        idx, w = route(logits, bias, top_k, scale)
+        counts = pick_counts(idx, first, count, n_real)
+    with jax.named_scope("moe.experts"):
+        out = held_experts(y, idx, w, first, gate, up, down)
+    with jax.named_scope("moe.identity"):
+        out = out + identity_weight(idx, w, n_real)[:, None] \
+            * y.astype(jnp.float32)
+    return out.astype(y.dtype), counts
+
+
+__all__ = ["MASKED_MAX_TOKENS", "route", "held_gates", "identity_weight",
+           "pick_counts", "masked_experts", "grouped_experts",
+           "held_experts", "shortcut_experts"]

@@ -160,6 +160,13 @@ class EngineConfig:
     speculative: Optional[str] = None     # None | "ngram" | "draft"
     draft_k: int = 4
     spec_ngram: int = 3
+    # the engine takes the tree it is given as its own: a tree whose
+    # floating leaves are all in the served type already is used where it
+    # lies, not copied by the cast — weights that fill most of a chip
+    # cannot be on it twice. The caller gives the tree up (the engine may
+    # delete its buffers); anything not yet in the served type is cast as
+    # always.
+    own_params: bool = False
 
 
 @dataclasses.dataclass
@@ -363,7 +370,11 @@ class ServingEngine:
                                      f"max_len={mcfg.max_len}")
                 NP = cfg.num_pages
                 if NP is None:
-                    # contiguous layout's byte budget, plus the trash page
+                    # as many positions as the contiguous layout's slots x
+                    # max_len, plus the trash page. In positions, not
+                    # bytes: a page's bytes follow the kind of cache the
+                    # model keeps (K and V a head, or one latent row —
+                    # `page_bytes()` counts them from the cache itself)
                     NP = cfg.slots * (mcfg.max_len // ps) + 1
                 self.page_allocator: Optional[PageAllocator] = \
                     PageAllocator(NP, ps)
@@ -397,7 +408,11 @@ class ServingEngine:
             # not synced: host-born weights may copy to the device while the
             # cache program below is traced; serve.init_cache waits for both
             with span("serve.cast_params"):
-                self.params = self._cast(params)
+                served = all(
+                    x.dtype == dt for x in jax.tree.leaves(params)
+                    if jnp.issubdtype(x.dtype, jnp.floating))
+                self.params = (params if cfg.own_params and served
+                               else self._cast(params))
             # where the persistent host-born operand (_prev_tok) must live so
             # that the FIRST decode step keys the same compiled program as
             # every later one, whose prev_tok is the previous step's output:
@@ -433,6 +448,25 @@ class ServingEngine:
             nblk = mcfg.max_len // ps if cfg.paged else 0
             self._nblk = nblk
 
+            # the model's own head where it has one (an untied matrix);
+            # CausalLM's is its tied table
+            head = getattr(dmodel, "head_logits", None)
+            if head is None:
+                from ..models.transformer import _head_matmul
+
+                def head(params, h):
+                    return _head_matmul(h, params["wte"]["embedding"])
+            # what a decode call of this model counts for itself, by name
+            # (an expert layer's routing): summed over the layers inside
+            # the step and fetched with its tokens, never a sync of its own
+            names = self._step_counters = tuple(
+                getattr(dmodel, "STEP_COUNTERS", ()))
+            counted = ["cache", "counters"] if names else ["cache"]
+
+            def step_counts(vars_):
+                return sum(jax.tree.leaves(vars_["counters"])) if names \
+                    else None
+
             def init_cache(params):
                 # a zero-token step apply materializes the cache collection
                 # at its serving shape; the hidden-state output is discarded
@@ -466,10 +500,8 @@ class ServingEngine:
                 # is shared so there is no row to slice out, and every
                 # waiting slot whose next chunk shares this bucket advances
                 # in the same program. Non-member rows carry zero tokens at
-                # their OWN cursor: their junk K/V lands exactly where their
-                # next real write (chunk or decode step) overwrites it, the
-                # same argument as the fixed-shape decode step's masked rows
-                # (free rows' tables are all trash-page entries).
+                # max_len, past the logical cache: the page scatter drops
+                # their writes (transformer.py, longcat.py).
                 positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
                 _, vars_ = dmodel.apply(
                     {"params": params, "cache": cache}, tokens,
@@ -484,16 +516,15 @@ class ServingEngine:
                 # (prev_tok = last step's output, rows with use_prev) or from
                 # the host (bonus token after prefill) — the chain is what
                 # lets the host dispatch step N+1 without reading step N.
-                from ..models.transformer import _head_matmul
                 tokens = jnp.where(use_prev, prev_tok, host_toks)
                 h, vars_ = dmodel.apply(
                     {"params": params, "cache": cache}, tokens[:, None],
                     positions=positions[:, None], with_head=False,
-                    mutable=["cache"])
-                logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
+                    mutable=counted)
+                logits = head(params, h[:, 0])
                 tok, logp = sample_slots(logits, rng, temperature, top_k,
                                          top_p, mode=mode)
-                return vars_["cache"], pin_tok(tok), logp
+                return vars_["cache"], pin_tok(tok), logp, step_counts(vars_)
 
             def step_paged(params, cache, prev_tok, host_toks, use_prev,
                            positions, rng, temperature, top_k, top_p, pages,
@@ -501,16 +532,15 @@ class ServingEngine:
                 # the decode step with the per-slot page tables as one extra
                 # [S, nblk] operand — table churn (admit/retire) never
                 # recompiles, exactly like cursor churn
-                from ..models.transformer import _head_matmul
                 tokens = jnp.where(use_prev, prev_tok, host_toks)
                 h, vars_ = dmodel.apply(
                     {"params": params, "cache": cache}, tokens[:, None],
                     positions=positions[:, None], with_head=False,
-                    mutable=["cache"], pages=pages)
-                logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
+                    mutable=counted, pages=pages)
+                logits = head(params, h[:, 0])
                 tok, logp = sample_slots(logits, rng, temperature, top_k,
                                          top_p, mode=mode)
-                return vars_["cache"], pin_tok(tok), logp
+                return vars_["cache"], pin_tok(tok), logp, step_counts(vars_)
 
             def _verify_targets(h, params, rng, temperature, top_k, top_p,
                                 mode):
@@ -521,10 +551,8 @@ class ServingEngine:
                 # greedy targets the drafts are checked against — argmax in
                 # float32, bitwise the same reduction sample_slots runs for
                 # a temperature-0 row, which is the token-exactness hinge.
-                from ..models.transformer import _head_matmul
                 Sv, W, E = h.shape
-                logits = _head_matmul(h.reshape(Sv * W, E),
-                                      params["wte"]["embedding"])
+                logits = head(params, h.reshape(Sv * W, E))
                 logits = logits.reshape(Sv, W, -1)
                 tok0, lp0 = sample_slots(logits[:, 0], rng, temperature,
                                          top_k, top_p, mode=mode)
@@ -689,6 +717,36 @@ class ServingEngine:
             "cast": self._cast._cache_size(),
         }
 
+    def decode_step_scopes(self) -> Dict[str, str]:
+        """{instruction of the compiled greedy decode step: the
+        `jax.named_scope` path it was traced under}, from the optimised
+        HLO. A device trace names an operation by its instruction
+        (`fusion.412`), not by the scope it came from; with this map a
+        reader can add up a step's device time by scope (`mla.`, `moe.`).
+        Lowers and compiles the step again (a load, where the persistent
+        cache has it): for after a measured window, never inside one."""
+        from ..telemetry.hlo_names import instruction_scopes
+        cfg = self.config
+        S = cfg.slots
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)     # noqa: E731
+        f32 = lambda *shape: jnp.zeros(shape, jnp.float32)   # noqa: E731
+        extra = (i32(S, self._nblk),) if cfg.paged else ()
+        lowered = self._step.lower(
+            self.params, self.cache, self._prev_tok, i32(S),
+            jnp.zeros((S,), bool), i32(S), self._base_rng, f32(S), i32(S),
+            f32(S), *extra, "greedy")
+        return instruction_scopes(lowered.compile().as_text())
+
+    def page_bytes(self) -> int:
+        """Bytes one page holds over every layer, counted from the cache
+        the model made: K and V a head (and int8 scales) for a per-head
+        cache, one latent row a position for a latent one."""
+        if self.page_allocator is None:
+            raise ValueError("page_bytes() of an engine that is not paged")
+        NP = self.page_allocator.num_pages
+        return sum(x.nbytes for x in jax.tree.leaves(self.cache)
+                   if x.shape[0] == NP) // NP
+
     def spec_stats(self) -> Dict[str, float]:
         """Speculation accounting since construction/reset().
         effective_tokens_per_step is tokens emitted PER ROW per verify
@@ -769,17 +827,18 @@ class ServingEngine:
         """Paged prefill: advance EVERY waiting slot whose next chunk
         shares the lead's bucket in one [S, C] program — deeper queues
         amortize the same ≤3 compiled widths instead of serializing one
-        chunk per loop iteration. Bound non-member rows run zero tokens
-        at their own cursor (junk lands at their next write offset)."""
+        chunk per loop iteration. Rows that are no member of the call run
+        zero tokens at `max_len`: a position past the logical cache, whose
+        writes the page scatter drops (as a verify step's padded tail),
+        and which a model that walks only the pages its queries reach
+        (the latent cache) does not walk for."""
         size = lead.chunks[0][1]
         with span("serve.prefill"):
             batch = [st for st in self.scheduler.active
                      if st.prefilling and st.chunks[0][1] == size]
             toks = np.zeros((self.config.slots, size), np.int32)
-            starts = np.zeros((self.config.slots,), np.int32)
-            for st in self.slots.states:
-                if st is not None:
-                    starts[st.slot] = st.pos
+            starts = np.full((self.config.slots,),
+                             self.model_config.max_len, np.int32)
             done = []
             for st in batch:
                 w, _ = st.chunks.pop(0)
@@ -853,7 +912,7 @@ class ServingEngine:
             step_t0 = time.perf_counter()
             extra = ((jnp.asarray(self._page_table_array()),)
                      if self.config.paged else ())
-            self.cache, out_tok, out_logp = self._step(
+            self.cache, out_tok, out_logp, out_counts = self._step(
                 self.params, self.cache, self._prev_tok,
                 jnp.asarray(toks), jnp.asarray(use_prev), jnp.asarray(pos),
                 rng, jnp.asarray(temps), jnp.asarray(top_ks),
@@ -872,7 +931,7 @@ class ServingEngine:
                 # land on top of (never under) this request's K/V.
                 self.slots.release(st)
                 st.slot_released = True
-        return out_tok, out_logp, consumers, step_t0, sp.id
+        return out_tok, out_logp, consumers, step_t0, sp.id, out_counts
 
     def _plan_drafts(self) -> Dict[int, List[int]]:
         """Host-side proposal pass: {slot: draft tokens} for every row
@@ -1063,14 +1122,19 @@ class ServingEngine:
         by EOS or length into `results`. A consumer already done at
         sync time took its one post-EOS junk step; its junk token is
         discarded here."""
-        dev_tok, dev_logp, consumers, step_t0, dispatch_id = pending
+        dev_tok, dev_logp, consumers, step_t0, dispatch_id, dev_counts = \
+            pending
         tel = self.telemetry
         with span("serve.sync", caused_by=dispatch_id):
             gap_t0 = time.perf_counter()
             out_tok = np.asarray(dev_tok)        # host sync: stream point
             out_logp = np.asarray(dev_logp)
+            counts = None if dev_counts is None else np.asarray(dev_counts)
             t_sync = time.perf_counter()
         if tel is not None:
+            if counts is not None:
+                tel.observe_step_counters(
+                    dict(zip(self._step_counters, counts.tolist())))
             # how long the host was BLOCKED on the device — near zero
             # when the dispatched work fully hides under host scheduling
             tel.host_gap_seconds.observe(t_sync - gap_t0)

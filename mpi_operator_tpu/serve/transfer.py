@@ -5,10 +5,13 @@ and decode on SEPARATE device pools; when a prompt finishes prefilling,
 its KV lives in the prefill pool's page arrays and must move into the
 decode pool's. Because the paged cache layout puts the page axis first
 on EVERY leaf — cached_key/cached_value are [num_pages, KV, page_size,
-D] and the int8 scale planes are [num_pages, KV, page_size] — one
-generic axis-0 gather/scatter over the cache pytree moves a page list
-uniformly for all dtypes: int8 payloads travel WITH their scale rows,
-nothing is dequantized in flight.
+D], the int8 scale planes are [num_pages, KV, page_size], and a latent
+cache's pool (models/longcat.py) is [num_pages, page_size, W], one row a
+position — one generic axis-0 gather/scatter over the cache pytree
+moves a page list uniformly for every kind of cache and all dtypes:
+int8 payloads travel WITH their scale rows, nothing is dequantized in
+flight, and a latent page moves as a per-head one does
+(tests/test_longcat.py).
 
 Three dispatches per handoff, all async:
 

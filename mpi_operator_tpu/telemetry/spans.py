@@ -41,7 +41,24 @@ The trees the program records:
 
   An attribute is kept only where something reads it (PERF.md section 3
   names the reader of each); the counts a tick could carry are
-  `ServeTelemetry`'s gauges already.
+  `ServeTelemetry`'s gauges already — and so are the counts a decode step
+  of the served model makes for itself: an expert layer's
+  `moe_held_picks`, `moe_identity_picks`, `moe_load_max`, summed over the
+  layers inside the step, fetched with its tokens under the same
+  `serve.sync` and observed on `ServeTelemetry.step_counters`, not set as
+  span attributes.
+
+  on the device (`jax.named_scope`, in the compiled programs; a device
+  trace shows a Pallas kernel under its scope's name, and
+  `ServingEngine.decode_step_scopes()` names every other instruction of
+  the decode step by the scope it was traced under)
+    mla.project      a latent-attention sublayer's q and kv projections
+    mla.cache_write  its rows into the latent page pool
+    mla.attend       the absorbed attention (the kernel, or the dense form)
+    mla.out          the output projection
+    moe.route        router logits, picks, weights, the step's counters
+    moe.experts      the held experts' part (masked or grouped)
+    moe.identity     the identity experts' part
 
   set-up
     serve.engine_init > serve.cast_params, serve.init_cache

@@ -81,6 +81,12 @@ Serve series (ServingEngine):
   spec_tokens_per_step    histogram — tokens emitted per row per verify
                                       step (accepted + the model's own
                                       bonus token; >1 is the speedup)
+  step_<name>             histogram — what a decode step of the served
+                                      model counts for itself, summed
+                                      over its layers and fetched with
+                                      the step's tokens: moe_held_picks,
+                                      moe_identity_picks, moe_load_max
+                                      (an expert layer that holds a share)
 
 Disaggregated serving creates one ServeTelemetry per pool with
 ``labels={"pool": "prefill"|"decode"}`` on a shared registry — the same
@@ -365,6 +371,23 @@ class ServeTelemetry:
             "tpu_worker_spec_tokens_per_step",
             "tokens emitted per row per verify step (bonus included)",
             lo=1.0, hi=64.0, labels=labels)
+        #: {name: histogram} of what a decode step of the served model
+        #: counts for itself (`observe_step_counters`)
+        self.step_counters: Dict[str, object] = {}
+
+    def observe_step_counters(self, values: Dict[str, float]) -> None:
+        """One decode step's model-side counts, as fetched with its
+        tokens — an expert layer's `moe_held_picks`,
+        `moe_identity_picks`, `moe_load_max` (models/longcat.py). A
+        histogram a name, made when the name is first seen."""
+        for name, value in values.items():
+            hist = self.step_counters.get(name)
+            if hist is None:
+                hist = self.step_counters[name] = self.registry.histogram(
+                    f"tpu_worker_step_{name}",
+                    f"{name} of one decode step, summed over the layers",
+                    lo=1.0, hi=1e5, labels=self.labels)
+            hist.observe(value)
 
 
 class RouterTelemetry:

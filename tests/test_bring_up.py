@@ -313,6 +313,20 @@ def test_kernel_parity_harness_runs_every_kernel_of_the_two_legs():
     assert all(r["ok"] for r in records), records
 
 
+def test_kernel_parity_harness_runs_the_latent_decode_kernel_when_asked():
+    from mpi_operator_tpu.examples.kernel_parity import (MLA_CASE,
+                                                         mla_decode_case)
+
+    rec = mla_decode_case(
+        slots=3, max_len=64, page_size=8, prefilled=40, hidden_size=48,
+        num_heads=4, q_lora_rank=12, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8)
+    assert rec["kernel"] == "mla_paged_decode_attention"
+    assert rec["traced"] == "pallas_mla_paged[pp=8]"
+    assert rec["max_rel_err"] <= 2e-2
+    assert MLA_CASE["max_len"] % MLA_CASE["page_size"] == 0
+
+
 # -- kernels on a multi-device mesh -------------------------------------------
 # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
 # automatically partitioned", first seen on the four-chip host). jax.export
