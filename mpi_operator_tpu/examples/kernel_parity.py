@@ -8,7 +8,8 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
 
   flash forward and gradients (dq, dk, dv)   vs `dense_attention`
   decode_attention, per-row cursor vector     vs the dense branch of
-  paged_decode_attention                         `Attention._decode_attend`
+  paged_decode_attention (the page pool as       `Attention._decode_attend`
+  rows [pages, page, KV * 2D])
   the int8-cache variants of both decode kernels
   mla_paged_decode_attention (absorbed latent   vs `mla_paged_attend`, the
   attention, LongCat-Flash's published widths)     dense form of

@@ -458,14 +458,21 @@ class Attention(nn.Module):
         shapes the kernel cannot tile).
 
         With cfg.decode_page_size the slot rows stop owning contiguous
-        cache: the cache variables become a POOL of pages
-        [num_pages, KV, page_size, D] and `pages` ([B, L // page_size])
-        maps each row's logical KV blocks to physical pages. Writes
-        scatter to (pages[pos // ps], pos % ps); the dense oracle gathers
-        the table back into the logical [B, KV, L, D] layout, and the
-        Pallas path resolves pages per block inside the kernel's index
-        maps (ops.attention.paged_decode_attention). Page 0 is the trash
-        sink for unallocated table entries."""
+        cache: the cache becomes ONE pool of pages, `cached_kv`
+        [num_pages, page_size, KV * 2D] — a row a position, head h's K
+        and V side by side in the lane-aligned columns
+        [2D*h, 2D*h + 2D) (ops.attention.kv_row_width: the form the chip
+        keeps row-major where it lies, so the donated pool is aliased
+        through a step and never copied) — and `pages`
+        ([B, L // page_size]) maps each row's logical KV blocks to
+        physical pages. A call writes its rows with one flat row scatter
+        at pages[pos // ps] * ps + pos % ps; the dense oracle gathers
+        the table back into the logical [B, L, KV, D] keys and values,
+        and the Pallas path resolves pages per block inside the kernel's
+        index maps (ops.attention.paged_decode_attention). An int8
+        pool's float32 scale planes are [num_pages, KV, page_size]. Page
+        0 is the trash sink for unallocated table entries; a tp mesh
+        splits the pool over the heads' columns."""
         cfg = self.config
         B, S, H, D = q.shape
         KV = k.shape[2]
@@ -506,19 +513,18 @@ class Attention(nn.Module):
                 phys = jnp.take_along_axis(pt, blk, axis=1)   # [B, S]
                 # junk positions past the logical cache (padded prefill
                 # tails, a retiring row's one post-EOS step) get an
-                # out-of-range page id: JAX scatters DROP out-of-bounds
+                # out-of-range index: scatters DROP out-of-bounds
                 # updates, so they never land anywhere — stronger than
                 # the contiguous path's clamp-to-last-row, which paging
                 # can't afford (a clamped write could land inside a
                 # SHARED prefix page)
                 phys = jnp.where(pos < L, phys, NP)
                 off = pos % ps
+                flat = (phys * ps + off).reshape(-1)
 
-                def upd4(c, u):   # pool [NP, KV, ps, D] ← [B, KV, S, D]
-                    # two advanced indices split by slices put the index
-                    # dims in front: target block is [B, S, KV, D]
-                    return c.at[phys, :, off, :].set(
-                        u.transpose(0, 2, 1, 3))
+                def upd_rows(c, u):   # pool [NP, ps, W] ← rows [B, S, W]
+                    return c.reshape(NP * ps, -1).at[flat].set(
+                        u.reshape(B * S, -1), mode="drop").reshape(c.shape)
 
                 def upd3(c, u):   # pool [NP, KV, ps] ← [B, KV, S]
                     return c.at[phys, :, off].set(u.transpose(0, 2, 1))
@@ -571,21 +577,9 @@ class Attention(nn.Module):
         if cfg.pos_embedding == "rope":
             q = rope(q, pos)
             k = rope(k, pos)
-        # incoming projections are [B, S, KV, D]; the cache wants the
-        # kv-head-major [B, KV, S, D] slab
-        k_t = k.transpose(0, 2, 1, 3)
-        v_t = v.transpose(0, 2, 1, 3)
-        if paged:
-            kv_shape, sc_shape = (NP, KV, ps, D), (NP, KV, ps)
-            # the page pool is GLOBAL state shared by all rows — there is
-            # no batch axis to shard, so skip the per-row cache constraint
-            # and let GSPMD place (replicate) it
-            constrain = lambda x_: x_                       # noqa: E731
-        else:
-            kv_shape, sc_shape = (B, KV, L, D), (B, KV, L)
-            constrain = _constrain_cache
         k_scale = v_scale = None
-        if cfg.kv_cache_dtype == "int8":
+        quantized = cfg.kv_cache_dtype == "int8"
+        if quantized:
             # symmetric per-vector int8: scale = max|x|/127 over the head
             # dim, stored alongside. The cache is the decode bandwidth
             # bottleneck (every step re-reads the filled prefix), so
@@ -596,32 +590,40 @@ class Attention(nn.Module):
                 scale = jnp.maximum(scale, 1e-8)
                 q8 = jnp.clip(jnp.round(x.astype(jnp.float32) / scale),
                               -127, 127).astype(jnp.int8)
-                return q8, scale[..., 0]
+                return q8, scale[..., 0].transpose(0, 2, 1)   # [B, KV, S]
 
+            k, k_sc = quant(k)
+            v, v_sc = quant(v)
+        if paged:
+            from ..ops.attention import kv_row_width, pack_kv_rows
+            # the page pool is GLOBAL state shared by all rows — there is
+            # no batch axis to shard, so no per-row cache constraint:
+            # GSPMD places (replicates) it
+            ckv = self.variable("cache", "cached_kv", jnp.zeros,
+                                (NP, ps, kv_row_width(KV, D)), k.dtype)
+            ckv.value = upd_rows(ckv.value, pack_kv_rows(k, v))
+            sc_shape = (NP, KV, ps)
+        else:
+            # incoming projections are [B, S, KV, D]; the contiguous
+            # cache wants the kv-head-major [B, KV, S, D] slab
             ck = self.variable("cache", "cached_key", jnp.zeros,
-                               kv_shape, jnp.int8)
+                               (B, KV, L, D), k.dtype)
             cv = self.variable("cache", "cached_value", jnp.zeros,
-                               kv_shape, jnp.int8)
+                               (B, KV, L, D), v.dtype)
+            ck.value = _constrain_cache(
+                upd4(ck.value, k.transpose(0, 2, 1, 3)))
+            cv.value = _constrain_cache(
+                upd4(cv.value, v.transpose(0, 2, 1, 3)))
+            sc_shape = (B, KV, L)
+        if quantized:
             ks = self.variable("cache", "key_scale", jnp.zeros,
                                sc_shape, jnp.float32)
             vs = self.variable("cache", "value_scale", jnp.zeros,
                                sc_shape, jnp.float32)
-            k8, k_sc = quant(k_t)
-            v8, v_sc = quant(v_t)
-            ck.value = constrain(upd4(ck.value, k8))
-            cv.value = constrain(upd4(cv.value, v8))
             ks.value = upd3(ks.value, k_sc)
             vs.value = upd3(vs.value, v_sc)
-            bump()
             k_scale, v_scale = ks.value, vs.value
-        else:
-            ck = self.variable("cache", "cached_key", jnp.zeros,
-                               kv_shape, k.dtype)
-            cv = self.variable("cache", "cached_value", jnp.zeros,
-                               kv_shape, v.dtype)
-            ck.value = constrain(upd4(ck.value, k_t))
-            cv.value = constrain(upd4(cv.value, v_t))
-            bump()
+        bump()
 
         from ..ops.attention import note_traced
         if cfg.decode_kernel and S == 1:
@@ -634,19 +636,19 @@ class Attention(nn.Module):
             # kernel against.
             if paged:
                 from ..ops.attention import paged_decode_attention
-                # Mosaic second-minor tiling for the (ps, D) page block:
+                # Mosaic second-minor tiling for a page's block of rows:
                 # int8 needs 32, bf16 16, f32 8
-                need = (32 if ck.value.dtype == jnp.int8
-                        else 16 if ck.value.dtype == jnp.bfloat16 else 8)
+                need = (32 if ckv.value.dtype == jnp.int8
+                        else 16 if ckv.value.dtype == jnp.bfloat16 else 8)
                 if ps % need == 0:
                     out = paged_decode_attention(
-                        q[:, 0], ck.value, cv.value, cur, pt,
+                        q[:, 0], ckv.value, cur, pt,
                         k_scale=k_scale, v_scale=v_scale)
                     return out[:, None]
                 untileable = (f"decode_page_size={ps} is not a multiple "
                               f"of {need}, the second-minor tile of a "
-                              f"{ck.value.dtype.name} page block "
-                              f"[{ps}, {D}]")
+                              f"{ckv.value.dtype.name} page block "
+                              f"[{ps}, {ckv.value.shape[-1]}]")
             else:
                 from ..ops.attention import (decode_attention,
                                              decode_block_k)
@@ -667,30 +669,38 @@ class Attention(nn.Module):
                     f"dense path.")
         note_traced("decode" if S == 1 else "prefill", "dense")
         # dense oracle path (prefill, CPU correctness, unaligned shapes).
-        # Paged caches gather the page table back into the logical
-        # [B, KV, L, D] layout first — trash/junk entries land at
-        # positions the visibility mask below excludes.
+        # Paged caches gather the page table back into the logical rows
+        # first, [B, L, KV, 2D] — trash/junk entries land at positions
+        # the visibility mask below excludes. The contiguous cache is
+        # kv-head-major [B, KV, L, D].
         if paged:
-            def gather4(c):           # [NP, KV, ps, D] → [B, KV, L, D]
-                g = c[pt]             # [B, nblk, KV, ps, D]
-                return g.transpose(0, 2, 1, 3, 4).reshape(B, KV, L, D)
-
-            def gather3(c):           # [NP, KV, ps] → [B, KV, L]
-                g = c[pt]
-                return g.transpose(0, 2, 1, 3).reshape(B, KV, L)
+            # keys AND values of head h are its whole 2D-wide column block
+            # (the kernel's trick): the query padded with zeros over the V
+            # lanes scores K alone, and probs . block carries probs . V in
+            # its V lanes — the gathered rows are never split by lanes,
+            # which on the chip would be two more passes over them
+            keys = values = ckv.value[pt].reshape(B, L, KV, 2 * D)
+            if quantized:
+                def scales(x):      # [NP, KV, ps] → [B, L, KV, 1]
+                    return x[pt].transpose(0, 1, 3, 2).reshape(
+                        B, L, KV, 1).astype(cfg.dtype)
+                is_k = jnp.arange(2 * D) < D
+                keys = values = keys.astype(cfg.dtype) * jnp.where(
+                    is_k, scales(k_scale), scales(v_scale))
+            q = jnp.concatenate([q, jnp.zeros_like(q)], -1)
+            kv_dims, head_axis = "bkhd", 2
         else:
-            gather4 = gather3 = lambda x_: x_               # noqa: E731
-        if cfg.kv_cache_dtype == "int8":
-            keys = (gather4(ck.value).astype(cfg.dtype)
-                    * gather3(k_scale)[..., None].astype(cfg.dtype))
-            values = (gather4(cv.value).astype(cfg.dtype)
-                      * gather3(v_scale)[..., None].astype(cfg.dtype))
-        else:
-            keys, values = gather4(ck.value), gather4(cv.value)
+            keys, values = ck.value, cv.value
+            if quantized:
+                keys = (keys.astype(cfg.dtype)
+                        * k_scale[..., None].astype(cfg.dtype))
+                values = (values.astype(cfg.dtype)
+                          * v_scale[..., None].astype(cfg.dtype))
+            kv_dims, head_axis = "bhkd", 1
         if KV != H:
-            keys = jnp.repeat(keys, H // KV, axis=1)
-            values = jnp.repeat(values, H // KV, axis=1)
-        logits = jnp.einsum("bqhd,bhkd->bhqk", q, keys)
+            keys, values = (jnp.repeat(x, H // KV, axis=head_axis)
+                            for x in (keys, values))
+        logits = jnp.einsum(f"bqhd,{kv_dims}->bhqk", q, keys)
         logits = logits.astype(jnp.float32) / jnp.sqrt(D)
         # per-row visibility: [B, S, L] (pos broadcasts from [S] in
         # lockstep mode, is genuinely per-row in slot mode)
@@ -698,7 +708,7 @@ class Attention(nn.Module):
                    <= jnp.broadcast_to(pos, (B, S))[:, :, None])
         logits = jnp.where(visible[:, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-        return jnp.einsum("bhqk,bhkd->bqhd", probs, values)
+        return jnp.einsum(f"bhqk,{kv_dims}->bqhd", probs, values)[..., -D:]
 
 
 def _attend(q, k, v, mask, cfg: TransformerConfig):

@@ -624,7 +624,15 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     materializes in HBM never exists here. The cursor vector is per-row
     ([B]): row b attends positions <= cur_ref[b], which is what lets
     the serving engine pack independent requests at unrelated
-    generation depths into one compiled step."""
+    generation depths into one compiled step.
+
+    `v_ref` None is the page pool's block (`kv_row_width`): `k_ref` is
+    one page's rows [block_k, hb * 2D], head j's K and V side by side in
+    its own lane-aligned columns. Keys AND values of head j are then
+    that whole column block: the query comes padded with zeros over the
+    V lanes, so a score sees K alone, and p . block carries p . V in its
+    V lanes (the wrapper reads those). A page is read once, nothing is
+    shuffled across lanes, and the body below is the same."""
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     cur = cur_ref[pl.program_id(0)]
@@ -638,14 +646,30 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     @pl.when(ki * block_k <= cur)
     def _attend():
         q = q_ref[0]                              # [hb, G, D]
-        k = k_ref[0]                              # [hb, block_k, D]
-        v = v_ref[0]
-        if ks_ref is not None:
-            # fused dequant: int8 cache block × per-position f32 scale,
-            # in the compute dtype (matches the dense oracle's
-            # cast-then-scale arithmetic exactly)
-            k = k.astype(q.dtype) * ks_ref[0].astype(q.dtype)
-            v = v.astype(q.dtype) * vs_ref[0].astype(q.dtype)
+        if v_ref is None:
+            hb, _, W = q.shape                    # W = 2D
+            # the heads' lane-aligned column blocks, stacked: whole vregs
+            # under another index, [hb, block_k, 2D]. One split, because
+            # a program traces this body once a layer (a slice of the ref
+            # a head runs the same and traces three times the operations);
+            # not reshape + swapaxes, which is a relayout in VMEM (18%
+            # slower on the chip; PERF.md section 6, PR 28)
+            k = jnp.stack(jnp.split(k_ref[0], hb, axis=1))
+            if ks_ref is not None:
+                is_k = jax.lax.broadcasted_iota(jnp.int32, k.shape, 2) \
+                    < W // 2
+                k = k.astype(q.dtype) * jnp.where(
+                    is_k, ks_ref[0], vs_ref[0]).astype(q.dtype)
+            v = k
+        else:
+            k = k_ref[0]                          # [hb, block_k, D]
+            v = v_ref[0]
+            if ks_ref is not None:
+                # fused dequant: int8 cache block × per-position f32
+                # scale, in the compute dtype (matches the dense oracle's
+                # cast-then-scale arithmetic exactly)
+                k = k.astype(q.dtype) * ks_ref[0].astype(q.dtype)
+                v = v.astype(q.dtype) * vs_ref[0].astype(q.dtype)
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * sm_scale  # [hb, G, block_k]
@@ -679,23 +703,34 @@ _KV_VMEM_BUDGET = 4 * 1024 * 1024
 
 
 def decode_head_block(kv_heads: int, block_k: int, head_dim: int,
-                      cache_dtype, vmem_budget: int) -> int:
+                      cache_dtype, vmem_budget: int,
+                      paged: bool = False) -> int:
     """How many kv heads one grid step of the decode kernels covers: the
-    largest divisor of `kv_heads` (the per-device count) whose K and V
-    blocks [hb, block_k, head_dim], double-buffered by the pipeline, fit
-    `vmem_budget` bytes. A function of shapes and dtype alone — a block
-    is counted as VMEM holds it, the minor dim padded to whole 128-lane
-    tiles, and an int8 cache brings its two float32 scale blocks
+    largest divisor of `kv_heads` (the per-device count) whose cache
+    blocks, double-buffered by the pipeline, fit `vmem_budget` bytes. A
+    function of shapes and dtype alone — a block is counted as VMEM
+    holds it. The contiguous cache brings a K and a V block
+    [hb, block_k, head_dim], the minor dim padded to whole 128-lane
+    tiles; the page pool (`paged`) ONE block [block_k, hb * 2 * head_dim]
+    of a page's rows, K and V of a head side by side (`kv_row_width`),
+    whose column block must be whole lane tiles unless it is the whole
+    row. An int8 cache brings its two float32 scale blocks
     [hb, block_k, 1], which pad to a lane tile a position. gpt2-xl's
-    page block (25 heads, 64 x 64 bfloat16) takes 1.6 MB: all 25 heads
-    in one step."""
+    page block (25 heads, 64 positions of 2 x 64 bfloat16) takes 0.8 MB:
+    all 25 heads in one step."""
     dtype = jnp.dtype(cache_dtype)
-    per_head = block_k * -(-head_dim // LANES) * LANES * dtype.itemsize
+    if paged:
+        per_head = 2 * block_k * 2 * head_dim * dtype.itemsize
+    else:                                   # K and V, two buffers each
+        per_head = 4 * block_k * -(-head_dim // LANES) * LANES \
+            * dtype.itemsize
     if dtype == jnp.int8:
-        per_head += block_k * LANES * 4
-    fit = vmem_budget // (4 * per_head)          # K and V, two buffers each
-    return max(d for d in range(1, kv_heads + 1)
-               if kv_heads % d == 0 and d <= max(fit, 1))
+        per_head += 4 * block_k * LANES * 4
+    legal = [d for d in range(1, kv_heads + 1) if kv_heads % d == 0
+             and (not paged or d == kv_heads
+                  or d * 2 * head_dim % LANES == 0)]
+    fit = [d for d in legal if d <= vmem_budget // per_head]
+    return max(fit) if fit else min(legal)
 
 
 def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
@@ -703,23 +738,37 @@ def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
     """The pallas_call both decode kernels share. q4 is [B, KV, G, D];
     `k`/`v` (and the int8 scales, given a trailing unit dim here) are
     blocked (1, hb, block_k, ·) at `kv_index(b, h, ki, *prefetch_refs)` —
-    the contiguous cache and the page pool differ in that index map and
-    in what they prefetch (`prefetch[0]` is the [B] cursor vector),
-    nothing else. Reports `name[hb=..]` as the traced decode
-    implementation, so a headline says how many heads a grid step took."""
+    the contiguous cache and the page pool differ in that index map, in
+    what they prefetch (`prefetch[0]` is the [B] cursor vector) and in
+    the form of the cache. `v` None is the page pool: `k` holds rows
+    [NP, block_k, KV * 2D], blocked (1, block_k, hb * 2D) at the page
+    `kv_index` names and column block h; the query is padded with zeros
+    over each head's V lanes and the output read from them
+    (`_decode_kernel`).
+    Reports `name[hb=..]` as the traced decode implementation, so a
+    headline says how many heads a grid step took."""
     B, KV, G, D = q4.shape
-    hb = decode_head_block(KV, block_k, D, k.dtype, _KV_VMEM_BUDGET)
+    paged = v is None
+    hb = decode_head_block(KV, block_k, D, k.dtype, _KV_VMEM_BUDGET, paged)
     note_traced("decode", f"{name}[hb={hb}]")
     quantized = k_scale is not None
     n_pre = len(prefetch)
+    W = 2 * D if paged else D           # columns of a head's q/out block
 
     def kv_spec(minor):
         return pl.BlockSpec((1, hb, block_k, minor), kv_index)
 
-    qo_spec = pl.BlockSpec((1, hb, G, D),
+    qo_spec = pl.BlockSpec((1, hb, G, W),
                            lambda b, h, ki, *pre: (b, h, 0, 0))
-    in_specs = [qo_spec, kv_spec(D), kv_spec(D)]
-    args = [q4, k, v]
+    if paged:
+        q4 = jnp.concatenate([q4, jnp.zeros_like(q4)], -1)
+        in_specs = [qo_spec, pl.BlockSpec(
+            (1, block_k, hb * W),
+            lambda *idx: (kv_index(*idx)[0], 0, idx[1]))]
+        args = [q4, k]
+    else:
+        in_specs = [qo_spec, kv_spec(D), kv_spec(D)]
+        args = [q4, k, v]
     if quantized:
         # [.., block_k] → [.., block_k, 1]: a trailing unit lane dim makes
         # the scale block Mosaic-legal (last dim equal to the array dim)
@@ -727,8 +776,10 @@ def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
         args += [k_scale[..., None], v_scale[..., None]]
 
     def kern(*refs):
-        # prefetch refs, q/k/v, the scales when quantized, out, scratch
-        q_ref, k_ref, v_ref, *rest = refs[n_pre:]
+        # prefetch refs, q, the cache block(s), the scales when quantized,
+        # out, scratch
+        q_ref, k_ref, *rest = refs[n_pre:]
+        v_ref = None if paged else rest.pop(0)
         ks_ref, vs_ref = ((rest.pop(0), rest.pop(0)) if quantized
                           else (None, None))
         _decode_kernel(refs[0], q_ref, k_ref, v_ref, ks_ref, vs_ref, *rest,
@@ -740,7 +791,7 @@ def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
         in_specs=in_specs,
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((hb, G, D), jnp.float32),      # acc
+            pltpu.VMEM((hb, G, W), jnp.float32),      # acc
             pltpu.VMEM((hb, G, LANES), jnp.float32),  # running max m
             pltpu.VMEM((hb, G, LANES), jnp.float32),  # running sum l
         ],
@@ -748,10 +799,10 @@ def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=_out_struct((B, KV, G, D), q4.dtype, q4, k, v),
+        out_shape=_out_struct((B, KV, G, W), q4.dtype, *args),
         interpret=interpret,
     )(*prefetch, *args)
-    return out.reshape(B, KV * G, D)
+    return out[..., W - D:].reshape(B, KV * G, D)
 
 
 def decode_block_k(max_len: int, block_k: Optional[int] = None) -> int:
@@ -828,15 +879,39 @@ def decode_attention(q, k_cache, v_cache, cache_index,
                         interpret)
 
 
-def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
+def kv_row_width(kv_heads: int, head_dim: int) -> int:
+    """Columns of one position's row in the per-head page pool
+    [NP, ps, kv_heads * 2 * head_dim]: head h owns the column block
+    [2D*h, 2D*h + 2D), its K in the first D lanes and its V in the rest
+    (`pack_kv_rows`). With D >= 64 every head's block is whole 128-lane
+    tiles (gpt2-xl: 25 x 128 = 3200, no padding), so a row-major
+    bfloat16 pool is what the chip keeps resident, what a flat row
+    scatter writes and what the kernel's block reads: one layout, the
+    donated pool aliased through a step. A pool [NP, KV, ps, 64] half
+    fills its lane tiles; the compiler kept it pages-minor and copied
+    all of it into the scatter's layout, the kernel's and back, for K
+    and for V, in every layer of every step (87% of a gpt2-xl step;
+    PERF.md section 6, PR 28)."""
+    return kv_heads * 2 * head_dim
+
+
+def pack_kv_rows(k, v):
+    """[..., KV, D] keys and values of some positions -> their pool rows
+    [..., KV * 2D] (`kv_row_width`)."""
+    return jnp.concatenate([k, v], -1).reshape(k.shape[:-2] + (-1,))
+
+
+def paged_decode_attention(q, pages, cache_index, page_table,
                            k_scale=None, v_scale=None,
                            interpret: Optional[bool] = None):
     """`decode_attention` over a PAGED cache — the serving engine's
     block-table layout (transformer.py decode_page_size).
 
     q            [B, H, D]          this step's queries (RoPE applied)
-    k_pages/v_pages [NP, KV, ps, D]  the global page POOL: NP fixed pages
-                 of ps positions each; bf16/f32, or int8 with scales
+    pages        [NP, ps, KV * 2D]  the global page POOL: NP fixed pages
+                 of ps positions, one row a position with each head's K
+                 and V side by side (`kv_row_width`); bf16/f32, or int8
+                 with scales
     cache_index  int32 [B] per-row cursors (same contract as the
                  contiguous kernel: row b attends positions <= cursor(b))
     page_table   int32 [B, nblk]: row b's logical KV block j lives in
@@ -848,24 +923,28 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
 
     The kernel body is the contiguous one — block_k equals the page size
     and logical block ki covers positions [ki*ps, ki*ps+ps), so the
-    cursor skip/mask arithmetic carries over unchanged. Only the index
-    map differs: the second scalar-prefetch operand (the page table)
+    cursor skip/mask arithmetic carries over unchanged. The index map
+    differs: the second scalar-prefetch operand (the page table)
     resolves which PHYSICAL page streams for logical block ki, with
     past-the-cursor blocks pinned to the boundary block's page so the
     pipeline re-reads a resident page instead of streaming dead pool.
+    And the block: a head's keys and values are ONE lane-aligned column
+    block of the page's rows (`_decode_kernel`).
 
-    Grid (B, KV // hb, nblk): one step takes `hb` kv heads of one page
-    (`decode_head_block`; all of them when they fit VMEM, as gpt2-xl's 25
-    do), which sit next to each other in the pool — one contiguous read
-    of hb*ps*D elements for K and one for V. A step's fixed cost, not
+    Grid (B, KV // hb, nblk): one step takes the columns of `hb` kv
+    heads of one page (`decode_head_block`; the whole rows when they fit
+    VMEM, as gpt2-xl's 25 heads do) — one contiguous read of ps*hb*2D
+    elements for K and V together. A step's fixed cost, not
     its bytes, was the whole of this kernel with one head a step (0.22 us
     x 25 600 steps a layer for gpt2-xl; ledger, PR 24), dead pages past a
     row's cursor included: they move nothing and still cost a step.
     """
     B, H, D = q.shape
-    NP, KV, ps, _ = k_pages.shape
-    if H % KV:
-        raise ValueError(f"H={H} must be a multiple of KV={KV}")
+    NP, ps, W = pages.shape
+    KV = W // (2 * D)
+    if W != kv_row_width(KV, D) or H % KV:
+        raise ValueError(f"pool rows of {W} columns do not hold K and V "
+                         f"of a divisor of H={H} heads of D={D}")
     if page_table.ndim != 2 or page_table.shape[0] != B:
         raise ValueError(f"page_table must be [B={B}, nblk], got shape "
                          f"{page_table.shape}")
@@ -879,14 +958,14 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
     mesh = _kernel_mesh(q)
     if mesh is not None:
         # rows and kv heads split as in decode_attention; the page POOL is
-        # global state every row may point into, so it is split over kv
-        # heads only
-        pool, scale = (None, "heads", None, None), (None, "heads", None)
+        # global state every row may point into, so it is split over the
+        # kv heads' columns only
+        pool, scale = (None, None, "heads"), (None, "heads", None)
         out = ("rows", "heads", None)
         return _per_device(
             functools.partial(paged_decode_attention, interpret=interpret),
-            mesh, B, KV, (q, k_pages, v_pages, cur, pt, k_scale, v_scale),
-            (out, pool, pool, ("rows",), ("rows", None), scale, scale), out)
+            mesh, B, KV, (q, pages, cur, pt, k_scale, v_scale),
+            (out, pool, ("rows",), ("rows", None), scale, scale), out)
 
     def kv_index(b, h, ki, cur_ref, pt_ref):
         # physical page for logical block ki, clamped to the row's
@@ -896,7 +975,7 @@ def paged_decode_attention(q, k_pages, v_pages, cache_index, page_table,
         return (pt_ref[b, jnp.minimum(ki, last)], h, 0, 0)
 
     return _decode_call("pallas_paged", q.reshape(B, KV, H // KV, D),
-                        k_pages, v_pages, k_scale, v_scale, (cur, pt), nblk,
+                        pages, None, k_scale, v_scale, (cur, pt), nblk,
                         kv_index, ps, interpret)
 
 
@@ -928,8 +1007,9 @@ def mla_row_width(rank: int, rope: int) -> int:
     whether the shape says so or not, and a shape that says 576 makes
     the compiler keep the pool pages-minor instead — and copy all of it
     into the kernel's layout and back, every step (what the per-head
-    pool of 64-wide rows pays, `PERF.md` section 5). The pad columns are
-    zeros in the cache and in the query, and add nothing to a score."""
+    pool paid while its rows were 64 wide, `kv_row_width`). The pad
+    columns are zeros in the cache and in the query, and add nothing to
+    a score."""
     return -(-(rank + rope) // LANES) * LANES
 
 
@@ -1126,7 +1206,8 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
 
 
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
-           "decode_head_block", "paged_decode_attention",
+           "decode_head_block", "paged_decode_attention", "kv_row_width",
+           "pack_kv_rows",
            "mla_paged_attend", "mla_paged_decode_attention",
            "mla_pages_per_step", "mla_row_width", "einsum_f32",
            "record_traced", "note_traced", "traced_name"]

@@ -37,8 +37,9 @@ model the way a frontend needs it served:
   token-identical at temperature 0, the A/B baseline the serving bench
   measures against.
 - **Paged KV + prefix caching** (`EngineConfig.paged`). The per-layer
-  cache becomes a POOL of fixed-size pages ([num_pages, KV, page_size,
-  D], transformer.py decode_page_size) and each slot carries a page
+  cache becomes a POOL of fixed-size pages ([num_pages, page_size,
+  KV * 2D]: a row a position, each head's K and V side by side —
+  transformer.py decode_page_size) and each slot carries a page
   TABLE instead of a contiguous row — slot count decouples from
   max_len, so the same cache bytes serve strictly more concurrent
   requests whenever typical spans run short of the worst case.
@@ -599,7 +600,8 @@ class ServingEngine:
             # cache buffers are donated — the engine holds the only live
             # reference, and the cache ([SLOTS, KV, L, D] per layer, or the
             # page pool) is the biggest allocation here; donation keeps it
-            # single-buffered. (CPU has no donation support and would warn
+            # single-buffered — and the pool's row-major form is the one
+            # every program reads and writes, so it is aliased, not copied. (CPU has no donation support and would warn
             # per program.) prev_tok is NOT donated: the pending sync still
             # reads its buffer after the next step consumed it.
             donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()

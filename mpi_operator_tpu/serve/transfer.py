@@ -4,9 +4,10 @@ The disaggregated engine (serve/engine.py DisaggEngine) runs prefill
 and decode on SEPARATE device pools; when a prompt finishes prefilling,
 its KV lives in the prefill pool's page arrays and must move into the
 decode pool's. Because the paged cache layout puts the page axis first
-on EVERY leaf — cached_key/cached_value are [num_pages, KV, page_size,
-D], the int8 scale planes are [num_pages, KV, page_size], and a latent
-cache's pool (models/longcat.py) is [num_pages, page_size, W], one row a
+on EVERY leaf — a per-head cache's `cached_kv` is [num_pages, page_size,
+KV * 2D] (one row a position, K and V of a head side by side), its int8
+scale planes are [num_pages, KV, page_size], and a latent cache's pool
+(models/longcat.py) is [num_pages, page_size, W], also one row a
 position — one generic axis-0 gather/scatter over the cache pytree
 moves a page list uniformly for every kind of cache and all dtypes:
 int8 payloads travel WITH their scale rows, nothing is dequantized in

@@ -415,16 +415,17 @@ def test_kernels_on_a_mesh_match_the_single_device_call():
     for got, want in zip(jax.jit(jax.grad(loss, (0, 1, 2)))(*sharded), ref):
         np.testing.assert_allclose(got, want, atol=1e-5)
 
-    # the paged pool replicated on the mesh, rows split over dp
+    # the paged pool replicated on the mesh: rows split over dp, the
+    # pool's rows over tp by the heads' columns (4 heads of 2 x 16)
     rep = NamedSharding(mesh, P())
     qd = jax.random.normal(keys[0], (8, 4, 16))
-    pool = jax.random.normal(keys[1], (8 * 4 + 1, 4, 16, 16))
+    pool = jax.random.normal(keys[1], (8 * 4 + 1, 16, 4 * 2 * 16))
     cur = jnp.asarray([0, 5, 15, 16, 31, 32, 63, 40], jnp.int32)
     table = jnp.asarray(np.random.RandomState(0).permutation(32)
                         .reshape(8, 4) + 1, jnp.int32)
-    want = paged_decode_attention(qd, pool, pool, cur, table)
+    want = paged_decode_attention(qd, pool, cur, table)
     got = jax.jit(paged_decode_attention)(
-        *(jax.device_put(x, rep) for x in (qd, pool, pool, cur, table)))
+        *(jax.device_put(x, rep) for x in (qd, pool, cur, table)))
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 

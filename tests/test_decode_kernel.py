@@ -186,6 +186,9 @@ GPT2_XL_PAGE = dict(kv_heads=25, block_k=64, head_dim=64,
                     cache_dtype=jnp.bfloat16)       # 64 KiB a head
 
 
+GPT2_XL_ROWS = dict(GPT2_XL_PAGE, paged=True)       # 32 KiB a head
+
+
 @pytest.mark.parametrize("shape,budget,hb", [
     (GPT2_XL_PAGE, 4 << 20, 25),         # the shipped budget: every head
     (GPT2_XL_PAGE, 25 << 16, 25),        # exactly fits
@@ -204,11 +207,27 @@ GPT2_XL_PAGE = dict(kv_heads=25, block_k=64, head_dim=64,
           cache_dtype="bfloat16"), 4 << 20, 8),
     (dict(kv_heads=1, block_k=128, head_dim=64,
           cache_dtype=np.float32), 4 << 20, 1),
+    # the page pool: ONE block of a page's rows, a head's K and V side by
+    # side in 2 x 64 columns — 32 KiB a head, half the padded pair's
+    (GPT2_XL_ROWS, 4 << 20, 25),
+    (GPT2_XL_ROWS, 25 << 15, 25),
+    (GPT2_XL_ROWS, (25 << 15) - 1, 5),
+    (GPT2_XL_ROWS, 0, 1),
+    # int8 rows: 16 KiB of payload and two padded scale blocks a head
+    (dict(GPT2_XL_ROWS, cache_dtype=jnp.int8), 5 * (1 << 14) + 5 * (1 << 17),
+     5),
+    # columns of a head block are whole lane tiles, or the whole row:
+    # two heads of 2 x 16 fit, and one alone would be 32 lanes
+    (dict(kv_heads=2, block_k=16, head_dim=16, cache_dtype=jnp.float32,
+          paged=True), 0, 2),
+    (dict(kv_heads=8, block_k=16, head_dim=16, cache_dtype=jnp.float32,
+          paged=True), 0, 4),
 ])
 def test_decode_head_block_is_a_function_of_shapes_and_dtype(shape, budget,
                                                              hb):
-    """The largest divisor of the kv heads whose double-buffered K and V
-    blocks fit the budget; nothing else goes in."""
+    """The largest divisor of the kv heads whose double-buffered cache
+    blocks fit the budget (and, for a page's rows, whose columns Mosaic
+    can block); nothing else goes in."""
     assert decode_head_block(vmem_budget=budget, **shape) == hb
     assert shape["kv_heads"] % hb == 0
 
@@ -217,19 +236,19 @@ def test_traced_decode_names_the_head_block(monkeypatch):
     """What a run's headline prints (`traced_name` of `record_traced`'s
     "decode") says which kernel ran and how many kv heads a grid step
     took, also when the budget made it fall back to one."""
-    B, H, KV, L, D, cur = 2, 6, 3, 32, 16, 20
+    B, H, KV, L, D, cur = 2, 6, 3, 32, 64, 20
     q, k, v, _, _ = _cache(B, H, KV, L, D, cur)
-    pool = jnp.zeros((5, KV, 16, D), jnp.float32)
+    pool = jnp.zeros((5, 16, KV * 2 * D), jnp.float32)
     curs = jnp.full((B,), cur, jnp.int32)
     table = jnp.ones((B, 2), jnp.int32)
     with record_traced() as traced:
         decode_attention(q, k, v, cur, block_k=16, interpret=True)
-        paged_decode_attention(q, pool, pool, curs, table, interpret=True)
+        paged_decode_attention(q, pool, curs, table, interpret=True)
     assert traced_name(traced["decode"]) == \
         "pallas[hb=3]+pallas_paged[hb=3]"
     monkeypatch.setattr(attention, "_KV_VMEM_BUDGET", 0)
     with record_traced() as traced:
-        paged_decode_attention(q, pool, pool, curs, table, interpret=True)
+        paged_decode_attention(q, pool, curs, table, interpret=True)
     assert traced_name(traced["decode"]) == "pallas_paged[hb=1]"
 
 

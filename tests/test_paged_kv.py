@@ -25,7 +25,8 @@ from flax.core import meta
 
 from mpi_operator_tpu.models import CausalLM, gpt2_config
 from mpi_operator_tpu.ops import attention
-from mpi_operator_tpu.ops.attention import (paged_decode_attention,
+from mpi_operator_tpu.ops.attention import (pack_kv_rows,
+                                            paged_decode_attention,
                                             record_traced)
 from mpi_operator_tpu.serve import (
     EngineConfig, PageAllocator, Request, Scheduler, ServingEngine,
@@ -274,6 +275,13 @@ def _scatter_pages(contig, pt, NP, ps):
     return pool
 
 
+def _pool_rows(k_pages, v_pages):
+    """Per-head pages [NP, KV, ps, D] of K and of V -> the pool's rows
+    [NP, ps, KV * 2D] (`ops.attention.kv_row_width`)."""
+    return pack_kv_rows(jnp.asarray(k_pages).transpose(0, 2, 1, 3),
+                        jnp.asarray(v_pages).transpose(0, 2, 1, 3))
+
+
 def _paged_vs_dense(H, KV, D, quantized, curs, shared=(), ps=16, nblk=4):
     """The paged kernel against the dense oracle on a shuffled page
     table; beyond-cursor pool content is poisoned so a wrong page
@@ -311,14 +319,14 @@ def _paged_vs_dense(H, KV, D, quantized, curs, shared=(), ps=16, nblk=4):
     else:
         k = np.where(dead, POISON, k)
         v = np.where(dead, POISON, v)
-    kp = jnp.asarray(_scatter_pages(k, pt, NP, ps))
-    vp = jnp.asarray(_scatter_pages(v, pt, NP, ps))
+    pool = _pool_rows(_scatter_pages(k, pt, NP, ps),
+                      _scatter_pages(v, pt, NP, ps))
     ref = _dense_ref(q, jnp.asarray(k), jnp.asarray(v),
                      jnp.asarray(curs),
                      None if ks is None else jnp.asarray(ks),
                      None if vs is None else jnp.asarray(vs))
     with record_traced() as traced:
-        out = paged_decode_attention(q, kp, vp, jnp.asarray(curs),
+        out = paged_decode_attention(q, pool, jnp.asarray(curs),
                                      jnp.asarray(pt), k_scale=ksp,
                                      v_scale=vsp, interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
@@ -344,10 +352,12 @@ def test_paged_kernel_head_blocks(monkeypatch, fit, hb, quantized):
     grid runs 6 // hb head blocks a row and the result does not change.
     Rows: cursor 0, a cursor on a page's last position, and two rows
     sharing their prefix page; dead pages stay poisoned and unread."""
-    ps, D = 16, 16
-    # what decode_head_block counts for one head: K and V blocks padded to
-    # 128 lanes (int8: plus a [ps, 1] f32 scale block each), two buffers
-    per_head = 4 * (ps * 128 * (1 + 4) if quantized else ps * 128 * 4)
+    ps, D = 16, 64
+    # what decode_head_block counts for one head: its 2D columns of a
+    # page's rows, two buffers (int8: plus a [ps, 1] f32 scale block for
+    # K and for V, padded to 128 lanes, two buffers each)
+    per_head = (2 * ps * 2 * D + 4 * ps * 128 * 4 if quantized
+                else 2 * ps * 2 * D * 4)
     monkeypatch.setattr(attention, "_KV_VMEM_BUDGET", fit * per_head)
     traced = _paged_vs_dense(12, 6, D, quantized, [0, ps - 1, 2 * ps + 3, 63],
                              shared=(1, 2), ps=ps)
@@ -372,10 +382,78 @@ def test_paged_kernel_shared_pages_between_rows():
                    for b in range(B)])
     ref = _dense_ref(q, jnp.asarray(gk), jnp.asarray(gv),
                      jnp.asarray(curs))
-    out = paged_decode_attention(q, jnp.asarray(pool_k),
-                                 jnp.asarray(pool_v), jnp.asarray(curs),
-                                 jnp.asarray(pt), interpret=True)
+    out = paged_decode_attention(q, _pool_rows(pool_k, pool_v),
+                                 jnp.asarray(curs), jnp.asarray(pt),
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the pool the model writes: rows [NP, ps, KV * 2D], one form for every cache
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kv_cache_dtype", [None, "int8"])
+@pytest.mark.parametrize("preset", ["gpt2", "llama"])       # MHA, GQA + RoPE
+def test_paged_cache_is_one_pool_of_rows_and_junk_writes_drop(preset,
+                                                              kv_cache_dtype):
+    """White box. A multi-token call writes each position's row at
+    (pages[pos // ps], pos % ps) with head h's K in columns
+    [2D*h, 2D*h + D) and its V in the next D — the values the contiguous
+    cache holds at the same positions — and a row at junk positions
+    (>= max_len: a padded tail, a non-member of a prefill call) writes
+    nothing anywhere, the trash page included."""
+    from mpi_operator_tpu.models.generate import decode_model
+    from mpi_operator_tpu.models.transformer import llama_config
+    make = gpt2_config if preset == "gpt2" else llama_config
+    cfg = make("test", attention="dense", dtype=jnp.float32, vocab_size=64,
+               max_len=32, kv_cache_dtype=kv_cache_dtype)
+    model = CausalLM(cfg)
+    KV, D, ps, NP = cfg.kv_heads, cfg.head_dim, 8, 9
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 4), 0, 64)
+    params = meta.unbox(model.init(jax.random.PRNGKey(0), tokens))["params"]
+    positions = jnp.asarray([[6, 7, 8, 9], [32, 33, 34, 35]], jnp.int32)
+    pages = jnp.asarray([[3, 5, 0, 0], [7, 8, 0, 0]], jnp.int32)
+
+    def cache_of(dmodel, **kw):
+        return dmodel.apply({"params": params}, tokens, positions=positions,
+                            with_head=False, mutable=["cache"],
+                            **kw)[1]["cache"]
+    paged = cache_of(decode_model(model, False, slots=True, page_size=ps,
+                                  num_pages=NP), pages=pages)
+    contig = cache_of(decode_model(model, False, slots=True))
+    leaves = lambda tree, name: [                               # noqa: E731
+        x for p_, x in jax.tree_util.tree_leaves_with_path(tree)
+        if name in jax.tree_util.keystr(p_)]
+    assert not leaves(paged, "cached_key") and not leaves(paged,
+                                                          "cached_value")
+    pools = leaves(paged, "cached_kv")
+    assert len(pools) == cfg.num_layers
+    for pool, ck, cv in zip(pools, leaves(contig, "cached_key"),
+                            leaves(contig, "cached_value")):
+        assert pool.shape == (NP, ps, KV * 2 * D)
+        assert pool.dtype == (jnp.int8 if kv_cache_dtype else jnp.float32)
+        rows = np.asarray(pool).reshape(NP, ps, KV, 2, D)
+        want = np.zeros_like(rows)
+        written = np.zeros((NP, ps), bool)
+        for pos in (6, 7, 8, 9):                    # row 0; row 1 is junk
+            at = (int(pages[0, pos // ps]), pos % ps)
+            written[at] = True
+            want[at][:, 0] = np.asarray(ck)[0, :, pos]
+            want[at][:, 1] = np.asarray(cv)[0, :, pos]
+        # the two paths sum attention in different orders: below the
+        # first layer the cached values agree to rounding (one int8 step)
+        np.testing.assert_allclose(rows, want,
+                                   atol=1 if kv_cache_dtype else 1e-5)
+        assert rows[written].any() and not rows[~written].any()
+        assert not rows[0].any()                    # nothing in the trash
+    for name in ("key_scale", "value_scale") if kv_cache_dtype else ():
+        for plane, flat in zip(leaves(paged, name), leaves(contig, name)):
+            assert plane.shape == (NP, KV, ps) and plane.dtype == jnp.float32
+            want = np.zeros((NP, KV, ps), np.float32)
+            want[3, :, 6:8] = np.asarray(flat)[0, :, 6:8]
+            want[5, :, 0:2] = np.asarray(flat)[0, :, 8:10]
+            np.testing.assert_allclose(np.asarray(plane), want, rtol=1e-4)
+            assert not np.asarray(plane)[want == 0].any()
 
 
 # ---------------------------------------------------------------------------

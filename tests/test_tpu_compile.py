@@ -2,7 +2,8 @@
 that is described, not attached (the `on-chip-measurement` guide, section
 2): what interpret mode cannot show — whether Mosaic takes the kernel,
 whether the program fits the chip, and whether the compiler leaves the
-latent page pool where it lies. Nothing runs, so nothing here is a time.
+page pools (latent rows, and K and V a head) where they lie. Nothing runs,
+so nothing here is a time.
 
 One file, the topology described inside a fixture: only the worker that
 runs this file loads the TPU's library.
@@ -49,10 +50,15 @@ def quiet_cache():
     compilation_cache.reset_cache()
 
 
-def _pool_copies(text):
+def _copies_of(text, *shapes):
+    """Lines of the compiled program that copy an array of one of `shapes`."""
+    dims = "|".join(",".join(map(str, sh)) for sh in shapes)
     return [ln.strip()[:160] for ln in text.splitlines()
-            if re.search(r"= bf16\[(4480,64,640|286720,640)\][^ ]* copy\(",
-                         ln)]
+            if re.search(rf"= \w+\[({dims})\][^ ]* copy\(", ln)]
+
+
+def _pool_copies(text):
+    return _copies_of(text, (PAGES, PAGE, 640), (PAGES * PAGE, 640))
 
 
 def test_latent_decode_kernel_and_its_cache_write_leave_the_pool_in_place(
@@ -148,4 +154,180 @@ def test_longcat_decode_step_fits_the_chip_beside_its_weights_and_pool(
     assert _pool_copies(text) == []
     assert 13.0e9 < m.argument_size_in_bytes < 13.6e9
     assert m.temp_size_in_bytes < 0.5e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+
+
+# ---------------------------------------------------------------------------
+# gpt2-xl: the per-head page pool as rows [pages, page, KV * 2D]
+# ---------------------------------------------------------------------------
+XL = dict(slots=64, pages=384, page=64, heads=25, head_dim=64, max_len=1024)
+
+
+def test_per_head_rows_pool_is_written_and_read_where_it_lies(
+        one_chip, quiet_cache):
+    """One layer's cache write and kernel call at gpt2-xl's widths: rows
+    of 25 x (64 + 64) = 3200 columns, 25 whole lane tiles. Mosaic takes
+    the kernel; the flat row scatter, the kernel's block and the resident
+    pool agree on one row-major layout, so the donated pool is aliased
+    and never copied."""
+    from mpi_operator_tpu.ops.attention import (kv_row_width,
+                                                paged_decode_attention)
+    S, NP, ps, KV, D = (XL[k] for k in ("slots", "pages", "page", "heads",
+                                        "head_dim"))
+    W = kv_row_width(KV, D)
+    assert W == 3200 and W % 128 == 0
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+
+    def layer(q, pool, cur, pt, rows, at):
+        pool = pool.reshape(NP * ps, W).at[at].set(
+            rows, mode="drop").reshape(NP, ps, W)
+        return pool, paged_decode_attention(q, pool, cur, pt,
+                                            interpret=False)
+    compiled = jax.jit(layer, donate_argnums=(1,)).lower(
+        spec((S, KV, D), jnp.bfloat16), spec((NP, ps, W), jnp.bfloat16),
+        spec((S,), jnp.int32), spec((S, XL["max_len"] // ps), jnp.int32),
+        spec((S, W), jnp.bfloat16), spec((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (NP, ps, W), (NP * ps, W)) == []
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= NP * ps * W * 2
+    assert m.temp_size_in_bytes < 64 << 20
+
+
+def test_a_pool_of_64_wide_head_rows_would_be_copied_between_three_layouts(
+        one_chip, quiet_cache):
+    """Why the pool is rows of whole lane tiles: K and V pools
+    [384, 25, 64, 64] half fill their tiles, the compiler keeps them
+    pages-minor `{0,3,2,1}`, and the page scatter wants `{3,1,2,0}`: four
+    pool-wide copies and 200 MB of temporaries for this one layer (six
+    and 674 MB with the Mosaic call, which wanted a third layout) — in
+    every layer of every step, 87% of a gpt2-xl decode step on the chip
+    (ledger, PR 27)."""
+    S, NP, ps, KV, D = (XL[k] for k in ("slots", "pages", "page", "heads",
+                                        "head_dim"))
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+
+    def layer(kp, vp, phys, off, k, v, pt):
+        kp = kp.at[phys, :, off, :].set(k)
+        vp = vp.at[phys, :, off, :].set(v)
+        read = jnp.einsum("bjhkd,bjhkd->bh", kp[pt], vp[pt],
+                          preferred_element_type=jnp.float32)
+        return kp, vp, read
+    pool = spec((NP, KV, ps, D), jnp.bfloat16)
+    compiled = jax.jit(layer, donate_argnums=(0, 1)).lower(
+        pool, pool, spec((S, 1), jnp.int32), spec((S, 1), jnp.int32),
+        spec((S, 1, KV, D), jnp.bfloat16), spec((S, 1, KV, D), jnp.bfloat16),
+        spec((S, 2), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(_copies_of(text, (NP, KV, ps, D))) >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes > NP * KV * ps * D \
+        * 2
+
+
+@pytest.fixture(scope="module")
+def gpt2_xl(one_chip):
+    """The decode model, parameter shapes and cache shapes of
+    `perfbench/configs/gpt2-xl.json` as the benchmark's engine builds
+    them (64 slots, 384 pages of 64), on the described chip."""
+    from mpi_operator_tpu.models.generate import decode_model
+    from mpi_operator_tpu.models.transformer import (CausalLM,
+                                                     TransformerConfig)
+    from perfbench import weights
+    with open(os.path.join(REPO, "perfbench", "configs", "gpt2-xl.json")) as f:
+        dims = weights.Dims.from_config(json.load(f))
+    assert (dims.heads, dims.embed // dims.heads) == (XL["heads"],
+                                                      XL["head_dim"])
+    model = CausalLM(TransformerConfig(
+        vocab_size=dims.vocab, max_len=dims.positions,
+        num_layers=dims.layers, num_heads=dims.heads, embed_dim=dims.embed,
+        mlp_dim=dims.mlp, causal=True, dtype=jnp.bfloat16,
+        decode_kernel=True))
+    dmodel = decode_model(model, True, slots=True, page_size=XL["page"],
+                          num_pages=XL["pages"])
+    on_chip = lambda tree: jax.tree.map(                        # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: weights.make_params(jax.random.PRNGKey(0), dims,
+                                    jnp.bfloat16)))
+    z = jnp.zeros((XL["slots"], 1), jnp.int32)
+    table = jnp.zeros((XL["slots"], XL["max_len"] // XL["page"]), jnp.int32)
+    cache = on_chip(jax.eval_shape(
+        lambda p: dmodel.apply({"params": p}, z, positions=z,
+                               with_head=False, mutable=["cache"],
+                               pages=table)[1]["cache"], params))
+    return dims, dmodel, params, cache
+
+
+def _xl_pool_copies(text):
+    NP, ps, W = XL["pages"], XL["page"], XL["heads"] * 2 * XL["head_dim"]
+    return _copies_of(text, (NP, ps, W), (NP * ps, W))
+
+
+def test_gpt2_xl_decode_step_keeps_its_pool_in_place(
+        one_chip, quiet_cache, monkeypatch, gpt2_xl):
+    """The whole `step_paged` of gpt2-xl as the engine runs it (the
+    model's decode call, the tied head, `sample_slots`): a Mosaic call
+    in each of the 48 layers, no copy of a pool-sized array, the 7.5 GB
+    of pools aliased, and weights + pools + temporaries fit the chip."""
+    from mpi_operator_tpu.models.transformer import _head_matmul
+    from mpi_operator_tpu.serve.engine import sample_slots
+    dims, dmodel, params, cache = gpt2_xl
+    S = XL["slots"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
+                                              sharding=one_chip)
+
+    def step_paged(params, cache, tokens, positions, rng, temperature,
+                   top_k, top_p, pages):
+        h, v = dmodel.apply({"params": params, "cache": cache},
+                            tokens[:, None], positions=positions[:, None],
+                            with_head=False, mutable=["cache"], pages=pages)
+        logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
+        tok, logp = sample_slots(logits, rng, temperature, top_k, top_p,
+                                 mode="greedy")
+        return v["cache"], tok, logp
+    compiled = jax.jit(step_paged, donate_argnums=(1,)).lower(
+        params, cache, arg(jnp.int32, S), arg(jnp.int32, S),
+        arg(jnp.uint32, 2), arg(jnp.float32, S), arg(jnp.int32, S),
+        arg(jnp.float32, S),
+        arg(jnp.int32, S, XL["max_len"] // XL["page"])).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    pools = dims.layers * XL["pages"] * XL["page"] * 3200 * 2
+    assert text.count("tpu_custom_call") == dims.layers == 48
+    assert _xl_pool_copies(text) == []
+    assert m.alias_size_in_bytes >= pools
+    assert 10.5e9 < m.argument_size_in_bytes < 10.9e9
+    assert m.temp_size_in_bytes < 0.5e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+
+
+def test_gpt2_xl_prefill_bucket_keeps_its_pool_in_place(
+        one_chip, quiet_cache, monkeypatch, gpt2_xl):
+    """One `prefill_paged` bucket ([64, 128] tokens): the chunk's rows go
+    into the pool by the same flat scatter and the dense path gathers
+    each row's pages from it — no copy of the pool, the pools aliased."""
+    dims, dmodel, params, cache = gpt2_xl
+    S, C = XL["slots"], 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,        # noqa: E731
+                                          sharding=one_chip)
+
+    def prefill_paged(params, cache, tokens, starts, pages):
+        positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
+        _, v = dmodel.apply({"params": params, "cache": cache}, tokens,
+                            positions=positions, with_head=False,
+                            mutable=["cache"], pages=pages)
+        return v["cache"]
+    compiled = jax.jit(prefill_paged, donate_argnums=(1,)).lower(
+        params, cache, i32(S, C), i32(S),
+        i32(S, XL["max_len"] // XL["page"])).compile()
+    m = compiled.memory_analysis()
+    assert _xl_pool_copies(compiled.as_text()) == []
+    assert m.alias_size_in_bytes >= dims.layers * XL["pages"] * XL["page"] \
+        * 3200 * 2
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
