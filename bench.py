@@ -424,13 +424,13 @@ def main() -> int:
             log=lambda s: print(s, file=sys.stderr))
 
     def serving_paged_metrics():
-        # the paged-KV engine over a shared-system-prompt trace: every
+        # the engine over a shared-system-prompt trace: every
         # request carries the same seeded prefix, so the first wave
         # prefills it cold and publishes while later waves pin the shared
         # pages — prefix_hit_rate, cold-vs-hit TTFT, and page-occupancy
         # peaks land in the JSONL under serving_paged_*. No sequential
         # baseline rerun (the serving leg already priced that); the
-        # contiguous serving leg in the same line IS the A/B.
+        # serving leg in the same line is the trace with no shared prefix.
         from mpi_operator_tpu.examples.serve_benchmark import (
             run_serving_benchmark)
         m = run_serving_benchmark(
@@ -441,7 +441,6 @@ def main() -> int:
             new_grid=(16, 32) if args.smoke else (32, 64),
             chunk_buckets=(8, 16) if args.smoke else (32, 128),
             dtype_name=args.dtype,
-            paged=True,
             page_size=16 if args.smoke else 64,
             shared_prefix_len=16 if args.smoke else 128,
             baseline=False,
@@ -451,7 +450,7 @@ def main() -> int:
 
     def serving_disagg_metrics():
         # disaggregated prefill/decode A/B at equal chip count: the same
-        # long-prompt-heavy greedy trace through a colocated paged
+        # long-prompt-heavy greedy trace through a colocated
         # engine and the two-pool DisaggEngine, TTFT/TPOT p50/p99 for
         # both plus kv_handoff p50/p99 and the token-identity + per-pool
         # compile-pin gates. Keys already carry the disagg_/coloc_
@@ -491,7 +490,6 @@ def main() -> int:
             new_grid=(16, 32) if args.smoke else (32, 64),
             chunk_buckets=(8, 16) if args.smoke else (32, 128),
             dtype_name=args.dtype,
-            paged=True,
             page_size=16 if args.smoke else 64,
             shared_prefix_len=16 if args.smoke else 128,
             speculative="ngram",
@@ -864,8 +862,8 @@ def main() -> int:
     # right behind the decode legs it builds on (same fast path,
     # ragged traffic); p50/p99 TTFT/TPOT land in the JSONL record
     leg("serving", serving_metrics)
-    # paged-KV serving over the shared-system-prompt trace (prefix
-    # hit rate + cold/hit TTFT; the contiguous leg above is its A/B)
+    # serving over the shared-system-prompt trace (prefix hit rate +
+    # cold/hit TTFT; the leg above is the trace with no shared prefix)
     leg("serving_paged", serving_paged_metrics)
     # speculative decoding over the same shared-prefix trace shape
     # (acceptance rate + effective tokens/row-step, no-spec A/B

@@ -15,8 +15,8 @@ would call, one child process after another:
            with the controller's env contract and a launcher that waits
            on rank 0's status channel and mirrors its exit code
   server   python -m mpi_operator_tpu.examples.serve_benchmark --family
-           gpt2 --paged: 8 slots, 16 mixed-length greedy requests, async
-           decode and cache donation on
+           gpt2: 8 slots over the page pool, 16 mixed-length greedy
+           requests, async decode and cache donation on
 
 This process never imports jax (nor `mpi_operator_tpu`): a chip belongs
 to one process at a time, so a parent that touched jax would hold it and
@@ -280,7 +280,7 @@ def main() -> int:
                      "TPU_NUM_PROCESSES": "1"},
                 companion_env={"TPU_LAUNCHER": "1"})
         leg("server",
-            ["-m", SERVE, "--family", "gpt2", "--paged",
+            ["-m", SERVE, "--family", "gpt2",
              "--slots", str(SLOTS), "--num-requests", str(REQUESTS),
              "--no-baseline"],
             check_server)

@@ -34,7 +34,7 @@ depend on observed progress rather than API state:
   - wedged serving gang: a Running serving job whose retired-token
     frontier freezes is caught by the SAME progress lease that catches
     training stalls, within progressDeadlineSeconds;
-  - request timeouts: an in-process paged engine retires every
+  - request timeouts: an in-process engine retires every
     past-deadline request with zero leaked slots and zero leaked KV
     pages (PageAllocator.check() clean).
 
@@ -776,7 +776,7 @@ def data_plane_request_timeouts(seed: int = 0) -> Dict:
     params = flax_meta.unbox(
         model.init(jax.random.PRNGKey(seed), probe))["params"]
     engine = ServingEngine(model, params, EngineConfig(
-        slots=2, chunk_buckets=(4, 8), paged=True, page_size=8,
+        slots=2, chunk_buckets=(4, 8), page_size=8,
         rng_seed=seed, request_timeout=0.0))
     reqs = [Request(i, [1 + (i % 5)] * 6, 16) for i in range(5)]
     results = engine.run(reqs)
@@ -908,7 +908,7 @@ def data_plane_router_failover(seed: int = 0) -> Dict:
 
     def mk():
         return ServingEngine(model, params, EngineConfig(
-            slots=2, chunk_buckets=(4, 8), paged=True, page_size=8,
+            slots=2, chunk_buckets=(4, 8), page_size=8,
             rng_seed=seed))
 
     rng = random.Random(seed)
@@ -994,7 +994,7 @@ def data_plane_trace_complete(seed: int = 0) -> Dict:
 
     def mk():
         return ServingEngine(model, params, EngineConfig(
-            slots=2, chunk_buckets=(4, 8), paged=True, page_size=8,
+            slots=2, chunk_buckets=(4, 8), page_size=8,
             rng_seed=seed))
 
     rng = random.Random(seed)
@@ -1257,7 +1257,7 @@ def data_plane_live_scale_engines(seed: int = 0) -> Dict:
 
     def mk():
         return ServingEngine(model, params, EngineConfig(
-            slots=2, chunk_buckets=(4, 8), paged=True, page_size=8,
+            slots=2, chunk_buckets=(4, 8), page_size=8,
             rng_seed=seed))
 
     rng = random.Random(seed)

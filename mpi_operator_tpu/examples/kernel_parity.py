@@ -7,18 +7,21 @@ GPT-2-medium legs of `chip_smoke.py` use, the decode kernels also with
 GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
 
   flash forward and gradients (dq, dk, dv)   vs `dense_attention`
-  decode_attention, per-row cursor vector     vs the dense branch of
-  paged_decode_attention (the page pool as       `Attention._decode_attend`
-  rows [pages, page, KV * 2D])
+  decode_attention (generate()'s contiguous   vs a dense masked softmax
+  cache), per-row cursor vector                  over the same cache
+  paged_decode_attention (the page pool as    vs the dense branch of
+  rows [pages, page, KV * 2D])                   `Attention._decode_attend`
   the int8-cache variants of both decode kernels
   mla_paged_decode_attention (absorbed latent   vs `mla_paged_attend`, the
   attention, LongCat-Flash's published widths)     dense form of
                                                    `LatentAttention`
 
-The decode cases go through the `Attention` module itself — one set of
-weights, one prefilled cache, the single-token step run once with
+The paged decode cases go through the `Attention` module itself — one
+set of weights, one prefilled pool, the single-token step run once with
 `decode_kernel=True` and once with `False` — so the reference is the
-repo's own dense branch, not a copy of it.
+repo's own dense branch, not a copy of it. The module drives the
+contiguous cache in lockstep only (`generate()`), so that kernel is
+called directly, every row at its own cursor.
 
 Closeness is measured at the output level, `max|a-b| / max|b|`, against a
 stated bf16 tolerance: the MXU does not sum in the interpreter's order,
@@ -110,11 +113,76 @@ def flash_cases(batch: int, seq: int, heads: int, head_dim: int
             for name, g, r in zip(("fwd", "dq", "dk", "dv"), got, ref)]
 
 
-def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
-                page_size: int, prefilled: int, heads: int, head_dim: int
+def _decode_cursors(slots: int, page_size: int, prefilled: int):
+    """Cursors on and around the block boundaries of both decode kernels
+    (k-tile 128, page 64), first and last filled position included."""
+    marks = [0, page_size - 1, page_size, 2 * page_size - 1,
+             2 * page_size, prefilled - 1, 5, prefilled - 9]
+    return [min(max(m, 0), prefilled - 1) for m in (marks * slots)[:slots]]
+
+
+def contiguous_decode_case(int8: bool, slots: int, max_len: int,
+                           page_size: int, prefilled: int, heads: int,
+                           head_dim: int) -> Dict[str, object]:
+    """`decode_attention` over a contiguous [slots, heads, max_len,
+    head_dim] cache filled to `prefilled`, every row at its own cursor,
+    against a float32 masked softmax over the same (dequantized) cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.attention import decode_attention, record_traced, traced_name
+
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+    shape = (slots, heads, max_len, head_dim)
+    live = (jnp.arange(max_len) < prefilled)[None, None, :, None]
+    q = jax.random.normal(kq, (slots, heads, head_dim), jnp.bfloat16)
+    k = jnp.where(live, jax.random.normal(kk, shape, jnp.bfloat16), 0)
+    v = jnp.where(live, jax.random.normal(kv, shape, jnp.bfloat16), 0)
+    cur = jnp.asarray(_decode_cursors(slots, page_size, prefilled),
+                      jnp.int32)
+    scales = {}
+    if int8:
+        def quant(x):       # the model's: symmetric, one scale a vector
+            x = x.astype(jnp.float32)
+            sc = jnp.maximum(jnp.max(jnp.abs(x), -1) / 127.0, 1e-8)
+            return (jnp.clip(jnp.round(x / sc[..., None]), -127, 127)
+                    .astype(jnp.int8), sc)
+        (k, ks), (v, vs) = quant(k), quant(v)
+        scales = dict(k_scale=ks, v_scale=vs)
+
+    kernel = jax.jit(lambda q, k, v: decode_attention(q, k, v, cur,
+                                                      **scales))
+    with record_traced() as traced:
+        _assert_mosaic(kernel, q, k, v)
+        got = kernel(q, k, v)
+    name = traced_name(traced["decode"]) or ""
+    if not name.startswith("pallas[hb=") or "+" in name:
+        raise AssertionError(f"decode kernel traced {traced['decode']}, "
+                             f"expected one pallas[hb=N]")
+
+    def dense(q, k, v):
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        if int8:
+            k, v = k * ks[..., None], v * vs[..., None]
+        s = jnp.einsum("bhd,bhld->bhl", q.astype(jnp.float32), k)
+        s = s / head_dim ** 0.5
+        s = jnp.where(jnp.arange(max_len)[None, None] <= cur[:, None, None],
+                      s, -1e30)
+        return jnp.einsum("bhl,bhld->bhd", jax.nn.softmax(s, -1), v)
+
+    return {"kernel": "decode_attention" + ("_int8" if int8 else ""),
+            "traced": name,
+            "shape": {"slots": slots, "heads": heads, "head_dim": head_dim,
+                      "max_len": max_len},
+            "cursors": [int(c) for c in cur],
+            "max_rel_err": _rel_err(got, jax.jit(dense)(q, k, v))}
+
+
+def decode_case(int8: bool, slots: int, max_len: int, page_size: int,
+                prefilled: int, heads: int, head_dim: int
                 ) -> Dict[str, object]:
-    """One single-token step through `Attention`, kernel vs dense branch,
-    on one prefilled cache with every row at its own cursor."""
+    """One single-token step through `Attention` over a prefilled page
+    pool, kernel vs dense branch, every row at its own cursor."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -125,60 +193,51 @@ def decode_case(paged: bool, int8: bool, slots: int, max_len: int,
     nblk = max_len // page_size
     cfg = TransformerConfig(
         num_heads=heads, embed_dim=heads * head_dim, max_len=max_len,
-        dtype=jnp.bfloat16, decode=True, decode_slots=True,
+        dtype=jnp.bfloat16, decode=True,
         kv_cache_dtype="int8" if int8 else None,
-        decode_page_size=page_size if paged else None,
-        decode_num_pages=slots * nblk + 1 if paged else 0)
+        decode_page_size=page_size, decode_num_pages=slots * nblk + 1)
     dense = Attention(dataclasses.replace(cfg, decode_kernel=False))
     kernel = Attention(dataclasses.replace(cfg, decode_kernel=True))
 
-    kw = {}
-    if paged:
-        # every row's logical blocks scattered over the pool (page 0 is
-        # the reserved trash page), so a kernel that ignored the table
-        # would read another row's pages
-        ids = np.random.RandomState(0).permutation(slots * nblk) + 1
-        kw["pages"] = jnp.asarray(ids.reshape(slots, nblk), jnp.int32)
+    # every row's logical blocks scattered over the pool (page 0 is the
+    # reserved trash page), so a kernel that ignored the table would
+    # read another row's pages
+    ids = np.random.RandomState(0).permutation(slots * nblk) + 1
+    pages = jnp.asarray(ids.reshape(slots, nblk), jnp.int32)
     kp, kx, ks = jax.random.split(jax.random.PRNGKey(1), 3)
     E = cfg.embed_dim
     x_fill = jax.random.normal(kx, (slots, prefilled, E), jnp.bfloat16)
     x_step = jax.random.normal(ks, (slots, 1, E), jnp.bfloat16)
     fill_pos = jnp.broadcast_to(jnp.arange(prefilled)[None],
                                 (slots, prefilled))
-    # cursors on and around the block boundaries of both kernels
-    # (k-tile 128, page 64), first and last filled position included
-    marks = [0, page_size - 1, page_size, 2 * page_size - 1,
-             2 * page_size, prefilled - 1, 5, prefilled - 9]
-    cur = jnp.asarray([min(max(m, 0), prefilled - 1)
-                       for m in (marks * slots)[:slots]], jnp.int32)
+    cur = jnp.asarray(_decode_cursors(slots, page_size, prefilled),
+                      jnp.int32)
 
-    params = dense.init(kp, x_step, positions=cur[:, None], **kw)["params"]
+    params = dense.init(kp, x_step, positions=cur[:, None],
+                        pages=pages)["params"]
     # the multi-token call is the dense branch under either setting
     _, filled = jax.jit(lambda p: dense.apply(
-        {"params": p}, x_fill, positions=fill_pos, mutable=["cache"],
-        **kw))(params)
+        {"params": p}, x_fill, positions=fill_pos, pages=pages,
+        mutable=["cache"]))(params)
 
     def step(module):
         return jax.jit(lambda p, c: module.apply(
             {"params": p, "cache": c}, x_step, positions=cur[:, None],
-            mutable=["cache"], **kw)[0])
+            pages=pages, mutable=["cache"])[0])
 
     with record_traced() as traced:
         _assert_mosaic(step(kernel), params, filled["cache"])
         got = step(kernel)(params, filled["cache"])
     # the kernel names itself with the kv heads a grid step took
-    want = ("pallas_paged" if paged else "pallas") + "[hb="
     name = traced_name(traced["decode"]) or ""
-    if not name.startswith(want) or "+" in name:
+    if not name.startswith("pallas_paged[hb=") or "+" in name:
         raise AssertionError(f"decode step traced {traced['decode']}, "
-                             f"expected one {want}N]")
+                             f"expected one pallas_paged[hb=N]")
     ref = step(dense)(params, filled["cache"])
-    return {"kernel": ("paged_decode_attention" if paged
-                       else "decode_attention") + ("_int8" if int8 else ""),
+    return {"kernel": "paged_decode_attention" + ("_int8" if int8 else ""),
             "traced": name,
             "shape": {"slots": slots, "heads": heads, "head_dim": head_dim,
-                      "max_len": max_len,
-                      **({"page_size": page_size} if paged else {})},
+                      "max_len": max_len, "page_size": page_size},
             "cursors": [int(c) for c in cur],
             "max_rel_err": _rel_err(got, ref)}
 
@@ -198,7 +257,7 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
 
     nblk = max_len // page_size
     cfg = LongcatConfig(max_len=max_len, dtype=jnp.bfloat16, decode=True,
-                        decode_slots=True, decode_page_size=page_size,
+                        decode_page_size=page_size,
                         decode_num_pages=slots * nblk + 1, **widths)
     dense = LatentAttention(dataclasses.replace(cfg, decode_kernel=False))
     kernel = LatentAttention(dataclasses.replace(cfg, decode_kernel=True))
@@ -261,10 +320,9 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
     records = flash_cases(train_shape["batch"], train_shape["seq"],
                           model["heads"], model["head_dim"])
     for geometry in decode_models or [model]:
-        for paged in (False, True):
+        for case in (contiguous_decode_case, decode_case):
             for int8 in (False, True):
-                records.append(
-                    decode_case(paged, int8, **serve_shape, **geometry))
+                records.append(case(int8, **serve_shape, **geometry))
     if mla:
         records.append(mla_decode_case(**mla))
     for rec in records:

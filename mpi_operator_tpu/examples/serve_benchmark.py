@@ -63,7 +63,6 @@ def run_serving_benchmark(
     temperature: float = 0.0,
     kv_cache_dtype: Optional[str] = None,
     decode_kernel: Optional[bool] = None,
-    paged: bool = False,
     page_size: int = 64,
     num_pages: Optional[int] = None,
     shared_prefix_len: int = 0,
@@ -92,14 +91,14 @@ def run_serving_benchmark(
     an EOS retirement costs the async loop one extra dispatched step, so
     the per-step rng stream shifts).
 
-    `paged` serves through the paged KV cache (EngineConfig.paged) with
-    `page_size`-token pages and `num_pages` physical pages (None = the
-    contiguous layout's byte budget). `shared_prefix_len` > 0 prepends
-    ONE seeded system prompt of that many tokens to every request — the
-    prefix-cache trace: the first wave prefills it cold and publishes,
-    later waves pin the shared pages and skip that prefill. The paged
-    report adds prefix_hit_rate, cold-vs-hit TTFT (admission-relative —
-    a hit skips prefill, not the queue), and page-occupancy peaks.
+    The engine's KV cache is a pool of `page_size`-token pages,
+    `num_pages` of them (None = every slot's worst case).
+    `shared_prefix_len` > 0 prepends ONE seeded system prompt of that
+    many tokens to every request — the prefix-cache trace: the first
+    wave prefills it cold and publishes, later waves pin the shared
+    pages and skip that prefill. The report carries prefix_hit_rate,
+    cold-vs-hit TTFT (admission-relative — a hit skips prefill, not the
+    queue), and page-occupancy peaks.
 
     `speculative` ("ngram") turns on speculative decoding with
     `draft_k` drafted tokens per greedy row; the report adds the
@@ -143,7 +142,7 @@ def run_serving_benchmark(
     # multiple of 128 — or anything <= 128 that the tile equals — works)
     need = shared_prefix_len + max(prompt_grid) + max(new_grid)
     max_len = need if need <= 128 else -(-need // 128) * 128
-    if paged and max_len % page_size:
+    if max_len % page_size:
         max_len = -(-max_len // page_size) * page_size
     name = f"{family}-{size}" if size else family
     model = create_lm(name, dtype=dtype, kv_cache_dtype=kv_cache_dtype,
@@ -180,7 +179,7 @@ def run_serving_benchmark(
     engine = ServingEngine(model, params, EngineConfig(
         slots=slots, chunk_buckets=tuple(chunk_buckets),
         decode_kernel=decode_kernel, rng_seed=seed,
-        paged=paged, page_size=page_size, num_pages=num_pages,
+        page_size=page_size, num_pages=num_pages,
         speculative=speculative, draft_k=draft_k),
         telemetry=wtel.serving, tracer=tracer)
     if metrics_port is not None:
@@ -275,7 +274,6 @@ def run_serving_benchmark(
         "serving_decode_kernel": bool(decode_kernel),
         "serving_async_decode": bool(engine.config.async_decode),
         "serving_cache_donated": engine.donates_cache,
-        "serving_paged": bool(paged),
     }
     if speculative is not None:
         # snapshot spec counters BEFORE any compare_* rerun resets them
@@ -301,41 +299,40 @@ def run_serving_benchmark(
             f"{out['serving_spec_effective_tokens_per_step']} effective "
             f"tokens/row-step over {spec['verify_steps']} verify steps, "
             f"{counts['verify']} verify compiles")
-    if paged:
-        # snapshot the allocator BEFORE any compare_sync rerun resets it
-        alloc = engine.page_allocator
-        lookups = alloc.hits + alloc.misses
-        ms = lambda v: round(v * 1e3, 3) if v is not None else None  # noqa: E731
-        # admission-relative TTFT: a prefix hit skips prefill work, not
-        # queueing delay, so the cold/hit split excludes the queue
-        adm = lambda r: r.token_times[0] - r.admitted_at  # noqa: E731
-        cold = _percentiles([adm(r) for r in results.values()
-                             if r.cached_tokens == 0 and r.token_times])
-        hit = _percentiles([adm(r) for r in results.values()
-                            if r.cached_tokens > 0 and r.token_times])
-        hit_reqs = sum(1 for r in results.values() if r.cached_tokens > 0)
-        out.update({
-            "serving_page_size": page_size,
-            "serving_pages_total": alloc.usable,
-            "serving_pages_in_use_peak": engine.pages_in_use_peak,
-            "serving_occupancy_peak": engine.occupancy_peak,
-            "serving_prefix_hit_rate": (round(alloc.hits / lookups, 4)
-                                        if lookups else 0.0),
-            "serving_prefix_hit_pages": alloc.hits,
-            "serving_prefix_miss_pages": alloc.misses,
-            "serving_prefix_hit_requests": hit_reqs,
-            "serving_ttft_cold_p50_ms": ms(cold[50]),
-            "serving_ttft_cold_p99_ms": ms(cold[99]),
-            "serving_ttft_hit_p50_ms": ms(hit[50]),
-            "serving_ttft_hit_p99_ms": ms(hit[99]),
-        })
-        log(f"paged KV: {alloc.usable} pages x {page_size} tokens, "
-            f"peak {engine.pages_in_use_peak} pages / "
-            f"{engine.occupancy_peak} slots in use; prefix hit rate "
-            f"{out['serving_prefix_hit_rate']} ({hit_reqs} hit reqs), "
-            f"TTFT-from-admission cold p50 "
-            f"{out['serving_ttft_cold_p50_ms']} ms vs hit p50 "
-            f"{out['serving_ttft_hit_p50_ms']} ms")
+    # snapshot the allocator BEFORE any compare_sync rerun resets it
+    alloc = engine.page_allocator
+    lookups = alloc.hits + alloc.misses
+    ms = lambda v: round(v * 1e3, 3) if v is not None else None  # noqa: E731
+    # admission-relative TTFT: a prefix hit skips prefill work, not
+    # queueing delay, so the cold/hit split excludes the queue
+    adm = lambda r: r.token_times[0] - r.admitted_at  # noqa: E731
+    cold = _percentiles([adm(r) for r in results.values()
+                         if r.cached_tokens == 0 and r.token_times])
+    hit = _percentiles([adm(r) for r in results.values()
+                        if r.cached_tokens > 0 and r.token_times])
+    hit_reqs = sum(1 for r in results.values() if r.cached_tokens > 0)
+    out.update({
+        "serving_page_size": page_size,
+        "serving_pages_total": alloc.usable,
+        "serving_pages_in_use_peak": engine.pages_in_use_peak,
+        "serving_occupancy_peak": engine.occupancy_peak,
+        "serving_prefix_hit_rate": (round(alloc.hits / lookups, 4)
+                                    if lookups else 0.0),
+        "serving_prefix_hit_pages": alloc.hits,
+        "serving_prefix_miss_pages": alloc.misses,
+        "serving_prefix_hit_requests": hit_reqs,
+        "serving_ttft_cold_p50_ms": ms(cold[50]),
+        "serving_ttft_cold_p99_ms": ms(cold[99]),
+        "serving_ttft_hit_p50_ms": ms(hit[50]),
+        "serving_ttft_hit_p99_ms": ms(hit[99]),
+    })
+    log(f"paged KV: {alloc.usable} pages x {page_size} tokens, "
+        f"peak {engine.pages_in_use_peak} pages / "
+        f"{engine.occupancy_peak} slots in use; prefix hit rate "
+        f"{out['serving_prefix_hit_rate']} ({hit_reqs} hit reqs), "
+        f"TTFT-from-admission cold p50 "
+        f"{out['serving_ttft_cold_p50_ms']} ms vs hit p50 "
+        f"{out['serving_ttft_hit_p50_ms']} ms")
     log(f"serving {name}: {num_requests} reqs over {slots} slots: "
         f"{tps:.0f} new tokens/sec, TTFT p50/p99 "
         f"{out['serving_ttft_p50_ms']}/{out['serving_ttft_p99_ms']} ms, "
@@ -479,7 +476,7 @@ def run_disagg_benchmark(
     """Disaggregated prefill/decode A/B vs the colocated engine at equal
     chip count: the same long-prompt-heavy greedy trace (the grid skews
     long — long prompts are exactly the TTFT/TPOT interference the
-    split removes) replays through a colocated paged ServingEngine and
+    split removes) replays through a colocated ServingEngine and
     a DisaggEngine built from the SAME params and config, reporting
     TTFT/TPOT p50/p99 for both, kv_handoff p50/p99, and the per-pool
     compile pins (prefill pool never compiles step, decode pool never
@@ -533,7 +530,7 @@ def run_disagg_benchmark(
     cfg = EngineConfig(
         slots=slots, chunk_buckets=tuple(chunk_buckets),
         decode_kernel=decode_kernel, rng_seed=seed,
-        paged=True, page_size=page_size, num_pages=num_pages)
+        page_size=page_size, num_pages=num_pages)
     coloc = ServingEngine(model, params, cfg)
     tracer = Tracer(sample=1.0)
     disagg = DisaggEngine(model, params, cfg, tracer=tracer)
@@ -665,7 +662,7 @@ def run_router_benchmark(
     log: Callable[[str], None] = print,
 ) -> Dict[str, object]:
     """Front-door A/B: the same seeded multi-tenant shared-system-prompt
-    trace through `replicas` paged engine replicas behind the Router,
+    trace through `replicas` engine replicas behind the Router,
     affinity ON vs OFF (pure load-aware), plus an overload burst.
 
     The trace draws each request's prompt as one of `num_tenants` seeded
@@ -743,7 +740,7 @@ def run_router_benchmark(
         e = ServingEngine(model, params, EngineConfig(
             slots=slots, chunk_buckets=tuple(chunk_buckets),
             decode_kernel=decode_kernel, rng_seed=seed,
-            paged=True, page_size=page_size, num_pages=num_pages))
+            page_size=page_size, num_pages=num_pages))
         e.run([Request(w.id, list(w.prompt), w.max_new_tokens)
                for w in warm])
         e.reset()
@@ -1005,7 +1002,7 @@ def run_livescale_benchmark(
         e = ServingEngine(model, params, EngineConfig(
             slots=slots, chunk_buckets=tuple(chunk_buckets),
             decode_kernel=decode_kernel, rng_seed=seed,
-            paged=True, page_size=page_size, num_pages=num_pages))
+            page_size=page_size, num_pages=num_pages))
         e.run([Request(w.id, list(w.prompt), w.max_new_tokens)
                for w in warm])
         e.reset()
@@ -1195,13 +1192,10 @@ def main(argv=None) -> int:
     parser.add_argument("--temperature", type=float, default=0.0)
     parser.add_argument("--kv-cache-dtype", default=None,
                         choices=[None, "int8"])
-    parser.add_argument("--paged", action="store_true",
-                        help="serve through the paged KV cache "
-                             "(block-table pages + prefix caching)")
     parser.add_argument("--page-size", type=int, default=64)
     parser.add_argument("--num-pages", type=int, default=None,
-                        help="physical KV pages (default: the contiguous "
-                             "layout's byte budget)")
+                        help="physical KV pages (default: every slot's "
+                             "worst case)")
     parser.add_argument("--shared-prefix-len", type=int, default=0,
                         help="prepend one seeded system prompt of this "
                              "many tokens to every request (the "
@@ -1236,7 +1230,7 @@ def main(argv=None) -> int:
                              "admission/shed threshold)")
     parser.add_argument("--disagg", action="store_true",
                         help="disaggregated prefill/decode A/B vs the "
-                             "colocated paged engine: same greedy trace "
+                             "colocated engine: same greedy trace "
                              "through both, TTFT/TPOT p50/p99 each, "
                              "kv_handoff p50/p99, token-identity + "
                              "per-pool compile pins")
@@ -1310,8 +1304,7 @@ def main(argv=None) -> int:
         size=args.size, family=args.family, slots=args.slots,
         num_requests=args.num_requests, dtype_name=args.dtype,
         temperature=args.temperature, kv_cache_dtype=args.kv_cache_dtype,
-        paged=args.paged, page_size=args.page_size,
-        num_pages=args.num_pages,
+        page_size=args.page_size, num_pages=args.num_pages,
         shared_prefix_len=args.shared_prefix_len,
         speculative=args.speculative, draft_k=args.draft_k,
         baseline=not args.no_baseline, compare_sync=args.compare_sync,

@@ -38,19 +38,17 @@ class GenerateResult(NamedTuple):
 
 
 def decode_model(model, decode_kernel: Optional[bool] = None,
-                 slots: bool = False, page_size: Optional[int] = None,
-                 num_pages: int = 0):
+                 page_size: Optional[int] = None, num_pages: int = 0):
     """The decode-mode twin of a trained CausalLM: same params (decode
     adds none, so checkpoints load directly), dense attention (the cache
     path does its own masking), no remat. `decode_kernel` None inherits
-    the model config. `slots=True` additionally flips `decode_slots` —
-    the per-row-cursor cache mode the serving engine drives
-    (serve/engine.py); generate() keeps the lockstep twin. `page_size`/
-    `num_pages` switch the slot cache to the paged page-pool layout
-    (transformer.py decode_page_size — requires slots=True)."""
+    the model config. generate() keeps the lockstep twin; `page_size`/
+    `num_pages` give the serving engine's (serve/programs.py): the
+    page-pool cache driven by per-row cursors and page tables
+    (transformer.py decode_page_size)."""
     cfg = model.config
     changes = dict(
-        decode=True, attention="dense", remat=False, decode_slots=slots,
+        decode=True, attention="dense", remat=False,
         decode_page_size=page_size, decode_num_pages=num_pages,
         decode_kernel=(cfg.decode_kernel if decode_kernel is None
                        else decode_kernel))

@@ -77,9 +77,8 @@ class LongcatConfig:
     causal: bool = True
     # decode mode, as in TransformerConfig (models/generate.decode_model
     # flips these on a copy): the latent cache is paged, so decode needs
-    # decode_slots and a page size
+    # a page size
     decode: bool = False
-    decode_slots: bool = False
     decode_page_size: Optional[int] = None
     decode_num_pages: int = 0
     decode_kernel: bool = False
@@ -177,12 +176,11 @@ class LatentAttention(nn.Module):
         B, S, H, dn = q_nope.shape
         rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         ps, NP = cfg.decode_page_size, cfg.decode_num_pages
-        if not cfg.decode_slots or ps is None or pages is None:
+        if ps is None or pages is None:
             raise ValueError(
                 "the latent cache is a page pool driven by the serving "
-                "engine: decode needs decode_slots=True, a "
-                "decode_page_size and the [B, max_len // page_size] page "
-                "table (EngineConfig(paged=True))")
+                "engine: decode needs a decode_page_size and the "
+                "[B, max_len // page_size] page table")
         L = cfg.max_len
         if ps < 1 or L % ps or NP < 2:
             raise ValueError(f"max_len={L} must be a multiple of "

@@ -94,27 +94,25 @@ class TransformerConfig:
     decode_kernel: bool = False
     # decode-kernel k-tile (None = ops.attention.decode_block_k default)
     decode_block_k: Optional[int] = None
-    # slot-cursor decode (serve/): every cache row is an independent
-    # request SLOT at its own generation depth. `positions` ([B, S])
-    # carries each row's absolute write/attend offsets, K/V writes
-    # scatter per-row, attention masks per-row, and the scalar
-    # `cache_index` variable is NOT created — the serving engine owns
-    # per-slot cursors host-side, so admitting/retiring requests never
-    # touches compiled code. Requires decode=True and explicit positions.
-    decode_slots: bool = False
-    # paged KV cache (serve/): the decode cache becomes a global POOL of
-    # fixed-size pages [decode_num_pages, KV, decode_page_size, D]
-    # instead of one contiguous [B, KV, max_len, D] row per slot. Each
-    # call takes `pages` ([B, max_len // page_size] int32): the per-row
-    # page table mapping logical KV blocks to physical pages. Writes
+    # the serving cache (serve/): with a page size the decode cache is a
+    # global POOL of fixed-size pages, `cached_kv` [decode_num_pages,
+    # decode_page_size, KV * 2D], instead of generate()'s lockstep
+    # [B, KV, max_len, D] rows, and every row of a call is an independent
+    # request SLOT at its own depth. `positions` ([B, S]) carries each
+    # row's absolute write/attend offsets and `pages` ([B, max_len //
+    # page_size] int32) its page table, mapping logical KV blocks to
+    # physical pages; the scalar `cache_index` variable is NOT created —
+    # the serving engine owns cursors and tables host-side, so
+    # admitting/retiring requests never touches compiled code. Writes
     # scatter to (table[pos // page_size], pos % page_size); reads gather
     # the table back into logical order (dense path) or index pages
     # directly per block (Pallas path). Page 0 is the reserved TRASH
     # page: unallocated table entries point at it, so fixed-shape junk
-    # writes from free/masked rows land somewhere harmless. Decouples
-    # slot count from max_len — HBM is budgeted in pages actually used,
-    # and prompt-prefix pages can be SHARED between requests (refcounted
-    # by the serving engine's PageAllocator). Requires decode_slots.
+    # writes from free/masked rows land somewhere harmless. HBM is
+    # budgeted in pages actually used, and prompt-prefix pages can be
+    # SHARED between requests (refcounted by the serving engine's
+    # PageAllocator); one page a slot (page_size == max_len) is the
+    # contiguous layout. Requires decode=True.
     decode_page_size: Optional[int] = None
     decode_num_pages: int = 0
     # latency-hiding tensor parallelism: run the tp-sharded projections
@@ -432,35 +430,27 @@ class Attention(nn.Module):
 
     def _decode_attend(self, q, k, v, positions=None, pages=None):
         """KV-cache attention for autoregressive decoding: append this
-        call's K/V at the cache cursor, attend q against everything
-        written so far (positions > cursor+S masked). Handles both the
-        multi-token prefill call and the steady-state single-token steps —
-        the cursor (`cache_index`) advances by the call's length. RoPE is
-        applied HERE (cursor-offset absolute positions) so cached keys
-        are pre-rotated.
+        call's K/V to the cache, attend q against everything written so
+        far. RoPE is applied HERE (absolute positions) so cached keys are
+        pre-rotated. Two regimes, told apart by `cfg.decode_page_size`:
 
-        With cfg.decode_slots the rows decouple: `positions` [B, S] gives
-        each row its OWN absolute offsets (row b writes its K/V at
-        positions[b] and attends cache <= positions[b]), the writes
-        become per-row scatters, and no cache_index variable exists —
-        the serving engine drives the cursors from the host, one
-        compiled step for any mix of request depths.
+        LOCKSTEP (no page size; `generate()`, the oracle): every row at
+        the same depth. One contiguous kv-head-MAJOR cache [B, KV, L, D]
+        (scales [B, KV, L]) — the tiled form the Pallas decode kernel
+        streams directly, and the layout whose head axis tp-shards
+        cleanly (logical "heads" → tp, parallel/sharding.py "cache" rule
+        for the length axis) — written at the scalar cursor
+        `cache_index`, which advances by the call's length; handles the
+        multi-token prefill call and the single-token steps.
 
-        Cache layout is kv-head-MAJOR [B, KV, L, D] (scales [B, KV, L]) —
-        the tiled form the Pallas decode kernel streams directly, and the
-        layout whose head axis tp-shards cleanly (logical "heads" → tp,
-        parallel/sharding.py "cache" rule for the length axis). GQA
-        caches the unrepeated kv_heads; with cfg.decode_kernel the
-        single-token steps run ops.attention.decode_attention, which is
-        GQA-native AND length-aware (only the filled prefix streams, int8
-        dequant fused into the read) — the dense path below stays the
-        correctness oracle and handles prefill (and, off TPU, cache
-        shapes the kernel cannot tile).
-
-        With cfg.decode_page_size the slot rows stop owning contiguous
-        cache: the cache becomes ONE pool of pages, `cached_kv`
-        [num_pages, page_size, KV * 2D] — a row a position, head h's K
-        and V side by side in the lane-aligned columns
+        PAGED (`serve/`): the rows are independent request slots.
+        `positions` [B, S] gives each row its OWN absolute offsets (row b
+        writes its K/V at positions[b] and attends cache <=
+        positions[b]) and no cache_index variable exists — the serving
+        engine drives the cursors from the host, one compiled step for
+        any mix of request depths. The cache is ONE pool of pages,
+        `cached_kv` [num_pages, page_size, KV * 2D] — a row a position,
+        head h's K and V side by side in the lane-aligned columns
         [2D*h, 2D*h + 2D) (ops.attention.kv_row_width: the form the chip
         keeps row-major where it lies, so the donated pool is aliased
         through a step and never copied) — and `pages`
@@ -472,7 +462,14 @@ class Attention(nn.Module):
         index maps (ops.attention.paged_decode_attention). An int8
         pool's float32 scale planes are [num_pages, KV, page_size]. Page
         0 is the trash sink for unallocated table entries; a tp mesh
-        splits the pool over the heads' columns."""
+        splits the pool over the heads' columns.
+
+        GQA caches the unrepeated kv_heads; with cfg.decode_kernel the
+        single-token steps run the regime's Pallas kernel, which is
+        GQA-native AND length-aware (only the filled prefix streams, int8
+        dequant fused into the read) — the dense path below stays the
+        correctness oracle and handles multi-token calls (and, off TPU,
+        cache shapes the kernel cannot tile)."""
         cfg = self.config
         B, S, H, D = q.shape
         KV = k.shape[2]
@@ -481,10 +478,6 @@ class Attention(nn.Module):
         if paged:
             ps = cfg.decode_page_size
             NP = cfg.decode_num_pages
-            if not cfg.decode_slots:
-                raise ValueError(
-                    "decode_page_size requires decode_slots=True (the "
-                    "serving engine owns the page tables)")
             if ps < 1 or L % ps:
                 raise ValueError(f"max_len={L} must be a multiple of "
                                  f"decode_page_size={ps}")
@@ -492,71 +485,34 @@ class Attention(nn.Module):
                 raise ValueError(
                     f"decode_num_pages={NP}: need >= 2 (page 0 is the "
                     f"reserved trash sink)")
-            if pages is None:
+            if positions is None or pages is None:
                 raise ValueError(
-                    "paged decode needs the [B, max_len//page_size] page "
-                    "table from the serving engine")
-        if cfg.decode_slots:
-            if positions is None:
-                raise ValueError(
-                    "decode_slots=True needs explicit positions ([B, S] "
-                    "absolute per-slot offsets from the serving engine)")
+                    "paged decode needs explicit positions ([B, S] "
+                    "absolute per-slot offsets) and the [B, max_len // "
+                    "page_size] page table from the serving engine")
             pos = jnp.broadcast_to(
                 jnp.asarray(positions, jnp.int32), (B, S))  # [B, S]
             cur = pos[:, 0]                       # [B] per-slot cursors
+            nblk = L // ps
+            pt = jnp.broadcast_to(jnp.asarray(pages, jnp.int32),
+                                  (B, nblk))
+            blk = jnp.minimum(pos // ps, nblk - 1)
+            phys = jnp.take_along_axis(pt, blk, axis=1)   # [B, S]
+            # junk positions past the logical cache (padded prefill
+            # tails, a retiring row's one post-EOS step) get an
+            # out-of-range index: scatters DROP out-of-bounds updates,
+            # so they never land anywhere (a clamped write could land
+            # inside a SHARED prefix page)
+            phys = jnp.where(pos < L, phys, NP)
+            off = pos % ps
+            flat = (phys * ps + off).reshape(-1)
 
-            if paged:
-                nblk = L // ps
-                pt = jnp.broadcast_to(jnp.asarray(pages, jnp.int32),
-                                      (B, nblk))
-                blk = jnp.minimum(pos // ps, nblk - 1)
-                phys = jnp.take_along_axis(pt, blk, axis=1)   # [B, S]
-                # junk positions past the logical cache (padded prefill
-                # tails, a retiring row's one post-EOS step) get an
-                # out-of-range index: scatters DROP out-of-bounds
-                # updates, so they never land anywhere — stronger than
-                # the contiguous path's clamp-to-last-row, which paging
-                # can't afford (a clamped write could land inside a
-                # SHARED prefix page)
-                phys = jnp.where(pos < L, phys, NP)
-                off = pos % ps
-                flat = (phys * ps + off).reshape(-1)
+            def upd_rows(c, u):   # pool [NP, ps, W] ← rows [B, S, W]
+                return c.reshape(NP * ps, -1).at[flat].set(
+                    u.reshape(B * S, -1), mode="drop").reshape(c.shape)
 
-                def upd_rows(c, u):   # pool [NP, ps, W] ← rows [B, S, W]
-                    return c.reshape(NP * ps, -1).at[flat].set(
-                        u.reshape(B * S, -1), mode="drop").reshape(c.shape)
-
-                def upd3(c, u):   # pool [NP, KV, ps] ← [B, KV, S]
-                    return c.at[phys, :, off].set(u.transpose(0, 2, 1))
-            elif S == 1:
-                def upd4(c, u):   # [B, KV, L, D] ← [B, KV, S, D] at cursors
-                    return jax.vmap(
-                        lambda cb, ub, s: jax.lax.dynamic_update_slice(
-                            cb, ub, (0, s, 0)))(c, u, cur)
-
-                def upd3(c, u):   # [B, KV, L] ← [B, KV, S] (int8 scales)
-                    return jax.vmap(
-                        lambda cb, ub, s: jax.lax.dynamic_update_slice(
-                            cb, ub, (0, s)))(c, u, cur)
-            else:
-                # multi-token decode (speculative verify, S = width > 1):
-                # dynamic_update_slice CLAMPS its start index, so a row
-                # whose window would cross L (cur + S > L) would silently
-                # shift its writes left over live history. Scatter with
-                # per-position indices instead: padded tail positions are
-                # set to L host-side and out-of-bounds scatter updates
-                # DROP, mirroring the paged path's trash-page semantics.
-                bidx = jnp.arange(B)[:, None]
-
-                def upd4(c, u):   # [B, KV, L, D] ← [B, KV, S, D] scatter
-                    # advanced indices [B, S] + slice dims put the index
-                    # dims in front: target block is [B, S, KV, D]
-                    return c.at[bidx, :, pos, :].set(
-                        u.transpose(0, 2, 1, 3), mode="drop")
-
-                def upd3(c, u):   # [B, KV, L] ← [B, KV, S] (int8 scales)
-                    return c.at[bidx, :, pos].set(
-                        u.transpose(0, 2, 1), mode="drop")
+            def upd3(c, u):   # pool [NP, KV, ps] ← [B, KV, S]
+                return c.at[phys, :, off].set(u.transpose(0, 2, 1))
 
             def bump():
                 pass          # the engine owns the cursors host-side
@@ -604,7 +560,7 @@ class Attention(nn.Module):
             ckv.value = upd_rows(ckv.value, pack_kv_rows(k, v))
             sc_shape = (NP, KV, ps)
         else:
-            # incoming projections are [B, S, KV, D]; the contiguous
+            # incoming projections are [B, S, KV, D]; the lockstep
             # cache wants the kv-head-major [B, KV, S, D] slab
             ck = self.variable("cache", "cached_key", jnp.zeros,
                                (B, KV, L, D), k.dtype)
@@ -671,7 +627,7 @@ class Attention(nn.Module):
         # dense oracle path (prefill, CPU correctness, unaligned shapes).
         # Paged caches gather the page table back into the logical rows
         # first, [B, L, KV, 2D] — trash/junk entries land at positions
-        # the visibility mask below excludes. The contiguous cache is
+        # the visibility mask below excludes. The lockstep cache is
         # kv-head-major [B, KV, L, D].
         if paged:
             # keys AND values of head h are its whole 2D-wide column block
@@ -703,7 +659,7 @@ class Attention(nn.Module):
         logits = jnp.einsum(f"bqhd,{kv_dims}->bhqk", q, keys)
         logits = logits.astype(jnp.float32) / jnp.sqrt(D)
         # per-row visibility: [B, S, L] (pos broadcasts from [S] in
-        # lockstep mode, is genuinely per-row in slot mode)
+        # the lockstep regime, is genuinely per-row in the paged one)
         visible = (jnp.arange(L)[None, None, :]
                    <= jnp.broadcast_to(pos, (B, S))[:, :, None])
         logits = jnp.where(visible[:, None], logits, -1e30)

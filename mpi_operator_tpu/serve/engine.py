@@ -5,21 +5,44 @@ prompts, lockstep to max_new_tokens, EOS rows burning full decode compute,
 no admission until the whole batch drains. This engine serves the same
 model the way a frontend needs it served:
 
-- **Slots.** The KV cache is ONE fixed [SLOTS, KV, L, D] buffer per layer
-  (transformer.py `decode_slots`); each row is an independent request at
-  its own depth, driven by per-row cursors the host owns. Finishing a
-  request frees its row immediately; the next queued request moves in.
-  Nothing about admission/retirement touches compiled code.
+- **Slots over one page pool.** The KV cache is ONE pool of fixed-size
+  pages per layer ([num_pages, page_size, KV * 2D]: a row a position,
+  each head's K and V side by side — transformer.py decode_page_size; a
+  latent row for longcat.py) and each of the SLOTS decode rows carries a
+  page TABLE. Every row is an independent request at its own depth,
+  driven by per-row cursors and tables the host owns. Finishing a
+  request frees its row and its pages immediately; the next queued
+  request moves in. Nothing about admission/retirement touches compiled
+  code. Slot count is decoupled from max_len, so the same cache bytes
+  serve strictly more concurrent requests whenever typical spans run
+  short of the worst case; `page_size == max_len` is one page a slot,
+  the contiguous layout, through the same path. Admission reserves a
+  request's whole worst-case page span up front (slots.PageAllocator;
+  scheduler packing skips past a head that doesn't fit), so decode
+  never allocates mid-flight.
+- **The compiled programs live in programs.py**, with the contract a
+  served model meets: `init_cache`, `prefill_paged`, `step_paged`,
+  `verify_paged`. This module is the host loop around them.
 - **One compiled decode step.** Every step advances ALL slots one token —
-  cursors, input tokens, and per-slot sampling params (temperature /
-  top-k / top-p, the traced-per-row generalization of generate's
-  `_sample`) are plain array operands. Compiled once, reused for the
-  lifetime of the engine (asserted via `compile_counts` in tests).
-- **Chunked prefill.** Prompts prefill in fixed windows bucketed to ≤3
-  compiled shapes (scheduler.plan_chunks), one chunk per engine loop
-  iteration, interleaved with decode steps — a long prompt cannot stall
-  in-flight decodes, and ragged prompt lengths stop forcing per-shape
-  recompiles.
+  cursors, page tables, input tokens, and per-slot sampling params
+  (temperature / top-k / top-p, the traced-per-row generalization of
+  generate's `_sample`) are plain array operands. Compiled once, reused
+  for the lifetime of the engine (asserted via `compile_counts` in tests).
+- **Chunked, batched prefill.** Prompts prefill in fixed windows
+  bucketed to ≤3 compiled shapes (scheduler.plan_chunks), one call per
+  engine loop iteration, interleaved with decode steps — a long prompt
+  cannot stall in-flight decodes, and ragged prompt lengths stop forcing
+  per-shape recompiles. One fixed-shape [slots, C] program per bucket
+  advances every waiting slot whose next chunk shares the bucket —
+  deeper queues amortize the same widths.
+- **Prefix caching** (`EngineConfig.prefix_cache`). Fully-prefilled
+  PROMPT pages are published into a refcounted prefix cache (chained
+  keys — exact token equality back to position 0), so a request sharing
+  a system prompt pins the existing pages and starts prefill at the
+  first divergent page; at worst-case TTFT the whole prompt is already
+  resident and the request goes straight to decode. Retired requests'
+  published pages linger in an evictable LRU until the free list runs
+  dry.
 - **Double-buffered decode.** The step's input tokens chain ON DEVICE:
   a decoding row's next input is the previous step's output for its slot
   (`jnp.where(use_prev, prev_tok, host_toks)`), so the host never has to
@@ -36,28 +59,6 @@ model the way a frontend needs it served:
   dispatch — same compiled program (compile_counts is mode-blind),
   token-identical at temperature 0, the A/B baseline the serving bench
   measures against.
-- **Paged KV + prefix caching** (`EngineConfig.paged`). The per-layer
-  cache becomes a POOL of fixed-size pages ([num_pages, page_size,
-  KV * 2D]: a row a position, each head's K and V side by side —
-  transformer.py decode_page_size) and each slot carries a page
-  TABLE instead of a contiguous row — slot count decouples from
-  max_len, so the same cache bytes serve strictly more concurrent
-  requests whenever typical spans run short of the worst case.
-  Admission reserves a request's whole worst-case page span up front
-  (slots.PageAllocator; scheduler packing skips past a head that
-  doesn't fit), so decode never allocates mid-flight. On top of pages:
-  fully-prefilled PROMPT pages are published into a refcounted prefix
-  cache (chained keys — exact token equality back to position 0), so a
-  request sharing a system prompt pins the existing pages and starts
-  prefill at the first divergent page; at worst-case TTFT the whole
-  prompt is already resident and the request goes straight to decode.
-  Retired requests' published pages linger in an evictable LRU until
-  the free list runs dry. Paged prefill is BATCHED: one fixed-shape
-  [slots, C] program per bucket advances every waiting slot whose next
-  chunk shares the bucket — same ≤3 compiled widths, deeper queues
-  amortize them. The contiguous path (paged=False, the default) stays
-  byte-for-byte what it was — it is the token-exactness oracle the
-  paged engine is pinned against in tests/test_paged_kv.py.
 
 - **Speculative decoding** (`EngineConfig.speculative`). Decode is one
   memory-bound HBM sweep per token; speculation turns k sequential
@@ -80,8 +81,9 @@ model the way a frontend needs it served:
   split gets speculation for free.
 
 Parity: at temperature 0 a single request produces token-for-token the
-same output as `generate()` — tests/test_serve.py pins this across the
-dense and Pallas decode-kernel paths, async and sync.
+same output as `generate()` — tests/test_serve.py and
+tests/test_paged_kv.py pin this across the dense and Pallas
+decode-kernel paths, async and sync.
 """
 from __future__ import annotations
 
@@ -93,11 +95,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from ..models.generate import cast_params, decode_model
+from ..models.generate import decode_model
 from ..telemetry import span
 from ..telemetry import events as ev
+from .programs import build_programs, cast_program
 from .scheduler import Request, RequestState, Scheduler
 from .slots import PageAllocator, SlotManager
 from .transfer import PageTransfer
@@ -114,15 +116,17 @@ class EngineConfig:
     double-buffered loop — see the module docstring); False drains every
     step before the next dispatch, through the same compiled program.
 
-    `paged` switches the cache to the page-pool layout: `page_size`
-    tokens per page (64 default — big enough that the page-table
-    indirection amortizes, small enough that a short request doesn't
-    strand half a row; must divide max_len, and the Pallas path wants a
-    multiple of 32 so every cache dtype tiles), `num_pages` physical
-    pages plus the reserved trash page (None sizes the pool to the
-    contiguous layout's bytes: slots * max_len // page_size, + 1 —
-    capacity wins then come from requests that DON'T use their worst
-    case). `prefix_cache` publishes fully-prefilled prompt pages for
+    The cache is a page pool: `page_size` tokens per page (64 default —
+    big enough that the page-table indirection amortizes, small enough
+    that a short request doesn't strand half a row; must divide max_len,
+    and the Pallas path wants a multiple of 32 so every cache dtype
+    tiles; `page_size == max_len` is one page a slot, the contiguous
+    layout), `num_pages` physical pages plus the reserved trash page
+    (None gives every slot its worst case: slots * max_len // page_size,
+    + 1 — capacity wins come from a smaller pool and requests that
+    DON'T use their worst case). `paged` has one legal value, True (the
+    benchmark's harness still passes it; False is refused).
+    `prefix_cache` publishes fully-prefilled prompt pages for
     cross-request sharing; False keeps pure paging. `admit_lookahead`
     bounds the packing scan past a head-of-queue that doesn't fit.
 
@@ -152,7 +156,7 @@ class EngineConfig:
     decode_kernel: Optional[bool] = None
     rng_seed: int = 0
     async_decode: bool = True
-    paged: bool = False
+    paged: bool = True
     page_size: int = 64
     num_pages: Optional[int] = None
     prefix_cache: bool = True
@@ -183,7 +187,7 @@ class RequestResult:
     #                                   before its first token)
     token_times: List[float]          # absolute (run-relative) per token
     cached_tokens: int = 0            # prompt span served from the prefix
-    #                                   cache (paged mode; 0 = cold)
+    #                                   cache (0 = cold)
     admitted_at: float = 0.0          # run-relative admission time —
     #                                   token_times[0] - admitted_at is
     #                                   TTFT with queueing excluded (the
@@ -344,6 +348,11 @@ class ServingEngine:
             mcfg = model.config
             if not mcfg.causal:
                 raise ValueError("serving needs a causal LM")
+            if not cfg.paged:
+                raise ValueError(
+                    "EngineConfig(paged=False): the page pool is the only "
+                    "cache regime. The contiguous-slot layout is a pool of "
+                    "one page a slot: page_size=max_len")
             for b in cfg.chunk_buckets:
                 if b > mcfg.max_len:
                     raise ValueError(f"chunk bucket {b} exceeds "
@@ -365,26 +374,21 @@ class ServingEngine:
             self.config = cfg
             self.model_config = mcfg
             ps = cfg.page_size
-            if cfg.paged:
-                if ps < 1 or mcfg.max_len % ps:
-                    raise ValueError(f"page_size={ps} must be >= 1 and divide "
-                                     f"max_len={mcfg.max_len}")
-                NP = cfg.num_pages
-                if NP is None:
-                    # as many positions as the contiguous layout's slots x
-                    # max_len, plus the trash page. In positions, not
-                    # bytes: a page's bytes follow the kind of cache the
-                    # model keeps (K and V a head, or one latent row —
-                    # `page_bytes()` counts them from the cache itself)
-                    NP = cfg.slots * (mcfg.max_len // ps) + 1
-                self.page_allocator: Optional[PageAllocator] = \
-                    PageAllocator(NP, ps)
-            else:
-                NP = 0
-                self.page_allocator = None
-            self.dmodel = decode_model(model, cfg.decode_kernel, slots=True,
-                                       page_size=ps if cfg.paged else None,
-                                       num_pages=NP)
+            if ps < 1 or mcfg.max_len % ps:
+                raise ValueError(f"page_size={ps} must be >= 1 and divide "
+                                 f"max_len={mcfg.max_len}")
+            NP = cfg.num_pages
+            if NP is None:
+                # every slot's worst case, slots x max_len positions,
+                # plus the trash page. In positions, not bytes: a page's
+                # bytes follow the kind of cache the model keeps (K and V
+                # a head, or one latent row — `page_bytes()` counts them
+                # from the cache itself)
+                NP = cfg.slots * (mcfg.max_len // ps) + 1
+            self.page_allocator = PageAllocator(NP, ps)
+            self._nblk = mcfg.max_len // ps
+            self.dmodel = decode_model(model, cfg.decode_kernel,
+                                       page_size=ps, num_pages=NP)
             self._base_rng = jax.random.PRNGKey(cfg.rng_seed)
             self._steps_dispatched = 0
             self.telemetry = telemetry
@@ -396,16 +400,13 @@ class ServingEngine:
             self._session_span = None
             if telemetry is not None:
                 telemetry.slots.set(cfg.slots)
-                if cfg.paged:
-                    telemetry.pages_total.set(self.page_allocator.usable)
+                telemetry.pages_total.set(self.page_allocator.usable)
 
-            dmodel = self.dmodel
-            dt = dmodel.config.dtype
+            dt = self.dmodel.config.dtype
             S = cfg.slots
 
-            # params cast once, device-resident across every step (decode is
-            # HBM-bound; see generate.cast_params for the barrier story)
-            self._cast = jax.jit(lambda p: cast_params(p, dt))
+            # params cast once, device-resident across every step
+            self._cast = cast_program(dt)
             # not synced: host-born weights may copy to the device while the
             # cache program below is traced; serve.init_cache waits for both
             with span("serve.cast_params"):
@@ -425,10 +426,10 @@ class ServingEngine:
             #   params uncommitted (the colocated default) — None, jit places
             #     everything on the default device.
             # Get this wrong and the step program compiles twice. On a mesh
-            # the step also PINS its token output there (pin_tok): left to
-            # GSPMD, a kernel that splits rows over dp hands back a
-            # dp-sharded token vector, and the second step would again see
-            # an input unlike the first's.
+            # the step also PINS its token output there (programs.py
+            # pin_tok): left to GSPMD, a kernel that splits rows over dp
+            # hands back a dp-sharded token vector, and the second step
+            # would again see an input unlike the first's.
             leaves = jax.tree.leaves(self.params)
             self._tok_sharding = None
             if leaves and isinstance(leaves[0].sharding,
@@ -439,186 +440,18 @@ class ServingEngine:
                 devs = leaves[0].devices()
                 if len(devs) == 1:
                     self._tok_sharding = next(iter(devs))
-            tok_sharding = self._tok_sharding
 
-            def pin_tok(tok):
-                if isinstance(tok_sharding, jax.sharding.NamedSharding):
-                    return lax.with_sharding_constraint(tok, tok_sharding)
-                return tok
-
-            nblk = mcfg.max_len // ps if cfg.paged else 0
-            self._nblk = nblk
-
-            # the model's own head where it has one (an untied matrix);
-            # CausalLM's is its tied table
-            head = getattr(dmodel, "head_logits", None)
-            if head is None:
-                from ..models.transformer import _head_matmul
-
-                def head(params, h):
-                    return _head_matmul(h, params["wte"]["embedding"])
-            # what a decode call of this model counts for itself, by name
-            # (an expert layer's routing): summed over the layers inside
-            # the step and fetched with its tokens, never a sync of its own
-            names = self._step_counters = tuple(
-                getattr(dmodel, "STEP_COUNTERS", ()))
-            counted = ["cache", "counters"] if names else ["cache"]
-
-            def step_counts(vars_):
-                return sum(jax.tree.leaves(vars_["counters"])) if names \
-                    else None
-
-            def init_cache(params):
-                # a zero-token step apply materializes the cache collection
-                # at its serving shape; the hidden-state output is discarded
-                z = jnp.zeros((S, 1), jnp.int32)
-                kw = ({"pages": jnp.zeros((S, nblk), jnp.int32)}
-                      if cfg.paged else {})
-                _, vars_ = dmodel.apply({"params": params}, z, positions=z,
-                                        with_head=False, mutable=["cache"],
-                                        **kw)
-                return vars_["cache"]
-
-            def prefill(params, cache, slot, tokens, start):
-                # one chunk for one slot: slice the row out, run the
-                # backbone headless over [1, C] tokens at absolute
-                # positions start..start+C, splice the row back. `slot` and
-                # `start` are traced operands — one compile per bucket C.
-                row = jax.tree.map(
-                    lambda x: lax.dynamic_slice_in_dim(x, slot, 1, 0), cache)
-                positions = (start + jnp.arange(tokens.shape[0]))[None]
-                _, vars_ = dmodel.apply(
-                    {"params": params, "cache": row}, tokens[None],
-                    positions=positions, with_head=False, mutable=["cache"])
-                return jax.tree.map(
-                    lambda full, r: lax.dynamic_update_slice_in_dim(
-                        full, r, slot, 0),
-                    cache, vars_["cache"])
-
-            def prefill_paged(params, cache, tokens, starts, pages):
-                # BATCHED chunk over the page pool: [S, C] tokens, one row
-                # per slot, writes routed through the page tables — the pool
-                # is shared so there is no row to slice out, and every
-                # waiting slot whose next chunk shares this bucket advances
-                # in the same program. Non-member rows carry zero tokens at
-                # max_len, past the logical cache: the page scatter drops
-                # their writes (transformer.py, longcat.py).
-                positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
-                _, vars_ = dmodel.apply(
-                    {"params": params, "cache": cache}, tokens,
-                    positions=positions, with_head=False, mutable=["cache"],
-                    pages=pages)
-                return vars_["cache"]
-
-            def step(params, cache, prev_tok, host_toks, use_prev, positions,
-                     rng, temperature, top_k, top_p, mode):
-                # ONE token for ALL slots: [S] tokens at [S] cursors. The
-                # input token per row comes from the DEVICE-side chain
-                # (prev_tok = last step's output, rows with use_prev) or from
-                # the host (bonus token after prefill) — the chain is what
-                # lets the host dispatch step N+1 without reading step N.
-                tokens = jnp.where(use_prev, prev_tok, host_toks)
-                h, vars_ = dmodel.apply(
-                    {"params": params, "cache": cache}, tokens[:, None],
-                    positions=positions[:, None], with_head=False,
-                    mutable=counted)
-                logits = head(params, h[:, 0])
-                tok, logp = sample_slots(logits, rng, temperature, top_k,
-                                         top_p, mode=mode)
-                return vars_["cache"], pin_tok(tok), logp, step_counts(vars_)
-
-            def step_paged(params, cache, prev_tok, host_toks, use_prev,
-                           positions, rng, temperature, top_k, top_p, pages,
-                           mode):
-                # the decode step with the per-slot page tables as one extra
-                # [S, nblk] operand — table churn (admit/retire) never
-                # recompiles, exactly like cursor churn
-                tokens = jnp.where(use_prev, prev_tok, host_toks)
-                h, vars_ = dmodel.apply(
-                    {"params": params, "cache": cache}, tokens[:, None],
-                    positions=positions[:, None], with_head=False,
-                    mutable=counted, pages=pages)
-                logits = head(params, h[:, 0])
-                tok, logp = sample_slots(logits, rng, temperature, top_k,
-                                         top_p, mode=mode)
-                return vars_["cache"], pin_tok(tok), logp, step_counts(vars_)
-
-            def _verify_targets(h, params, rng, temperature, top_k, top_p,
-                                mode):
-                # shared verify tail: [S, W] hidden states → per-position
-                # target tokens + logprobs. Column 0 is the plain decode
-                # step's sample (same sample_slots, so sampling rows in a
-                # mixed batch still draw correctly); columns 1.. are the
-                # greedy targets the drafts are checked against — argmax in
-                # float32, bitwise the same reduction sample_slots runs for
-                # a temperature-0 row, which is the token-exactness hinge.
-                Sv, W, E = h.shape
-                logits = head(params, h.reshape(Sv * W, E))
-                logits = logits.reshape(Sv, W, -1)
-                tok0, lp0 = sample_slots(logits[:, 0], rng, temperature,
-                                         top_k, top_p, mode=mode)
-                f32 = logits.astype(jnp.float32)
-                logp = jax.nn.log_softmax(f32)
-                greedy = jnp.argmax(f32, axis=-1)
-                glp = jnp.take_along_axis(logp, greedy[..., None],
-                                          axis=-1)[..., 0]
-                targets = greedy.at[:, 0].set(tok0)
-                return targets, glp.at[:, 0].set(lp0)
-
-            def verify(params, cache, toks, positions, rng, temperature,
-                       top_k, top_p, mode):
-                # ONE batched pass over [S, W] proposed tokens at explicit
-                # per-position cursors — a chunked-prefill-shaped step with
-                # right-aligned ragged rows. Row layout (host-built): column
-                # 0 = the row's real next input, columns 1..k = drafts,
-                # padded tail positions = max_len (out-of-bounds, so their
-                # K/V writes DROP — transformer.py's multi-token scatter).
-                # K/V for every column is written BEFORE attention reads it,
-                # and each query position attends only <= itself, so a
-                # row's rejected tail never contaminates an accepted
-                # position; the cursor rewind makes it invisible to every
-                # later step too.
-                h, vars_ = dmodel.apply(
-                    {"params": params, "cache": cache}, toks,
-                    positions=positions, with_head=False, mutable=["cache"])
-                targets, tlp = _verify_targets(h, params, rng, temperature,
-                                               top_k, top_p, mode)
-                return vars_["cache"], targets, tlp
-
-            def verify_paged(params, cache, toks, positions, rng, temperature,
-                             top_k, top_p, pages, mode):
-                # padded tail positions hit the trash-page guard instead of
-                # the scatter bound — same dropped-write semantics
-                h, vars_ = dmodel.apply(
-                    {"params": params, "cache": cache}, toks,
-                    positions=positions, with_head=False, mutable=["cache"],
-                    pages=pages)
-                targets, tlp = _verify_targets(h, params, rng, temperature,
-                                               top_k, top_p, mode)
-                return vars_["cache"], targets, tlp
-
-            # cache buffers are donated — the engine holds the only live
-            # reference, and the cache ([SLOTS, KV, L, D] per layer, or the
-            # page pool) is the biggest allocation here; donation keeps it
-            # single-buffered — and the pool's row-major form is the one
-            # every program reads and writes, so it is aliased, not copied. (CPU has no donation support and would warn
-            # per program.) prev_tok is NOT donated: the pending sync still
-            # reads its buffer after the next step consumed it.
-            donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()
-            self.donates_cache = bool(donate)
-            self._init_cache = jax.jit(init_cache)
-            if cfg.paged:
-                self._prefill = jax.jit(prefill_paged, donate_argnums=donate)
-                self._step = jax.jit(step_paged, donate_argnums=donate,
-                                     static_argnums=(11,))
-                self._verify = jax.jit(verify_paged, donate_argnums=donate,
-                                       static_argnums=(9,))
-            else:
-                self._prefill = jax.jit(prefill, donate_argnums=donate)
-                self._step = jax.jit(step, donate_argnums=donate,
-                                     static_argnums=(10,))
-                self._verify = jax.jit(verify, donate_argnums=donate,
-                                       static_argnums=(8,))
+            # the compiled programs (programs.py holds the model's
+            # contract); `sample_slots` is read off this module here, so
+            # whoever replaces it before construction is served
+            progs = build_programs(self.dmodel, cfg, self._tok_sharding,
+                                   sample_slots)
+            self._init_cache = progs.init_cache
+            self._prefill = progs.prefill
+            self._step = progs.step
+            self._verify = progs.verify
+            self._step_counters = progs.step_counters
+            self.donates_cache = progs.donates_cache
 
             self.scheduler = Scheduler(cfg.chunk_buckets, mcfg.max_len,
                                        admit_lookahead=cfg.admit_lookahead,
@@ -640,9 +473,9 @@ class ServingEngine:
             self._heartbeat = None
             self._heartbeat_last: Optional[float] = None
             # high-water marks over a run(): the capacity story in one pair
-            # of numbers (paged mode sustains more slots than contiguous at
-            # equal cache bytes exactly when pages_in_use_peak stays under
-            # the pool while occupancy_peak exceeds the contiguous slot cap)
+            # of numbers (a pool sustains more slots than slots x max_len
+            # positions would exactly when pages_in_use_peak stays under the
+            # pool while occupancy_peak exceeds that layout's slot cap)
             self.occupancy_peak = 0
             self.pages_in_use_peak = 0
             # speculation run counters (host truth the bench reads;
@@ -676,17 +509,16 @@ class ServingEngine:
                                    .admit_lookahead,
                                    reserve=self.RESERVE)
         self.slots = SlotManager(self.config.slots)
-        if self.page_allocator is not None:
-            if os.environ.get("TPU_DEBUG_PAGES") == "1":
-                # O(num_pages) invariant audit of the state the trace
-                # left behind — debug builds only (the test suite sets
-                # TPU_DEBUG_PAGES=1), so the bench's warmup→measure
-                # reset stays O(slots)
-                self.page_allocator.check()
-            # rewind refcounts, free list, AND the prefix cache — cached
-            # pages index into a cache whose contents init_cache is about
-            # to zero, so carrying them over would serve stale K/V
-            self.page_allocator.reset()
+        if os.environ.get("TPU_DEBUG_PAGES") == "1":
+            # O(num_pages) invariant audit of the state the trace left
+            # behind — debug builds only (the test suite sets
+            # TPU_DEBUG_PAGES=1), so the bench's warmup→measure reset
+            # stays O(slots)
+            self.page_allocator.check()
+        # rewind refcounts, free list, AND the prefix cache — cached
+        # pages index into a cache whose contents init_cache is about
+        # to zero, so carrying them over would serve stale K/V
+        self.page_allocator.reset()
         self.cache = self._init_cache(self.params)
         self._prev_tok = self._zeros_tok(self.config.slots)
         self._prefill_queued = (0, 0)
@@ -728,23 +560,19 @@ class ServingEngine:
         Lowers and compiles the step again (a load, where the persistent
         cache has it): for after a measured window, never inside one."""
         from ..telemetry.hlo_names import instruction_scopes
-        cfg = self.config
-        S = cfg.slots
+        S = self.config.slots
         i32 = lambda *shape: jnp.zeros(shape, jnp.int32)     # noqa: E731
         f32 = lambda *shape: jnp.zeros(shape, jnp.float32)   # noqa: E731
-        extra = (i32(S, self._nblk),) if cfg.paged else ()
         lowered = self._step.lower(
             self.params, self.cache, self._prev_tok, i32(S),
             jnp.zeros((S,), bool), i32(S), self._base_rng, f32(S), i32(S),
-            f32(S), *extra, "greedy")
+            f32(S), i32(S, self._nblk), "greedy")
         return instruction_scopes(lowered.compile().as_text())
 
     def page_bytes(self) -> int:
         """Bytes one page holds over every layer, counted from the cache
         the model made: K and V a head (and int8 scales) for a per-head
         cache, one latent row a position for a latent one."""
-        if self.page_allocator is None:
-            raise ValueError("page_bytes() of an engine that is not paged")
         NP = self.page_allocator.num_pages
         return sum(x.nbytes for x in jax.tree.leaves(self.cache)
                    if x.shape[0] == NP) // NP
@@ -789,29 +617,6 @@ class ServingEngine:
 
     # -- the loop ---------------------------------------------------------
 
-    def _run_prefill_chunk(self, st: RequestState) -> None:
-        w, size = st.chunks.pop(0)
-        p1 = len(st.req.prompt) - 1
-        with span("serve.prefill"):
-            window = list(st.req.prompt[w:min(w + size, p1)])
-            window += [0] * (size - len(window))  # right-pad short prompts
-            t0 = time.perf_counter()
-            self.cache = self._prefill(
-                self.params, self.cache, jnp.int32(st.slot),
-                jnp.asarray(window, jnp.int32), jnp.int32(w))
-        self._note_prefill_queued(1, size)
-        if self.telemetry is not None:
-            # async dispatch: host wall time, not device time — the next
-            # decode step's sync absorbs any queued prefill work (that
-            # step's serve.decode_step span carries prefill_rows, and its
-            # serve.sync is the wait)
-            self.telemetry.prefill_seconds.observe(time.perf_counter() - t0)
-        st.pos = min(p1, w + size)
-        if not st.chunks:
-            rt = self._trace(st.req.id)
-            if rt is not None:
-                rt.begin_hop(self.POST_PREFILL_HOP, self._trace_now())
-
     def _note_prefill_queued(self, rows: int, bucket: int) -> None:
         r, b = self._prefill_queued
         self._prefill_queued = (r + rows, max(b, bucket))
@@ -826,10 +631,10 @@ class ServingEngine:
         return pt
 
     def _run_prefill_batched(self, lead: RequestState) -> None:
-        """Paged prefill: advance EVERY waiting slot whose next chunk
-        shares the lead's bucket in one [S, C] program — deeper queues
-        amortize the same ≤3 compiled widths instead of serializing one
-        chunk per loop iteration. Rows that are no member of the call run
+        """Prefill: advance EVERY waiting slot whose next chunk shares
+        the lead's bucket in one [S, C] program — deeper queues amortize
+        the same ≤3 compiled widths instead of serializing one chunk per
+        loop iteration. Rows that are no member of the call run
         zero tokens at `max_len`: a position past the logical cache, whose
         writes the page scatter drops (as a verify step's padded tail),
         and which a model that walks only the pages its queries reach
@@ -856,6 +661,10 @@ class ServingEngine:
                 jnp.asarray(starts), jnp.asarray(self._page_table_array()))
         self._note_prefill_queued(len(batch), size)
         if self.telemetry is not None:
+            # async dispatch: host wall time, not device time — the next
+            # decode step's sync absorbs any queued prefill work (that
+            # step's serve.decode_step span carries prefill_rows, and its
+            # serve.sync is the wait)
             self.telemetry.prefill_seconds.observe(time.perf_counter() - t0)
         for st, w, p1 in done:
             st.pos = max(st.pos, min(p1, w + size))
@@ -912,13 +721,12 @@ class ServingEngine:
             rng = jax.random.fold_in(self._base_rng, self._steps_dispatched)
             self._steps_dispatched += 1
             step_t0 = time.perf_counter()
-            extra = ((jnp.asarray(self._page_table_array()),)
-                     if self.config.paged else ())
             self.cache, out_tok, out_logp, out_counts = self._step(
                 self.params, self.cache, self._prev_tok,
                 jnp.asarray(toks), jnp.asarray(use_prev), jnp.asarray(pos),
                 rng, jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), *extra, mode)
+                jnp.asarray(top_ps),
+                jnp.asarray(self._page_table_array()), mode)
         self._prev_tok = out_tok                 # the device-side chain
         for st in consumers:
             st.pos += 1                          # the step wrote at pos
@@ -1027,12 +835,11 @@ class ServingEngine:
             rng = jax.random.fold_in(self._base_rng, self._steps_dispatched)
             self._steps_dispatched += 1
             step_t0 = time.perf_counter()
-            extra = ((jnp.asarray(self._page_table_array()),)
-                     if cfg.paged else ())
             self.cache, dev_tg, dev_lp = self._verify(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(posn), rng, jnp.asarray(temps),
-                jnp.asarray(top_ks), jnp.asarray(top_ps), *extra, mode)
+                jnp.asarray(top_ks), jnp.asarray(top_ps),
+                jnp.asarray(self._page_table_array()), mode)
         tel = self.telemetry
         with span("serve.sync", caused_by=sp.id):
             gap_t0 = time.perf_counter()
@@ -1043,7 +850,6 @@ class ServingEngine:
             tel.host_gap_seconds.observe(t_sync - gap_t0)
             tel.decode_step_seconds.observe(t_sync - step_t0)
         now = now_fn()
-        ps = cfg.page_size if cfg.paged else None
         finished: List[RequestState] = []
         self.spec_steps += 1
         spec_p0, spec_a0 = self.spec_proposed, self.spec_accepted
@@ -1065,7 +871,7 @@ class ServingEngine:
                 written = len(d) + 1         # columns this row really wrote
                 st.pos += written
                 if written > emit:
-                    self.slots.rewind(st.slot, written - emit, page_size=ps)
+                    self.slots.rewind(st.slot, written - emit, cfg.page_size)
                 st.dispatched += emit
                 if d:
                     self.spec_proposed += len(d)
@@ -1201,7 +1007,7 @@ class ServingEngine:
                                  slot=st.slot,
                                  prompt_len=len(st.req.prompt),
                                  cached_tokens=st.cached_tokens)
-            if tel is not None and alloc is not None:
+            if tel is not None:
                 ps_ = alloc.page_size
                 full = (len(st.req.prompt) - 1) // ps_
                 hit = st.cached_tokens // ps_
@@ -1218,14 +1024,12 @@ class ServingEngine:
         if not st.slot_released:          # EOS path: freed here; the
             self.slots.release(st)        # length path freed its row
             st.slot_released = True       # at dispatch already
-        if alloc is not None:
-            # drop every reference this request held — pinned shared
-            # prefix pages and private pages alike; its PUBLISHED pages
-            # park in the evictable LRU where future lookups still find
-            # them
-            for p in st.owned_pages:
-                alloc.release(p)
-            st.owned_pages = []
+        # drop every reference this request held — pinned shared prefix
+        # pages and private pages alike; its PUBLISHED pages park in the
+        # evictable LRU where future lookups still find them
+        for p in st.owned_pages:
+            alloc.release(p)
+        st.owned_pages = []
         if self.events is not None:
             self.events.emit(
                 ev.SLOT_RETIRE, request=st.req.id, slot=st.slot,
@@ -1326,11 +1130,10 @@ class ServingEngine:
         if last is not None and now - last < interval:
             return
         self._heartbeat_last = now
-        alloc = self.page_allocator
         hook(now=now,
              queue_depth=len(self.scheduler.queue),
              free_slots=len(self.slots.free),
-             free_pages=alloc.available if alloc is not None else 0)
+             free_pages=self.page_allocator.available)
 
     def submit(self, req: Request) -> None:
         """Queue one request into the open session (front-door entry
@@ -1339,16 +1142,15 @@ class ServingEngine:
         if self._session is None:
             raise RuntimeError("submit() outside a session (call start())")
         alloc = self.page_allocator
-        if alloc is not None:
-            need = Scheduler.pages_needed(req, alloc.page_size)
-            if need > alloc.usable:
-                # a request the pool can NEVER satisfy would sit at
-                # the head of the queue forever (admission livelock);
-                # reject it up front like an over-max_len prompt
-                raise ValueError(
-                    f"request {req.id}: worst-case span needs {need} KV "
-                    f"pages but the pool has {alloc.usable} usable "
-                    f"(raise num_pages or lower max_new_tokens)")
+        need = Scheduler.pages_needed(req, alloc.page_size)
+        if need > alloc.usable:
+            # a request the pool can NEVER satisfy would sit at the head
+            # of the queue forever (admission livelock); reject it up
+            # front like an over-max_len prompt
+            raise ValueError(
+                f"request {req.id}: worst-case span needs {need} KV "
+                f"pages but the pool has {alloc.usable} usable "
+                f"(raise num_pages or lower max_new_tokens)")
         self.scheduler.submit(req)
         if self.tracer is not None:
             # open (or, behind a router / on a failover replay, JOIN)
@@ -1391,19 +1193,16 @@ class ServingEngine:
                 # its slot before this iteration's admission fills the rows
                 self._sweep_timeouts(now, results)
                 self._note_admissions(
-                    self.scheduler.admit(self.slots.free, now,
-                                         allocator=alloc))
+                    self.scheduler.admit(self.slots.free, now, alloc))
             self.occupancy_peak = max(self.occupancy_peak,
                                       self.slots.occupied)
-            if alloc is not None:
-                self.pages_in_use_peak = max(self.pages_in_use_peak,
-                                             alloc.in_use)
+            self.pages_in_use_peak = max(self.pages_in_use_peak,
+                                         alloc.in_use)
             if tel is not None:
                 tel.queue_depth.set(len(self.scheduler.queue))
                 tel.slot_occupancy.set(self.slots.occupied)
-                if alloc is not None:
-                    tel.pages_in_use.set(alloc.in_use)
-                    tel.pages_cached.set(alloc.cached_pages)
+                tel.pages_in_use.set(alloc.in_use)
+                tel.pages_cached.set(alloc.cached_pages)
             # heartbeat AFTER admission: the published queue depth is what
             # is still waiting behind the slots, not this instant's intake
             self._maybe_heartbeat(now)
@@ -1417,10 +1216,7 @@ class ServingEngine:
                     return False
             st = self.scheduler.next_prefill()
             if st is not None:
-                if self.config.paged:
-                    self._run_prefill_batched(st)
-                else:
-                    self._run_prefill_chunk(st)
+                self._run_prefill_batched(st)
             planned = {}
             if (self.config.speculative is not None
                     and self.scheduler.decoding()):
@@ -1534,9 +1330,6 @@ class PrefillEngine(ServingEngine):
     def __init__(self, model, params, config: Optional[EngineConfig] = None,
                  telemetry=None, events=None, tracer=None):
         cfg = config or EngineConfig()
-        if not cfg.paged:
-            raise ValueError("disaggregated serving requires paged=True "
-                             "(the handoff unit is a page list)")
         # the prefill pool never decodes, so it never drafts either —
         # strip the speculation knob rather than make it validate a
         # drafter it will not call
@@ -1569,15 +1362,6 @@ class DecodeEngine(ServingEngine):
     engine — a handed-off prompt whose prefix is already resident here
     needs NO bytes moved for those pages (DisaggEngine transfers only
     the misses)."""
-
-    def __init__(self, model, params, config: Optional[EngineConfig] = None,
-                 telemetry=None, events=None, drafter=None, tracer=None):
-        cfg = config or EngineConfig()
-        if not cfg.paged:
-            raise ValueError("disaggregated serving requires paged=True "
-                             "(the handoff unit is a page list)")
-        super().__init__(model, params, cfg, telemetry=telemetry,
-                         events=events, drafter=drafter, tracer=tracer)
 
     def install_handoff(self, req: Request, reserved, now: float,
                         cached_tokens: int = 0,
@@ -1653,7 +1437,7 @@ class DisaggEngine:
     deadlock on pages.
 
     Token parity: at temperature 0 the facade is token-for-token
-    identical to a colocated paged ServingEngine over the same trace
+    identical to a colocated ServingEngine over the same trace
     (tests/test_disagg.py pins it, dense and Pallas-kernel, int8 KV
     included): per-slot prefill/step rows are computed independently,
     so batching composition doesn't change a row's KV; the handoff
@@ -1672,10 +1456,8 @@ class DisaggEngine:
                  *, prefill_config: Optional[EngineConfig] = None,
                  registry=None, events=None, devices=None, drafter=None,
                  tracer=None):
-        cfg = config or EngineConfig(paged=True)
+        cfg = config or EngineConfig()
         pcfg = prefill_config or cfg
-        if not cfg.paged or not pcfg.paged:
-            raise ValueError("disaggregated serving requires paged=True")
         if pcfg.page_size != cfg.page_size:
             raise ValueError(
                 f"prefill/decode page_size disagree "
@@ -1906,7 +1688,7 @@ class DisaggEngine:
             with span("serve.schedule"):
                 pre._note_admissions(
                     pre.scheduler.admit(pre.slots.free, now,
-                                        allocator=pre.page_allocator))
+                                        pre.page_allocator))
             for eng, qdepth in ((pre, len(pre.scheduler.queue)),
                                 (dec, len(self._handoff_q))):
                 eng.occupancy_peak = max(eng.occupancy_peak,

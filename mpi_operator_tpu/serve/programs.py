@@ -1,0 +1,189 @@
+"""The serving engine's compiled programs, and what a served model owes
+them.
+
+`build_programs` is the one place where the engine meets the model: it
+jits the four programs every tick dispatches (`init_cache`,
+`prefill_paged`, `step_paged`, `verify_paged` — the names a device trace
+shows them under) over a decode-mode model's `apply`. The host loop in
+engine.py owns everything else: which rows a call carries, cursors, page
+tables, retirement. Narrowing prefill, or serving another model family,
+is an edit here and nowhere in the loop.
+"""
+from __future__ import annotations
+
+from typing import Callable, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..models.generate import cast_params
+
+
+class Programs(NamedTuple):
+    init_cache: Callable      # (params) -> cache
+    prefill: Callable         # (params, cache, tokens, starts, pages)
+    step: Callable            # (params, cache, prev_tok, host_toks,
+    #                            use_prev, positions, rng, temperature,
+    #                            top_k, top_p, pages, mode)
+    verify: Callable          # (params, cache, toks, positions, rng,
+    #                            temperature, top_k, top_p, pages, mode)
+    step_counters: Tuple[str, ...]   # the model's STEP_COUNTERS
+    donates_cache: bool
+
+
+def cast_program(dtype):
+    """The weights' one cast to the served dtype, its output resident
+    across every step (decode is HBM-bound; see generate.cast_params for
+    the barrier story)."""
+    return jax.jit(lambda p: cast_params(p, dtype))
+
+
+def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
+    """Jit the engine's programs over `dmodel` for `cfg` (an
+    EngineConfig: `slots` rows, pages of `page_size`). `tok_sharding` is
+    where the step's token output is pinned on a mesh (engine.py
+    `_tok_sharding`); `sample` is the engine's `sample_slots`.
+
+    The contract a served model meets (`CausalLM` and `LongcatLM` do):
+
+    - `dmodel.config` has `max_len`, and was made by
+      `generate.decode_model(model, kernel, page_size=, num_pages=)`: a
+      flax module in decode mode whose cache is a page pool.
+    - `dmodel.apply({"params": p, "cache": c}, tokens, positions=,
+      pages=, with_head=False, mutable=[...])` takes `[B, S]` tokens at
+      `[B, S]` absolute positions with the `[B, max_len // page_size]`
+      page tables and returns the final hidden states `[B, S, E]` and
+      the mutated collections: `cache` always; `counters` when asked. A
+      position at `max_len` is junk: its write is dropped and nothing
+      reads it. Applied WITHOUT a `cache` collection it creates one at
+      the call's batch.
+    - the cache's pooled leaves lead with `num_pages`
+      (`ServingEngine.page_bytes()`, `transfer.PageTransfer`).
+    - optional `head_logits(params, h)`: `[T, E]` hidden states to
+      `[T, vocab]` logits, for an untied head. Without it the head is
+      the tied table `params["wte"]["embedding"]`.
+    - optional `STEP_COUNTERS`: names for what a decode call sows into
+      `counters` (equal-shaped leaves, one entry a name), summed over
+      the layers inside the step and fetched with its tokens.
+    """
+    S = cfg.slots
+    nblk = dmodel.config.max_len // cfg.page_size
+
+    def pin_tok(tok):
+        if isinstance(tok_sharding, jax.sharding.NamedSharding):
+            return lax.with_sharding_constraint(tok, tok_sharding)
+        return tok
+
+    head = getattr(dmodel, "head_logits", None)
+    if head is None:
+        from ..models.transformer import _head_matmul
+
+        def head(params, h):
+            return _head_matmul(h, params["wte"]["embedding"])
+    names = tuple(getattr(dmodel, "STEP_COUNTERS", ()))
+    counted = ["cache", "counters"] if names else ["cache"]
+
+    def step_counts(vars_):
+        return sum(jax.tree.leaves(vars_["counters"])) if names else None
+
+    def init_cache(params):
+        # a zero-token step apply materializes the cache collection
+        # at its serving shape; the hidden-state output is discarded
+        z = jnp.zeros((S, 1), jnp.int32)
+        _, vars_ = dmodel.apply({"params": params}, z, positions=z,
+                                with_head=False, mutable=["cache"],
+                                pages=jnp.zeros((S, nblk), jnp.int32))
+        return vars_["cache"]
+
+    def prefill_paged(params, cache, tokens, starts, pages):
+        # BATCHED chunk over the page pool: [S, C] tokens, one row
+        # per slot, writes routed through the page tables — the pool
+        # is shared so there is no row to slice out, and every
+        # waiting slot whose next chunk shares this bucket advances
+        # in the same program. Non-member rows carry zero tokens at
+        # max_len, past the logical cache: the page scatter drops
+        # their writes (transformer.py, longcat.py).
+        positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
+        _, vars_ = dmodel.apply(
+            {"params": params, "cache": cache}, tokens,
+            positions=positions, with_head=False, mutable=["cache"],
+            pages=pages)
+        return vars_["cache"]
+
+    def step_paged(params, cache, prev_tok, host_toks, use_prev,
+                   positions, rng, temperature, top_k, top_p, pages,
+                   mode):
+        # ONE token for ALL slots: [S] tokens at [S] cursors. The
+        # input token per row comes from the DEVICE-side chain
+        # (prev_tok = last step's output, rows with use_prev) or from
+        # the host (bonus token after prefill) — the chain is what
+        # lets the host dispatch step N+1 without reading step N.
+        # The per-slot page tables are one [S, nblk] operand — table
+        # churn (admit/retire) never recompiles, exactly like cursor
+        # churn
+        tokens = jnp.where(use_prev, prev_tok, host_toks)
+        h, vars_ = dmodel.apply(
+            {"params": params, "cache": cache}, tokens[:, None],
+            positions=positions[:, None], with_head=False,
+            mutable=counted, pages=pages)
+        logits = head(params, h[:, 0])
+        tok, logp = sample(logits, rng, temperature, top_k, top_p,
+                           mode=mode)
+        return vars_["cache"], pin_tok(tok), logp, step_counts(vars_)
+
+    def verify_paged(params, cache, toks, positions, rng, temperature,
+                     top_k, top_p, pages, mode):
+        # ONE batched pass over [S, W] proposed tokens at explicit
+        # per-position cursors — a chunked-prefill-shaped step with
+        # right-aligned ragged rows. Row layout (host-built): column
+        # 0 = the row's real next input, columns 1..k = drafts,
+        # padded tail positions = max_len (past the logical cache, so
+        # their K/V writes DROP). K/V for every column is written
+        # BEFORE attention reads it, and each query position attends
+        # only <= itself, so a row's rejected tail never contaminates
+        # an accepted position; the cursor rewind makes it invisible
+        # to every later step too.
+        h, vars_ = dmodel.apply(
+            {"params": params, "cache": cache}, toks,
+            positions=positions, with_head=False, mutable=["cache"],
+            pages=pages)
+        # [S, W] hidden states → per-position target tokens +
+        # logprobs. Column 0 is the plain decode step's sample (same
+        # sampler, so sampling rows in a mixed batch still draw
+        # correctly); columns 1.. are the greedy targets the drafts
+        # are checked against — argmax in float32, bitwise the same
+        # reduction the sampler runs for a temperature-0 row, which is
+        # the token-exactness hinge.
+        Sv, W, E = h.shape
+        logits = head(params, h.reshape(Sv * W, E))
+        logits = logits.reshape(Sv, W, -1)
+        tok0, lp0 = sample(logits[:, 0], rng, temperature, top_k, top_p,
+                           mode=mode)
+        f32 = logits.astype(jnp.float32)
+        logp = jax.nn.log_softmax(f32)
+        greedy = jnp.argmax(f32, axis=-1)
+        glp = jnp.take_along_axis(logp, greedy[..., None],
+                                  axis=-1)[..., 0]
+        return (vars_["cache"], greedy.at[:, 0].set(tok0),
+                glp.at[:, 0].set(lp0))
+
+    # cache buffers are donated — the engine holds the only live
+    # reference, and the page pool is the biggest allocation here;
+    # donation keeps it single-buffered — and the pool's row-major form
+    # is the one every program reads and writes, so it is aliased, not
+    # copied. (CPU has no donation support and would warn per program.)
+    # prev_tok is NOT donated: the pending sync still reads its buffer
+    # after the next step consumed it.
+    donate = (1,) if jax.default_backend() in ("tpu", "gpu") else ()
+    return Programs(
+        init_cache=jax.jit(init_cache),
+        prefill=jax.jit(prefill_paged, donate_argnums=donate),
+        step=jax.jit(step_paged, donate_argnums=donate,
+                     static_argnums=(11,)),
+        verify=jax.jit(verify_paged, donate_argnums=donate,
+                       static_argnums=(9,)),
+        step_counters=names, donates_cache=bool(donate))
+
+
+__all__ = ["Programs", "build_programs", "cast_program"]

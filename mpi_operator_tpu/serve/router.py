@@ -134,11 +134,8 @@ class ReplicaHandle:
     def affinity_pages(self, prompt: Sequence[int]) -> int:
         """Warm-chain depth (pages) this replica's prefix cache holds
         for `prompt` — PageAllocator.probe, i.e. EXACTLY the keying its
-        own admission lookup will walk. 0 without paging."""
-        alloc = self.engine.page_allocator
-        if alloc is None:
-            return 0
-        return alloc.probe(prompt)
+        own admission lookup will walk."""
+        return self.engine.page_allocator.probe(prompt)
 
     def load(self) -> tuple:
         """Load-aware dispatch key, ascending = less loaded: in-flight
@@ -148,19 +145,15 @@ class ReplicaHandle:
         `tpu_worker_kv_pages_*` gauges an out-of-process router would
         scrape; in-process it reads the same state directly."""
         eng = self.engine
-        alloc = eng.page_allocator
-        free_pages = alloc.available if alloc is not None else 0
         return (len(self.inflight) + len(eng.scheduler.queue),
                 -len(eng.slots.free),
-                -free_pages)
+                -eng.page_allocator.available)
 
     def fits(self, req: Request) -> bool:
         """Whether this replica could EVER hold the request's worst-case
         page span — a span the pool can't cover is submit()-rejected, so
         it is not a routing candidate."""
         alloc = self.engine.page_allocator
-        if alloc is None:
-            return True
         return Scheduler.pages_needed(req, alloc.page_size) <= alloc.usable
 
 
@@ -312,9 +305,8 @@ class Router:
         # load-chosen replica happened to be) so the A/B hit-rate
         # comparison is honest, not affinity-counting-itself
         warm = rep.affinity_pages(req.prompt)
-        alloc = rep.engine.page_allocator
-        full = (max(0, (len(req.prompt) - 1) // alloc.page_size)
-                if alloc is not None else 0)
+        full = max(0, (len(req.prompt) - 1)
+                   // rep.engine.page_allocator.page_size)
         self.affinity_hit_pages += warm
         self.affinity_miss_pages += full - warm
         tel = self.telemetry
@@ -554,13 +546,12 @@ class Router:
     @staticmethod
     def _verify_reclaim(rep: ReplicaHandle) -> None:
         eng = rep.engine
-        alloc = getattr(eng, "page_allocator", None)
-        if alloc is not None:
-            alloc.check()
-            if alloc.in_use != 0:
-                raise RuntimeError(
-                    f"detach leak: replica {rep.index} still pins "
-                    f"{alloc.in_use} KV page(s) after drain")
+        alloc = eng.page_allocator
+        alloc.check()
+        if alloc.in_use != 0:
+            raise RuntimeError(
+                f"detach leak: replica {rep.index} still pins "
+                f"{alloc.in_use} KV page(s) after drain")
         slots = getattr(eng, "slots", None)
         total = getattr(slots, "n", None)
         if total is not None and len(slots.free) != total:

@@ -78,11 +78,11 @@ class RequestState:
     # KV pages reclaimed like any EOS, so one wedged request can neither
     # freeze the serving progress frontier nor leak pages.
     deadline: Optional[float] = None
-    # paged-KV mode only (all None/zero otherwise): `page_table` maps the
-    # slot's logical KV blocks to physical pages (length max_len //
-    # page_size, unallocated entries = trash page 0); `owned_pages` are
-    # the references this request holds — pinned shared prefix pages plus
-    # its private pages — each release()d exactly once at retirement.
+    # `page_table` maps the slot's logical KV blocks to physical pages
+    # (length max_len // page_size, unallocated entries = trash page 0);
+    # `owned_pages` are the references this request holds — pinned shared
+    # prefix pages plus its private pages — each release()d exactly once
+    # at retirement.
     # The request's whole worst-case span is reserved at ADMISSION
     # (ceil((P-1 + max_new) / page_size) pages, minus prefix hits), so
     # decode never allocates mid-flight and can never deadlock.
@@ -154,8 +154,8 @@ class Scheduler:
     (`next_prefill`, oldest-admitted first so a burst of long prompts
     drains in arrival order while decode steps interleave).
 
-    In paged mode admission also reserves KV pages (the binding
-    resource): a request needs its worst-case page span free — minus
+    Admission also reserves KV pages (the binding resource): a
+    request needs its worst-case page span free — minus
     whatever its prompt prefix resolves to in the cache — before it gets
     a slot. When the head of the queue doesn't fit, `admit` looks ahead
     up to `admit_lookahead` arrived requests for one whose page demand
@@ -200,7 +200,7 @@ class Scheduler:
         self.gate = None
         self.queue: deque[Request] = deque()
         self.active: List[RequestState] = []
-        # slot-aware reserve-ahead (paged mode): page reservations made
+        # slot-aware reserve-ahead: page reservations made
         # while NO slot was free, keyed by request id — see admit().
         # Dies with the scheduler (engine reset() also resets the
         # allocator, so no pins leak).
@@ -271,12 +271,13 @@ class Scheduler:
         return chain, private, table
 
     def admit(self, free_slots: List[int], now: float,
-              allocator=None) -> List[RequestState]:
-        """Move arrived requests into free slots, FCFS. With a
-        PageAllocator, a request is admitted only when its page span
-        reserves (see `_reserve_pages`); a head that doesn't fit lets up
-        to `admit_lookahead` arrived requests behind it try (packing).
-        Returns the new RequestStates (also tracked in self.active).
+              allocator) -> List[RequestState]:
+        """Move arrived requests into free slots, FCFS. A request is
+        admitted only when its page span reserves from `allocator` (a
+        slots.PageAllocator; see `_reserve_pages`); a head that doesn't
+        fit lets up to `admit_lookahead` arrived requests behind it try
+        (packing). Returns the new RequestStates (also tracked in
+        self.active).
 
         Slot-aware reserve-ahead (the dual of the lookahead above): when
         pages FIT but no slot is free, up to `admit_lookahead` arrived
@@ -293,9 +294,6 @@ class Scheduler:
                     break
                 if self.gate is not None and not self.gate(req):
                     continue              # backpressured; let others try
-                if allocator is None:
-                    picked = (idx, req, None)
-                    break
                 reserved = self.staged.pop(req.id, None)
                 if reserved is None:
                     reserved = self._reserve_pages(req, allocator)
@@ -308,25 +306,19 @@ class Scheduler:
             del self.queue[idx]
             slot = free_slots.pop(0)
             p1 = len(req.prompt) - 1          # bonus token excluded
+            chain, private, table = reserved
+            span = len(chain) * allocator.page_size   # prefix-cache hits
             st = RequestState(
-                req=req, slot=slot, pos=0,
-                chunks=plan_chunks(p1, self.chunk_buckets),
-                next_input=int(req.prompt[-1]), admitted_at=now)
-            if reserved is not None:
-                chain, private, table = reserved
-                ps = allocator.page_size
-                span = len(chain) * ps        # prefix-cache hit span
-                st.page_table = table
-                st.owned_pages = chain + private
-                st.cached_tokens = span
-                st.published_pages = len(chain)
-                st.publish_parent = chain[-1] if chain else -1
-                st.pos = span                 # prefill starts past the hits
-                st.chunks = plan_chunks(p1, self.chunk_buckets,
-                                        start=span)
+                req=req, slot=slot,
+                pos=span,                     # prefill starts past the hits
+                chunks=plan_chunks(p1, self.chunk_buckets, start=span),
+                next_input=int(req.prompt[-1]), admitted_at=now,
+                page_table=table, owned_pages=chain + private,
+                cached_tokens=span, published_pages=len(chain),
+                publish_parent=chain[-1] if chain else -1)
             self.active.append(st)
             out.append(st)
-        if allocator is not None and not free_slots:
+        if not free_slots:
             for idx, req in enumerate(self.queue):
                 if idx >= self.admit_lookahead or req.arrival > now:
                     break

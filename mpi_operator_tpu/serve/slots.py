@@ -1,21 +1,17 @@
 """Slot and page bookkeeping for the fixed-shape serving cache.
 
-The device cache is [SLOTS, KV, L, D] per layer (transformer.py
-decode_slots mode) and NEVER changes shape: requests come and go by
-host-side bookkeeping only — a freed slot is just a row whose cursor
-resets, and the stale K/V it leaves behind is unreachable (every row
-attends only positions <= its own cursor, and a new occupant rewrites
-[0, len) before its cursor gets there). That is the whole trick that
-makes admission/retirement free of recompiles.
-
-In paged mode (EngineConfig.paged) the cache is instead a global pool of
-fixed-size pages (transformer.py decode_page_size) and `PageAllocator`
-here owns the physical pages: a free list, per-page refcounts, and the
-prefix cache that lets requests sharing a prompt prefix resolve to the
-SAME physical pages and skip prefilling them. The same junk-write
-argument carries over page-by-page: a page's stale content is
-unreachable until a new owner's cursor crosses it, and the owner rewrites
-each position before the cursor does.
+The device cache is one global pool of fixed-size pages per layer
+(transformer.py decode_page_size) and NEVER changes shape: requests come
+and go by host-side bookkeeping only. A slot is a row of the decode
+batch with a cursor and a page table; `PageAllocator` here owns the
+physical pages: a free list, per-page refcounts, and the prefix cache
+that lets requests sharing a prompt prefix resolve to the SAME physical
+pages and skip prefilling them. A freed slot is just a row whose cursor
+resets and whose pages go back, and the stale K/V they hold is
+unreachable: every row attends only positions <= its own cursor through
+its own table, and a page's new owner rewrites each position before its
+cursor gets there. That is the whole trick that makes
+admission/retirement free of recompiles.
 
 This module owns which row belongs to which request and builds the
 per-step cursor/token/sampling arrays the compiled decode step consumes.
@@ -58,7 +54,7 @@ def prefix_chain_windows(prompt: Sequence[int], page_size: int,
 
 
 class PageAllocator:
-    """Physical KV pages for the paged serving cache: a free list,
+    """Physical KV pages for the serving cache: a free list,
     per-page refcounts, and the prompt-prefix cache.
 
     Page 0 is the reserved TRASH page — unallocated page-table entries
@@ -296,8 +292,7 @@ class SlotManager:
         self.free.append(st.slot)
         self.free.sort()
 
-    def rewind(self, slot: int, n: int,
-               page_size: Optional[int] = None) -> None:
+    def rewind(self, slot: int, n: int, page_size: int) -> None:
         """Roll slot's cursor back `n` positions after a speculative
         verify step rejected the tail of its writes. The rejected K/V
         stays in place as dead weight — every reader masks positions
@@ -306,12 +301,13 @@ class SlotManager:
         rejected span that crossed into a fresh page leaves that page
         allocated — it is still inside the request's reserved span).
 
-        In paged mode (`page_size` given) the cursor must not drop below
-        the published-page frontier: published pages are immutable prefix
-        -cache entries other requests may already share, so un-publishing
-        is refused loudly rather than corrupting shared state. The engine
-        never trips this (decode tokens are never published), but the
-        guard keeps a buggy caller from silently poisoning the cache."""
+        The cursor must not drop below the published-page frontier
+        (`published_pages` pages of `page_size`): published pages are
+        immutable prefix-cache entries other requests may already share,
+        so un-publishing is refused loudly rather than corrupting shared
+        state. The engine never trips this (decode tokens are never
+        published), but the guard keeps a buggy caller from silently
+        poisoning the cache."""
         st = self.states[slot]
         if st is None:
             raise ValueError(f"rewind on free slot {slot}")
@@ -321,13 +317,12 @@ class SlotManager:
         if new < 0:
             raise ValueError(
                 f"rewind({slot}, {n}) would move the cursor to {new} < 0")
-        if page_size is not None:
-            floor = st.published_pages * page_size
-            if new < floor:
-                raise ValueError(
-                    f"rewind({slot}, {n}) would un-publish: cursor {new} "
-                    f"< published frontier {floor} "
-                    f"({st.published_pages} pages x {page_size})")
+        floor = st.published_pages * page_size
+        if new < floor:
+            raise ValueError(
+                f"rewind({slot}, {n}) would un-publish: cursor {new} "
+                f"< published frontier {floor} "
+                f"({st.published_pages} pages x {page_size})")
         st.pos = new
 
     @property

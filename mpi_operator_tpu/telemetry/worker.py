@@ -57,8 +57,8 @@ Serve series (ServingEngine):
   prefill_compiles        gauge     — prefill compile count
   requests_total          counter   — requests retired
   tokens_total            counter   — new tokens emitted
-  kv_pages_total          gauge     — usable KV pages (paged mode;
-                                      pool minus the trash page)
+  kv_pages_total          gauge     — usable KV pages (the pool minus
+                                      the trash page)
   kv_pages_in_use         gauge     — pages referenced by live requests
   kv_pages_cached         gauge     — idle prefix-cache pages retained
                                       for future lookups (evictable)
@@ -336,7 +336,7 @@ class ServeTelemetry:
             labels=labels)
         self.pages_total = reg.gauge(
             "tpu_worker_kv_pages_total",
-            "usable KV pages (paged mode; pool minus the trash page)",
+            "usable KV pages (the pool minus the trash page)",
             labels=labels)
         self.pages_in_use = reg.gauge(
             "tpu_worker_kv_pages_in_use",

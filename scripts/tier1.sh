@@ -25,7 +25,7 @@
 #
 #   ./scripts/tier1.sh --serving runs the OUT-OF-PROCESS disaggregated
 #   prefill/decode A/B smoke: the same greedy trace through the
-#   colocated paged engine and the two-pool DisaggEngine, gated on
+#   colocated engine and the two-pool DisaggEngine, gated on
 #   token identity + the per-pool compile pins + actual KV handoffs.
 #
 #   ./scripts/tier1.sh --router runs the OUT-OF-PROCESS front-door

@@ -117,7 +117,7 @@ def test_untileable_decode_kernel_raises_on_tpu_and_falls_back_on_cpu(
     # bf16 pages need a multiple of 16 positions; 8 cannot tile
     cfg = TransformerConfig(
         num_heads=2, embed_dim=32, max_len=32, dtype=jnp.bfloat16,
-        decode=True, decode_slots=True, decode_kernel=True,
+        decode=True, decode_kernel=True,
         decode_page_size=8, decode_num_pages=9)
     x = jnp.zeros((2, 1, 32), jnp.bfloat16)
     kw = dict(positions=jnp.zeros((2, 1), jnp.int32),
@@ -210,7 +210,7 @@ def test_serve_benchmark_headline_names_device_and_traced_decode(capsys):
     from mpi_operator_tpu.examples import serve_benchmark
 
     rc = serve_benchmark.main([
-        "--size", "test", "--paged", "--slots", "2", "--num-requests", "3",
+        "--size", "test", "--slots", "2", "--num-requests", "3",
         "--no-baseline"])
     assert rc == 0
     head = _last_json(capsys.readouterr().out)
@@ -379,11 +379,13 @@ def test_decode_step_with_kernel_lowers_for_tpu_on_dp4(monkeypatch, paged):
     model = create_lm("gpt2-test", dtype=jnp.bfloat16, max_len=128)
     variables, _ = shard_init(model, mesh, jax.random.PRNGKey(0),
                               jnp.zeros((1, 32), jnp.int32))
-    dmodel = decode_model(model, True, slots=True,
+    dmodel = decode_model(model, True,
                           page_size=32 if paged else None,
                           num_pages=8 * 4 + 1 if paged else 0)
 
-    def step(params):                 # what the engine's init_cache runs
+    # paged: what the engine's init_cache runs; not: generate()'s
+    # lockstep step, the contiguous kernel's only caller
+    def step(params):
         z = jnp.zeros((8, 1), jnp.int32)
         kw = {"pages": jnp.zeros((8, 4), jnp.int32)} if paged else {}
         return dmodel.apply({"params": params}, z, positions=z,
@@ -443,7 +445,7 @@ def test_engine_on_a_dp4_mesh_compiles_its_step_once_and_matches():
     model = create_lm("gpt2-test", dtype=jnp.float32, max_len=64)
     prompt = jnp.zeros((1, 8), jnp.int32)
     cfg = EngineConfig(slots=8, chunk_buckets=(8,), decode_kernel=True,
-                       paged=True, page_size=8)
+                       page_size=8)
 
     def serve(devices):
         mesh = make_mesh(MeshConfig(dp=len(devices)), devices=devices)

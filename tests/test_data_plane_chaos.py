@@ -426,7 +426,7 @@ def test_engine_request_timeouts_retire_and_reclaim():
     )["params"]
     sink = _EventSink()
     engine = ServingEngine(model, params, EngineConfig(
-        slots=2, chunk_buckets=(4, 8), paged=True, page_size=8,
+        slots=2, chunk_buckets=(4, 8), page_size=8,
         rng_seed=0, request_timeout=0.0), events=sink)
     reqs = [Request(i, [1 + (i % 5)] * 6, 8) for i in range(3)]
     results = engine.run(reqs)

@@ -21,8 +21,8 @@ from flax.core import meta
 
 from mpi_operator_tpu.models import CausalLM, gpt2_config
 from mpi_operator_tpu.serve import (
-    DisaggEngine, EngineConfig, PageTransfer, Request, Scheduler,
-    ServingEngine,
+    DisaggEngine, EngineConfig, PageAllocator, PageTransfer, Request,
+    Scheduler, ServingEngine,
 )
 from mpi_operator_tpu.telemetry import events as ev
 from mpi_operator_tpu.telemetry.core import Registry
@@ -63,11 +63,12 @@ def test_scheduler_gate_blocks_and_packs_past():
     s.submit(Request(0, [1] * 8, 4))
     s.submit(Request(1, [2] * 4, 4))
     s.gate = lambda req: req.id != 0
-    admitted = s.admit([0, 1], now=0.0)
+    a = PageAllocator(9, 8)
+    admitted = s.admit([0, 1], 0.0, a)
     assert [st.req.id for st in admitted] == [1]
     assert [r.id for r in s.queue] == [0]
     s.gate = None
-    assert [st.req.id for st in s.admit([0], now=0.0)] == [0]
+    assert [st.req.id for st in s.admit([0], 0.0, a)] == [0]
 
 
 def test_transfer_width_bucketing():
@@ -90,8 +91,8 @@ def _setup(decode_kernel=False, kv_cache_dtype=None, slots=4,
     probe = jnp.zeros((1, 4), jnp.int32)
     params = meta.unbox(model.init(jax.random.PRNGKey(0), probe))["params"]
     ecfg = EngineConfig(slots=slots, chunk_buckets=(4, 8),
-                        decode_kernel=decode_kernel, paged=True,
-                        page_size=page_size, num_pages=num_pages)
+                        decode_kernel=decode_kernel, page_size=page_size,
+                        num_pages=num_pages)
     colocated = ServingEngine(model, params, ecfg)
     disagg = DisaggEngine(model, params, ecfg, **disagg_kw)
     return colocated, disagg
@@ -243,8 +244,7 @@ def test_disagg_per_pool_telemetry_and_handoff_events(tmp_path):
     log = EventLog(str(tmp_path / "events.jsonl"))
     disagg = DisaggEngine(
         model, params,
-        EngineConfig(slots=4, chunk_buckets=(4, 8), paged=True,
-                     page_size=8),
+        EngineConfig(slots=4, chunk_buckets=(4, 8), page_size=8),
         registry=reg, events=log)
     disagg.run(_mixed_trace(n=4))
     log.close()

@@ -89,7 +89,7 @@ def test_prefill_then_decode_through_the_latent_pages_matches_reference(
     prompts = [rng.randint(0, 128, n).tolist() for n in (7, 19, 12)]
     tel = ServeTelemetry()
     eng = ServingEngine(_model(), params, EngineConfig(
-        slots=2, chunk_buckets=(4, 8), paged=True, page_size=8, num_pages=24,
+        slots=2, chunk_buckets=(4, 8), page_size=8, num_pages=24,
         decode_kernel=kernel), telemetry=tel)
     res = eng.run([Request(id=i, prompt=p, max_new_tokens=6 + i)
                    for i, p in enumerate(prompts)])
@@ -114,8 +114,8 @@ def test_absorbed_attention_over_pages_equals_the_non_absorbed_form():
     window with K and V expanded, against one multi-token call through the
     latent pages with the up-projection absorbed."""
     cfg = _model().config
-    dcfg = dataclasses.replace(cfg, decode=True, decode_slots=True,
-                               decode_page_size=8, decode_num_pages=17)
+    dcfg = dataclasses.replace(cfg, decode=True, decode_page_size=8,
+                               decode_num_pages=17)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, DIMS.hidden))
     plain = LatentAttention(cfg)
     p = plain.init(jax.random.PRNGKey(1), x)["params"]
@@ -357,8 +357,7 @@ def test_step_counters_are_the_steps_own_routing():
     """What the engine fetches with a step's tokens is what the router
     picked in that step, summed over the layers."""
     params = _params()
-    dmodel = _model(decode=True, decode_slots=True, decode_page_size=8,
-                    decode_num_pages=9)
+    dmodel = _model(decode=True, decode_page_size=8, decode_num_pages=9)
     tokens = jnp.asarray([[5], [9]])
     pages = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]] * 2, jnp.int32)
     _, vars_ = dmodel.apply({"params": params}, tokens,
@@ -383,36 +382,36 @@ def test_page_bytes_follow_the_kind_of_cache():
                        jnp.zeros((1, 4), jnp.int32))["params"]
     g = gpt.config
     eng = ServingEngine(gpt, gparams, EngineConfig(
-        slots=2, chunk_buckets=(8,), paged=True, page_size=8, num_pages=5))
+        slots=2, chunk_buckets=(8,), page_size=8, num_pages=5))
     assert eng.page_bytes() == (2 * g.num_layers * g.kv_heads * g.head_dim
                                 * 8 * 4)
     lat = ServingEngine(_model(), _params(), EngineConfig(
-        slots=2, chunk_buckets=(8,), paged=True, page_size=8, num_pages=5))
+        slots=2, chunk_buckets=(8,), page_size=8, num_pages=5))
     assert lat.page_bytes() == 2 * DIMS.layers * 128 * 8 * 4
-    with pytest.raises(ValueError, match="not paged"):
-        ServingEngine(gpt, gparams, EngineConfig(
-            slots=2, chunk_buckets=(8,))).page_bytes()
 
 
 def test_the_latent_cache_is_paged_or_says_why_not():
+    """At the model: a decode-mode `LongcatLM` applied without a page
+    size (as `generate()` would) says why it cannot."""
     with pytest.raises(ValueError, match="latent cache is a page pool"):
-        ServingEngine(_model(), _params(),
-                      EngineConfig(slots=2, chunk_buckets=(8,)))
+        _model(decode=True).apply({"params": _params()},
+                                  jnp.zeros((1, 4), jnp.int32),
+                                  positions=jnp.arange(4)[None])
 
 
 def test_own_params_serves_a_tree_in_the_served_type_where_it_lies():
     params = _params()
     eng = ServingEngine(_model(), params, EngineConfig(
-        slots=2, chunk_buckets=(8,), paged=True, page_size=8, num_pages=9,
+        slots=2, chunk_buckets=(8,), page_size=8, num_pages=9,
         own_params=True))
     assert eng.params["embedding"] is params["embedding"]
     copy = ServingEngine(_model(), params, EngineConfig(
-        slots=2, chunk_buckets=(8,), paged=True, page_size=8, num_pages=9))
+        slots=2, chunk_buckets=(8,), page_size=8, num_pages=9))
     assert copy.params["embedding"] is not params["embedding"]
     # a tree not yet in the served type is cast, owned or not
     half = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
     cast = ServingEngine(_model(), half, EngineConfig(
-        slots=2, chunk_buckets=(8,), paged=True, page_size=8, num_pages=9,
+        slots=2, chunk_buckets=(8,), page_size=8, num_pages=9,
         own_params=True))
     assert cast.params["embedding"].dtype == F32
 
@@ -436,7 +435,7 @@ def test_page_transfer_moves_latent_pages():
 
 def test_decode_step_scopes_name_the_steps_instructions():
     eng = ServingEngine(_model(), _params(), EngineConfig(
-        slots=2, chunk_buckets=(8,), paged=True, page_size=8, num_pages=9))
+        slots=2, chunk_buckets=(8,), page_size=8, num_pages=9))
     scopes = eng.decode_step_scopes()
     assert scopes and all(isinstance(v, str) for v in scopes.values())
     joined = " ".join(scopes.values())

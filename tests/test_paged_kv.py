@@ -9,11 +9,11 @@ Three layers, each pinned against the layer below it:
 - `paged_decode_attention` (ops/attention.py): the Pallas kernel over a
   page pool must match the gathered dense oracle, with POISON in every
   page slot past each row's cursor so any stray read is loud.
-- The paged `ServingEngine` (`EngineConfig.paged`): token-exact against
-  the CONTIGUOUS engine — same model, same trace, both attention paths —
-  including prefix-cache hits, slot reuse, int8 caches, and the capacity
-  claim (more concurrent requests than contiguous under the same cache
-  byte budget).
+- The `ServingEngine` over its page pool: token-exact against
+  `generate()`, the fixed-batch oracle — same model, same requests, both
+  attention paths — including prefix-cache hits, slot reuse, int8
+  caches, and the capacity claim (more concurrent requests than
+  slots x max_len positions of contiguous rows would hold).
 """
 import dataclasses
 
@@ -32,6 +32,7 @@ from mpi_operator_tpu.serve import (
     EngineConfig, PageAllocator, Request, Scheduler, ServingEngine,
     plan_chunks,
 )
+from test_serve import _oracle
 
 pytestmark = pytest.mark.serving
 
@@ -398,8 +399,8 @@ def test_paged_cache_is_one_pool_of_rows_and_junk_writes_drop(preset,
                                                               kv_cache_dtype):
     """White box. A multi-token call writes each position's row at
     (pages[pos // ps], pos % ps) with head h's K in columns
-    [2D*h, 2D*h + D) and its V in the next D — the values the contiguous
-    cache holds at the same positions — and a row at junk positions
+    [2D*h, 2D*h + D) and its V in the next D — the values the lockstep
+    model's cache holds at the same positions — and a row at junk positions
     (>= max_len: a padded tail, a non-member of a prefill call) writes
     nothing anywhere, the trash page included."""
     from mpi_operator_tpu.models.generate import decode_model
@@ -418,9 +419,21 @@ def test_paged_cache_is_one_pool_of_rows_and_junk_writes_drop(preset,
         return dmodel.apply({"params": params}, tokens, positions=positions,
                             with_head=False, mutable=["cache"],
                             **kw)[1]["cache"]
-    paged = cache_of(decode_model(model, False, slots=True, page_size=ps,
+    paged = cache_of(decode_model(model, False, page_size=ps,
                                   num_pages=NP), pages=pages)
-    contig = cache_of(decode_model(model, False, slots=True))
+    # the lockstep model (generate()'s) over row 0 alone, its cursor at
+    # the row's first position over an empty cache
+    lock = decode_model(model, False)
+
+    def lockstep(cache):
+        return lock.apply({"params": params, **cache}, tokens[:1],
+                          positions=positions[:1], with_head=False,
+                          mutable=["cache"])[1]["cache"]
+    empty = jax.tree.map(
+        lambda x: (jnp.full((), 6, x.dtype) if x.shape == ()
+                   else jnp.zeros(x.shape, x.dtype)),
+        jax.eval_shape(lambda: lockstep({})))
+    contig = lockstep({"cache": empty})
     leaves = lambda tree, name: [                               # noqa: E731
         x for p_, x in jax.tree_util.tree_leaves_with_path(tree)
         if name in jax.tree_util.keystr(p_)]
@@ -457,7 +470,7 @@ def test_paged_cache_is_one_pool_of_rows_and_junk_writes_drop(preset,
 
 
 # ---------------------------------------------------------------------------
-# the paged engine vs the contiguous oracle
+# the engine over its page pool vs generate(), the oracle
 # ---------------------------------------------------------------------------
 
 def _setup(decode_kernel=False, kv_cache_dtype=None, slots=4,
@@ -468,12 +481,10 @@ def _setup(decode_kernel=False, kv_cache_dtype=None, slots=4,
     model = CausalLM(cfg)
     probe = jnp.zeros((1, 4), jnp.int32)
     params = meta.unbox(model.init(jax.random.PRNGKey(0), probe))["params"]
-    contiguous = ServingEngine(model, params, EngineConfig(
-        slots=slots, chunk_buckets=(4, 8), decode_kernel=decode_kernel))
     paged = ServingEngine(model, params, EngineConfig(
         slots=slots, chunk_buckets=(4, 8), decode_kernel=decode_kernel,
-        paged=True, page_size=page_size, num_pages=num_pages))
-    return contiguous, paged
+        page_size=page_size, num_pages=num_pages))
+    return (lambda req: _oracle(model, params, req)), paged
 
 
 def _mixed_trace(n=8, seed=7, eos=None):
@@ -487,18 +498,16 @@ def _mixed_trace(n=8, seed=7, eos=None):
 
 @pytest.mark.parametrize("decode_kernel", [False, True])
 def test_paged_engine_token_exact_vs_contiguous(decode_kernel):
-    """The acceptance gate: greedy decode through the paged cache is
-    token-for-token identical to the contiguous engine on the same
+    """The acceptance gate: greedy decode through the page pool is
+    token-for-token identical to generate() on every request of a
     trace — mixed prompt lengths, more requests than slots (slot AND
     page reuse across retire/admit), dense and kernel paths."""
-    contiguous, paged = _setup(decode_kernel)
+    oracle, paged = _setup(decode_kernel)
     trace = _mixed_trace()
-    want = contiguous.run(trace)
     got = paged.run(trace)
     for r in trace:
-        assert got[r.id].tokens == want[r.id].tokens, \
-            f"request {r.id} diverged"
-        assert got[r.id].finish_reason == want[r.id].finish_reason
+        assert got[r.id].tokens == oracle(r), f"request {r.id} diverged"
+        assert got[r.id].finish_reason == "length"
     alloc = paged.page_allocator
     alloc.check()
     assert alloc.in_use == 0                 # every page released
@@ -508,29 +517,24 @@ def test_paged_engine_token_exact_vs_contiguous(decode_kernel):
 
 def test_paged_engine_int8_cache_token_exact():
     """The quantized cache pages ([NP, KV, ps] scale planes) through the
-    same oracle: int8 contiguous vs int8 paged, dense path."""
-    contiguous, paged = _setup(kv_cache_dtype="int8")
+    same oracle: generate()'s int8 rows vs the int8 pool, dense path."""
+    oracle, paged = _setup(kv_cache_dtype="int8")
     trace = _mixed_trace(n=5)
-    want = contiguous.run(trace)
     got = paged.run(trace)
     for r in trace:
-        assert got[r.id].tokens == want[r.id].tokens, \
-            f"request {r.id} diverged"
+        assert got[r.id].tokens == oracle(r), f"request {r.id} diverged"
 
 
 def test_paged_engine_eos_retirement_reuses_pages():
     """EOS mid-flight: retired requests release pages that later
-    arrivals re-allocate; tokens still match the contiguous engine."""
-    contiguous, paged = _setup()
-    probe = contiguous.run(_mixed_trace(n=1))
-    eos = probe[0].tokens[2]
-    contiguous.reset()
+    arrivals re-allocate; tokens still match the oracle's."""
+    oracle, paged = _setup()
+    eos = oracle(_mixed_trace(n=1)[0])[2]
     trace = _mixed_trace(eos=eos)            # 8 requests over 4 slots
-    want = contiguous.run(trace)
     got = paged.run(trace)
     assert any(r.finish_reason == "eos" for r in got.values())
     for r in trace:
-        assert got[r.id].tokens == want[r.id].tokens
+        assert got[r.id].tokens == oracle(r)
     assert paged.page_allocator.in_use == 0
 
 
@@ -538,19 +542,17 @@ def test_paged_engine_eos_retirement_reuses_pages():
 def test_prefix_hit_token_exact_and_skips_prefill(decode_kernel):
     """A request sharing a cached prompt prefix admits with
     cached_tokens > 0, runs FEWER prefill chunks, produces the exact
-    contiguous tokens, and reaches its first token faster from admission
+    oracle tokens, and reaches its first token faster from admission
     (the queue-independent TTFT the bench reports)."""
-    contiguous, paged = _setup(decode_kernel)
+    oracle, paged = _setup(decode_kernel)
     rs = np.random.RandomState(3)
     shared = list(rs.randint(0, 64, (40,)))      # 5 full pages of 8
     cold = Request(0, shared + list(rs.randint(0, 64, (3,))), 6)
     hot = Request(1, shared + list(rs.randint(0, 64, (3,))), 6)
-    want0 = contiguous.run([cold])
-    want1 = contiguous.run([hot])
     got0 = paged.run([cold])                 # publishes the 5 pages
     got1 = paged.run([hot])                  # pins them
-    assert got0[0].tokens == want0[0].tokens
-    assert got1[1].tokens == want1[1].tokens
+    assert got0[0].tokens == oracle(cold)
+    assert got1[1].tokens == oracle(hot)
     assert got0[0].cached_tokens == 0
     assert got1[1].cached_tokens == 40
     # the hit skipped the shared prefill: first token comes faster from
@@ -567,55 +569,43 @@ def test_prefix_divergence_is_copy_on_write():
     """Two prompts equal through page 2 then diverging INSIDE page 3:
     the hit stops at the divergence page, which stays private — the
     original's cached page is untouched and both match the oracle."""
-    contiguous, paged = _setup()
+    oracle, paged = _setup()
     rs = np.random.RandomState(13)
     head = list(rs.randint(0, 64, (16,)))        # 2 full pages of 8
     a = Request(0, head + list(rs.randint(0, 64, (7,))), 5)
     b = Request(1, head + list(rs.randint(0, 64, (7,))), 5)
-    want_a = contiguous.run([a])
-    want_b = contiguous.run([b])
     got_a = paged.run([a])
     got_b = paged.run([b])
-    assert got_a[0].tokens == want_a[0].tokens
-    assert got_b[1].tokens == want_b[1].tokens
+    assert got_a[0].tokens == oracle(a)
+    assert got_b[1].tokens == oracle(b)
     assert got_b[1].cached_tokens == 16          # only the shared pages
     # replaying A must still hit ITS chain exactly (page 3 not clobbered)
-    want_a2 = contiguous.run([a])
     got_a2 = paged.run([a])
-    assert got_a2[0].tokens == want_a2[0].tokens
+    assert got_a2[0].tokens == oracle(a)
     paged.page_allocator.check()
 
 
 def test_paged_capacity_beats_contiguous_at_equal_bytes():
-    """The tentpole's capacity claim: under the SAME cache byte budget
-    (2 contiguous rows of max_len=64 vs 16+1 pages of 8), the paged
-    engine sustains strictly more concurrent requests because short
-    requests reserve their actual worst case, not a whole row."""
-    budget_rows = 2
-    contiguous, paged = _setup(
-        slots=budget_rows, page_size=8,
-        num_pages=budget_rows * (64 // 8) + 1)   # byte parity + trash
+    """The capacity claim: a cache of 2 x max_len positions held as
+    contiguous rows serves 2 requests at a time, whatever their length.
+    The same positions as 16 (+1) pages of 8 sustain strictly more,
+    because short requests reserve their actual worst case, not a whole
+    row."""
+    budget_rows, max_len, ps = 2, 64, 8
+    oracle, paged_wide = _setup(
+        slots=6, page_size=ps, max_len=max_len,
+        num_pages=budget_rows * (max_len // ps) + 1)   # + trash
+    assert paged_wide.page_allocator.usable * ps == budget_rows * max_len
     # 6 short requests: each needs (6-2+6)//8+1 = 2 pages — the pool
-    # fits 6 concurrently (12 of 16 pages), contiguous caps at 2 rows
+    # fits 6 concurrently (12 of 16 pages), contiguous rows cap at 2
     reqs = [Request(i, [int(t) for t in
                         np.random.RandomState(i).randint(0, 64, (6,))],
                     max_new_tokens=6) for i in range(6)]
-    want = contiguous.run(reqs)
-    assert contiguous.occupancy_peak == budget_rows
-    # a paged engine with MORE slots over the SAME pool bytes
-    cfg = gpt2_config("test", attention="dense", dtype=jnp.float32,
-                      vocab_size=64, max_len=64)
-    model = CausalLM(cfg)
-    probe = jnp.zeros((1, 4), jnp.int32)
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), probe))["params"]
-    paged_wide = ServingEngine(model, params, EngineConfig(
-        slots=6, chunk_buckets=(4, 8), paged=True, page_size=8,
-        num_pages=budget_rows * (64 // 8) + 1))
     got = paged_wide.run(reqs)
     for r in reqs:
-        assert got[r.id].tokens == want[r.id].tokens
+        assert got[r.id].tokens == oracle(r)
     assert paged_wide.occupancy_peak > budget_rows
-    assert paged_wide.pages_in_use_peak <= budget_rows * (64 // 8)
+    assert paged_wide.pages_in_use_peak <= budget_rows * (max_len // ps)
 
 
 def test_paged_engine_rejects_unservable_request():

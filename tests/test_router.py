@@ -431,7 +431,7 @@ def test_affinity_hit_pages_match_replica_side_hits():
     params = flax_meta.unbox(
         model.init(jax.random.PRNGKey(0), probe))["params"]
     mk = lambda: ServingEngine(model, params, EngineConfig(  # noqa: E731
-        slots=2, chunk_buckets=(8, 32), paged=True, page_size=8,
+        slots=2, chunk_buckets=(8, 32), page_size=8,
         rng_seed=0))
     engines = [mk(), mk()]
     # 17 tokens = 2 complete pages @ 8 (+1 bonus token outside paging)

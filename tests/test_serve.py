@@ -17,8 +17,8 @@ from flax.core import meta
 from mpi_operator_tpu.models import CausalLM, generate, gpt2_config
 from mpi_operator_tpu.models.generate import _sample
 from mpi_operator_tpu.serve import (
-    EngineConfig, Request, Scheduler, ServingEngine, SlotManager,
-    plan_chunks, sample_slots,
+    EngineConfig, PageAllocator, Request, Scheduler, ServingEngine,
+    SlotManager, plan_chunks, sample_slots,
 )
 
 pytestmark = pytest.mark.serving
@@ -72,21 +72,22 @@ def test_scheduler_validates():
 
 def test_scheduler_fcfs_admission_and_retire():
     s = Scheduler((4,), max_len=32)
+    a = PageAllocator(33, 4)                   # pages never bind here
     for i in range(3):
         s.submit(Request(i, [1, 2, 3, 4, 5], 4, arrival=float(i)))
     free = [0, 1]
-    admitted = s.admit(free, now=10.0)
+    admitted = s.admit(free, 10.0, a)
     assert [st.req.id for st in admitted] == [0, 1] and free == []
     # bonus token: prompt[:-1] prefills, last token is the first input
     assert admitted[0].next_input == 5
     assert admitted[0].chunks == [(0, 4)]
-    assert s.admit([], now=10.0) == []         # no slot, no admission
+    assert s.admit([], 10.0, a) == []          # no slot, no admission
     s.retire(admitted[0])
-    third, = s.admit([admitted[0].slot], now=10.0)
+    third, = s.admit([admitted[0].slot], 10.0, a)
     assert third.req.id == 2
     # future arrivals stay queued
     s.submit(Request(9, [1, 2], 2, arrival=99.0))
-    assert s.admit([5], now=10.0) == []
+    assert s.admit([5], 10.0, a) == []
     assert s.next_arrival() == 99.0
 
 
@@ -96,7 +97,7 @@ def test_slot_manager_reuse_and_step_arrays():
     s.submit(Request(0, list(range(1, 7)), 4))        # needs prefill
     # single-token prompt: no prefill (the bonus token IS the prompt)
     s.submit(Request(1, [8], 4, temperature=0.5, top_k=3, top_p=0.9))
-    for st in s.admit(m.free, now=0.0):
+    for st in s.admit(m.free, 0.0, PageAllocator(17, 4)):
         m.bind(st)
     toks, pos, use_prev, temps, top_ks, top_ps, consumers = m.step_arrays()
     # slot 0 is mid-prefill: present in pos, absent from consumers
@@ -174,6 +175,7 @@ def test_sample_slots_mixed_rows_independent():
 # ---------------------------------------------------------------------------
 
 def _setup(decode_kernel=False, vocab=64, max_len=64, **cfg_kw):
+    cfg_kw.setdefault("page_size", 8)
     cfg = gpt2_config("test", attention="dense", dtype=jnp.float32,
                       vocab_size=vocab, max_len=max_len)
     model = CausalLM(cfg)
@@ -206,6 +208,31 @@ def test_engine_single_request_token_exact(decode_kernel):
     assert len(res[0].logprobs) == 10
     assert all(lp <= 0 for lp in res[0].logprobs)
     assert res[0].ttft >= 0 and len(res[0].token_times) == 10
+
+
+@pytest.mark.parametrize("decode_kernel", [False, True])
+def test_one_page_a_slot_is_token_exact(decode_kernel):
+    """`page_size == max_len`: every slot's table is one page, which is
+    the contiguous-slot layout, through the one path — mixed lengths,
+    retirement and slot reuse included."""
+    model, params, engine = _setup(decode_kernel, page_size=64)
+    assert engine.page_allocator.usable == engine.config.slots
+    rs = np.random.RandomState(5)
+    reqs = [Request(i, list(rs.randint(0, 64, (p,))), max_new_tokens=n)
+            for i, (p, n) in enumerate([(13, 10), (1, 6), (9, 4), (20, 7),
+                                        (5, 5), (7, 8)])]
+    results = engine.run(reqs)
+    for req in reqs:
+        assert results[req.id].tokens == _oracle(model, params, req), \
+            f"request {req.id} diverged"
+
+
+def test_the_contiguous_slot_option_is_refused_and_says_where_it_went():
+    cfg = gpt2_config("test", attention="dense", dtype=jnp.float32,
+                      vocab_size=64, max_len=64)
+    with pytest.raises(ValueError, match="page_size=max_len"):
+        ServingEngine(CausalLM(cfg), {}, EngineConfig(paged=False))
+    assert EngineConfig().paged is True
 
 
 def test_engine_mixed_lengths_match_oracle_per_request():
@@ -244,16 +271,14 @@ def test_engine_eos_retirement_and_slot_reuse():
             assert results[req.id].tokens[-1] == eos
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_engine_compile_counts_stay_fixed(paged):
+def test_engine_compile_counts_stay_fixed():
     """The no-recompile contract: after a mixed greedy+sampling trace, a
     reset, and a second different-shape trace, the step has at most one
-    program per sample_slots mode and prefill one per bucket. In paged
-    mode the reset must ALSO rewind the page allocator and prefix cache
-    — a replay of the same trace admits with zero carried-over state
-    (and identical tokens), still without recompiling."""
-    _, _, engine = _setup(**({"paged": True, "page_size": 8}
-                             if paged else {}))
+    program per sample_slots mode and prefill one per bucket. The reset
+    must ALSO rewind the page allocator and prefix cache — a replay of
+    the same trace admits with zero carried-over state (and identical
+    tokens), still without recompiling."""
+    _, _, engine = _setup()
     rs = np.random.RandomState(13)
 
     def trace(base):
@@ -267,15 +292,14 @@ def test_engine_compile_counts_stay_fixed(paged):
     a = engine.run(t0)
     first = engine.compile_counts()
     engine.reset()
-    if paged:
-        # the allocator rewound with the rest of the serving state:
-        # every page free, no refcounts, no cached prefixes (stale K/V
-        # must not survive into the zeroed cache)
-        alloc = engine.page_allocator
-        assert alloc.in_use == 0 and alloc.cached_pages == 0
-        assert alloc.available == alloc.usable
-        assert alloc.hits == alloc.misses == 0
-        alloc.check()
+    # the allocator rewound with the rest of the serving state: every
+    # page free, no refcounts, no cached prefixes (stale K/V must not
+    # survive into the zeroed cache)
+    alloc = engine.page_allocator
+    assert alloc.in_use == 0 and alloc.cached_pages == 0
+    assert alloc.available == alloc.usable
+    assert alloc.hits == alloc.misses == 0
+    alloc.check()
     engine.run(trace(100))
     second = engine.compile_counts()
     assert first == second                    # reset must not recompile
@@ -313,7 +337,7 @@ def test_engine_sampling_reproducible_and_in_support():
     engine.reset()
     assert engine.run([req])[0].tokens == a
     other = ServingEngine(model, params, EngineConfig(
-        slots=4, chunk_buckets=(4, 8), rng_seed=1))
+        slots=4, chunk_buckets=(4, 8), page_size=8, rng_seed=1))
     b = other.run([req])[0].tokens
     assert len(a) == len(b) == 6
     ctx = list(prompt)
@@ -393,7 +417,7 @@ def test_engine_with_sharded_params_matches_oracle():
                               jnp.zeros((1, 4), jnp.int32))
     params = variables["params"]
     engine = ServingEngine(model, params, EngineConfig(
-        slots=2, chunk_buckets=(4, 8)))
+        slots=2, chunk_buckets=(4, 8), page_size=8))
     rs = np.random.RandomState(17)
     reqs = [Request(i, list(rs.randint(0, 64, (p,))), max_new_tokens=5)
             for i, p in enumerate([4, 9, 6])]
@@ -408,8 +432,6 @@ def test_paged_admission_stages_reservations_when_no_slot_free():
     land before decode churn can evict their prefixes, and when a slot
     frees the head admits off its parked reservation instead of paying
     reservation work on the critical path."""
-    from mpi_operator_tpu.serve import PageAllocator
-
     s = Scheduler((4,), max_len=16)
     a = PageAllocator(20, 4)                  # 19 usable pages
     for i in range(3):
@@ -417,19 +439,19 @@ def test_paged_admission_stages_reservations_when_no_slot_free():
     need = Scheduler.pages_needed(s.queue[0], a.page_size)
 
     avail0 = a.available
-    st0, = s.admit([0], now=1.0, allocator=a)
+    st0, = s.admit([0], 1.0, a)
     assert st0.req.id == 0
     # the head consumed the only slot — the SAME admit call already
     # stages the two queued spans behind it
     assert set(s.staged) == {1, 2}
     assert a.available == avail0 - 3 * need
     # idempotent: a slotless pass admits nothing and stages nothing twice
-    assert s.admit([], now=1.0, allocator=a) == []
+    assert s.admit([], 1.0, a) == []
     assert set(s.staged) == {1, 2}
     assert a.available == avail0 - 3 * need
 
     # a slot frees: the staged head admits, CONSUMING its reservation
-    st1, = s.admit([1], now=1.0, allocator=a)
+    st1, = s.admit([1], 1.0, a)
     assert st1.req.id == 1 and 1 not in s.staged
     assert a.available == avail0 - 3 * need       # no double reserve
     assert st1.page_table is not None
@@ -440,16 +462,14 @@ def test_reserve_ahead_respects_future_arrivals_and_pool_limits():
     """Staging follows the same gates as admission: requests that have
     not arrived yet are never staged, and a span the pool can't cover
     stays unstaged (no partial pins left behind)."""
-    from mpi_operator_tpu.serve import PageAllocator
-
     s = Scheduler((4,), max_len=16)
     a = PageAllocator(5, 4)                   # 4 usable pages
     s.submit(Request(0, [1, 2, 3, 4, 5], 8, arrival=0.0))   # needs 3 pages
     s.submit(Request(1, [1, 2, 3, 4, 5], 8, arrival=0.0))   # won't fit too
     s.submit(Request(2, [1, 2], 2, arrival=99.0))           # future
-    assert s.admit([], now=1.0, allocator=a) == []
+    assert s.admit([], 1.0, a) == []
     assert set(s.staged) == {0}               # 1 doesn't fit, 2 not arrived
     free_before = a.available
-    assert s.admit([], now=1.0, allocator=a) == []
+    assert s.admit([], 1.0, a) == []
     assert a.available == free_before         # failed fits leak nothing
     a.check()

@@ -312,7 +312,7 @@ def _paged_engine(toy, **kw):
     tel.decode_step_seconds = _Recorder()
     tel.prefill_seconds = _Recorder()
     eng = ServingEngine(model, params, EngineConfig(
-        slots=4, chunk_buckets=(8, 16), paged=True, page_size=16,
+        slots=4, chunk_buckets=(8, 16), page_size=16,
         num_pages=20, **kw), telemetry=tel)
     return eng, tel
 

@@ -19,8 +19,8 @@ from flax.core import meta
 
 from mpi_operator_tpu.models import CausalLM, generate, gpt2_config
 from mpi_operator_tpu.serve import (
-    EngineConfig, Request, Scheduler, ServingEngine, SlotManager,
-    propose_ngram,
+    EngineConfig, PageAllocator, Request, Scheduler, ServingEngine,
+    SlotManager, propose_ngram,
 )
 
 pytestmark = [pytest.mark.serving, pytest.mark.spec]
@@ -59,7 +59,7 @@ def _bound_state():
     m = SlotManager(2)
     s = Scheduler((4,), max_len=64)
     s.submit(Request(0, list(range(1, 7)), 8))
-    st, = s.admit(m.free, now=0.0)
+    st, = s.admit(m.free, 0.0, PageAllocator(9, 8))
     m.bind(st)
     return m, st
 
@@ -67,9 +67,9 @@ def _bound_state():
 def test_rewind_moves_the_cursor_back():
     m, st = _bound_state()
     st.pos = 10
-    m.rewind(st.slot, 3)
+    m.rewind(st.slot, 3, 8)
     assert st.pos == 7
-    m.rewind(st.slot, 0)                     # no-op rewind is legal
+    m.rewind(st.slot, 0, 8)                     # no-op rewind is legal
     assert st.pos == 7
 
 
@@ -77,12 +77,12 @@ def test_rewind_validates_slot_and_bounds():
     m, st = _bound_state()
     st.pos = 2
     with pytest.raises(ValueError, match="negative"):
-        m.rewind(st.slot, -1)
+        m.rewind(st.slot, -1, 8)
     with pytest.raises(ValueError, match="< 0"):
-        m.rewind(st.slot, 3)                 # underflow past 0
+        m.rewind(st.slot, 3, 8)              # underflow past 0
     free = next(i for i in range(len(m.states)) if m.states[i] is None)
     with pytest.raises(ValueError, match="free slot"):
-        m.rewind(free, 1)
+        m.rewind(free, 1, 8)
 
 
 def test_rewind_crosses_unpublished_page_boundaries():
@@ -113,6 +113,7 @@ def test_rewind_refuses_to_unpublish_pages():
 
 def _setup(decode_kernel=False, vocab=64, max_len=64, kv_cache_dtype=None,
            drafter=None, **cfg_kw):
+    cfg_kw.setdefault("page_size", 8)
     cfg = gpt2_config("test", attention="dense", dtype=jnp.float32,
                       vocab_size=vocab, max_len=max_len,
                       kv_cache_dtype=kv_cache_dtype)
@@ -157,14 +158,10 @@ def _oracle(model, params, req):
     return list(np.asarray(out.tokens[0, len(req.prompt):]))
 
 
-@pytest.mark.parametrize("decode_kernel,engine_kw", [
-    (False, {}),
-    (True, {}),
-    (False, dict(paged=True, page_size=8)),
-    (True, dict(paged=True, page_size=8)),
-], ids=["dense", "kernel", "paged", "paged-kernel"])
-def test_spec_greedy_token_exact_across_modes(decode_kernel, engine_kw):
-    _, _, engine = _setup(decode_kernel, speculative="ngram", **engine_kw)
+@pytest.mark.parametrize("decode_kernel", [False, True],
+                         ids=["paged", "paged-kernel"])
+def test_spec_greedy_token_exact_across_modes(decode_kernel):
+    _, _, engine = _setup(decode_kernel, speculative="ngram")
     reqs = _trace()
     spec = engine.run(reqs)
     stats = engine.spec_stats()
@@ -188,11 +185,8 @@ def test_spec_single_request_matches_generate_oracle():
     assert res[0].ttft >= 0 and len(res[0].token_times) == 10
 
 
-@pytest.mark.parametrize("engine_kw", [
-    {}, dict(paged=True, page_size=8)], ids=["contiguous", "paged"])
-def test_spec_int8_kv_cache_token_exact(engine_kw):
-    _, _, engine = _setup(kv_cache_dtype="int8", speculative="ngram",
-                          **engine_kw)
+def test_spec_int8_kv_cache_token_exact():
+    _, _, engine = _setup(kv_cache_dtype="int8", speculative="ngram")
     reqs = _trace(seed=17)
     spec = engine.run(reqs)
     assert engine.spec_stats()["proposed"] > 0
@@ -228,7 +222,7 @@ def test_spec_mixed_sampling_rows_ride_along():
 # ---------------------------------------------------------------------------
 
 def test_spec_reset_replay_holds_the_verify_compile_pins():
-    _, _, engine = _setup(speculative="ngram", paged=True, page_size=8)
+    _, _, engine = _setup(speculative="ngram")
     # draft_k=4 buckets: a narrow width-2 program + the full k+1
     assert engine._verify_buckets == (2, 5)
     reqs = _trace(seed=23)
@@ -271,10 +265,9 @@ def test_spec_composes_with_disagg_decode_pool():
     # speculative engine
     from mpi_operator_tpu.serve import DisaggEngine
 
-    model, params, coloc = _setup(speculative="ngram", paged=True,
-                                  page_size=8)
+    model, params, coloc = _setup(speculative="ngram", page_size=8)
     disagg = DisaggEngine(model, params, EngineConfig(
-        slots=4, chunk_buckets=(4, 8), paged=True, page_size=8,
+        slots=4, chunk_buckets=(4, 8), page_size=8,
         speculative="ngram"))
     reqs = _trace(seed=53, n=6)
     a = coloc.run(reqs)
@@ -420,7 +413,7 @@ def test_all_timeout_engine_trace_reports_without_crashing():
     # everything with finish_reason "timeout"; the benchmark's latency
     # assembly must survive it with no negative field
     _, _, engine = _setup(speculative=None, request_timeout=0.0,
-                          paged=True, page_size=8)
+                          page_size=8)
     reqs = [Request(i, [1 + i, 2, 3, 4, 5, 6], 8) for i in range(3)]
     results = engine.run(reqs)
     assert all(r.finish_reason == "timeout" for r in results.values())

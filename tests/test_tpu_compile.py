@@ -108,6 +108,26 @@ def test_a_576_wide_pool_would_be_copied_whole_into_the_kernel(
         * 576 * 2
 
 
+def _engine_programs(dmodel, slots, page):
+    """The engine's own compiled programs over `dmodel`. Call with
+    `jax.default_backend` patched to "tpu": the cache is donated there."""
+    from mpi_operator_tpu.serve import EngineConfig
+    from mpi_operator_tpu.serve.engine import sample_slots
+    from mpi_operator_tpu.serve.programs import build_programs
+    return build_programs(dmodel, EngineConfig(slots=slots, page_size=page),
+                          None, sample_slots)
+
+
+def _lower_greedy_step(progs, params, cache, slots, nblk, one_chip):
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
+                                              sharding=one_chip)
+    i32, f32 = arg(jnp.int32, slots), arg(jnp.float32, slots)
+    return progs.step.lower(
+        params, cache, i32, i32, arg(jnp.bool_, slots), i32,
+        arg(jnp.uint32, 2), f32, i32, f32, arg(jnp.int32, slots, nblk),
+        "greedy")
+
+
 def test_longcat_decode_step_fits_the_chip_beside_its_weights_and_pool(
         one_chip, quiet_cache, monkeypatch):
     """The whole decode step of `longcat-flash-1of32` as the engine runs
@@ -122,7 +142,7 @@ def test_longcat_decode_step_fits_the_chip_beside_its_weights_and_pool(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     dmodel = decode_model(
         _serve_longcat.model_of(dims, jnp.bfloat16, MAX_LEN, True), True,
-        slots=True, page_size=PAGE, num_pages=PAGES)
+        page_size=PAGE, num_pages=PAGES)
     on_chip = lambda tree: jax.tree.map(                        # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         tree)
@@ -134,20 +154,9 @@ def test_longcat_decode_step_fits_the_chip_beside_its_weights_and_pool(
         lambda p: dmodel.apply({"params": p}, z, positions=z,
                                with_head=False, mutable=["cache"],
                                pages=table)[1]["cache"], params))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,        # noqa: E731
-                                          sharding=one_chip)
-
-    def step(params, cache, tokens, positions, pages):
-        h, v = dmodel.apply({"params": params, "cache": cache},
-                            tokens[:, None], positions=positions[:, None],
-                            with_head=False, mutable=["cache", "counters"],
-                            pages=pages)
-        logits = dmodel.head_logits(params, h[:, 0])
-        return v["cache"], jnp.argmax(logits, -1), sum(
-            jax.tree.leaves(v["counters"]))
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        params, cache, i32(SLOTS), i32(SLOTS),
-        i32(SLOTS, MAX_LEN // PAGE)).compile()
+    compiled = _lower_greedy_step(
+        _engine_programs(dmodel, SLOTS, PAGE), params, cache, SLOTS,
+        MAX_LEN // PAGE, one_chip).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     assert text.count("tpu_custom_call") == 2 * dims.layers
@@ -245,7 +254,7 @@ def gpt2_xl(one_chip):
         num_layers=dims.layers, num_heads=dims.heads, embed_dim=dims.embed,
         mlp_dim=dims.mlp, causal=True, dtype=jnp.bfloat16,
         decode_kernel=True))
-    dmodel = decode_model(model, True, slots=True, page_size=XL["page"],
+    dmodel = decode_model(model, True, page_size=XL["page"],
                           num_pages=XL["pages"])
     on_chip = lambda tree: jax.tree.map(                        # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
@@ -269,32 +278,16 @@ def _xl_pool_copies(text):
 
 def test_gpt2_xl_decode_step_keeps_its_pool_in_place(
         one_chip, quiet_cache, monkeypatch, gpt2_xl):
-    """The whole `step_paged` of gpt2-xl as the engine runs it (the
-    model's decode call, the tied head, `sample_slots`): a Mosaic call
-    in each of the 48 layers, no copy of a pool-sized array, the 7.5 GB
-    of pools aliased, and weights + pools + temporaries fit the chip."""
-    from mpi_operator_tpu.models.transformer import _head_matmul
-    from mpi_operator_tpu.serve.engine import sample_slots
+    """The engine's own `step_paged` over gpt2-xl (serve/programs.py:
+    the model's decode call, the tied head, `sample_slots`): a Mosaic
+    call in each of the 48 layers, no copy of a pool-sized array, the
+    7.5 GB of pools aliased, and weights + pools + temporaries fit the
+    chip."""
     dims, dmodel, params, cache = gpt2_xl
-    S = XL["slots"]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
-                                              sharding=one_chip)
-
-    def step_paged(params, cache, tokens, positions, rng, temperature,
-                   top_k, top_p, pages):
-        h, v = dmodel.apply({"params": params, "cache": cache},
-                            tokens[:, None], positions=positions[:, None],
-                            with_head=False, mutable=["cache"], pages=pages)
-        logits = _head_matmul(h[:, 0], params["wte"]["embedding"])
-        tok, logp = sample_slots(logits, rng, temperature, top_k, top_p,
-                                 mode="greedy")
-        return v["cache"], tok, logp
-    compiled = jax.jit(step_paged, donate_argnums=(1,)).lower(
-        params, cache, arg(jnp.int32, S), arg(jnp.int32, S),
-        arg(jnp.uint32, 2), arg(jnp.float32, S), arg(jnp.int32, S),
-        arg(jnp.float32, S),
-        arg(jnp.int32, S, XL["max_len"] // XL["page"])).compile()
+    compiled = _lower_greedy_step(
+        _engine_programs(dmodel, XL["slots"], XL["page"]), params, cache,
+        XL["slots"], XL["max_len"] // XL["page"], one_chip).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     pools = dims.layers * XL["pages"] * XL["page"] * 3200 * 2
@@ -308,22 +301,16 @@ def test_gpt2_xl_decode_step_keeps_its_pool_in_place(
 
 def test_gpt2_xl_prefill_bucket_keeps_its_pool_in_place(
         one_chip, quiet_cache, monkeypatch, gpt2_xl):
-    """One `prefill_paged` bucket ([64, 128] tokens): the chunk's rows go
-    into the pool by the same flat scatter and the dense path gathers
-    each row's pages from it — no copy of the pool, the pools aliased."""
+    """The engine's own `prefill_paged` at one bucket ([64, 128] tokens):
+    the chunk's rows go into the pool by the same flat scatter and the
+    dense path gathers each row's pages from it — no copy of the pool,
+    the pools aliased."""
     dims, dmodel, params, cache = gpt2_xl
     S, C = XL["slots"], 128
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,        # noqa: E731
                                           sharding=one_chip)
-
-    def prefill_paged(params, cache, tokens, starts, pages):
-        positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
-        _, v = dmodel.apply({"params": params, "cache": cache}, tokens,
-                            positions=positions, with_head=False,
-                            mutable=["cache"], pages=pages)
-        return v["cache"]
-    compiled = jax.jit(prefill_paged, donate_argnums=(1,)).lower(
+    compiled = _engine_programs(dmodel, S, XL["page"]).prefill.lower(
         params, cache, i32(S, C), i32(S),
         i32(S, XL["max_len"] // XL["page"])).compile()
     m = compiled.memory_analysis()

@@ -275,8 +275,7 @@ def test_disagg_handoff_hop_carries_pages(small_model):
     tracer = Tracer(sample=1.0)
     eng = DisaggEngine(
         model, params,
-        EngineConfig(slots=2, chunk_buckets=(8,), paged=True,
-                     page_size=8, num_pages=32),
+        EngineConfig(slots=2, chunk_buckets=(8,), page_size=8, num_pages=32),
         tracer=tracer)
     results = eng.run(_requests(2))
     trees = build_trees(tracer.ring)
