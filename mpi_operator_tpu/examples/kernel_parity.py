@@ -269,12 +269,15 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
     x_step = jax.random.normal(ks, (slots, 1, E), jnp.bfloat16)
     fill_pos = jnp.broadcast_to(jnp.arange(prefilled)[None],
                                 (slots, prefilled))
-    # cursors on and around the page boundaries and the kernel's groups
-    # of pages, first and last filled position included
-    from ..ops.attention import mla_pages_per_step
-    group = mla_pages_per_step(nblk) * page_size
-    marks = [0, page_size - 1, page_size, group - 1, group, prefilled - 1,
-             5, prefilled - 9]
+    # cursors on and around the page boundaries, the kernel's turns of
+    # several pages and its pair of slots, first and last filled
+    # position included
+    from ..ops.attention import mla_pages_per_turn, mla_row_width
+    turn = page_size * mla_pages_per_turn(
+        nblk, page_size * jnp.dtype(cfg.dtype).itemsize * mla_row_width(
+            cfg.kv_lora_rank, cfg.qk_rope_head_dim))
+    marks = [0, page_size - 1, page_size, turn - 1, turn, 2 * turn - 1,
+             2 * turn, prefilled - 1, 5, prefilled - 9]
     cur = jnp.asarray([min(max(m, 0), prefilled - 1)
                        for m in (marks * slots)[:slots]], jnp.int32)
     params = dense.init(kp, x_step, positions=cur[:, None],
@@ -292,9 +295,9 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
         _assert_mosaic(step(kernel), params, filled["cache"])
         got = step(kernel)(params, filled["cache"])
     name = traced_name(traced["decode"]) or ""
-    if not name.startswith("pallas_mla_paged[pp=") or "+" in name:
+    if not name.startswith("pallas_mla_paged[live,pages=") or "+" in name:
         raise AssertionError(f"decode step traced {traced['decode']}, "
-                             f"expected one pallas_mla_paged[pp=N]")
+                             f"expected one pallas_mla_paged[live,pages=N]")
     ref = step(dense)(params, filled["cache"])
     return {"kernel": "mla_paged_decode_attention", "traced": name,
             "shape": {"slots": slots, "max_len": max_len,

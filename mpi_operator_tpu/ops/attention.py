@@ -74,7 +74,9 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
       "attention" — full-sequence attention: "flash" | "dense" | "ring"
       "decode"    — single-token KV-cache steps: "pallas[hb=N]" |
                     "pallas_paged[hb=N]" (N: kv heads a grid step of
-                    the kernel covers, `decode_head_block`) | "dense"
+                    the kernel covers, `decode_head_block`) |
+                    "pallas_mla_paged[live,pages=N]" (the latent kernel
+                    that walks a row's live pages, N a turn) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
     A jitted function traces once, so wrap the whole run (first call
     included), not a later window."""
@@ -1004,12 +1006,10 @@ def mla_row_width(rank: int, rope: int) -> int:
     """Columns of a latent cache row: `rank + rope` rounded up to whole
     128-lane tiles (576 -> 640). A row-major bfloat16 array on the chip
     is tiled (8, 128)(2, 1): a 576-wide row occupies five lane tiles
-    whether the shape says so or not, and a shape that says 576 makes
-    the compiler keep the pool pages-minor instead — and copy all of it
-    into the kernel's layout and back, every step (what the per-head
-    pool paid while its rows were 64 wide, `kv_row_width`). The pad
-    columns are zeros in the cache and in the query, and add nothing to
-    a score."""
+    whether the shape says so or not, and the decode kernel's page
+    copies move whole tiles: Mosaic refuses one out of a pool whose
+    shape says 576 (`tests/test_tpu_compile.py`). The pad columns are
+    zeros in the cache and in the query, and add nothing to a score."""
     return -(-(rank + rope) // LANES) * LANES
 
 
@@ -1080,64 +1080,110 @@ def mla_paged_attend(q, pool, positions, page_table, rank, sm_scale):
 _MLA_QUERY_ROWS = 65536
 
 
-def _mla_decode_kernel(cur_ref, pt_ref, q_ref, *rest, sm_scale, ps, rank,
-                       pp):
-    """One decode step for one (row, group of `pp` pages): grid
-    (B, nblk // pp), pages innermost; the `pp` page blocks of a step are
-    the same pool under `pp` index maps. All H heads of the row share
-    each page: scores [H, ps] from the absorbed query against the whole
-    row, p.v against its first `rank` columns. Pages past the row's
-    cursor are skipped and their index map pins to the boundary page, as
-    in `_decode_kernel`."""
-    page_refs, (o_ref, acc_ref, m_ref, l_ref) = rest[:pp], rest[pp:]
-    g = pl.program_id(1)
-    cur = cur_ref[pl.program_id(0)]
-
-    @pl.when(g == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    for i, page_ref in enumerate(page_refs):
-        first = (g * pp + i) * ps
-
-        @pl.when(first <= cur)
-        def _attend(page_ref=page_ref, first=first):
-            page = page_ref[0]                                # [ps, W]
-            s = jax.lax.dot_general(
-                q_ref[0], page, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [H, ps]
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(first + cols <= cur, s, NEG_INF)
-            m_prev = m_ref[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(
-                p, axis=-1, keepdims=True)
-            acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-                p.astype(page.dtype), page[:, :rank],
-                preferred_element_type=jnp.float32)
-            m_ref[:, :1] = m_new
-
-    @pl.when(g == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-                    ).astype(o_ref.dtype)
+#: VMEM the latent decode kernel gives its pages in flight: two slots of
+#: `mla_pages_per_turn` pages each, one attended while the other fills
+_MLA_PAGES_VMEM_BUDGET = 1280 * 1024
 
 
-def mla_pages_per_step(nblk: int, most: int = 10) -> int:
-    """Pages one grid step of the latent decode kernel takes: the largest
-    divisor of the table's length up to `most`. A grid step costs 0.3-0.5
-    us whatever it moves, a dead one (past the row's cursor) too, and a
-    64-position bfloat16 page is 80 KB, a tenth of a microsecond of HBM
-    time: with four pages a step, 64 rows of a 100-page table were 12 800
-    steps a step of the model over 8 sublayers and 6.7 ms, most of it
-    dead steps (my chip run, PR 27); ten a step are 5 120. Ten
-    double-buffered pages are 1.6 MB of VMEM; a page past the cursor is
-    pinned to the boundary page and moves nothing."""
-    return max(d for d in range(1, most + 1) if nblk % d == 0)
+def mla_pages_per_turn(nblk: int, page_bytes: int) -> int:
+    """Pages one turn of the latent decode kernel's loop takes: what fits
+    a slot of `_MLA_PAGES_VMEM_BUDGET`, at least one, at most the table.
+    More pages a turn give the MXU a wider score block and the loop
+    fewer turns; a turn's pages past the row's last live one are fetched
+    again from that page, so a wide turn wastes bytes on short contexts
+    (PERF.md, PR 30: eight 80 KB pages at LongCat-Flash's widths)."""
+    return max(1, min(nblk, _MLA_PAGES_VMEM_BUDGET // (2 * page_bytes)))
+
+
+def _mla_decode_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
+                       sem_ref, slot_ref, acc_ref, m_ref, l_ref, *,
+                       sm_scale, ps, rank, nblk, pages):
+    """One decode step for one row: grid (B,), rows in order. The pool
+    stays in HBM; the row's LIVE pages — logical pages 0 .. min(cursor //
+    ps, nblk - 1), and no others — are walked `pages` at a time, each
+    turn's pages copied into one of two VMEM slots while the other
+    slot's are attended: all H heads share them, scores [H, pages * ps]
+    from the absorbed query against whole rows, p.v against their first
+    `rank` columns, an online softmax across turns. The row's last turn
+    starts the next row's first copies, so only the call's first row
+    waits for a page; `slot_ref` carries which slot they went to.
+
+    A turn's pages past the last live one are copied from that page
+    again and masked: nothing a dead table entry points at is read. A
+    cursor past the logical cache (a retiring row's post-EOS step)
+    attends the whole table, as the dense form's clamp does.
+
+    A turn's copies are started, and waited for, in a loop over its
+    pages and not one by one in Python: a kernel with a descriptor a
+    page in its text cost a serving process 0.3 s of tracing a call,
+    sixteen calls a set-up (PERF.md, PR 30)."""
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    width = pages * ps
+
+    def last_live(row):
+        return jnp.minimum(cur_ref[row] // ps, nblk - 1)
+
+    def copy(page, slot, k):
+        return pltpu.make_async_copy(pool_ref.at[page], buf_ref.at[slot, k],
+                                     sem_ref.at[slot])
+
+    def start(row, turn, slot):
+        last = last_live(row)
+
+        def one(k, _):
+            copy(pt_ref[row, jnp.minimum(turn * pages + k, last)], slot,
+                 k).start()
+        jax.lax.fori_loop(0, pages, one, None)
+
+    def wait(slot):
+        def one(k, _):
+            copy(0, slot, k).wait()     # a wait reads the size, not the page
+        jax.lax.fori_loop(0, pages, one, None)
+
+    turns = last_live(b) // pages + 1
+    cur = jnp.minimum(cur_ref[b], nblk * ps - 1)
+
+    @pl.when(b == 0)
+    def _first_row():
+        slot_ref[0] = 0
+        start(b, 0, 0)
+
+    first_slot = slot_ref[0]
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    def turn(i, _):
+        slot = (first_slot + i) % 2
+        more = i + 1 < turns
+
+        @pl.when(more | (b + 1 < nb))
+        def _next():    # this row's next turn, or the next row's first
+            start(jnp.where(more, b, jnp.minimum(b + 1, nb - 1)),
+                  jnp.where(more, i + 1, 0), 1 - slot)
+
+        wait(slot)
+        rows = buf_ref[slot].reshape(width, buf_ref.shape[-1])
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale    # [H, width]
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(i * width + cols <= cur, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(rows.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32)
+        m_ref[:, :1] = m_new
+
+    jax.lax.fori_loop(0, turns, turn, None)
+    slot_ref[0] = (first_slot + turns) % 2
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+                ).astype(o_ref.dtype)
 
 
 def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
@@ -1148,8 +1194,9 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
 
     q [B, H, W] (see `mla_paged_attend`), pool [NP, ps, W], cache_index
     int32 [B] (row b attends positions <= cursor(b)), page_table int32
-    [B, nblk]. Returns u [B, H, rank]. Grid (B, nblk // pp), `pp` =
-    `mla_pages_per_step(nblk)`."""
+    [B, nblk]. Returns u [B, H, rank]. One grid step a row, which walks
+    the row's live pages with its own copies (`_mla_decode_kernel`): the
+    time follows the contexts, not the table's length."""
     B, H, W = q.shape
     NP, ps, _ = pool.shape
     if pool.shape[2] != W or rank > W:
@@ -1173,24 +1220,21 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
                               sm_scale=sm_scale, interpret=interpret),
             mesh, B, 1, (q, pool, cur, pt),
             (rows, (None, None, None), ("rows",), ("rows", None)), rows)
-    pp = mla_pages_per_step(nblk)
-    note_traced("decode", f"pallas_mla_paged[pp={pp}]")
-
-    def page_spec(i):
-        def index(b, g, cur_ref, pt_ref):
-            last = jnp.minimum(cur_ref[b] // ps, nblk - 1)
-            return (pt_ref[b, jnp.minimum(g * pp + i, last)], 0, 0)
-        return pl.BlockSpec((1, ps, W), index)
+    pages = mla_pages_per_turn(nblk, ps * W * pool.dtype.itemsize)
+    note_traced("decode", f"pallas_mla_paged[live,pages={pages}]")
 
     def row_spec(minor):
-        return pl.BlockSpec((1, H, minor), lambda b, g, *pre: (b, 0, 0))
+        return pl.BlockSpec((1, H, minor), lambda b, *pre: (b, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nblk // pp),
-        in_specs=[row_spec(W)] + [page_spec(i) for i in range(pp)],
+        grid=(B,),
+        in_specs=[row_spec(W), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=row_spec(rank),
         scratch_shapes=[
+            pltpu.VMEM((2, pages, ps, W), pool.dtype),  # pages in flight
+            pltpu.SemaphoreType.DMA((2,)),              # one a slot
+            pltpu.SMEM((1,), jnp.int32),          # the next row's slot
             pltpu.VMEM((H, rank), jnp.float32),   # acc
             pltpu.VMEM((H, LANES), jnp.float32),  # running max m
             pltpu.VMEM((H, LANES), jnp.float32),  # running sum l
@@ -1198,16 +1242,19 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
     )
     return pl.pallas_call(
         functools.partial(_mla_decode_kernel, sm_scale=sm_scale, ps=ps,
-                          rank=rank, pp=pp),
+                          rank=rank, nblk=nblk, pages=pages),
         grid_spec=grid_spec,
         out_shape=_out_struct((B, H, rank), q.dtype, q, pool),
+        # rows in order: a row's last turn fetches for the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(cur, pt, q, *([pool] * pp))
+    )(cur, pt, q, pool)
 
 
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
            "decode_head_block", "paged_decode_attention", "kv_row_width",
            "pack_kv_rows",
            "mla_paged_attend", "mla_paged_decode_attention",
-           "mla_pages_per_step", "mla_row_width", "einsum_f32",
+           "mla_pages_per_turn", "mla_row_width", "einsum_f32",
            "record_traced", "note_traced", "traced_name"]

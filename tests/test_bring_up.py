@@ -322,7 +322,7 @@ def test_kernel_parity_harness_runs_the_latent_decode_kernel_when_asked():
         num_heads=4, q_lora_rank=12, kv_lora_rank=16, qk_nope_head_dim=8,
         qk_rope_head_dim=4, v_head_dim=8)
     assert rec["kernel"] == "mla_paged_decode_attention"
-    assert rec["traced"] == "pallas_mla_paged[pp=8]"
+    assert rec["traced"] == "pallas_mla_paged[live,pages=8]"
     assert rec["max_rel_err"] <= 2e-2
     assert MLA_CASE["max_len"] % MLA_CASE["page_size"] == 0
 

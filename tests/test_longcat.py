@@ -17,7 +17,7 @@ from mpi_operator_tpu.models.longcat import (LatentAttention, LongcatLM,
                                              rope_interleaved)
 from mpi_operator_tpu.ops.attention import (mla_paged_attend,
                                             mla_paged_decode_attention,
-                                            mla_pages_per_step,
+                                            mla_pages_per_turn,
                                             mla_row_width)
 from mpi_operator_tpu.parallel import held_experts as he
 from mpi_operator_tpu.serve import EngineConfig, Request, ServingEngine
@@ -147,30 +147,116 @@ def test_rope_rotates_interleaved_pairs_as_the_reference_does():
 
 # -- the kernel ------------------------------------------------------------
 
-@pytest.mark.parametrize("nblk,cursors", [
-    (4, [0, 15, 16, 63]),          # one grid step of four pages
-    (22, [5, 31, 175, 351]),       # eleven steps of two pages
-    (13, [207, 0, 17, 48])])       # thirteen steps of one page
-def test_latent_decode_kernel_interpreted_matches_the_dense_form(
-        nblk, cursors):
-    B, H, R, W, ps = 4, 4, 32, 128, 16
+def _latent_case(nblk, B=4, H=4, R=32, W=128, ps=16):
+    """q, a pool of B * nblk pages behind the trash page, and a table
+    that gives every row nblk pages of its own."""
     k = jax.random.split(jax.random.PRNGKey(nblk), 2)
     q = jax.random.normal(k[0], (B, H, W), F32)
     pool = jax.random.normal(k[1], (B * nblk + 1, ps, W), F32)
     pt = jnp.asarray(np.random.RandomState(nblk).permutation(B * nblk)
                      .reshape(B, nblk) + 1, jnp.int32)
-    cur = jnp.asarray(cursors, jnp.int32)
-    got = mla_paged_decode_attention(q, pool, cur, pt, R, 0.2)
-    want = mla_paged_attend(q[:, None], pool, cur[:, None], pt, R, 0.2)[:, 0]
+    return q, pool, pt
+
+
+def _brute_latent(q, pool, cur, pt, R, sm_scale):
+    """Softmax over the gathered rows, nothing online, nothing paged."""
+    B, nblk = pt.shape
+    ps, W = pool.shape[1:]
     gathered = pool[pt].reshape(B, nblk * ps, W)
-    s = jnp.einsum("bhw,bkw->bhk", q, gathered) * 0.2
+    s = jnp.einsum("bhw,bkw->bhk", q, gathered) * sm_scale
     s = jnp.where(jnp.arange(nblk * ps)[None, None] <= cur[:, None, None],
                   s, -1e30)
-    brute = jnp.einsum("bhk,bkr->bhr", jax.nn.softmax(s, -1),
-                       gathered[..., :R])
+    return jnp.einsum("bhk,bkr->bhr", jax.nn.softmax(s, -1),
+                      gathered[..., :R])
+
+
+def _pages_a_turn(monkeypatch, pages, ps=16, W=128):
+    """Size the kernel's page slots for `pages` float32 pages a turn."""
+    from mpi_operator_tpu.ops import attention
+    monkeypatch.setattr(attention, "_MLA_PAGES_VMEM_BUDGET",
+                        2 * pages * ps * W * 4)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3, 100])
+@pytest.mark.parametrize("nblk,cursors", [
+    (4, [0, 15, 16, 63]),          # the first page, its end, the table's
+    (22, [5, 31, 175, 351]),       # one page to the whole table
+    (13, [207, 0, 17, 48]),        # a long row first, then short ones
+    # a cursor at nblk * ps and beyond: a retiring row's post-EOS step
+    # attends the whole table and reads nothing outside it
+    (13, [208, 1000, 2 ** 30, 12]),
+    # one either side of a page (16), of a turn of two pages (32), of
+    # the pair of slots (64) and of two pairs (128)
+    (13, [15, 16, 31, 32]),
+    (13, [63, 64, 127, 128])])
+def test_latent_decode_kernel_interpreted_matches_the_dense_form(
+        monkeypatch, nblk, cursors, pages):
+    _pages_a_turn(monkeypatch, pages)
+    q, pool, pt = _latent_case(nblk)
+    cur = jnp.asarray(cursors, jnp.int32)
+    got = mla_paged_decode_attention(q, pool, cur, pt, 32, 0.2)
+    brute = _brute_latent(q, pool, jnp.minimum(cur, nblk * 16 - 1), pt, 32,
+                          0.2)
     assert float(jnp.abs(got - brute).max()) < 1e-5
-    assert float(jnp.abs(want - brute).max()) < 1e-5
-    assert nblk % mla_pages_per_step(nblk) == 0
+    inside = cur < nblk * 16        # the dense form skips rows past the cache
+    want = mla_paged_attend(q[:, None], pool, cur[:, None], pt, 32, 0.2)[:, 0]
+    assert float(jnp.abs(want - brute)[inside].max()) < 1e-5
+
+
+@pytest.mark.parametrize("pages", [1, 2, 100])
+def test_latent_decode_kernel_walks_a_free_row_beside_live_ones(
+        monkeypatch, pages):
+    """A free slot's table is all trash and its cursor 0: it walks one
+    page (the trash page) and disturbs neither neighbour — the row
+    before it fetched its first page for it."""
+    _pages_a_turn(monkeypatch, pages)
+    q, pool, pt = _latent_case(6)
+    pt = pt.at[1].set(0).at[3].set(0)
+    cur = jnp.asarray([70, 0, 33, 0], jnp.int32)
+    got = mla_paged_decode_attention(q, pool, cur, pt, 32, 0.2)
+    assert float(jnp.abs(got - _brute_latent(q, pool, cur, pt, 32, 0.2)
+                         ).max()) < 1e-5
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3, 100])
+def test_latent_decode_kernel_reads_no_page_past_a_rows_cursor(
+        monkeypatch, pages):
+    """Every table entry past a row's last live page points at a page
+    full of NaN: a kernel that fetched one into a turn would carry it
+    into the output through 0 x NaN."""
+    _pages_a_turn(monkeypatch, pages)
+    nblk, ps = 9, 16
+    q, pool, pt = _latent_case(nblk)
+    cur = jnp.asarray([0, 16, 47, 143], jnp.int32)
+    dead = jnp.arange(nblk)[None] > (cur // ps)[:, None]
+    poison = pool.shape[0]
+    poisoned = jnp.concatenate([pool, jnp.full((1,) + pool.shape[1:],
+                                               jnp.nan, F32)])
+    got = mla_paged_decode_attention(q, poisoned, cur,
+                                     jnp.where(dead, poison, pt), 32, 0.2)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - _brute_latent(q, pool, cur, pt, 32, 0.2)
+                         ).max()) < 1e-5
+
+
+def test_latent_decode_kernel_sizes_a_turn_from_the_pages_bytes():
+    # LongCat-Flash: bfloat16 pages of 64 rows of 640, a table of 100
+    assert mla_pages_per_turn(100, 64 * 640 * 2) == 8
+    assert mla_pages_per_turn(3, 64 * 640 * 2) == 3       # a short table
+    assert mla_pages_per_turn(100, 1 << 30) == 1          # never none
+
+
+def test_latent_decode_kernel_starts_a_turns_copies_in_a_loop():
+    """Two places start copies (the call's first row; the next turn or
+    the next row) and one waits, however many pages a turn takes: a
+    descriptor a page in the kernel's text is paid for in every trace of
+    a program that calls it (PERF.md, PR 30)."""
+    q, pool, pt = _latent_case(13)
+    text = str(jax.make_jaxpr(
+        lambda *a: mla_paged_decode_attention(*a, 32, 0.2, interpret=False))(
+        q, pool, jnp.zeros(4, jnp.int32), pt))
+    assert mla_pages_per_turn(13, 16 * 128 * 4) > 2
+    assert text.count("dma_start") == 2 and text.count("dma_wait") == 1
 
 
 def test_dense_form_walks_rows_in_groups_and_skips_rows_past_the_cache():

@@ -92,20 +92,25 @@ def test_latent_decode_kernel_and_its_cache_write_leave_the_pool_in_place(
     assert m.temp_size_in_bytes < 64 << 20
 
 
-def test_a_576_wide_pool_would_be_copied_whole_into_the_kernel(
+def test_a_576_wide_pool_is_refused_by_the_kernels_page_copies(
         one_chip, quiet_cache):
-    """Why the row is 640: given rows of 576 the compiler keeps the pool
-    pages-minor and copies all of it into the kernel's layout."""
+    """Why the row is 640: in HBM a 576-wide bfloat16 row occupies five
+    lane tiles whatever its shape says, and Mosaic copies whole tiles:
+    it refuses the kernel's page copy out of such a pool. (Before PR 30
+    the pool went through a BlockSpec, and the compiler kept a 576-wide
+    one pages-minor and copied all of it into the kernel's layout, every
+    step.)"""
     from mpi_operator_tpu.ops.attention import mla_paged_decode_attention
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
                                                   sharding=one_chip)
-    compiled = jax.jit(lambda q, pool, cur, pt: mla_paged_decode_attention(
-        q, pool, cur, pt, 512, 192 ** -0.5, interpret=False)).lower(
-        spec((SLOTS, 64, 576), jnp.bfloat16),
-        spec((PAGES, PAGE, 576), jnp.bfloat16), spec((SLOTS,), jnp.int32),
-        spec((SLOTS, MAX_LEN // PAGE), jnp.int32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes > PAGES * PAGE \
-        * 576 * 2
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\), but "
+                                        r"is 576"):
+        jax.jit(lambda q, pool, cur, pt: mla_paged_decode_attention(
+            q, pool, cur, pt, 512, 192 ** -0.5, interpret=False)).lower(
+            spec((SLOTS, 64, 576), jnp.bfloat16),
+            spec((PAGES, PAGE, 576), jnp.bfloat16),
+            spec((SLOTS,), jnp.int32),
+            spec((SLOTS, MAX_LEN // PAGE), jnp.int32)).compile()
 
 
 def _engine_programs(dmodel, slots, page):
