@@ -8,7 +8,11 @@ would call, one child process after another:
 
   kernels  python -m mpi_operator_tpu.examples.kernel_parity
            every Pallas kernel the two legs below use, compiled by Mosaic
-           and compared with its dense reference at the legs' shapes
+           and compared with its dense reference at the legs' shapes; and
+           the kernels of the other served models at theirs (the latent
+           decode kernel; the paged decode kernel with its lower bound
+           over a ring and a long table; the state-space scan, a chunk
+           against its steps)
   trainer  python -m mpi_operator_tpu.examples.lm_benchmark --workload gpt2
            --size medium --seq-len 512 (global batch 16 over all visible
            chips), started the way the operator starts a gang: a worker

@@ -15,6 +15,11 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   mla_paged_decode_attention (absorbed latent   vs `mla_paged_attend`, the
   attention, LongCat-Flash's published widths)     dense form of
                                                    `LatentAttention`
+  paged_decode_attention with a lower bound     vs a dense masked softmax
+  (a window), over a slot's ring of pages and     over the gathered rows
+  over a long table, at Phi-4-mini-flash's
+  widths (40 heads over 10 pairs of 128)
+  selective_scan over a chunk (`ops/ssm.py`)    vs its own steps one by one
 
 The paged decode cases go through the `Attention` module itself — one
 set of weights, one prefilled pool, the single-token step run once with
@@ -57,6 +62,14 @@ SERVE_SHAPE = dict(slots=8, max_len=256, page_size=64, prefilled=200)
 #: the latent decode kernel at LongCat-Flash's published widths (the
 #: module's defaults): 64 heads share rows of 512 + 64, padded to 640
 MLA_CASE = dict(slots=8, max_len=1280, page_size=64, prefilled=1000)
+#: Phi-4-mini-flash's differential attention as the kernel sees it: 40
+#: query heads over 10 key/value pairs of 128, scores / 8, a window of 512
+#: over a ring of 9 pages a slot, and the same call without a window over
+#: a table of 64 pages
+WINDOW_CASE = dict(slots=8, heads=40, kv_heads=10, head_dim=128,
+                   page_size=64, window=512, table=64)
+#: a state-space layer's chunk at its published widths
+SCAN_CASE = dict(rows=8, chunk=64, channels=5120, states=16)
 
 
 def _rel_err(got, ref) -> float:
@@ -306,17 +319,127 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
             "max_rel_err": _rel_err(got, ref)}
 
 
+def window_decode_cases(slots: int, heads: int, kv_heads: int, head_dim: int,
+                        page_size: int, window: int, table: int
+                        ) -> List[Dict[str, object]]:
+    """`paged_decode_attention` with its lower bound, called as a window
+    layer calls it — the slot's ring as `window / page + 1` pages, the
+    table counted from the window's first page, cursors relative to it —
+    and over a long table with and without the bound, each against a
+    float32 masked softmax over the gathered rows. Cursors sit on both
+    sides of page edges and of the window's edge."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..ops.attention import (pack_kv_rows, paged_decode_attention,
+                                 record_traced, traced_name)
+
+    scale = 1.0 / (head_dim // 2) ** 0.5
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (slots, heads, head_dim), jnp.bfloat16)
+
+    def pool_of(pages):
+        k, v = (jax.random.normal(key, (pages, page_size, kv_heads,
+                                        head_dim), jnp.bfloat16)
+                for key in (kk, kv))
+        return pack_kv_rows(k, v)
+
+    def dense(pool, cur, tbl, bound):
+        rows = pool[tbl].reshape(slots, -1, kv_heads, 2, head_dim).astype(
+            jnp.float32)
+        k = jnp.repeat(rows[:, :, :, 0], heads // kv_heads, axis=2)
+        v = jnp.repeat(rows[:, :, :, 1], heads // kv_heads, axis=2)
+        s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32), k) * scale
+        p = jnp.arange(rows.shape[1])[None, None]
+        seen = p <= cur[:, None, None]
+        if bound:
+            seen &= p > cur[:, None, None] - bound
+        return jnp.einsum("bht,bthd->bhd", jax.nn.softmax(
+            jnp.where(seen, s, -1e30), -1), v)
+
+    records = []
+    nr = window // page_size + 1
+    long_marks = [0, page_size - 1, page_size, window - 1, window,
+                  window + 1, table * page_size - 1, 2 * window + 7]
+    ring_marks = [m % (nr * page_size) if m >= window else m
+                  for m in long_marks]
+    # a long table's pages are scattered over the pool (page 0 the trash)
+    ids = np.random.RandomState(1).permutation(slots * table) + 1
+    scattered = jnp.asarray(ids.reshape(slots, table), jnp.int32)
+    long_pool = pool_of(slots * table + 1)
+    cases = [
+        ("ring", pool_of(slots * nr),
+         jnp.arange(slots * nr, dtype=jnp.int32).reshape(slots, nr),
+         ring_marks, window),
+        ("table", long_pool, scattered, long_marks, window),
+        ("table", long_pool, scattered, long_marks, None)]
+    for form, pool, tbl, marks, bound in cases:
+        cur = jnp.asarray((marks * slots)[:slots], jnp.int32)
+        call = jax.jit(lambda q, pool, cur, tbl, bound=bound:
+                       paged_decode_attention(q, pool, cur, tbl,
+                                              window=bound, sm_scale=scale))
+        with record_traced() as traced:
+            _assert_mosaic(call, q, pool, cur, tbl)
+            got = call(q, pool, cur, tbl)
+        records.append({
+            "kernel": "paged_decode_attention_"
+                      + (f"window_{form}" if bound else "pairs"),
+            "traced": traced_name(traced["decode"]),
+            "shape": {"slots": slots, "heads": heads, "kv_heads": kv_heads,
+                      "head_dim": head_dim, "page_size": page_size,
+                      "window": bound, "table": int(tbl.shape[1])},
+            "cursors": [int(c) for c in cur],
+            "max_rel_err": _rel_err(got, dense(pool, cur, tbl, bound))})
+    return records
+
+
+def scan_case(rows: int, chunk: int, channels: int, states: int
+              ) -> Dict[str, object]:
+    """`selective_scan` over a chunk with a state carried in, against its
+    own steps one position at a time (what a prefill chunk is to the
+    decode steps that follow it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.ssm import selective_scan
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    x = jax.random.normal(ks[0], (rows, chunk, channels))
+    delta = jax.nn.softplus(jax.random.normal(ks[1], x.shape) - 4.0)
+    A = -jnp.broadcast_to(jnp.arange(1.0, states + 1)[:, None],
+                          (states, channels))
+    B, C = (jax.random.normal(k, (rows, chunk, states)) for k in ks[2:4])
+    D = jnp.ones((channels,))
+    s0 = jax.random.normal(ks[4], (rows, states, channels))
+    y, last = jax.jit(selective_scan)(x, delta, A, B, C, D, s0)
+    step = jax.jit(selective_scan)
+    s, ys = s0, []
+    for t in range(chunk):
+        y_t, s = step(x[:, t:t + 1], delta[:, t:t + 1], A, B[:, t:t + 1],
+                      C[:, t:t + 1], D, s)
+        ys.append(y_t)
+    return {"kernel": "selective_scan_chunk_vs_steps",
+            "shape": {"rows": rows, "chunk": chunk, "channels": channels,
+                      "states": states},
+            "max_rel_err": max(_rel_err(y, jnp.concatenate(ys, 1)),
+                               _rel_err(last, s))}
+
+
 def run_kernel_parity(train_shape: Optional[dict] = None,
                       serve_shape: Optional[dict] = None,
                       model: Optional[dict] = None,
                       decode_models: Optional[List[dict]] = None,
                       mla: Optional[dict] = None,
+                      window: Optional[dict] = None,
+                      scan: Optional[dict] = None,
                       tol: float = BF16_TOL) -> List[Dict[str, object]]:
     """Every kernel the two legs use, at their shapes; one record each
     with its measured error and `ok`. The decode kernels run once per
     entry of `decode_models` (default: `model` alone); the latent decode
-    kernel where `mla` gives its case. Off TPU the kernels interpret (the
-    tier-1 test runs tiny shapes that way)."""
+    kernel where `mla` gives its case, the windowed decode kernel and the
+    scan where `window` and `scan` give theirs. Off TPU the kernels
+    interpret (the tier-1 test runs tiny shapes that way)."""
     train_shape = train_shape or TRAIN_SHAPE
     serve_shape = serve_shape or SERVE_SHAPE
     model = model or GPT2_MEDIUM
@@ -328,6 +451,10 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
                 records.append(case(int8, **serve_shape, **geometry))
     if mla:
         records.append(mla_decode_case(**mla))
+    if window:
+        records += window_decode_cases(**window)
+    if scan:
+        records.append(scan_case(**scan))
     for rec in records:
         rec["tol"] = tol
         rec["ok"] = bool(rec["max_rel_err"] <= tol)
@@ -352,7 +479,8 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     device = device_record()
     records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL],
-                                mla=MLA_CASE)
+                                mla=MLA_CASE, window=WINDOW_CASE,
+                                scan=SCAN_CASE)
     for rec in records:
         print(json.dumps({**rec, **device}))
     ok = all(rec["ok"] for rec in records)
@@ -362,7 +490,7 @@ def main(argv=None) -> int:
                                                for r in records),
                       "tol": BF16_TOL,
                       "decode_traced": sorted({r["traced"] for r in records
-                                               if "traced" in r}),
+                                               if r.get("traced")}),
                       **device,
                       "compile_cache_dir": cache_dir}))
     return 0 if ok else 1

@@ -605,7 +605,7 @@ def flash_attention(q, k, v, causal: bool = True,
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, sm_scale, block_k):
+                   acc_ref, m_ref, l_ref, *, sm_scale, block_k, window=None):
     """One decode step for one (row, block of kv heads, k block): grid
     (B, KV // hb, nk), k innermost. A grid step covers `hb` kv heads at
     once — K/V blocks [hb, block_k, D], the q block [hb, G, D] with ALL
@@ -634,7 +634,12 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     that whole column block: the query comes padded with zeros over the
     V lanes, so a score sees K alone, and p . block carries p . V in its
     V lanes (the wrapper reads those). A page is read once, nothing is
-    shuffled across lanes, and the body below is the same."""
+    shuffled across lanes, and the body below is the same.
+
+    `window` (static; None: none) is a lower bound a row: row b attends
+    positions cursor - window < p <= cursor. Blocks wholly behind it are
+    skipped as blocks past the cursor are, and the block it cuts masks
+    the columns behind it."""
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     cur = cur_ref[pl.program_id(0)]
@@ -645,7 +650,11 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    @pl.when(ki * block_k <= cur)
+    live = ki * block_k <= cur
+    if window is not None:
+        live &= (ki + 1) * block_k > cur - window + 1
+
+    @pl.when(live)
     def _attend():
         q = q_ref[0]                              # [hb, G, D]
         if v_ref is None:
@@ -676,7 +685,10 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * sm_scale  # [hb, G, block_k]
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(ki * block_k + cols <= cur, s, NEG_INF)
+        seen = ki * block_k + cols <= cur
+        if window is not None:
+            seen &= ki * block_k + cols > cur - window
+        s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_ref[:, :, :1]
         l_prev = l_ref[:, :, :1]
@@ -736,7 +748,7 @@ def decode_head_block(kv_heads: int, block_k: int, head_dim: int,
 
 
 def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
-                 block_k, interpret):
+                 block_k, interpret, sm_scale=None, window=None):
     """The pallas_call both decode kernels share. q4 is [B, KV, G, D];
     `k`/`v` (and the int8 scales, given a trailing unit dim here) are
     blocked (1, hb, block_k, ·) at `kv_index(b, h, ki, *prefetch_refs)` —
@@ -748,7 +760,8 @@ def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
     over each head's V lanes and the output read from them
     (`_decode_kernel`).
     Reports `name[hb=..]` as the traced decode implementation, so a
-    headline says how many heads a grid step took."""
+    headline says how many heads a grid step took. `sm_scale` None is
+    1 / sqrt(D); `window` is `_decode_kernel`'s."""
     B, KV, G, D = q4.shape
     paged = v is None
     hb = decode_head_block(KV, block_k, D, k.dtype, _KV_VMEM_BUDGET, paged)
@@ -785,7 +798,8 @@ def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
         ks_ref, vs_ref = ((rest.pop(0), rest.pop(0)) if quantized
                           else (None, None))
         _decode_kernel(refs[0], q_ref, k_ref, v_ref, ks_ref, vs_ref, *rest,
-                       sm_scale=1.0 / (D ** 0.5), block_k=block_k)
+                       sm_scale=sm_scale or 1.0 / (D ** 0.5),
+                       block_k=block_k, window=window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pre,
@@ -905,7 +919,9 @@ def pack_kv_rows(k, v):
 
 def paged_decode_attention(q, pages, cache_index, page_table,
                            k_scale=None, v_scale=None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None,
+                           sm_scale: Optional[float] = None):
     """`decode_attention` over a PAGED cache — the serving engine's
     block-table layout (transformer.py decode_page_size).
 
@@ -922,6 +938,12 @@ def paged_decode_attention(q, pages, cache_index, page_table,
                  page (their positions sit beyond the cursor, so the
                  column mask already excludes them)
     k_scale/v_scale [NP, KV, ps] f32  int8 per-(page-slot, head) scales
+    window       static: row b attends cursor(b) - window < p <= cursor(b)
+                 and pages wholly behind that are neither fetched nor
+                 scored (they still cost a grid step, as dead pages past
+                 the cursor do). None: no lower bound, and the program
+                 lowered is the one without this argument
+    sm_scale     static: the scores' scale; None is 1 / sqrt(D)
 
     The kernel body is the contiguous one — block_k equals the page size
     and logical block ki covers positions [ki*ps, ki*ps+ps), so the
@@ -965,7 +987,8 @@ def paged_decode_attention(q, pages, cache_index, page_table,
         pool, scale = (None, None, "heads"), (None, "heads", None)
         out = ("rows", "heads", None)
         return _per_device(
-            functools.partial(paged_decode_attention, interpret=interpret),
+            functools.partial(paged_decode_attention, interpret=interpret,
+                              window=window, sm_scale=sm_scale),
             mesh, B, KV, (q, pages, cur, pt, k_scale, v_scale),
             (out, pool, ("rows",), ("rows", None), scale, scale), out)
 
@@ -974,11 +997,16 @@ def paged_decode_attention(q, pages, cache_index, page_table,
         # boundary block (blocks past the cursor re-use its page — the
         # kernel skips their compute anyway)
         last = jnp.minimum(cur_ref[b] // ps, nblk - 1)
-        return (pt_ref[b, jnp.minimum(ki, last)], h, 0, 0)
+        ki = jnp.minimum(ki, last)
+        if window is not None:
+            # and pages behind the window re-use its first page
+            ki = jnp.maximum(ki, jnp.minimum(
+                jnp.maximum(cur_ref[b] - window + 1, 0) // ps, last))
+        return (pt_ref[b, ki], h, 0, 0)
 
     return _decode_call("pallas_paged", q.reshape(B, KV, H // KV, D),
                         pages, None, k_scale, v_scale, (cur, pt), nblk,
-                        kv_index, ps, interpret)
+                        kv_index, ps, interpret, sm_scale, window)
 
 
 # ---------------------------------------------------------------------------
@@ -1252,9 +1280,93 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
     )(cur, pt, q, pool)
 
 
+# ---------------------------------------------------------------------------
+# A chunk of queries over the per-head page pool, in plain jax
+# ---------------------------------------------------------------------------
+
+#: float32 scores (rows x heads x queries x keys of a turn) one pass of
+#: `paged_attend` holds: 256 MB of them, beside probabilities in the
+#: cache's type and an accumulator an eighth of that
+_PAGED_SCORES = 1 << 26
+#: cached positions a turn of `paged_attend`'s walk scores at once
+_PAGED_TURN = 512
+
+
+def paged_attend(q, pool, positions, page_table,
+                 sm_scale: Optional[float] = None):
+    """Attention of a chunk of queries over the per-head page pool
+    (`kv_row_width`) without the pool's rows ever being gathered for a
+    whole table: what `mla_paged_attend` is for the latent pool. The
+    multi-token path (prefill chunks) of a model whose contexts are too
+    long for `[rows, max_len]` scores, and its decode step where no
+    kernel was asked for.
+
+    q [B, S, H, D]; pool [NP, ps, KV * 2D]; positions [B, S] absolute (a
+    position >= nblk * ps marks a query whose result nobody reads);
+    page_table [B, nblk]. Query head h reads kv head h // (H // KV).
+    Returns softmax(q . K * sm_scale) . V, [B, S, H, D]; `sm_scale` None
+    is 1 / sqrt(D).
+
+    Pages are walked in logical order, `_PAGED_TURN` positions a turn,
+    with an online softmax, up to the furthest page a live query of the
+    rows in hand reaches; rows go through in groups so that a turn's
+    float32 scores stay under `_PAGED_SCORES`."""
+    B, S, H, D = q.shape
+    NP, ps, W = pool.shape
+    KV = W // (2 * D)
+    if W != kv_row_width(KV, D) or H % KV:
+        raise ValueError(f"pool rows of {W} columns do not hold K and V "
+                         f"of a divisor of H={H} heads of D={D}")
+    nblk = page_table.shape[1]
+    scale = sm_scale or 1.0 / (D ** 0.5)
+    pb = max(d for d in range(1, nblk + 1)
+             if nblk % d == 0 and d <= max(1, _PAGED_TURN // ps))
+    T = pb * ps
+    pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B, S))
+    pt = jnp.asarray(page_table, jnp.int32)
+
+    def attend(q, qpos, pt):
+        """q [G, S, H, D], qpos [G, S], pt [G, nblk] -> [G, S, H, D]."""
+        G = q.shape[0]
+        q5 = q.reshape(G, S, KV, H // KV, D)
+        turns = jnp.max(jnp.where(qpos < nblk * ps, qpos // T + 1, 0))
+
+        def body(j, carry):
+            m, l, acc = carry
+            pages = pool[jax.lax.dynamic_slice_in_dim(pt, j * pb, pb, 1)]
+            kv = pages.reshape(G, T, KV, 2, D)
+            s = einsum_f32("gskqd,gtkd->gkqst", q5, kv[:, :, :, 0]) * scale
+            cols = j * T + jnp.arange(T, dtype=jnp.int32)
+            s = jnp.where(cols <= qpos[:, None, None, :, None], s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + p.sum(-1, keepdims=True)
+            acc = acc * alpha + einsum_f32(
+                "gkqst,gtkd->gkqsd", p.astype(pool.dtype), kv[:, :, :, 1])
+            return m_new, l, acc
+
+        stat = (G, KV, H // KV, S, 1)
+        init = (jnp.full(stat, NEG_INF, jnp.float32),
+                jnp.zeros(stat, jnp.float32),
+                jnp.zeros(stat[:-1] + (D,), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, turns, body, init)
+        out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+        return out.transpose(0, 3, 1, 2, 4).reshape(G, S, H, D)
+
+    G = max(d for d in range(1, B + 1)
+            if B % d == 0 and d <= max(1, _PAGED_SCORES // (H * S * T)))
+    if G == B:
+        return attend(q, pos, pt)
+    grouped = lambda x: x.reshape((B // G, G) + x.shape[1:])      # noqa: E731
+    out = jax.lax.map(lambda a: attend(*a),
+                      (grouped(q), grouped(pos), grouped(pt)))
+    return out.reshape(B, S, H, D)
+
+
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
            "decode_head_block", "paged_decode_attention", "kv_row_width",
-           "pack_kv_rows",
+           "pack_kv_rows", "paged_attend",
            "mla_paged_attend", "mla_paged_decode_attention",
            "mla_pages_per_turn", "mla_row_width", "einsum_f32",
            "record_traced", "note_traced", "traced_name"]

@@ -327,6 +327,10 @@ class ServingEngine:
     #: off instead (telemetry/trace.py taxonomy)
     POST_PREFILL_HOP = "serve.decode"
 
+    #: whether a request's pages leave or enter this pool through
+    #: `transfer.PageTransfer` (the two halves of a disaggregated pair)
+    HANDS_OFF_PAGES = False
+
     def __init__(self, model, params, config: Optional[EngineConfig] = None,
                  telemetry=None, events=None, drafter=None, tracer=None):
         """telemetry: a telemetry.ServeTelemetry — live TTFT/TPOT/step
@@ -452,10 +456,11 @@ class ServingEngine:
             self._verify = progs.verify
             self._step_counters = progs.step_counters
             self.donates_cache = progs.donates_cache
+            # cache leaves the model keeps a slot, not a page (programs.py)
+            self._slot_state = progs.slot_state
+            self._refuse_for_slot_state(cfg)
 
-            self.scheduler = Scheduler(cfg.chunk_buckets, mcfg.max_len,
-                                       admit_lookahead=cfg.admit_lookahead,
-                                       reserve=self.RESERVE)
+            self.scheduler = self._new_scheduler()
             self.slots = SlotManager(S)
             # closes on a device sync, so the engine's set-up span holds the
             # weights' copy and both programs' time; tick() never blocks for
@@ -463,6 +468,8 @@ class ServingEngine:
             with span("serve.init_cache"):
                 self.cache = jax.block_until_ready(
                     self._init_cache(self.params))
+            if telemetry is not None:
+                telemetry.slot_state_bytes.set(self.slot_state_bytes())
             self._prev_tok = self._zeros_tok(S)
             # (rows, widest bucket) of the prefill calls dispatched since the
             # last decode dispatch: the device runs them BEFORE that step, so
@@ -488,6 +495,38 @@ class ServingEngine:
 
         # -- bookkeeping ------------------------------------------------------
 
+    def _refuse_for_slot_state(self, cfg: "EngineConfig") -> None:
+        """A model that keeps state a slot (programs.py, SLOT_STATE) is
+        served without what would need snapshots of that state: each
+        refusal names the piece that is missing."""
+        if not self._slot_state:
+            return
+        kept = (f"{type(self.dmodel).__name__} keeps state a slot "
+                f"({', '.join(self._slot_state)}), not only pages")
+        if cfg.prefix_cache:
+            raise ValueError(
+                f"EngineConfig(prefix_cache=True): {kept}. A prefix hit "
+                f"would need a snapshot of that state at the page "
+                f"boundary the hit ends on, and none is taken: pass "
+                f"prefix_cache=False")
+        if cfg.speculative is not None:
+            raise ValueError(
+                f"EngineConfig(speculative={cfg.speculative!r}): {kept}. "
+                f"A rejected draft rewinds the cursor, and a recurrent "
+                f"state or a window's ring that has consumed the draft "
+                f"cannot be rewound without a snapshot: there is none")
+        if self.HANDS_OFF_PAGES:
+            raise ValueError(
+                f"{type(self).__name__}: {kept}. PageTransfer moves "
+                f"pages only; handing a request to another pool needs a "
+                f"transfer of its slot's state, which does not exist")
+
+    def _new_scheduler(self) -> Scheduler:
+        return Scheduler(self.config.chunk_buckets, self.model_config.max_len,
+                         admit_lookahead=self.config.admit_lookahead,
+                         reserve=self.RESERVE,
+                         overlap_chunks=not self._slot_state)
+
     def _zeros_tok(self, n: int):
         """The device-side token chain's initial value, placed where the
         step's own outputs land (see __init__) so step 1 and step N hit
@@ -503,11 +542,7 @@ class ServingEngine:
         what the bench calls between the warmup trace and the measured
         trace. A reset engine replays a trace with identical tokens AND
         identical compile counts."""
-        self.scheduler = Scheduler(self.config.chunk_buckets,
-                                   self.model_config.max_len,
-                                   admit_lookahead=self.config
-                                   .admit_lookahead,
-                                   reserve=self.RESERVE)
+        self.scheduler = self._new_scheduler()
         self.slots = SlotManager(self.config.slots)
         if os.environ.get("TPU_DEBUG_PAGES") == "1":
             # O(num_pages) invariant audit of the state the trace left
@@ -577,6 +612,16 @@ class ServingEngine:
         return sum(x.nbytes for x in jax.tree.leaves(self.cache)
                    if x.shape[0] == NP) // NP
 
+    def slot_state_bytes(self) -> int:
+        """Bytes ONE slot holds beside its pages, whatever its context:
+        the leaves the model names in `SLOT_STATE` (a window layer's
+        ring, a recurrent layer's state), counted from the cache itself.
+        0 for a model whose cache is pages alone."""
+        flat = jax.tree_util.tree_flatten_with_path(self.cache)[0]
+        return sum(x.nbytes for path, x in flat
+                   if getattr(path[-1], "key", None) in self._slot_state
+                   ) // self.config.slots
+
     def spec_stats(self) -> Dict[str, float]:
         """Speculation accounting since construction/reset().
         effective_tokens_per_step is tokens emitted PER ROW per verify
@@ -640,25 +685,38 @@ class ServingEngine:
         and which a model that walks only the pages its queries reach
         (the latent cache) does not walk for."""
         size = lead.chunks[0][1]
-        with span("serve.prefill"):
+        with span("serve.prefill") as sp:
             batch = [st for st in self.scheduler.active
                      if st.prefilling and st.chunks[0][1] == size]
             toks = np.zeros((self.config.slots, size), np.int32)
             starts = np.full((self.config.slots,),
                              self.model_config.max_len, np.int32)
+            lengths = np.zeros((self.config.slots,), np.int32)
             done = []
             for st in batch:
                 w, _ = st.chunks.pop(0)
                 p1 = len(st.req.prompt) - 1
                 window = list(st.req.prompt[w:min(w + size, p1)])
+                lengths[st.slot] = len(window)
                 window += [0] * (size - len(window))
                 toks[st.slot] = window
                 starts[st.slot] = w
                 done.append((st, w, p1))
+            # a model that keeps state a slot is told where each row's
+            # real tokens end (its pads then sit at a junk position), and
+            # starts a row whose chunk begins at 0 from zeros
+            extra = ()
+            if self._slot_state:
+                extra = (jnp.asarray(lengths),)
+                fresh = sum(1 for _, w, _ in done if w == 0)
+                sp.set(state_rows=fresh)
+                if self.telemetry is not None:
+                    self.telemetry.slot_state_starts.inc(fresh)
             t0 = time.perf_counter()
             self.cache = self._prefill(
                 self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(starts), jnp.asarray(self._page_table_array()))
+                jnp.asarray(starts), jnp.asarray(self._page_table_array()),
+                *extra)
         self._note_prefill_queued(len(batch), size)
         if self.telemetry is not None:
             # async dispatch: host wall time, not device time — the next
@@ -710,7 +768,8 @@ class ServingEngine:
         tokens are in flight."""
         with span("serve.decode_step") as sp:
             toks, pos, use_prev, temps, top_ks, top_ps, consumers = \
-                self.slots.step_arrays()
+                self.slots.step_arrays(
+                    self.model_config.max_len if self._slot_state else None)
             if not consumers:
                 sp.drop()
                 return None
@@ -1322,6 +1381,7 @@ class PrefillEngine(ServingEngine):
     a colocated engine could."""
 
     RESERVE = "prompt"
+    HANDS_OFF_PAGES = True
 
     #: a prefilled prompt's next hop in this pool is the page handoff,
     #: not decode — trace hop names follow the disaggregated flow
@@ -1362,6 +1422,8 @@ class DecodeEngine(ServingEngine):
     engine — a handed-off prompt whose prefix is already resident here
     needs NO bytes moved for those pages (DisaggEngine transfers only
     the misses)."""
+
+    HANDS_OFF_PAGES = True
 
     def install_handoff(self, req: Request, reserved, now: float,
                         cached_tokens: int = 0,

@@ -22,7 +22,8 @@ from ..models.generate import cast_params
 
 class Programs(NamedTuple):
     init_cache: Callable      # (params) -> cache
-    prefill: Callable         # (params, cache, tokens, starts, pages)
+    prefill: Callable         # (params, cache, tokens, starts, pages
+    #                            [, lengths]) -> cache
     step: Callable            # (params, cache, prev_tok, host_toks,
     #                            use_prev, positions, rng, temperature,
     #                            top_k, top_p, pages, mode)
@@ -30,6 +31,7 @@ class Programs(NamedTuple):
     #                            temperature, top_k, top_p, pages, mode)
     step_counters: Tuple[str, ...]   # the model's STEP_COUNTERS
     donates_cache: bool
+    slot_state: Tuple[str, ...]      # the model's SLOT_STATE
 
 
 def cast_program(dtype):
@@ -60,6 +62,32 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
       the call's batch.
     - the cache's pooled leaves lead with `num_pages`
       (`ServingEngine.page_bytes()`, `transfer.PageTransfer`).
+    - optional `SLOT_STATE`: names of cache leaves of another kind, which
+      lead with `slots` and belong to a row whatever pages it holds: the
+      ring of a layer that attends inside a window, the state of a
+      recurrent layer (`models/phi4flash.py` has all three kinds of leaf
+      in one model: ONE pooled leaf read by eight layers, eight rings,
+      nine states). `ServingEngine.slot_state_bytes()` counts them. For
+      a model that names any, the contract widens, and the engine keeps
+      its side of it:
+        * a row's real positions in a call are consecutive and come
+          FIRST; after them, and in every row that is no member of the
+          call (prefill) or consumes no token (a decode step: rows
+          mid-prefill, free rows), positions are `max_len`. Over such a
+          position the model leaves the row's slot leaves EXACTLY as they
+          were: a pad token never enters a recurrence, and a row
+          mid-prefill survives the decode steps between its chunks.
+          `prefill` takes the rows' real `lengths` for that;
+        * no position is computed twice: chunk plans do not overlap
+          (`scheduler.plan_chunks(overlap=False)`);
+        * a call whose first position is 0 starts its row from zeros, so
+          admission onto a used slot needs no reset program;
+        * what cannot be kept right without snapshots of those leaves is
+          refused at construction: the prefix cache, speculation's
+          rewind, the handoff of pages between pools.
+    - optional `PREFILL_CACHE_ONLY`: `apply(..., cache_only=True)` may
+      stop after the last layer that keeps anything; prefill, which
+      returns the cache alone, asks for it.
     - optional `head_logits(params, h)`: `[T, E]` hidden states to
       `[T, vocab]` logits, for an untied head. Without it the head is
       the tied table `params["wte"]["embedding"]`.
@@ -82,6 +110,9 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
         def head(params, h):
             return _head_matmul(h, params["wte"]["embedding"])
     names = tuple(getattr(dmodel, "STEP_COUNTERS", ()))
+    slot_state = tuple(getattr(dmodel, "SLOT_STATE", ()))
+    cache_only = ({"cache_only": True}
+                  if getattr(dmodel, "PREFILL_CACHE_ONLY", False) else {})
     counted = ["cache", "counters"] if names else ["cache"]
 
     def step_counts(vars_):
@@ -96,19 +127,24 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
                                 pages=jnp.zeros((S, nblk), jnp.int32))
         return vars_["cache"]
 
-    def prefill_paged(params, cache, tokens, starts, pages):
+    def prefill_paged(params, cache, tokens, starts, pages, lengths=None):
         # BATCHED chunk over the page pool: [S, C] tokens, one row
         # per slot, writes routed through the page tables — the pool
         # is shared so there is no row to slice out, and every
         # waiting slot whose next chunk shares this bucket advances
         # in the same program. Non-member rows carry zero tokens at
         # max_len, past the logical cache: the page scatter drops
-        # their writes (transformer.py, longcat.py).
+        # their writes (transformer.py, longcat.py). `lengths` (a model
+        # with SLOT_STATE) puts a row's pads there too.
         positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
+        if lengths is not None:
+            positions = jnp.where(
+                jnp.arange(tokens.shape[1])[None] < lengths[:, None],
+                positions, dmodel.config.max_len)
         _, vars_ = dmodel.apply(
             {"params": params, "cache": cache}, tokens,
             positions=positions, with_head=False, mutable=["cache"],
-            pages=pages)
+            pages=pages, **cache_only)
         return vars_["cache"]
 
     def step_paged(params, cache, prev_tok, host_toks, use_prev,
@@ -183,7 +219,8 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
                      static_argnums=(11,)),
         verify=jax.jit(verify_paged, donate_argnums=donate,
                        static_argnums=(9,)),
-        step_counters=names, donates_cache=bool(donate))
+        step_counters=names, donates_cache=bool(donate),
+        slot_state=slot_state)
 
 
 __all__ = ["Programs", "build_programs", "cast_program"]

@@ -108,8 +108,8 @@ class RequestState:
         return self.finish_reason is not None
 
 
-def plan_chunks(n: int, buckets: Sequence[int],
-                start: int = 0) -> List[Tuple[int, int]]:
+def plan_chunks(n: int, buckets: Sequence[int], start: int = 0,
+                overlap: bool = True) -> List[Tuple[int, int]]:
     """Windows (start, size) covering prompt positions [start, n), sizes
     drawn from the ≤3 compiled `buckets` (ascending). Full largest-bucket
     windows walk left→right; the ragged tail takes the smallest bucket
@@ -127,7 +127,13 @@ def plan_chunks(n: int, buckets: Sequence[int],
     aligned with padding instead of right-aligned — reaching backwards
     would rewrite SHARED pages, which other requests may be attending
     concurrently. The pad writes land past n where the decode cursor
-    overwrites them, same as the short-prompt case."""
+    overwrites them, same as the short-prompt case.
+
+    `overlap=False` is for a model that keeps recurrent state
+    (programs.py, SLOT_STATE): recomputing a suffix would feed tokens
+    through its scan twice, so the tail is left-aligned and padded from
+    position 0 on as well — and the engine puts those pads at a junk
+    position, not at real ones."""
     if n < 0:
         raise ValueError(f"negative prefill length {n}")
     if not 0 <= start <= n:
@@ -140,7 +146,7 @@ def plan_chunks(n: int, buckets: Sequence[int],
         done += big
     if done < n:
         size = next(b for b in buckets if b >= n - done)
-        if start > 0:
+        if start > 0 or not overlap:
             out.append((done, size))            # left-aligned, padded
         else:
             out.append((max(0, n - size), size))
@@ -164,7 +170,8 @@ class Scheduler:
     pages left over. FCFS order is preserved whenever the head fits."""
 
     def __init__(self, chunk_buckets: Sequence[int], max_len: int,
-                 admit_lookahead: int = 8, reserve: str = "full"):
+                 admit_lookahead: int = 8, reserve: str = "full",
+                 overlap_chunks: bool = True):
         buckets = tuple(chunk_buckets)
         if not 1 <= len(buckets) <= 3:
             raise ValueError(f"chunk_buckets must have 1-3 entries "
@@ -190,6 +197,8 @@ class Scheduler:
         # disaggregated PREFILL pool's mode, where the decode span is
         # the decode pool's problem (serve/engine.py PrefillEngine).
         self.reserve = reserve
+        # False for a model with recurrent state (plan_chunks)
+        self.overlap_chunks = overlap_chunks
         # optional admission gate: a predicate over the candidate
         # request checked before any reservation work. The
         # disaggregated facade installs the decode-pool backpressure
@@ -311,7 +320,8 @@ class Scheduler:
             st = RequestState(
                 req=req, slot=slot,
                 pos=span,                     # prefill starts past the hits
-                chunks=plan_chunks(p1, self.chunk_buckets, start=span),
+                chunks=plan_chunks(p1, self.chunk_buckets, start=span,
+                                   overlap=self.overlap_chunks),
                 next_input=int(req.prompt[-1]), admitted_at=now,
                 page_table=table, owned_pages=chain + private,
                 cached_tokens=span, published_pages=len(chain),

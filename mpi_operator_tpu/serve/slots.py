@@ -329,14 +329,18 @@ class SlotManager:
     def occupied(self) -> int:
         return self.n - len(self.free)
 
-    def step_arrays(self):
+    def step_arrays(self, idle_pos: Optional[int] = None):
         """The decode step's host-built inputs: tokens, cursors, use_prev
         flags, and per-slot sampling params, plus which states actually
         consume this step's samples. Slots mid-prefill or free still get
         a row (the step is fixed-shape): their position is their own next
         write offset, so the one junk K/V they write lands exactly
         where the next real write (chunk or cursor) overwrites it, and
-        their sampled token is simply discarded.
+        their sampled token is simply discarded. With `idle_pos` (the
+        model's `max_len`, for a model that keeps state a slot:
+        programs.py) every row that consumes nothing sits at that junk
+        position instead: a recurrent state has no later write to be
+        overwritten by.
 
         use_prev marks rows whose input token is the PREVIOUS step's
         device output for the same slot (st.dispatched >= 1: a decoding
@@ -354,7 +358,7 @@ class SlotManager:
         a drained state still tracked here is skipped — only the final
         sync's bookkeeping remains for it."""
         toks = np.zeros((self.n,), np.int32)
-        pos = np.zeros((self.n,), np.int32)
+        pos = np.full((self.n,), idle_pos or 0, np.int32)
         use_prev = np.zeros((self.n,), bool)
         temps = np.zeros((self.n,), np.float32)
         top_ks = np.zeros((self.n,), np.int32)
@@ -365,9 +369,11 @@ class SlotManager:
                 continue
             if not st.prefilling and st.dispatched >= st.req.max_new_tokens:
                 continue                  # drained: awaiting final sync
-            pos[st.slot] = st.pos
             if st.prefilling:
+                if idle_pos is None:
+                    pos[st.slot] = st.pos
                 continue
+            pos[st.slot] = st.pos
             toks[st.slot] = st.next_input
             use_prev[st.slot] = st.dispatched >= 1 and not st.host_next
             temps[st.slot] = st.req.temperature

@@ -69,7 +69,7 @@ class PageTransfer:
 
         def gather(cache, ids):
             # page-pool leaves all carry the pool's page count on axis
-            # 0; anything else (none today) passes through untouched
+            # 0 (`move` refuses a cache with leaves of another kind)
             return jax.tree.map(
                 lambda x: x[ids] if x.shape[0] == src_num_pages else x,
                 cache)
@@ -97,6 +97,15 @@ class PageTransfer:
         if len(src_ids) != len(dst_ids):
             raise ValueError(f"src/dst page lists disagree: "
                              f"{len(src_ids)} vs {len(dst_ids)}")
+        odd = [tuple(x.shape) for x in jax.tree.leaves(src_cache)
+               if x.shape[0] != self.src_num_pages]
+        if odd:
+            raise ValueError(
+                f"PageTransfer moves pages only, and this cache has leaves "
+                f"that lead with something else than its {self.src_num_pages}"
+                f" pages ({odd[:3]}): state a slot keeps (a window's ring, "
+                f"a recurrent layer's state) needs a state transfer, which "
+                f"does not exist")
         n = len(src_ids)
         if n == 0:
             return dst_cache, 0

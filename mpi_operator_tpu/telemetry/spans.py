@@ -33,6 +33,8 @@ The trees the program records:
   a serving tick (serve/engine.py `tick()`; one `serve.tick` a worked tick)
     serve.schedule     timeouts, admission
     serve.prefill      one prefill call's dispatch, arrays built
+                       state_rows (a model that keeps state a slot): the
+                       rows whose chunk started at 0, and so from zeros
     serve.decode_step  one decode dispatch (serve.verify_step when
                        speculating)      prefill_rows, prefill_bucket: the
                        prefill calls queued ahead of it on the device
@@ -46,7 +48,9 @@ The trees the program records:
   `moe_held_picks`, `moe_identity_picks`, `moe_load_max`, summed over the
   layers inside the step, fetched with its tokens under the same
   `serve.sync` and observed on `ServeTelemetry.step_counters`, not set as
-  span attributes.
+  span attributes. What a slot holds beside its pages is a gauge
+  (`slot_state_bytes`), the rows started over a counter
+  (`slot_state_starts`).
 
   on the device (`jax.named_scope`, in the compiled programs; a device
   trace shows a Pallas kernel under its scope's name, and
@@ -59,6 +63,20 @@ The trees the program records:
     moe.route        router logits, picks, weights, the step's counters
     moe.experts      the held experts' part (masked or grouped)
     moe.identity     the identity experts' part
+    ssm.project      a state-space layer's in-, x- and dt-projections
+    ssm.conv         its causal conv over the slot's tail, and the silu
+    ssm.scan         the recurrence over the slot's state (one step, or a
+                     chunk with the state carried in)
+    ssm.out          the gate and the output projection
+    swa.project      a window layer's q, k, v;  swa.cache_write  its row
+                     into the slot's ring;  swa.attend  the window (the
+                     paged kernel with its lower bound, or dense);
+                     swa.out  the pairs' subtraction, norm and projection
+    yoco.project, yoco.cache_write (the one layer that owns the pool),
+    yoco.attend, yoco.out   the same for the layers that read the ONE
+                     pool of keys and values
+    gmu              a gated memory unit
+    mlp              the gated MLP of a phi4flash layer
 
   set-up
     serve.engine_init > serve.cast_params, serve.init_cache

@@ -345,6 +345,15 @@ class ServeTelemetry:
             "tpu_worker_kv_pages_cached",
             "idle prefix-cache pages retained for future lookups",
             labels=labels)
+        self.slot_state_bytes = reg.gauge(
+            "tpu_worker_slot_state_bytes",
+            "bytes one slot holds beside its pages, whatever its context "
+            "(a window layer's ring, a recurrent layer's state); 0 for a "
+            "model whose cache is pages alone", labels=labels)
+        self.slot_state_starts = reg.counter(
+            "tpu_worker_slot_state_starts_total",
+            "rows whose slot state started over from zeros (a prefill "
+            "chunk at position 0)", labels=labels)
         self.prefix_hit_pages = reg.counter(
             "tpu_worker_prefix_hit_pages_total",
             "prompt pages served from the prefix cache at admission",

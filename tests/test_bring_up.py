@@ -327,6 +327,22 @@ def test_kernel_parity_harness_runs_the_latent_decode_kernel_when_asked():
     assert MLA_CASE["max_len"] % MLA_CASE["page_size"] == 0
 
 
+def test_kernel_parity_harness_runs_the_windowed_kernel_and_the_scan():
+    from mpi_operator_tpu.examples.kernel_parity import (scan_case,
+                                                         window_decode_cases)
+
+    records = window_decode_cases(slots=3, heads=4, kv_heads=2, head_dim=16,
+                                  page_size=8, window=24, table=6)
+    assert [r["kernel"] for r in records] == [
+        "paged_decode_attention_window_ring",
+        "paged_decode_attention_window_table",
+        "paged_decode_attention_pairs"]
+    assert all(r["traced"].startswith("pallas_paged[hb=") for r in records)
+    assert all(r["max_rel_err"] <= 2e-2 for r in records), records
+    rec = scan_case(rows=2, chunk=6, channels=16, states=4)
+    assert rec["max_rel_err"] <= 1e-5
+
+
 # -- kernels on a multi-device mesh -------------------------------------------
 # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
 # automatically partitioned", first seen on the four-chip host). jax.export

@@ -323,3 +323,72 @@ def test_gpt2_xl_prefill_bucket_keeps_its_pool_in_place(
     assert m.alias_size_in_bytes >= dims.layers * XL["pages"] * XL["page"] \
         * 3200 * 2
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+
+
+# ---------------------------------------------------------------------------
+# phi4-mini-flash: a window layer's ring a slot, read through the paged
+# kernel's lower bound, and the ONE pool of keys and values
+# ---------------------------------------------------------------------------
+PHI = dict(slots=64, page=64, window=512, heads=40, pairs=10, pair_dim=128,
+           pages=10752, max_len=16384)
+
+
+def test_a_window_layers_ring_is_written_and_read_where_it_lies(
+        one_chip, quiet_cache):
+    """One window layer's step at Phi-4-mini-flash's widths: the ring
+    [64, 576, 2560] (9 pages of 64 a slot; rows of 10 x 256 columns, 20
+    lane tiles) takes the step's rows by one flat scatter and is read as
+    [64 * 9, 64, 2560] pages by `paged_decode_attention(window=512)`.
+    Mosaic takes the kernel with its lower bound; the reshape is no copy,
+    so the donated ring is aliased through the step."""
+    from mpi_operator_tpu.ops.attention import (kv_row_width,
+                                                paged_decode_attention)
+    S, ps, W, H, KV, D = (PHI[k] for k in ("slots", "page", "window", "heads",
+                                           "pairs", "pair_dim"))
+    nr = W // ps + 1
+    R, width = nr * ps, kv_row_width(KV, D)
+    assert (R, width) == (576, 2560)
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+
+    def layer(q, ring, cur, rows):
+        at = jnp.arange(S) * R + cur % R
+        ring = ring.reshape(S * R, width).at[at].set(
+            rows, mode="drop").reshape(S, R, width)
+        first = jnp.maximum(cur - W + 1, 0) // ps
+        table = (jnp.arange(S)[:, None] * nr
+                 + (first[:, None] + jnp.arange(nr)[None]) % nr)
+        return ring, paged_decode_attention(
+            q, ring.reshape(S * nr, ps, width), cur - first * ps, table,
+            interpret=False, window=W, sm_scale=0.125)
+    compiled = jax.jit(layer, donate_argnums=(1,)).lower(
+        spec((S, H, D), jnp.bfloat16), spec((S, R, width), jnp.bfloat16),
+        spec((S,), jnp.int32), spec((S, width), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (S, R, width), (S * R, width),
+                      (S * nr, ps, width)) == []
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= S * R * width * 2
+    assert m.temp_size_in_bytes < 64 << 20
+
+
+def test_the_once_cached_pool_is_read_by_a_table_of_256_pages(
+        one_chip, quiet_cache):
+    """The eight reads of layer 17's keys and values: 40 query heads over
+    10 pairs of 128 against [10752, 64, 2560], a table of 256 pages a
+    row, all ten pairs in one grid step."""
+    from mpi_operator_tpu.ops.attention import (decode_head_block,
+                                                paged_decode_attention)
+    S, ps, H, KV, D, NP, L = (PHI[k] for k in (
+        "slots", "page", "heads", "pairs", "pair_dim", "pages", "max_len"))
+    assert decode_head_block(KV, ps, D, jnp.bfloat16, 4 << 20, True) == KV
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+    compiled = jax.jit(lambda q, pool, cur, pt: paged_decode_attention(
+        q, pool, cur, pt, interpret=False, sm_scale=0.125)).lower(
+        spec((S, H, D), jnp.bfloat16), spec((NP, ps, KV * 2 * D),
+                                            jnp.bfloat16),
+        spec((S,), jnp.int32), spec((S, L // ps), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
