@@ -213,8 +213,9 @@ def check_trainer(head, device) -> dict:
 
 def check_server(head, device) -> dict:
     _same_device(head, device)
-    # the kernel's name carries the kv heads a grid step took
-    _require(str(head.get("decode_impl")).startswith("pallas_paged[hb="),
+    # the kernel's name carries its form (a walk of live pages), the pages
+    # a turn and the kv heads a grid step took
+    _require(str(head.get("decode_impl")).startswith("pallas_paged[live,"),
              f"decode step traced {head.get('decode_impl')!r}, not the "
              f"paged Pallas kernel")
     _require(head.get("serving_requests_complete") is True,

@@ -241,11 +241,13 @@ def decode_case(int8: bool, slots: int, max_len: int, page_size: int,
     with record_traced() as traced:
         _assert_mosaic(step(kernel), params, filled["cache"])
         got = step(kernel)(params, filled["cache"])
-    # the kernel names itself with the kv heads a grid step took
+    # the kernel names itself: the walk of live pages with its pages a
+    # turn, or (an int8 pool) the grid form; and the kv heads a grid step
     name = traced_name(traced["decode"]) or ""
-    if not name.startswith("pallas_paged[hb=") or "+" in name:
+    form = "pallas_paged[hb=" if int8 else "pallas_paged[live,pages="
+    if not name.startswith(form) or "+" in name:
         raise AssertionError(f"decode step traced {traced['decode']}, "
-                             f"expected one pallas_paged[hb=N]")
+                             f"expected one {form}N..]")
     ref = step(dense)(params, filled["cache"])
     return {"kernel": "paged_decode_attention" + ("_int8" if int8 else ""),
             "traced": name,

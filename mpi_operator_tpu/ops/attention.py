@@ -73,8 +73,11 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
     Yields a dict the dispatch sites fill at TRACE time:
       "attention" — full-sequence attention: "flash" | "dense" | "ring"
       "decode"    — single-token KV-cache steps: "pallas[hb=N]" |
-                    "pallas_paged[hb=N]" (N: kv heads a grid step of
-                    the kernel covers, `decode_head_block`) |
+                    "pallas_paged[live,pages=N,hb=M]" (the paged kernel
+                    that walks a row's live pages, N a turn, M kv heads
+                    a grid step) | "pallas_paged[hb=N]" (an int8 pool:
+                    the grid form; N: kv heads a grid step of the kernel
+                    covers, `decode_head_block`) |
                     "pallas_mla_paged[live,pages=N]" (the latent kernel
                     that walks a row's live pages, N a turn) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
@@ -710,9 +713,10 @@ def _decode_kernel(cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
 
 
 #: VMEM the double-buffered K and V blocks of one decode grid step may
-#: take. Mosaic's scoped limit on a v5e is 16 MiB; a quarter of it for
-#: the streamed blocks leaves the rest to the step's own temporaries
-#: (dequantized blocks, scores), which are of the same order.
+#: take, or the walking paged kernel's two slots of pages. Mosaic's
+#: scoped limit on a v5e is 16 MiB; a quarter of it for the streamed
+#: blocks leaves the rest to the step's own temporaries (dequantized
+#: blocks, a turn's stacked heads, scores), which are of the same order.
 _KV_VMEM_BUDGET = 4 * 1024 * 1024
 
 
@@ -749,7 +753,13 @@ def decode_head_block(kv_heads: int, block_k: int, head_dim: int,
 
 def _decode_call(name, q4, k, v, k_scale, v_scale, prefetch, nk, kv_index,
                  block_k, interpret, sm_scale=None, window=None):
-    """The pallas_call both decode kernels share. q4 is [B, KV, G, D];
+    """The pallas_call of the decode kernels that are a grid over k
+    blocks: the contiguous cache's (`decode_attention`) and the int8 page
+    pool's (`_paged_grid_call`; an unquantised pool is walked,
+    `_paged_walk_call`, and shares nothing with this). The `v is None`
+    branches here and in `_decode_kernel` live for that int8 pool alone,
+    until its scale planes have a walked form (ROADMAP queue 3, item
+    12f). q4 is [B, KV, G, D];
     `k`/`v` (and the int8 scales, given a trailing unit dim here) are
     blocked (1, hb, block_k, ·) at `kv_index(b, h, ki, *prefetch_refs)` —
     the contiguous cache and the page pool differ in that index map, in
@@ -917,6 +927,226 @@ def pack_kv_rows(k, v):
     return jnp.concatenate([k, v], -1).reshape(k.shape[:-2] + (-1,))
 
 
+def paged_pages_per_turn(nblk: int, page_bytes: int, ps: int,
+                         window: Optional[int] = None) -> int:
+    """Pages one turn of the walking paged kernel takes; its two slots
+    of that many pages, one attended while the other fills, share
+    `_KV_VMEM_BUDGET` (the grid form's two buffers of a block do). From
+    half of what fits up to all of it, the count that leaves least of
+    the last turn empty when a row has live all it can — the table's
+    `nblk` pages, or the pages a `window` touches — and the larger on a
+    tie: a turn's pages past the row's last live one are fetched again
+    from that page, so a ring of 9 pages walks 3 + 3 + 3 (5 + 5 cost 8%
+    more) and gpt2-xl's table of 16 walks 4 a turn (8 a turn cost a row
+    of 1-4 pages 70% more; PERF.md, PR 32). Wide turns pay the turn's own
+    work (a fifth of a microsecond) once for several pages; from 4 a turn
+    the copies are what a page costs, at nine tenths of the HBM peak. A
+    function of shapes and dtype alone."""
+    live = nblk if window is None else min(nblk, (window + ps - 2) // ps + 1)
+    cap = max(1, min(live, _KV_VMEM_BUDGET // (2 * page_bytes)))
+    return min(range(-(-cap // 2), cap + 1), key=lambda n: (-live % n, -n))
+
+
+def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
+                       sem_ref, slot_ref, acc_ref, m_ref, l_ref, *,
+                       sm_scale, ps, nblk, pages, hb, k_lanes, window):
+    """One decode step for one (row, block of `hb` kv heads) over the
+    per-head page pool (`kv_row_width`): grid (B, KV // hb), in order.
+    The form of `_mla_decode_kernel`: the pool stays in HBM; the row's
+    LIVE pages — logical pages first .. last, last = min(cursor // ps,
+    nblk - 1), first = 0 or with `window` the page that holds cursor -
+    window + 1, and no others — are walked `pages` at a time, each turn's
+    pages (their `hb` heads' columns) copied into one of two VMEM slots
+    while the other slot's are attended. A grid step's last turn starts
+    the next grid step's first copies, so only the call's first step
+    waits for a page; `slot_ref` carries which slot they went to.
+
+    A turn: scores [hb, G, pages * ps] of every query head of a group
+    against the turn's rows, an online softmax across turns, statistics
+    and accumulator float32 a head, the products in the pool's type
+    summed in float32 — `_decode_kernel`'s mathematics. `k_lanes` (a
+    head's K and V halves are each whole 128-lane tiles) contracts the
+    scores over the K lanes and p . V over the V lanes alone: no padded
+    query and half the MXU work of the other form (7% of a call at one
+    page a turn, nothing measurable from 4, where the copies bound it;
+    PERF.md, PR 32), in which K and V share a lane tile, the query comes
+    padded with zeros over the V lanes and the output is read from them
+    (splitting a lane tile is a relayout: 18% slower, PERF.md PR 28).
+
+    A turn's pages past `last` are copied from that page again and
+    masked: nothing a dead table entry points at is read. A cursor past
+    the logical cache (a retiring row's post-EOS step) attends the whole
+    table (inside its `window`, if any), as the grid form's clamp does.
+
+    A turn's copies are started, and waited for, in a loop over its
+    pages and not one by one in Python (`_mla_decode_kernel`)."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    nb, nh = pl.num_programs(0), pl.num_programs(1)
+    width = pages * ps
+    cols = buf_ref.shape[-1]                    # hb * 2D
+
+    def span(row):
+        """The row's first and last live logical page."""
+        last = jnp.minimum(cur_ref[row] // ps, nblk - 1)
+        if window is None:
+            return 0, last
+        return jnp.minimum(
+            jnp.maximum(cur_ref[row] - window + 1, 0) // ps, last), last
+
+    def copy(page, head_block, slot, k):
+        src = pool_ref.at[page]
+        if cols != pool_ref.shape[-1]:
+            src = src.at[:, pl.ds(pl.multiple_of(head_block * cols, LANES),
+                                  cols)]
+        return pltpu.make_async_copy(src, buf_ref.at[slot, k],
+                                     sem_ref.at[slot])
+
+    def start(row, head_block, turn, slot):
+        first, last = span(row)
+
+        def one(k, _):
+            copy(pt_ref[row, jnp.minimum(first + turn * pages + k, last)],
+                 head_block, slot, k).start()
+        jax.lax.fori_loop(0, pages, one, None)
+
+    def wait(slot):
+        def one(k, _):
+            copy(0, 0, slot, k).wait()  # a wait reads the size, not the page
+        jax.lax.fori_loop(0, pages, one, None)
+
+    first, last = span(b)
+    turns = (last - first) // pages + 1
+    cur_raw = cur_ref[b]
+    cur = jnp.minimum(cur_raw, nblk * ps - 1)
+    step = b * nh + h
+    nxt = jnp.minimum(step + 1, nb * nh - 1)
+
+    @pl.when(step == 0)
+    def _first_step():
+        slot_ref[0] = 0
+        start(b, h, 0, 0)
+
+    first_slot = slot_ref[0]
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    def turn(i, _):
+        slot = (first_slot + i) % 2
+        more = i + 1 < turns
+
+        @pl.when(more | (step + 1 < nb * nh))
+        def _next():    # this step's next turn, or the next step's first
+            start(jnp.where(more, b, nxt // nh), jnp.where(more, h, nxt % nh),
+                  jnp.where(more, i + 1, 0), 1 - slot)
+
+        wait(slot)
+        q = q_ref[0]                                      # [hb, G, D or 2D]
+        rows = buf_ref[slot].reshape(width, cols)
+        # the heads' lane-aligned column blocks, stacked: whole vregs
+        # under another index (`_decode_kernel`)
+        if k_lanes:
+            halves = jnp.split(rows, 2 * hb, axis=1)
+            k, v = jnp.stack(halves[0::2]), jnp.stack(halves[1::2])
+        else:
+            k = v = jnp.stack(jnp.split(rows, hb, axis=1))
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale  # [hb, G, width]
+        pos = (first + i * pages) * ps \
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        seen = pos <= cur
+        if window is not None:
+            seen &= pos > cur_raw - window
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:, :, :1] = l_ref[:, :, :1] * alpha + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_ref[:, :, :1] = m_new
+
+    jax.lax.fori_loop(0, turns, turn, None)
+    slot_ref[0] = (first_slot + turns) % 2
+    o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def _paged_walk_call(q4, pool, cur, pt, sm_scale, window, interpret,
+                     pages: Optional[int] = None,
+                     k_lanes: Optional[bool] = None):
+    """The walking kernel over q4 [B, KV, G, D] and the pool [NP, ps,
+    KV * 2D], cursors [B] and page table [B, nblk]: settles the sizes and
+    reports `pallas_paged[live,pages=N,hb=M]` as the traced decode
+    implementation — the form, the pages a turn and the kv heads a grid
+    step took. `pages` None is `paged_pages_per_turn` and `k_lanes` None
+    the form the head size gives (the microbenchmark passes its own, to
+    price each)."""
+    KV, D = q4.shape[1], q4.shape[3]
+    ps = pool.shape[1]
+    # heads a grid step: all of them where one page, two slots deep, fits
+    hb = decode_head_block(KV, ps, D, pool.dtype, _KV_VMEM_BUDGET, paged=True)
+    if pages is None:
+        pages = paged_pages_per_turn(
+            pt.shape[1], ps * hb * 2 * D * pool.dtype.itemsize, ps, window)
+    if k_lanes is None:
+        k_lanes = D % LANES == 0
+    note_traced("decode", f"pallas_paged[live,pages={pages},hb={hb}]")
+    return _paged_walk(q4, pool, cur, pt, sm_scale or 1.0 / (D ** 0.5),
+                       window, interpret, pages, hb, k_lanes)
+
+
+# jitted, and inlined where it is called: a model's layers call the kernel
+# with the same shapes, and the kernel's body is traced once a program and
+# not once a layer (48 times for gpt2-xl). A body traced a layer cost the
+# benchmark's process 0.3 s a call, +10 s of Phi-4-mini-flash's set-up
+# (PERF.md, PR 32). Inlined, the call keeps the caller's named scope in
+# its instruction's name (`yoco.attend.3`), which the trace readers match.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9), inline=True)
+def _paged_walk(q4, pool, cur, pt, sm_scale, window, interpret, pages, hb,
+                k_lanes):
+    """`_paged_walk_kernel`'s pallas_call: cursors and page table as
+    scalar prefetch, the pool an HBM operand, two slots of `pages` pages
+    of `hb` heads' columns."""
+    B, KV, G, D = q4.shape
+    ps, nblk = pool.shape[1], pt.shape[1]
+    if not k_lanes:
+        q4 = jnp.concatenate([q4, jnp.zeros_like(q4)], -1)
+    Wq = q4.shape[-1]
+
+    qo_spec = pl.BlockSpec((1, hb, G, Wq), lambda b, h, *pre: (b, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, KV // hb),
+        in_specs=[qo_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=qo_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, ps, hb * 2 * D), pool.dtype),  # in flight
+            pltpu.SemaphoreType.DMA((2,)),                # one a slot
+            pltpu.SMEM((1,), jnp.int32),            # the next step's slot
+            pltpu.VMEM((hb, G, Wq), jnp.float32),     # acc
+            pltpu.VMEM((hb, G, LANES), jnp.float32),  # running max m
+            pltpu.VMEM((hb, G, LANES), jnp.float32),  # running sum l
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_walk_kernel, sm_scale=sm_scale, ps=ps,
+                          nblk=nblk, pages=pages, hb=hb, k_lanes=k_lanes,
+                          window=window),
+        grid_spec=grid_spec,
+        out_shape=_out_struct((B, KV, G, Wq), q4.dtype, q4, pool),
+        # steps in order: a step's last turn fetches for the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(cur, pt, q4, pool)
+    return out[..., Wq - D:].reshape(B, KV * G, D)
+
+
 def paged_decode_attention(q, pages, cache_index, page_table,
                            k_scale=None, v_scale=None,
                            interpret: Optional[bool] = None,
@@ -940,28 +1170,27 @@ def paged_decode_attention(q, pages, cache_index, page_table,
     k_scale/v_scale [NP, KV, ps] f32  int8 per-(page-slot, head) scales
     window       static: row b attends cursor(b) - window < p <= cursor(b)
                  and pages wholly behind that are neither fetched nor
-                 scored (they still cost a grid step, as dead pages past
-                 the cursor do). None: no lower bound, and the program
-                 lowered is the one without this argument
+                 scored. None: no lower bound, and the program lowered is
+                 the one without this argument
     sm_scale     static: the scores' scale; None is 1 / sqrt(D)
 
-    The kernel body is the contiguous one — block_k equals the page size
-    and logical block ki covers positions [ki*ps, ki*ps+ps), so the
-    cursor skip/mask arithmetic carries over unchanged. The index map
-    differs: the second scalar-prefetch operand (the page table)
-    resolves which PHYSICAL page streams for logical block ki, with
-    past-the-cursor blocks pinned to the boundary block's page so the
-    pipeline re-reads a resident page instead of streaming dead pool.
-    And the block: a head's keys and values are ONE lane-aligned column
-    block of the page's rows (`_decode_kernel`).
+    An unquantised pool is WALKED (`_paged_walk_kernel`): grid
+    (B, KV // hb), the pool an HBM operand, a row's live pages copied
+    `paged_pages_per_turn` a turn into two VMEM slots. Nothing is spent
+    on the table's dead entries, so a call's time follows the contexts
+    and not the table's length, and a turn's work is paid once for
+    several pages: the grid form's step a (row, page) cost 0.105 us dead
+    or live and a live page 0.61 us more, of which the copy is 0.40
+    (PERF.md, PR 32).
 
-    Grid (B, KV // hb, nblk): one step takes the columns of `hb` kv
-    heads of one page (`decode_head_block`; the whole rows when they fit
-    VMEM, as gpt2-xl's 25 heads do) — one contiguous read of ps*hb*2D
-    elements for K and V together. A step's fixed cost, not
-    its bytes, was the whole of this kernel with one head a step (0.22 us
-    x 25 600 steps a layer for gpt2-xl; ledger, PR 24), dead pages past a
-    row's cursor included: they move nothing and still cost a step.
+    An int8 pool (`k_scale` given) stays on the grid form, the
+    contiguous kernel's body with another index map (`_decode_call`,
+    `_decode_kernel` with `v_ref` None): grid (B, KV // hb, nblk), one
+    step the columns of `hb` kv heads of one page, the page table
+    resolving which PHYSICAL page streams for logical block ki, blocks
+    past the cursor (or behind the window) pinned to the boundary
+    block's page. No cell runs it; its scale planes have no walked form
+    yet (ROADMAP queue 3, item 12f).
     """
     B, H, D = q.shape
     NP, ps, W = pages.shape
@@ -992,6 +1221,22 @@ def paged_decode_attention(q, pages, cache_index, page_table,
             mesh, B, KV, (q, pages, cur, pt, k_scale, v_scale),
             (out, pool, ("rows",), ("rows", None), scale, scale), out)
 
+    q4 = q.reshape(B, KV, H // KV, D)
+    if k_scale is None:
+        return _paged_walk_call(q4, pages, cur, pt, sm_scale, window,
+                                interpret)
+    return _paged_grid_call(q4, pages, cur, pt, k_scale, v_scale, sm_scale,
+                            window, interpret)
+
+
+def _paged_grid_call(q4, pages, cur, pt, k_scale, v_scale, sm_scale, window,
+                     interpret):
+    """The grid form over the page pool: `_decode_call` with the page
+    table's index map. The int8 pool's path; the microbenchmark
+    (`scripts/paged_decode_microbench.py`) also times it unquantised
+    beside the walk."""
+    ps, nblk = pages.shape[1], pt.shape[1]
+
     def kv_index(b, h, ki, cur_ref, pt_ref):
         # physical page for logical block ki, clamped to the row's
         # boundary block (blocks past the cursor re-use its page — the
@@ -1004,9 +1249,9 @@ def paged_decode_attention(q, pages, cache_index, page_table,
                 jnp.maximum(cur_ref[b] - window + 1, 0) // ps, last))
         return (pt_ref[b, ki], h, 0, 0)
 
-    return _decode_call("pallas_paged", q.reshape(B, KV, H // KV, D),
-                        pages, None, k_scale, v_scale, (cur, pt), nblk,
-                        kv_index, ps, interpret, sm_scale, window)
+    return _decode_call("pallas_paged", q4, pages, None, k_scale, v_scale,
+                        (cur, pt), nblk, kv_index, ps, interpret, sm_scale,
+                        window)
 
 
 # ---------------------------------------------------------------------------
@@ -1365,7 +1610,8 @@ def paged_attend(q, pool, positions, page_table,
 
 
 __all__ = ["flash_attention", "decode_attention", "decode_block_k",
-           "decode_head_block", "paged_decode_attention", "kv_row_width",
+           "decode_head_block", "paged_decode_attention",
+           "paged_pages_per_turn", "kv_row_width",
            "pack_kv_rows", "paged_attend",
            "mla_paged_attend", "mla_paged_decode_attention",
            "mla_pages_per_turn", "mla_row_width", "einsum_f32",

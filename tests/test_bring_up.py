@@ -337,7 +337,8 @@ def test_kernel_parity_harness_runs_the_windowed_kernel_and_the_scan():
         "paged_decode_attention_window_ring",
         "paged_decode_attention_window_table",
         "paged_decode_attention_pairs"]
-    assert all(r["traced"].startswith("pallas_paged[hb=") for r in records)
+    assert all(r["traced"].startswith("pallas_paged[live,pages=")
+               for r in records)
     assert all(r["max_rel_err"] <= 2e-2 for r in records), records
     rec = scan_case(rows=2, chunk=6, channels=16, states=4)
     assert rec["max_rel_err"] <= 1e-5

@@ -235,7 +235,9 @@ def test_decode_head_block_is_a_function_of_shapes_and_dtype(shape, budget,
 def test_traced_decode_names_the_head_block(monkeypatch):
     """What a run's headline prints (`traced_name` of `record_traced`'s
     "decode") says which kernel ran and how many kv heads a grid step
-    took, also when the budget made it fall back to one."""
+    took, also when the budget made it fall back to one; the paged
+    kernel over an unquantised pool says that it walks live pages, and
+    how many a turn."""
     B, H, KV, L, D, cur = 2, 6, 3, 32, 64, 20
     q, k, v, _, _ = _cache(B, H, KV, L, D, cur)
     pool = jnp.zeros((5, 16, KV * 2 * D), jnp.float32)
@@ -245,11 +247,32 @@ def test_traced_decode_names_the_head_block(monkeypatch):
         decode_attention(q, k, v, cur, block_k=16, interpret=True)
         paged_decode_attention(q, pool, curs, table, interpret=True)
     assert traced_name(traced["decode"]) == \
-        "pallas[hb=3]+pallas_paged[hb=3]"
+        "pallas[hb=3]+pallas_paged[live,pages=2,hb=3]"
     monkeypatch.setattr(attention, "_KV_VMEM_BUDGET", 0)
     with record_traced() as traced:
         paged_decode_attention(q, pool, curs, table, interpret=True)
+    assert traced_name(traced["decode"]) == "pallas_paged[live,pages=1,hb=1]"
+    # an int8 pool stays on the grid form
+    scale = jnp.ones((5, KV, 16), jnp.float32)
+    with record_traced() as traced:
+        paged_decode_attention(q, pool.astype(jnp.int8), curs, table,
+                               k_scale=scale, v_scale=scale, interpret=True)
     assert traced_name(traced["decode"]) == "pallas_paged[hb=1]"
+
+
+@pytest.mark.parametrize("nblk,page_bytes,window,pages", [
+    (16, 64 * 3200 * 2, None, 4),     # gpt2-xl: 410 KB pages, a table of 16
+    (256, 64 * 2560 * 2, None, 4),    # Phi-4-mini-flash's pool: 327 KB pages
+    (9, 64 * 2560 * 2, 512, 3),       # its ring: 9 pages walk 3 + 3 + 3
+    (256, 64 * 2560 * 2, 512, 3),     # a window over a long table: the same
+    (17, 64 * 3200 * 2, None, 3),     # a prime table: 3 x 6, not 5 x 4
+    (4, 1 << 30, None, 1),            # a page over the budget: one a turn
+    (3, 1024, None, 3),               # a short table: all of it
+])
+def test_pages_a_turn_are_a_function_of_shapes_and_dtype(nblk, page_bytes,
+                                                         window, pages):
+    from mpi_operator_tpu.ops.attention import paged_pages_per_turn
+    assert paged_pages_per_turn(nblk, page_bytes, 64, window) == pages
 
 
 def _e2e(cfg, new_tokens=8, seed=1):

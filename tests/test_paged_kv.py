@@ -27,7 +27,7 @@ from mpi_operator_tpu.models import CausalLM, gpt2_config
 from mpi_operator_tpu.ops import attention
 from mpi_operator_tpu.ops.attention import (pack_kv_rows,
                                             paged_decode_attention,
-                                            record_traced)
+                                            record_traced, traced_name)
 from mpi_operator_tpu.serve import (
     EngineConfig, PageAllocator, Request, Scheduler, ServingEngine,
     plan_chunks,
@@ -343,7 +343,10 @@ def test_paged_kernel_matches_dense(H, KV, D, quantized):
     """Per-row cursors at block starts/interiors/ends; every kv head of a
     page in one grid step (the default budget holds them all)."""
     traced = _paged_vs_dense(H, KV, D, quantized, [0, 17, 31, 63])
-    assert traced == {f"pallas_paged[hb={KV}]"}
+    # an int8 pool stays on the grid form; an unquantised one is walked,
+    # the table's four pages in one turn
+    assert traced == {f"pallas_paged[hb={KV}]" if quantized
+                      else f"pallas_paged[live,pages=4,hb={KV}]"}
 
 
 @pytest.mark.parametrize("fit,hb", [(6, 6), (4, 3), (1, 1), (0, 1)])
@@ -362,7 +365,10 @@ def test_paged_kernel_head_blocks(monkeypatch, fit, hb, quantized):
     monkeypatch.setattr(attention, "_KV_VMEM_BUDGET", fit * per_head)
     traced = _paged_vs_dense(12, 6, D, quantized, [0, ps - 1, 2 * ps + 3, 63],
                              shared=(1, 2), ps=ps)
-    assert traced == {f"pallas_paged[hb={hb}]"}
+    # the walk under the same budget: one page a turn, the head block's
+    # columns of it copied by themselves
+    assert traced == {f"pallas_paged[hb={hb}]" if quantized
+                      else f"pallas_paged[live,pages=1,hb={hb}]"}
 
 
 def test_paged_kernel_shared_pages_between_rows():
@@ -387,6 +393,133 @@ def test_paged_kernel_shared_pages_between_rows():
                                  jnp.asarray(curs), jnp.asarray(pt),
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the walk of a row's live pages (`_paged_walk_kernel`)
+# ---------------------------------------------------------------------------
+
+def _walk_vs_gathered(H, KV, D, cursors, ps=8, nblk=12, window=None,
+                      pages=None, dtype=jnp.float32, atol=2e-5):
+    """The walking kernel against dense attention over the gathered
+    table. A row's live span is logical pages first .. last (`window`
+    moves first); EVERY table entry outside it points at page 0, which
+    holds inf: a page the walk must not fetch — a dead entry, a page
+    behind the window, a turn's pages past `last` — turns the result
+    into nan. `pages` None: the public entry point and its own choice of
+    pages a turn; else that many through `_paged_walk_call`."""
+    cur = np.asarray(cursors, np.int32)
+    B, L = len(cur), ps * nblk
+    last = np.minimum(cur // ps, nblk - 1)
+    first = np.zeros_like(last) if window is None else np.minimum(
+        np.maximum(cur - window + 1, 0) // ps, last)
+    rs = np.random.RandomState(3)
+    blocks = np.arange(nblk)[None]
+    live = (blocks >= first[:, None]) & (blocks <= last[:, None])
+    table = np.where(live, 1 + rs.permutation(B * nblk).reshape(B, nblk), 0)
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    q = jax.random.normal(keys[0], (B, H, D), dtype)
+    finite = jax.random.normal(keys[1], (1 + B * nblk, ps, KV * 2 * D),
+                               dtype)
+    pool = finite.at[0].set(jnp.inf)
+    # the reference reads zeros where the kernel must read nothing
+    rows = finite.at[0].set(0)[table].reshape(B, L, KV, 2, D).astype(
+        jnp.float32)
+    k = jnp.repeat(rows[:, :, :, 0], H // KV, axis=2)
+    v = jnp.repeat(rows[:, :, :, 1], H // KV, axis=2)
+    s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32), k) / D ** 0.5
+    p = np.arange(L)[None, None]
+    seen = p <= np.minimum(cur, L - 1)[:, None, None]
+    if window is not None:
+        seen &= p > (cur - window)[:, None, None]
+    want = jnp.einsum("bht,bthd->bhd",
+                      jax.nn.softmax(jnp.where(seen, s, -1e30), -1), v)
+    args = (pool, jnp.asarray(cur), jnp.asarray(table, jnp.int32))
+    with record_traced() as traced:
+        if pages is None:
+            got = paged_decode_attention(q, *args, window=window,
+                                         interpret=True)
+        else:
+            got = attention._paged_walk_call(
+                q.reshape(B, KV, H // KV, D), *args, None, window, True,
+                pages=pages)
+    np.testing.assert_allclose(np.asarray(want),
+                               np.asarray(got, np.float32), atol=atol)
+    return traced_name(traced["decode"])
+
+
+# pages of 8, a table of 12, 4 pages a turn: the turns' edges lie at
+# positions 32 and 64
+_WALK_CURSORS = {
+    "first-page": (0, 7, 8),
+    "a-turns-edge": (23, 24, 31, 32, 39, 40),    # the edge -1, 0, +1 page
+    "the-second-edge": (63, 64, 71, 72),
+    "table-end-and-past": (94, 95, 96, 101, 200),
+    # rows of one call at unrelated depths: a row's last turn fetches the
+    # first pages of a row that is nowhere near it
+    "unrelated-depths": (95, 0, 40, 8, 77, 3),
+}
+
+
+@pytest.mark.parametrize("cursors", list(_WALK_CURSORS.values()),
+                         ids=list(_WALK_CURSORS))
+@pytest.mark.parametrize("H,KV,D", [
+    (5, 5, 64),       # gpt2-xl's form: G 1, K and V halves of a lane tile
+    (8, 2, 128),      # Phi-4-mini-flash's pairs: G 4, scores on the K lanes
+    (6, 3, 64),       # GQA on the padded-query form
+    (4, 2, 256),      # GQA, a head's K two lane tiles
+], ids=["mha64", "pairs128", "gqa64", "gqa256"])
+def test_walk_matches_dense_and_reads_nothing_dead(H, KV, D, cursors):
+    name = _walk_vs_gathered(H, KV, D, cursors, pages=4)
+    assert name == f"pallas_paged[live,pages=4,hb={KV}]"
+
+
+@pytest.mark.parametrize("pages", [1, 2, 5, 12, 16])
+@pytest.mark.parametrize("H,KV,D", [(5, 5, 64), (8, 2, 128)],
+                         ids=["mha64", "pairs128"])
+def test_walk_at_any_pages_a_turn(H, KV, D, pages):
+    """One page a turn, a count that does not divide the table, the whole
+    table and more than the table: the result does not change."""
+    _walk_vs_gathered(H, KV, D, (0, 95, 33, 64, 300, 17), pages=pages)
+
+
+@pytest.mark.parametrize("cursors", [
+    (0, 5, 18),          # contexts shorter than the window
+    (19, 20, 21),        # the window's own edge: position 0 leaves at 20
+    (27, 28, 35, 36),    # a window that starts mid-page, and on a page's edge
+    (95, 96, 110, 112),  # the table's end and past it, the window still on it
+    (95, 2, 50, 21),     # unrelated depths
+], ids=["short", "window-edge", "mid-page", "past-table", "unrelated"])
+@pytest.mark.parametrize("H,KV,D,pages", [
+    (5, 5, 64, None), (8, 2, 128, None), (8, 2, 128, 1), (6, 3, 64, 2)],
+    ids=["mha64", "pairs128", "pairs128-1", "gqa64-2"])
+def test_walk_under_a_window_starts_at_the_windows_page(H, KV, D, pages,
+                                                         cursors):
+    """window 20 over pages of 8: at most four pages are live, the pages
+    behind them point at the inf page like the dead ones past the
+    cursor."""
+    name = _walk_vs_gathered(H, KV, D, cursors, window=20, pages=pages)
+    if pages is None:       # the window's four pages, not the table's 12
+        assert name == f"pallas_paged[live,pages=4,hb={KV}]"
+
+
+def test_walk_in_bfloat16_sums_in_float32():
+    _walk_vs_gathered(8, 2, 128, (95, 0, 40, 8), dtype=jnp.bfloat16,
+                      atol=3e-2)
+    _walk_vs_gathered(5, 5, 64, (95, 0, 40, 8), dtype=jnp.bfloat16,
+                      atol=3e-2)
+
+
+@pytest.mark.parametrize("fit,hb", [(2, 2), (1, 1)])
+def test_walk_by_head_blocks_of_whole_lane_tiles(monkeypatch, fit, hb):
+    """A budget that holds `fit` of 4 pairs of 128 (G = 2): the grid runs
+    4 // hb head blocks a row, each copying its own columns of a page,
+    and a row's last block fetches the next row's first."""
+    ps, D = 8, 128
+    monkeypatch.setattr(attention, "_KV_VMEM_BUDGET",
+                        fit * 2 * ps * 2 * D * 4)
+    name = _walk_vs_gathered(8, 4, D, (0, 95, 33, 64, 300), ps=ps)
+    assert name == f"pallas_paged[live,pages=1,hb={hb}]"
 
 
 # ---------------------------------------------------------------------------

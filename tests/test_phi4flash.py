@@ -300,6 +300,59 @@ def test_kernel_window_matches_the_dense_form_around_page_and_window_edges(
     assert float(jnp.abs(again - want).max()) < 1e-5
 
 
+@pytest.mark.parametrize("cursors", [
+    (0, 5, 22),         # contexts shorter than the window: nothing wraps yet
+    (23, 24, 31, 32),   # the window's edge, and the ring's first wrap
+    (100, 77, 250, 39),  # rings that have wrapped many times, unrelated rows
+    (41, 44, 47, 48),   # windows that start mid-page and on a page's edge
+], ids=["short", "first-wrap", "wrapped", "mid-page"])
+@pytest.mark.parametrize("H,KV,D", [(8, 2, 128), (4, 2, 16)],
+                         ids=["pairs128", "gqa16"])
+def test_kernel_walks_a_ring_whose_pages_wrap(H, KV, D, cursors):
+    """A window layer's decode step as `_ring` makes it: the ring
+    [B, 4 pages of 8, width] holds position p at p % 32, the table is the
+    ring's pages counted from the window's first, the cursor counted from
+    that page. Against dense attention over the absolute positions
+    cur - 24 < p <= cur; what the ring holds of older positions, and its
+    rows past the cursor, are inf or huge where the kernel may not look."""
+    ps, nr, window = 8, 4, 24
+    R, B = nr * ps, len(cursors)
+    cur = jnp.asarray(cursors, jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(keys[0], (B, H, D), jnp.float32)
+    T = int(cur.max()) + 1
+    k, v = (jax.random.normal(kk, (B, T, KV, D), jnp.float32)
+            for kk in keys[1:])
+    rows = pack_kv_rows(k, v)                               # [B, T, width]
+    # the ring after position cur was written: slot i holds the newest
+    # position <= cur that is i modulo R, if there is one
+    slots = jnp.arange(R)[None]
+    held = cur[:, None] - (cur[:, None] - slots) % R              # [B, R]
+    ring = jnp.take_along_axis(rows, jnp.maximum(held, 0)[..., None], 1)
+    first = jnp.maximum(cur - window + 1, 0) // ps
+    # a page wholly outside the window holds inf; a row past the cursor
+    # in the cursor's page something huge and finite (it is masked)
+    page_of = held // ps
+    outside = (held < 0) | (page_of < first[:, None])
+    in_cursors_page = slots // ps == (cur // ps % nr)[:, None]
+    ring = jnp.where(outside[..., None],
+                     jnp.where(in_cursors_page[..., None], 1e4, jnp.inf),
+                     ring)
+    table = (jnp.arange(B)[:, None] * nr
+             + (first[:, None] + jnp.arange(nr)[None]) % nr)
+    got = paged_decode_attention(
+        q, ring.reshape(B * nr, ps, -1), cur - first * ps, table,
+        window=window, sm_scale=0.3)
+    kk = jnp.repeat(k, H // KV, axis=2)
+    vv = jnp.repeat(v, H // KV, axis=2)
+    s = jnp.einsum("bhd,bthd->bht", q, kk) * 0.3
+    p = jnp.arange(T)[None, None]
+    seen = (p <= cur[:, None, None]) & (p > cur[:, None, None] - window)
+    want = jnp.einsum("bht,bthd->bhd",
+                      jax.nn.softmax(jnp.where(seen, s, -1e30), -1), vv)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+
+
 def test_without_a_window_the_kernel_lowers_to_the_program_it_was():
     """The lower bound is static: `window=None` (and the default scale)
     traces the body it always did, with no comparison against a bound."""

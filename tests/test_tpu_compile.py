@@ -61,6 +61,28 @@ def _pool_copies(text):
     return _copies_of(text, (PAGES, PAGE, 640), (PAGES * PAGE, 640))
 
 
+#: what Mosaic lets a kernel take of a v5e's VMEM unless it is told
+#: otherwise, and no kernel here tells it otherwise
+SCOPED_VMEM = 16 << 20
+
+
+def _walk_vmem(nblk, ps, KV, D, G, window=None, itemsize=2):
+    """(pages a turn, bytes) of the walking paged kernel's VMEM as its
+    shapes give them: two slots of `pages` pages; a turn's temporaries,
+    counted as if nothing were reused — the heads' column blocks stacked
+    (one slot again), float32 scores and probabilities [KV, G, pages *
+    ps] with G padded to 8 sublanes, the probabilities once more in the
+    pool's type; accumulator and statistics. That Mosaic compiles the
+    call under its scoped limit is the proof; this is the number."""
+    from mpi_operator_tpu.ops.attention import paged_pages_per_turn
+    page = ps * KV * 2 * D * itemsize
+    pages = paged_pages_per_turn(nblk, page, ps, window)
+    sub = -(-G // 8) * 8
+    scores = KV * sub * pages * ps * 4
+    acc = KV * sub * (2 * D if D % 128 else D) * 4 + 2 * KV * sub * 128 * 4
+    return pages, 3 * pages * page + 2 * scores + scores // 2 + acc
+
+
 def test_latent_decode_kernel_and_its_cache_write_leave_the_pool_in_place(
         one_chip, quiet_cache):
     """LongCat-Flash's widths: 64 heads on rows of 640 (576 padded), 4480
@@ -181,15 +203,20 @@ def test_per_head_rows_pool_is_written_and_read_where_it_lies(
         one_chip, quiet_cache):
     """One layer's cache write and kernel call at gpt2-xl's widths: rows
     of 25 x (64 + 64) = 3200 columns, 25 whole lane tiles. Mosaic takes
-    the kernel; the flat row scatter, the kernel's block and the resident
-    pool agree on one row-major layout, so the donated pool is aliased
-    and never copied."""
+    the walking kernel (4 pages of 410 KB a turn, two slots, all 25
+    heads a grid step) under its scoped VMEM limit; the flat row
+    scatter, the kernel's page copies and the resident pool agree on one
+    row-major layout, so the donated pool is aliased and never copied."""
     from mpi_operator_tpu.ops.attention import (kv_row_width,
                                                 paged_decode_attention)
     S, NP, ps, KV, D = (XL[k] for k in ("slots", "pages", "page", "heads",
                                         "head_dim"))
     W = kv_row_width(KV, D)
     assert W == 3200 and W % 128 == 0
+    pages, vmem = _walk_vmem(XL["max_len"] // ps, ps, KV, D, 1)
+    assert pages == 4 and vmem < SCOPED_VMEM, (
+        f"{pages} pages a turn take {vmem} bytes of VMEM, slots and a "
+        f"turn's temporaries; Mosaic's scoped limit is {SCOPED_VMEM}")
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
                                                   sharding=one_chip)
 
@@ -339,8 +366,9 @@ def test_a_window_layers_ring_is_written_and_read_where_it_lies(
     [64, 576, 2560] (9 pages of 64 a slot; rows of 10 x 256 columns, 20
     lane tiles) takes the step's rows by one flat scatter and is read as
     [64 * 9, 64, 2560] pages by `paged_decode_attention(window=512)`.
-    Mosaic takes the kernel with its lower bound; the reshape is no copy,
-    so the donated ring is aliased through the step."""
+    Mosaic takes the walking kernel with its lower bound (the window's 9
+    pages in turns of 3) under its scoped VMEM limit; the reshape is no
+    copy, so the donated ring is aliased through the step."""
     from mpi_operator_tpu.ops.attention import (kv_row_width,
                                                 paged_decode_attention)
     S, ps, W, H, KV, D = (PHI[k] for k in ("slots", "page", "window", "heads",
@@ -348,6 +376,10 @@ def test_a_window_layers_ring_is_written_and_read_where_it_lies(
     nr = W // ps + 1
     R, width = nr * ps, kv_row_width(KV, D)
     assert (R, width) == (576, 2560)
+    pages, vmem = _walk_vmem(nr, ps, KV, D, H // KV, window=W)
+    assert pages == 3 and vmem < SCOPED_VMEM, (
+        f"{pages} pages a turn take {vmem} bytes of VMEM, slots and a "
+        f"turn's temporaries; Mosaic's scoped limit is {SCOPED_VMEM}")
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
                                                   sharding=one_chip)
 
@@ -377,12 +409,17 @@ def test_the_once_cached_pool_is_read_by_a_table_of_256_pages(
         one_chip, quiet_cache):
     """The eight reads of layer 17's keys and values: 40 query heads over
     10 pairs of 128 against [10752, 64, 2560], a table of 256 pages a
-    row, all ten pairs in one grid step."""
+    row: the walking kernel, all ten pairs a grid step, 4 pages of 327
+    KB a turn, scores on the K lanes, under Mosaic's scoped VMEM limit."""
     from mpi_operator_tpu.ops.attention import (decode_head_block,
                                                 paged_decode_attention)
     S, ps, H, KV, D, NP, L = (PHI[k] for k in (
         "slots", "page", "heads", "pairs", "pair_dim", "pages", "max_len"))
     assert decode_head_block(KV, ps, D, jnp.bfloat16, 4 << 20, True) == KV
+    pages, vmem = _walk_vmem(L // ps, ps, KV, D, H // KV)
+    assert pages == 4 and vmem < SCOPED_VMEM, (
+        f"{pages} pages a turn take {vmem} bytes of VMEM, slots and a "
+        f"turn's temporaries; Mosaic's scoped limit is {SCOPED_VMEM}")
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
                                                   sharding=one_chip)
     compiled = jax.jit(lambda q, pool, cur, pt: paged_decode_attention(
@@ -392,3 +429,99 @@ def test_the_once_cached_pool_is_read_by_a_table_of_256_pages(
         spec((S,), jnp.int32), spec((S, L // ps), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("H,KV,D,ps,nblk,window", [
+    (32, 8, 128, 64, 128, None),     # GQA, heads of 128: llama-like
+    (32, 8, 128, 128, 64, 4096),     # the same under a window, pages of 128
+    (16, 16, 256, 64, 64, None),     # a head's K two lane tiles
+    (12, 6, 64, 64, 32, None),       # GQA on the padded-query form
+], ids=["gqa128", "gqa128-window", "mha256", "gqa64"])
+def test_walking_kernel_compiles_at_shapes_no_cell_runs(
+        one_chip, quiet_cache, H, KV, D, ps, nblk, window):
+    from mpi_operator_tpu.ops.attention import paged_decode_attention
+    S, NP = 16, 512
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+    pages, vmem = _walk_vmem(nblk, ps, KV, D, H // KV, window)
+    assert vmem < SCOPED_VMEM, (pages, vmem)
+    compiled = jax.jit(lambda q, pool, cur, pt: paged_decode_attention(
+        q, pool, cur, pt, interpret=False, window=window)).lower(
+        spec((S, H, D), jnp.bfloat16),
+        spec((NP, ps, KV * 2 * D), jnp.bfloat16),
+        spec((S,), jnp.int32), spec((S, nblk), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_walking_kernel_compiles_by_head_blocks(one_chip, quiet_cache):
+    """A page too wide for two slots of all its heads (64 kv heads of
+    128, pages of 128: 4 MB): the grid takes head blocks, each copying
+    its own lane-aligned columns of a page."""
+    from mpi_operator_tpu.ops.attention import (paged_decode_attention,
+                                                record_traced, traced_name)
+    S, NP, H, KV, D, ps, nblk = 8, 64, 64, 64, 128, 128, 16
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+    with record_traced() as traced:
+        compiled = jax.jit(lambda q, pool, cur, pt: paged_decode_attention(
+            q, pool, cur, pt, interpret=False)).lower(
+            spec((S, H, D), jnp.bfloat16),
+            spec((NP, ps, KV * 2 * D), jnp.bfloat16),
+            spec((S,), jnp.int32), spec((S, nblk), jnp.int32)).compile()
+    assert traced_name(traced["decode"]) == "pallas_paged[live,pages=1,hb=32]"
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def phi4_flash(one_chip):
+    """The decode model, parameter shapes and cache shapes of
+    `perfbench/configs/phi4-mini-flash.json` as the benchmark's engine
+    builds them (64 slots, 10752 pages of 64, contexts to 16384)."""
+    from mpi_operator_tpu.models.generate import decode_model
+    from perfbench import weights_phi4flash as weights
+    from perfbench.kinds import _serve_phi4flash
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "phi4-mini-flash.json")) as f:
+        dims = weights.Dims.from_config(json.load(f))
+    model = _serve_phi4flash.model_of(dims, jnp.bfloat16, PHI["max_len"],
+                                      True)
+    dmodel = decode_model(model, True, page_size=PHI["page"],
+                          num_pages=PHI["pages"])
+    on_chip = lambda tree: jax.tree.map(                        # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: weights.make_params(jax.random.PRNGKey(0), dims,
+                                    jnp.bfloat16)))
+    z = jnp.zeros((PHI["slots"], 1), jnp.int32)
+    table = jnp.zeros((PHI["slots"], PHI["max_len"] // PHI["page"]),
+                      jnp.int32)
+    cache = on_chip(jax.eval_shape(
+        lambda p: dmodel.apply({"params": p}, z, positions=z,
+                               with_head=False, mutable=["cache"],
+                               pages=table)[1]["cache"], params))
+    return dims, dmodel, params, cache
+
+
+def test_phi4_flash_decode_step_keeps_its_pool_and_rings_in_place(
+        one_chip, quiet_cache, monkeypatch, phi4_flash):
+    """The engine's own `step_paged` over Phi-4-mini-flash: sixteen
+    Mosaic calls (eight walks of the one pool, eight of a ring), no copy
+    of the pool [10752, 64, 2560] or of a ring [64, 576, 2560], both
+    aliased through the step, and everything fits the chip."""
+    dims, dmodel, params, cache = phi4_flash
+    S, ps, NP = PHI["slots"], PHI["page"], PHI["pages"]
+    width = PHI["pairs"] * 2 * PHI["pair_dim"]
+    R = (PHI["window"] // ps + 1) * ps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _lower_greedy_step(
+        _engine_programs(dmodel, S, ps), params, cache, S,
+        PHI["max_len"] // ps, one_chip).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 16
+    assert _copies_of(text, (NP, ps, width), (NP * ps, width), (S, R, width),
+                      (S * R, width), (S * R // ps, ps, width)) == []
+    assert m.alias_size_in_bytes >= (NP * ps + 8 * S * R) * width * 2
+    assert m.temp_size_in_bytes < 0.5e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
