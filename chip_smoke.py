@@ -11,8 +11,8 @@ would call, one child process after another:
            and compared with its dense reference at the legs' shapes; and
            the kernels of the other served models at theirs (the latent
            decode kernel; the paged decode kernel with its lower bound
-           over a ring and a long table; the state-space scan, a chunk
-           against its steps)
+           over a ring and a long table; the state-space scans, a chunk
+           against its steps, Mamba-2's through its state-update kernel)
   trainer  python -m mpi_operator_tpu.examples.lm_benchmark --workload gpt2
            --size medium --seq-len 512 (global batch 16 over all visible
            chips), started the way the operator starts a gang: a worker

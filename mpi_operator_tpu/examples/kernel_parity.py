@@ -20,6 +20,9 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   over a long table, at Phi-4-mini-flash's
   widths (40 heads over 10 pairs of 128)
   selective_scan over a chunk (`ops/ssm.py`)    vs its own steps one by one
+  ssd_chunk_scan over a chunk, the matmul form   vs the steps of
+  (Falcon-H1-34B's 32 heads of 128 over 256        `ssd_state_update`, the
+  states)                                          Pallas kernel, one by one
 
 The paged decode cases go through the `Attention` module itself — one
 set of weights, one prefilled pool, the single-token step run once with
@@ -70,6 +73,9 @@ WINDOW_CASE = dict(slots=8, heads=40, kv_heads=10, head_dim=128,
                    page_size=64, window=512, table=64)
 #: a state-space layer's chunk at its published widths
 SCAN_CASE = dict(rows=8, chunk=64, channels=5120, states=16)
+#: Mamba-2's chunk and step at Falcon-H1-34B's widths
+SSD_CASE = dict(rows=8, chunk=128, heads=32, head_dim=128, groups=2,
+                states=256)
 
 
 def _rel_err(got, ref) -> float:
@@ -428,6 +434,39 @@ def scan_case(rows: int, chunk: int, channels: int, states: int
                                _rel_err(last, s))}
 
 
+def ssd_case(rows: int, chunk: int, heads: int, head_dim: int, groups: int,
+             states: int) -> Dict[str, object]:
+    """`ssd_chunk_scan` over a chunk with a state carried in, against the
+    steps of `ssd_state_update` (on the chip: the Pallas kernel, its state
+    donated) one position at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.ssm import ssd_chunk_scan, ssd_state_update
+
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    x = jax.random.normal(ks[0], (rows, chunk, heads, head_dim))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], x.shape[:3]) - 4.0)
+    A = -(1.0 + 15.0 * jax.random.uniform(ks[2], (heads,)))
+    B, C = (jax.random.normal(k, (rows, chunk, groups, states))
+            for k in ks[3:5])
+    D = jnp.ones((heads,))
+    s0 = jax.random.normal(ks[5], (rows, heads, states, head_dim))
+    y, last = jax.jit(ssd_chunk_scan)(x, dt, A, B, C, D, s0)
+    step = jax.jit(ssd_state_update, donate_argnums=(6,))
+    _assert_mosaic(step, x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D, s0)
+    s, ys = s0 + 0.0, []
+    for t in range(chunk):
+        y_t, s = step(x[:, t], dt[:, t], A, B[:, t], C[:, t], D, s)
+        ys.append(y_t)
+    return {"kernel": "ssd_chunk_scan_vs_state_update_steps",
+            "shape": {"rows": rows, "chunk": chunk, "heads": heads,
+                      "head_dim": head_dim, "groups": groups,
+                      "states": states},
+            "max_rel_err": max(_rel_err(y, jnp.stack(ys, 1)),
+                               _rel_err(last, s))}
+
+
 def run_kernel_parity(train_shape: Optional[dict] = None,
                       serve_shape: Optional[dict] = None,
                       model: Optional[dict] = None,
@@ -435,12 +474,13 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
                       mla: Optional[dict] = None,
                       window: Optional[dict] = None,
                       scan: Optional[dict] = None,
+                      ssd: Optional[dict] = None,
                       tol: float = BF16_TOL) -> List[Dict[str, object]]:
     """Every kernel the two legs use, at their shapes; one record each
     with its measured error and `ok`. The decode kernels run once per
     entry of `decode_models` (default: `model` alone); the latent decode
     kernel where `mla` gives its case, the windowed decode kernel and the
-    scan where `window` and `scan` give theirs. Off TPU the kernels
+    scans where `window`, `scan` and `ssd` give theirs. Off TPU the kernels
     interpret (the tier-1 test runs tiny shapes that way)."""
     train_shape = train_shape or TRAIN_SHAPE
     serve_shape = serve_shape or SERVE_SHAPE
@@ -457,6 +497,8 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
         records += window_decode_cases(**window)
     if scan:
         records.append(scan_case(**scan))
+    if ssd:
+        records.append(ssd_case(**ssd))
     for rec in records:
         rec["tol"] = tol
         rec["ok"] = bool(rec["max_rel_err"] <= tol)
@@ -482,7 +524,7 @@ def main(argv=None) -> int:
     device = device_record()
     records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL],
                                 mla=MLA_CASE, window=WINDOW_CASE,
-                                scan=SCAN_CASE)
+                                scan=SCAN_CASE, ssd=SSD_CASE)
     for rec in records:
         print(json.dumps({**rec, **device}))
     ok = all(rec["ok"] for rec in records)

@@ -1,25 +1,60 @@
-"""A selective state-space layer's recurrence (Mamba-1, arXiv:2312.00752)
-with the state carried in and handed back: the form a served model needs,
-where a prompt arrives in chunks and a decode step is a chunk of one.
+"""The recurrences of state-space layers with the state carried in and
+handed back: the form a served model needs, where a prompt arrives in
+chunks and a decode step is a chunk of one.
+
+Mamba-1 (arXiv:2312.00752), `selective_scan`: a decay a channel and state,
 
     s_t = exp(delta_t A) * s_{t-1} + (delta_t x_t) B_t^T      [Din x N]
     y_t = s_t C_t + D * x_t                                    [Din]
 
-The state is held `[N, Din]`, channels minor: a float32 array whose minor
+the state held `[N, Din]`, channels minor: a float32 array whose minor
 dimension is the 16 states would fill an eighth of each 128-lane tile,
-on the chip eight times its bytes.
-
-Plain `jax.numpy`, float32: a `lax.scan` over the chunk's positions (one
-position is the step itself, no loop). A position whose `delta` is 0
-leaves the state exactly as it was (exp(0) = 1, and + 0), which is how a
-caller holds the state over pad tokens and over rows that are no member
-of a call. There is no kernel; XLA fuses a position's few elementwise
+on the chip eight times its bytes. Plain `jax.numpy`, float32: a
+`lax.scan` over the chunk's positions (one position is the step itself,
+no loop). There is no kernel; XLA fuses a position's few elementwise
 passes over the state.
+
+Mamba-2's SSD (arXiv:2405.21060), `ssd_chunk_scan` and
+`ssd_state_update`: a SCALAR decay a head, heads of P channels over N
+states, B and C shared by the heads of a group,
+
+    S_t = exp(dt_t A_h) * S_{t-1} + B_t (dt_t x_t)^T          [N x P]
+    y_t = S_t^T C_t + D_h * x_t                                [P]
+
+the state held `[H, N, P]`, again the states major and the channels
+minor: B and C, which every head of a group shares, are then what has to
+be turned into columns, once a block of heads, and a head's own x, dt
+and y stay rows of 128 lanes. A state is 32 x 256 x 128 float32 = 4 MB
+a layer and row (Falcon-H1-34B), so a scan of positions would pass it
+through HBM once a position: a chunk goes through the chunked matmul
+form (`ssd_chunk_scan`: the decay matrix inside a chunk of 128, the
+chunk's state, the carried state; float32, products at the highest
+precision), and a decode step through ONE Pallas kernel on the TPU
+(`ssd_state_update`: grid (rows, head blocks), the state aliased in and
+out, each `[256, 128]` tile read once and written once).
+
+In both, a position whose step (`delta`, `dt`) is 0 leaves the state
+exactly as it was (exp(0) = 1, and + 0), which is how a caller holds the
+state over pad tokens and over rows that are no member of a call.
 """
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..utils.compat import out_struct as _out_struct
+
+#: positions a chunk of `ssd_chunk_scan` takes (`mamba_chunk_size`)
+SSD_CHUNK = 128
+#: heads a grid step of the state-update kernel takes: 8 tiles of
+#: [256, 128] float32 are 1 MB in and 1 MB out, twice for the pipeline's
+#: two buffers, a quarter of what Mosaic lets a kernel take of VMEM
+_SSD_HEAD_BLOCK = 8
 
 
 def selective_scan(x, delta, A, B, C, D, state):
@@ -58,4 +93,161 @@ def causal_conv(x, tail, w, b, count):
         tail.dtype)
 
 
-__all__ = ["selective_scan", "causal_conv"]
+def _ssd_chunk(state, x, dt, A, B, C):
+    """One chunk of L positions in the matmul form. state [G, H, N, P];
+    x [G, L, H, P]; dt [G, L, H]; B, C [G, L, K, N], head h of group
+    h // (H // K) -> (y [G, L, H, P] without the D term, the state after
+    position L-1)."""
+    G, L, H, P = x.shape
+    K, N = B.shape[2:]
+    J = H // K
+    ein = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    a = dt * A                                         # [G, L, H], <= 0
+    cs = jnp.cumsum(a, axis=1)
+    x5 = x.reshape(G, L, K, J, P)
+    s5 = state.reshape(G, K, J, N, P)
+    # inside the chunk: position t sees s <= t through exp(a_{s+1} + .. +
+    # a_t), each sum made of its own terms (a difference of two running
+    # sums loses what the sums have in common: 1e-4 of a decay at the end
+    # of a chunk of large steps)
+    below = jnp.tril(jnp.ones((L, L), bool), -1)[None, :, :, None]
+    seg = jnp.cumsum(jnp.where(below, a[:, :, None], 0.0), axis=1)
+    seen = jnp.tril(jnp.ones((L, L), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(seen, seg, -jnp.inf))    # [G, t, s, H]
+    w = (decay * dt[:, None]).reshape(G, L, L, K, J) \
+        * ein("gtkn,gskn->gtsk", C, B)[..., None]
+    y = ein("gtskj,gskjp->gtkjp", w, x5)
+    # what the carried state gives position t
+    y += ein("gtkn,gkjnp->gtkjp", C, s5) * jnp.exp(cs).reshape(G, L, K, J, 1)
+    # the state the chunk leaves: all dt 0 gives 1 * state + 0
+    left = (decay[:, -1] * dt).reshape(G, L, K, J, 1)
+    s5 = jnp.exp(cs[:, -1]).reshape(G, K, J, 1, 1) * s5 \
+        + ein("gskn,gskjp->gkjnp", B, left * x5)
+    return y.reshape(G, L, H, P), s5.reshape(G, H, N, P)
+
+
+def ssd_chunk_scan(x, dt, A, B, C, D, state, chunk: int = SSD_CHUNK):
+    """The SSD recurrence over T positions, `chunk` at a time in the
+    matmul form (arXiv:2405.21060, section 6): x [G, T, H, P]; dt
+    [G, T, H] (after its softplus; 0 at a junk position); A, D [H]; B, C
+    [G, T, K, N], K groups of H // K heads; state [G, H, N, P] -> (y
+    [G, T, H, P], the state after position T-1), all float32. A chunk
+    costs the state one pass, not one a position. T past a chunk and no
+    multiple of it is padded with positions of dt 0."""
+    G, T, H, P = x.shape
+    L = min(T, chunk)
+    pad = -T % L
+    xs = (x, dt, B, C)
+    if pad:
+        xs = tuple(jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                   for a in xs)
+    n = (T + pad) // L
+    if n == 1:
+        y, state = _ssd_chunk(state, *xs[:2], A, *xs[2:])
+    else:
+        def step(s, at):
+            y, s = _ssd_chunk(s, at[0], at[1], A, at[2], at[3])
+            return s, y
+        state, y = jax.lax.scan(step, state, tuple(
+            jnp.moveaxis(a.reshape((G, n, L) + a.shape[2:]), 1, 0)
+            for a in xs))
+        y = jnp.moveaxis(y, 0, 1).reshape(G, n * L, H, P)[:, :T]
+    return y + D[:, None] * x, state
+
+
+def _ssd_update_kernel(fresh_ref, x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
+                       s_ref, y_ref, o_ref, *, hb):
+    """One position for one (row, block of `hb` heads of one group):
+    each head's [N, P] tile comes in once and goes out once. B and C
+    arrive as rows [1, N] and are turned into columns constant along the
+    lanes (a row broadcast down the sublanes, transposed), once for the
+    block; a head's decay, dt x and y are rows of P lanes. A row that
+    starts a sequence (`fresh`) takes zeros for its tiles."""
+    N, P = s_ref.shape[2:]
+    x, dt = x_ref[0], dt_ref[0]                              # [hb, P]
+    da = jnp.exp(dt * a_ref[...])
+    dtx = dt * x
+    skip = d_ref[...] * x
+    bcol = jnp.broadcast_to(b_ref[0, 0], (P, N)).T           # [N, P]
+    ccol = jnp.broadcast_to(c_ref[0, 0], (P, N)).T
+
+    def update(tile):
+        for h in range(hb):
+            s = tile(h) * da[h:h + 1] + bcol * dtx[h:h + 1]
+            o_ref[0, h] = s
+            y_ref[0, h:h + 1] = jnp.sum(s * ccol, axis=0, keepdims=True) \
+                + skip[h:h + 1]
+
+    fresh = fresh_ref[pl.program_id(0)] != 0
+
+    @pl.when(jnp.logical_not(fresh))
+    def _carried():
+        update(lambda h: s_ref[0, h])
+
+    @pl.when(fresh)
+    def _from_zeros():
+        update(lambda h: jnp.zeros((N, P), jnp.float32))
+
+
+# jitted and inlined, as `attention._paged_walk`: the layers of a model
+# call it with the same shapes, so its body is traced once a program, and
+# the call keeps its caller's named scope in its instruction's name
+# (`ssd.update.3`), which the trace readers match.
+@functools.partial(jax.jit, static_argnums=(8,), inline=True)
+def _ssd_update_call(x, dt, A, B, C, D, state, fresh, interpret):
+    G, H, P = x.shape
+    K, N = B.shape[1:]
+    hb = min(_SSD_HEAD_BLOCK, H // K)
+    if (H // K) % hb:
+        raise ValueError(f"a group's {H // K} heads are no multiple of the "
+                         f"{hb} a grid step takes")
+    lanes = lambda a: jnp.broadcast_to(a[..., None], a.shape + (P,))  # noqa
+    row = pl.BlockSpec((1, hb, P), lambda r, j, *_: (r, j, 0))
+    head = pl.BlockSpec((hb, P), lambda r, j, *_: (j, 0))
+    group = pl.BlockSpec((1, 1, 1, N),
+                         lambda r, j, *_: (r, j * hb // (H // K), 0, 0))
+    tile = pl.BlockSpec((1, hb, N, P), lambda r, j, *_: (r, j, 0, 0))
+    y, state = pl.pallas_call(
+        functools.partial(_ssd_update_kernel, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(G, H // hb),
+            in_specs=[row, row, head, head, group, group, tile],
+            out_specs=[row, tile]),
+        out_shape=[_out_struct((G, H, P), jnp.float32, x, state),
+                   _out_struct(state.shape, jnp.float32, x, state)],
+        # operand 7 (the prefetched flags are operand 0) is the state
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(fresh.astype(jnp.int32), x, lanes(dt), lanes(A), lanes(D),
+      B[:, :, None], C[:, :, None], state)
+    return y, state
+
+
+def ssd_state_update(x, dt, A, B, C, D, state, fresh=None,
+                     interpret: Optional[bool] = None):
+    """`ssd_chunk_scan` for a chunk of one, a decode step: x [G, H, P];
+    dt [G, H]; A, D [H]; B, C [G, K, N]; state [G, H, N, P]; `fresh` [G]
+    bool, rows that start from zeros whatever their state holds -> (y
+    [G, H, P], state), float32 throughout. On the TPU one Pallas kernel
+    (`_ssd_update_kernel`) whose state operand is its state result, so a
+    donated state is updated where it lies; plain `jax.numpy` elsewhere
+    (`interpret=True`: the kernel, interpreted, for the tests)."""
+    if fresh is None:
+        fresh = jnp.zeros(x.shape[:1], bool)
+    if interpret is None and jax.default_backend() != "tpu":
+        G, H, P = x.shape
+        K, N = B.shape[1:]
+        s5 = jnp.where(fresh[:, None, None, None], 0.0, state).reshape(
+            G, K, H // K, N, P)
+        s5 = jnp.exp(dt * A).reshape(G, K, H // K, 1, 1) * s5 \
+            + B[:, :, None, :, None] * (dt[..., None] * x).reshape(
+                G, K, H // K, 1, P)
+        y = jnp.sum(s5 * C[:, :, None, :, None], axis=3).reshape(G, H, P)
+        return y + D[:, None] * x, s5.reshape(G, H, N, P)
+    return _ssd_update_call(x, dt, A, B, C, D, state, fresh, bool(interpret))
+
+
+__all__ = ["selective_scan", "causal_conv", "ssd_chunk_scan",
+           "ssd_state_update", "SSD_CHUNK"]

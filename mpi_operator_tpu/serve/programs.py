@@ -47,7 +47,8 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
     where the step's token output is pinned on a mesh (engine.py
     `_tok_sharding`); `sample` is the engine's `sample_slots`.
 
-    The contract a served model meets (`CausalLM` and `LongcatLM` do):
+    The contract a served model meets (`CausalLM`, `LongcatLM`,
+    `Phi4FlashLM` and `FalconH1LM` do):
 
     - `dmodel.config` has `max_len`, and was made by
       `generate.decode_model(model, kernel, page_size=, num_pages=)`: a
@@ -66,10 +67,13 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
       lead with `slots` and belong to a row whatever pages it holds: the
       ring of a layer that attends inside a window, the state of a
       recurrent layer (`models/phi4flash.py` has all three kinds of leaf
-      in one model: ONE pooled leaf read by eight layers, eight rings,
-      nine states). `ServingEngine.slot_state_bytes()` counts them. For
-      a model that names any, the contract widens, and the engine keeps
-      its side of it:
+      in one model, each in layers of its own: ONE pooled leaf read by
+      eight layers, eight rings, nine states; in `models/falcon_h1.py`
+      EVERY layer has both kinds, its own pooled pages and beside them
+      its recurrent state and conv tail, 4 MB a slot and layer).
+      `ServingEngine.slot_state_bytes()` counts them. For a model that
+      names any, the contract widens, and the engine keeps its side of
+      it:
         * a row's real positions in a call are consecutive and come
           FIRST; after them, and in every row that is no member of the
           call (prefill) or consumes no token (a decode step: rows
@@ -163,9 +167,11 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
             {"params": params, "cache": cache}, tokens[:, None],
             positions=positions[:, None], with_head=False,
             mutable=counted, pages=pages)
-        logits = head(params, h[:, 0])
-        tok, logp = sample(logits, rng, temperature, top_k, top_p,
-                           mode=mode)
+        # the scope a device trace splits the step by, whatever the model
+        with jax.named_scope("head"):
+            logits = head(params, h[:, 0])
+            tok, logp = sample(logits, rng, temperature, top_k, top_p,
+                               mode=mode)
         return vars_["cache"], pin_tok(tok), logp, step_counts(vars_)
 
     def verify_paged(params, cache, toks, positions, rng, temperature,

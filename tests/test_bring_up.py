@@ -344,6 +344,15 @@ def test_kernel_parity_harness_runs_the_windowed_kernel_and_the_scan():
     assert rec["max_rel_err"] <= 1e-5
 
 
+def test_kernel_parity_harness_runs_the_ssd_chunk_against_its_steps():
+    from mpi_operator_tpu.examples.kernel_parity import SSD_CASE, ssd_case
+
+    rec = ssd_case(rows=2, chunk=12, heads=4, head_dim=8, groups=2, states=16)
+    assert rec["kernel"] == "ssd_chunk_scan_vs_state_update_steps"
+    assert rec["max_rel_err"] <= 1e-5
+    assert SSD_CASE["heads"] * SSD_CASE["head_dim"] == 4096
+
+
 # -- kernels on a multi-device mesh -------------------------------------------
 # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
 # automatically partitioned", first seen on the four-chip host). jax.export

@@ -525,3 +525,133 @@ def test_phi4_flash_decode_step_keeps_its_pool_and_rings_in_place(
     assert m.alias_size_in_bytes >= (NP * ps + 8 * S * R) * width * 2
     assert m.temp_size_in_bytes < 0.5e9
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+
+
+# ---------------------------------------------------------------------------
+# Falcon-H1-34B as 4 of its 72 layers: a pool, a state and a conv tail in
+# every layer, 96 slots
+# ---------------------------------------------------------------------------
+H1 = dict(slots=96, pages=5312, page=64, max_len=4096, ssm_heads=32,
+          d_state=256, ssm_head_dim=128, groups=2)
+
+
+def test_ssd_state_update_kernel_updates_the_donated_state_where_it_lies(
+        one_chip, quiet_cache):
+    """One layer's state update at Falcon-H1-34B's widths, 96 rows of 32
+    tiles [256, 128] float32: Mosaic takes the kernel (8 heads a grid
+    step, the transposes that turn B and C into columns) under its
+    scoped VMEM limit; the 403 MB of state are the call's operand and
+    its result, aliased, and nothing of that size is copied or made
+    beside them."""
+    from mpi_operator_tpu.ops.ssm import ssd_state_update
+    S, H, N, P, K = (H1["slots"], H1["ssm_heads"], H1["d_state"],
+                     H1["ssm_head_dim"], H1["groups"])
+    spec = lambda *shape: jax.ShapeDtypeStruct(             # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: ssd_state_update(*a[:-1], fresh=a[-1], interpret=False),
+        donate_argnums=(6,)).lower(
+            spec(S, H, P), spec(S, H), spec(H), spec(S, K, N), spec(S, K, N),
+            spec(H), spec(S, H, N, P),
+            jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+        ).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    state = S * H * N * P * 4
+    assert text.count("tpu_custom_call") == 1
+    assert _copies_of(text, (S, H, N, P)) == []
+    assert m.alias_size_in_bytes >= state == 96 * 4194304
+    assert m.temp_size_in_bytes < 16 << 20
+
+
+@pytest.fixture(scope="module")
+def falcon_h1(one_chip):
+    """The decode model, parameter shapes and cache shapes of
+    `perfbench/configs/falcon-h1-34b-4of72.json` as the benchmark's
+    engine builds them (96 slots, 5312 pages of 64, contexts to 4096)."""
+    from mpi_operator_tpu.models.generate import decode_model
+    from perfbench import weights_falconh1 as weights
+    from perfbench.kinds import _serve_falconh1
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "falcon-h1-34b-4of72.json")) as f:
+        dims = weights.Dims.from_config(json.load(f))
+    model = _serve_falconh1.model_of(dims, jnp.bfloat16, H1["max_len"], True)
+    dmodel = decode_model(model, True, page_size=H1["page"],
+                          num_pages=H1["pages"])
+    on_chip = lambda tree: jax.tree.map(                        # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: weights.make_params(jax.random.PRNGKey(0), dims,
+                                    jnp.bfloat16)))
+    z = jnp.zeros((H1["slots"], 1), jnp.int32)
+    table = jnp.zeros((H1["slots"], H1["max_len"] // H1["page"]), jnp.int32)
+    cache = on_chip(jax.eval_shape(
+        lambda p: dmodel.apply({"params": p}, z, positions=z,
+                               with_head=False, mutable=["cache"],
+                               pages=table)[1]["cache"], params))
+    return dims, dmodel, params, cache
+
+
+def _h1_big_copies(text):
+    S, NP, ps = H1["slots"], H1["pages"], H1["page"]
+    return _copies_of(
+        text, (NP, ps, 1024), (NP * ps, 1024),
+        (S, H1["ssm_heads"], H1["d_state"], H1["ssm_head_dim"]))
+
+
+def test_falcon_h1_decode_step_passes_each_state_and_pool_through_once(
+        one_chip, quiet_cache, monkeypatch, falcon_h1):
+    """The engine's own `step_paged` over the four layers and the whole
+    vocabulary: eight Mosaic calls (a state update and a walk of the
+    layer's pool a layer, each under its scope's name), no copy of a
+    pool [5312, 64, 1024] or of a state [96, 32, 256, 128], all 4.3 GB
+    of them aliased through the step, and 8.79 GB of weights, the cache
+    and the temporaries (the [96, 261120] float32 logits among them) fit
+    the chip."""
+    dims, dmodel, params, cache = falcon_h1
+    S, ps, NP = H1["slots"], H1["page"], H1["pages"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _lower_greedy_step(
+        _engine_programs(dmodel, S, ps), params, cache, S,
+        H1["max_len"] // ps, one_chip).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert sorted(c.rsplit(".", 1)[0] for c in calls) == (
+        ["h1attn.attend"] * 4 + ["ssd.update"] * 4)
+    assert _h1_big_copies(text) == []
+    held = dims.layers * (NP * ps * 1024 * 2 + S * 4194304)
+    assert m.alias_size_in_bytes >= held
+    assert 13.0e9 < m.argument_size_in_bytes < 13.3e9
+    assert m.temp_size_in_bytes < 0.5e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+
+
+def test_falcon_h1_prefill_bucket_fits_beside_what_the_chip_holds(
+        one_chip, quiet_cache, monkeypatch, falcon_h1):
+    """The engine's own `prefill_paged` at the cell's one bucket
+    ([96, 128] tokens through the chunked scan, rows in groups of 32):
+    no copy of a pool, the cache aliased, and the program's temporaries
+    beside the 13.2 GB the engine holds (of which this program is not
+    handed the head) stay under the chip's 16 GB."""
+    dims, dmodel, params, cache = falcon_h1
+    S, ps = H1["slots"], H1["page"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
+                                              sharding=one_chip)
+    compiled = _engine_programs(dmodel, S, ps).prefill.lower(
+        params, cache, arg(jnp.int32, S, 128), arg(jnp.int32, S),
+        arg(jnp.int32, S, H1["max_len"] // ps),
+        arg(jnp.int32, S)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert "tpu_custom_call" not in text
+    assert _copies_of(text, (H1["pages"], ps, 1024),
+                      (H1["pages"] * ps, 1024)) == []
+    assert m.alias_size_in_bytes >= held - 8.8e9
+    assert m.temp_size_in_bytes < 2.4e9
+    assert held + m.temp_size_in_bytes < 15.6e9
