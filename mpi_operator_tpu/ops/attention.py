@@ -5,25 +5,34 @@ The reference delegates all device compute to out-of-repo CUDA libraries
 /opt/skills/guides/pallas_guide.md: the attention score matrix never
 materializes in HBM, in either direction.
 
-Layouts (all Mosaic-legal):
-  q/k/v/o        [BH, S, D]          blocks (1, block, D)
-  lse / delta    [BH, S, 128]        blocks (1, block_q, 128) — the row
-                 statistic broadcast across a 128-lane minor dim, the same
-                 trick jax's reference TPU kernel uses (Mosaic requires the
-                 last two block dims divisible by (8, 128) or equal to the
-                 array dims; a bare [BH, S] row vector can't block legally)
-  kv mask        [B, 8, S]           blocks (1, 8, block_k) — valid-key
-                 mask broadcast across a sublane dim; indexed b = bh // H
+Two forms of the same algorithm, chosen from the operands alone
+(`flash_attention`):
 
-Three kernels:
-  fwd   grid (BH, nq, nk), k innermost: online softmax in VMEM scratch
-        (running max m, running sum l, f32 accumulator), output + lse
-        written on the last k step. Causal jobs skip fully-masked k blocks
-        (@pl.when — the MXU never sees them).
-  dq    grid (BH, nq, nk), k innermost: dq accumulates in VMEM scratch,
-        ds = p * (dp - delta) recomputed blockwise from the lse residual.
-  dkv   grid (BH, nk, nq), q innermost: dk/dv accumulate in VMEM scratch;
-        causal jobs skip q blocks strictly above the diagonal.
+  resident  what the trainer's step runs (no key mask, heads that fill
+    128-lane tiles, a sequence whose blocks fit VMEM). TWO kernels, grid
+    (B, H·D / 128), one step a lane block of heads — a head of 128, or a
+    PAIR of 64 — whole in VMEM:
+      q/k/v/o and gradients  [B, S, H·D]   blocks (1, S, 128)
+      lse                    [B, H/hp, hp, S]  blocks (1, 1, hp, S): rows
+    fwd   loops over q blocks and, inside, the k blocks up to the
+          diagonal: online softmax, output + lse written a q block.
+    bwd   the same loops with the scores TRANSPOSED (sT = k qT), so that
+          lse and delta are rows; s, p, dp, ds computed ONCE a block and
+          added into dq, dk and dv; delta computed in the kernel.
+  streamed  everything else that tiles, and ring attention's pairings.
+    THREE kernels over [BH, S, D] blocks (1, block, D), lse / delta
+    [BH, S, 128] blocks (1, block_q, 128) (the row statistic broadcast
+    across a 128-lane minor dim: a bare [BH, S] row can't block legally),
+    kv mask [B, 8, S] blocks (1, 8, block_k), indexed b = bh // H:
+    fwd   grid (BH, nq, nk), k innermost: online softmax in VMEM scratch,
+          output + lse written on the last k step.
+    dq    grid (BH, nq, nk), k innermost: dq accumulates in VMEM scratch,
+          ds = p * (dp - delta) recomputed blockwise from the lse residual.
+    dkv   grid (BH, nk, nq), q innermost: dk/dv accumulate in VMEM scratch.
+
+In both, every product takes its operands in the caller's type and sums
+in float32; a causal block above the diagonal is neither visited nor
+fetched, and only a block the diagonal cuts is masked.
 
 Key-padding masks are first-class: `kv_mask` [B, S] (True = real token)
 masks score columns in all three kernels, so padded BERT batches keep the
@@ -43,6 +52,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import math
 from typing import Dict, Iterator, Optional, Set
 
 import jax
@@ -72,6 +82,11 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
 
     Yields a dict the dispatch sites fill at TRACE time:
       "attention" — full-sequence attention: "flash" | "dense" | "ring"
+      "flash"     — the form of the flash kernels traced, with the heads
+                    a lane block holds and the loops' steps:
+                    "resident[heads=2,q=512,k=512]" (a lane block of
+                    heads whole in VMEM, two kernels) |
+                    "streamed[q=512,k=512]" (a grid over blocks, three)
       "decode"    — single-token KV-cache steps: "pallas[hb=N]" |
                     "pallas_paged[live,pages=N,hb=M]" (the paged kernel
                     that walks a row's live pages, N a turn, M kv heads
@@ -83,8 +98,8 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
       "prefill"   — multi-token KV-cache calls (always "dense" today)
     A jitted function traces once, so wrap the whole run (first call
     included), not a later window."""
-    rec: Dict[str, Set[str]] = {"attention": set(), "decode": set(),
-                                "prefill": set()}
+    rec: Dict[str, Set[str]] = {"attention": set(), "flash": set(),
+                                "decode": set(), "prefill": set()}
     token = _TRACED.set(rec)
     try:
         yield rec
@@ -147,6 +162,19 @@ def _kernel_mesh(x):
     return mesh
 
 
+#: the mesh axis heads are split over
+_HEAD_AXES = ("tp",)
+
+
+def _dividing_axes(mesh, dim: int, axes):
+    """(the names among `axes` that split a dim of `dim` over `mesh`, how
+    many ways): (None, 1) where the mesh has none of them or they do not
+    divide it, and the dim stays whole on every device."""
+    names = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+    n = math.prod(mesh.shape[a] for a in names)
+    return (names, n) if names and dim % n == 0 else (None, 1)
+
+
 def _per_device(fn, mesh, rows: int, heads: int, args, layouts, out_layout):
     """Run `fn(*args)` on each device's block of a multi-device mesh.
 
@@ -158,15 +186,8 @@ def _per_device(fn, mesh, rows: int, heads: int, args, layouts, out_layout):
     from ..parallel.mesh import BATCH_AXES
     from ..utils.compat import shard_map
 
-    def axes_dividing(dim, axes):
-        names = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
-        n = 1
-        for a in names:
-            n *= mesh.shape[a]
-        return names if names and dim % n == 0 else None
-
-    split = {"rows": axes_dividing(rows, BATCH_AXES),
-             "heads": axes_dividing(heads, ("tp",)), None: None}
+    split = {"rows": _dividing_axes(mesh, rows, BATCH_AXES)[0],
+             "heads": _dividing_axes(mesh, heads, _HEAD_AXES)[0], None: None}
 
     def spec(layout):
         return P(*(split[role] for role in layout))
@@ -188,16 +209,89 @@ def _per_device(fn, mesh, rows: int, heads: int, args, layouts, out_layout):
 
 
 # ---------------------------------------------------------------------------
-# Forward
+# What the two forms share
 # ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))      # a bT: contract the minor dim of both
+_NN = (((1,), (0,)), ((), ()))      # a b
+_TN = (((0,), (0,)), ((), ()))      # aT b: contract the major dim of both
+
+
+def _dot(a, b, dims):
+    """A product on the operands' own type, summed in float32."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _folds_scale(sm_scale: float, dtype) -> bool:
+    """Whether the scores' scale can ride on q ([block, D]) instead of on
+    the scores ([block_q, block_k], eight times the elements): where the
+    scaled q is the same number — float32 operands, or a power of two
+    (heads of 64: 1/8) in any type. Elsewhere (bfloat16 heads of 128) the
+    scores are scaled, as they always were."""
+    return (jnp.dtype(dtype) == jnp.float32
+            or math.frexp(sm_scale)[0] == 0.5)
+
+
+def _scaled(x, sm_scale, fold, keep=None):
+    """`x` (a block of q, or of do with nothing to fold) times the scale
+    if it is folded, zero outside the lanes `keep` marks; float32
+    arithmetic, the operand's type back."""
+    if not fold and keep is None:
+        return x
+    y = x.astype(jnp.float32)
+    if fold:
+        y = y * sm_scale
+    if keep is not None:
+        y = jnp.where(keep, y, 0.0)
+    return y.astype(x.dtype)
+
+
+def _under_diagonal(q0, k0, shape, q_axis):
+    """Where key position <= query position in a block of scores whose
+    first query is `q0` and first key `k0`; queries along `q_axis`."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return kpos <= qpos
+
+
+# ---------------------------------------------------------------------------
+# Streamed form: a grid over (head, q block, k block)
+# ---------------------------------------------------------------------------
+# What a shape takes that the resident form below does not cover: a key
+# mask, a head whose sequence does not fit VMEM, heads that do not fill
+# whole lane tiles (gpt2-xl's 25 of 64); and what ring attention pairs
+# q-spans with k-spans through (`_flash_fwd`, `_dq_call`, `_dkv_call`).
+# Every product takes its operands as the caller passed them, a block the
+# diagonal does not cut is not masked, and a block above it is neither
+# visited nor fetched (its index map pins to the last block seen).
+
+def _last_k_block(qi, block_q, block_k):
+    """The last k block a causal q block sees."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _for_visited_block(body, causal, qi, ki, block_q, block_k):
+    """`body(cut)` if the block (qi, ki) is visited: `cut` False where it
+    lies clear of the diagonal (or nothing is causal), True where the
+    diagonal cuts it — its last key past its first query — and it must
+    be masked; a block above the diagonal runs nothing."""
+    if not causal:
+        body(False)
+        return
+    seen = ki <= _last_k_block(qi, block_q, block_k)
+    cut = ki * block_k + block_k - 1 > qi * block_q
+    pl.when(seen & jnp.logical_not(cut))(functools.partial(body, False))
+    pl.when(seen & cut)(functools.partial(body, True))
+
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, sm_scale, causal,
-                block_q, block_k, num_heads):
-    del num_heads
+                block_q, block_k):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    fold = _folds_scale(sm_scale, q_ref.dtype)
 
     @pl.when(ki == 0)
     def _init():
@@ -205,39 +299,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    run = True
-    if causal:  # k-block strictly above the diagonal touches nothing
-        run = ki * block_k <= qi * block_q + (block_q - 1)
-
-    @pl.when(run)
-    def _attend():
-        q = q_ref[0]                              # [block_q, d]
+    def attend(cut):
+        q = _scaled(q_ref[0], sm_scale, fold)     # [block_q, d]
         k = k_ref[0]                              # [block_k, d]
         v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where((qi * block_q + rows) >= (ki * block_k + cols),
-                          s, NEG_INF)
+        s = _dot(q, k, _NT)
+        if not fold:
+            s = s * sm_scale
+        if cut:
+            s = jnp.where(_under_diagonal(qi * block_q, ki * block_k,
+                                          s.shape, 0), s, NEG_INF)
         if mask_ref is not None:
-            valid = mask_ref[0, :1] > 0           # [1, block_k]
-            s = jnp.where(valid, s, NEG_INF)
+            s = jnp.where(mask_ref[0, :1] > 0, s, NEG_INF)  # [1, block_k]
 
         m_prev = m_ref[:, :1]                     # [block_q, 1]
         l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                    # [block_q, block_k]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_ref[:, :1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
         m_ref[:, :1] = m_new
-        l_ref[:, :1] = l_new
+
+    _for_visited_block(attend, causal, qi, ki, block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -247,40 +331,54 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                                       (block_q, LANES))
 
 
+def _streamed_specs(D, block_q, block_k, causal, kv_mask, num_heads,
+                    q_major=True):
+    """Block specs of the streamed kernels: (a q-side block of `width`,
+    a k-side block, the key mask's or None). The grid is (head, q block,
+    k block), or with `q_major` False (head, k block, q block). Under a
+    causal mask a block the kernel skips takes the index of the nearest
+    one it visits, so nothing is fetched for it."""
+    def qk(a, b):
+        qi, ki = (a, b) if q_major else (b, a)
+        if causal and q_major:
+            ki = jnp.minimum(ki, _last_k_block(qi, block_q, block_k))
+        elif causal:
+            qi = jnp.maximum(qi, (ki * block_k) // block_q)
+        return qi, ki
+
+    def q_side(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, j: (b, qk(i, j)[0], 0))
+
+    k_side = pl.BlockSpec((1, block_k, D),
+                          lambda b, i, j: (b, qk(i, j)[1], 0))
+    mask = None if kv_mask is None else pl.BlockSpec(
+        (1, 8, block_k), lambda b, i, j: (b // num_heads, 0, qk(i, j)[1]))
+    return q_side, k_side, mask
+
+
 def _flash_fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k,
                num_heads, interpret):
     """q/k/v: [BH, S, D]; kv_mask: [B, 8, S] f32 or None.
     Returns (out [BH, S, D], lse [BH, S, LANES])."""
     BH, S, D = q.shape
-    grid = (BH, S // block_q, S // block_k)
-    kern = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, num_heads=num_heads)
-    H = num_heads
-    in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-    ]
-    args = [q, k, v]
+    q_side, k_side, mask_spec = _streamed_specs(
+        D, block_q, block_k, causal, kv_mask, num_heads)
+    in_specs, args = [q_side(D), k_side, k_side], [q, k, v]
     if kv_mask is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 8, block_k), lambda b, i, j: (b // H, 0, j)))
+        in_specs.append(mask_spec)
         args.append(kv_mask)
-    else:
-        def kern_nomask(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                        _inner=kern):
-            return _inner(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                          *scratch)
-        kern = kern_nomask
-    out, lse = pl.pallas_call(
+
+    def kern(*refs):
+        mask_ref = refs[3] if kv_mask is not None else None
+        _fwd_kernel(*refs[:3], mask_ref, *refs[len(args):],
+                    sm_scale=sm_scale, causal=causal, block_q=block_q,
+                    block_k=block_k)
+    return pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(BH, S // block_q, S // block_k),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0)),
-        ],
+        out_specs=[q_side(D), q_side(LANES)],
         out_shape=[
             _out_struct((BH, S, D), q.dtype, q, k, v),
             _out_struct((BH, S, LANES), jnp.float32, q, k, v),
@@ -292,35 +390,41 @@ def _flash_fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k,
         ],
         interpret=interpret,
     )(*args)
-    return out, lse
 
 
-# ---------------------------------------------------------------------------
-# Backward: dq kernel (grid over q blocks, k innermost)
-# ---------------------------------------------------------------------------
-
-def _masked_p(s, lse_blk, causal, qi, ki, block_q, block_k, mask_ref):
-    """p = exp(s - lse) with explicit re-masking: fully-masked rows have a
-    degenerate lse, so a bare exp would resurrect masked positions."""
-    masked = s > NEG_INF / 2
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        masked = jnp.logical_and(
-            masked, (qi * block_q + rows) >= (ki * block_k + cols))
-        s = jnp.where(masked, s, NEG_INF)
+def _block_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                 qi, ki, cut, *, sm_scale, block_q, block_k):
+    """(q as the scores took it, do, p, ds) of one block of the backward:
+    p = exp(s - lse) from the forward's row statistics and ds = p (dp -
+    delta), the scores' gradient before their scale (which the caller
+    puts on dq, or has on q already). p is ZEROED where the forward
+    masked, not the scores masked again: a row with no key left has a
+    degenerate lse, and a bare exp would resurrect its masked keys."""
+    fold = _folds_scale(sm_scale, q_ref.dtype)
+    q = _scaled(q_ref[0], sm_scale, fold)
+    k = k_ref[0]
+    do = do_ref[0]
+    s = _dot(q, k, _NT)
+    if not fold:
+        s = s * sm_scale
+    p = jnp.exp(s - lse_ref[0, :, :1])            # [block_q, block_k]
+    keep = None
+    if cut:
+        keep = _under_diagonal(qi * block_q, ki * block_k, s.shape, 0)
     if mask_ref is not None:
         valid = mask_ref[0, :1] > 0
-        masked = jnp.logical_and(masked, valid)
-        s = jnp.where(masked, s, NEG_INF)
-    p = jnp.where(masked, jnp.exp(s - lse_blk), 0.0)
-    return p
+        keep = valid if keep is None else jnp.logical_and(keep, valid)
+    if keep is not None:
+        p = jnp.where(keep, p, 0.0)
+    dp = _dot(do, v_ref[0], _NT)
+    ds = p * (dp - delta_ref[0, :, :1])
+    if not fold:
+        ds = ds * sm_scale
+    return q, do, p, ds
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-               dq_ref, dq_acc, *, sm_scale, causal, block_q, block_k,
-               num_heads):
-    del num_heads
+               dq_ref, dq_acc, *, sm_scale, causal, block_q, block_k):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -329,43 +433,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = True
-    if causal:
-        run = ki * block_k <= qi * block_q + (block_q - 1)
-
-    @pl.when(run)
-    def _accumulate():
-        q = q_ref[0]
+    def accumulate(cut):
+        *_, ds = _block_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, qi,
+            ki, cut, sm_scale=sm_scale, block_q=block_q, block_k=block_k)
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse_blk = lse_ref[0, :, :1]               # [block_q, 1]
-        delta_blk = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        p = _masked_p(s, lse_blk, causal, qi, ki, block_q, block_k, mask_ref)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk) * sm_scale      # [block_q, block_k]
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_acc[:] += _dot(ds.astype(k.dtype), k, _NN)
+
+    _for_visited_block(accumulate, causal, qi, ki, block_q, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq = dq_acc[:]
+        if _folds_scale(sm_scale, q_ref.dtype):
+            dq = dq * sm_scale
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
-
-# ---------------------------------------------------------------------------
-# Backward: dk/dv kernel (grid over k blocks, q innermost)
-# ---------------------------------------------------------------------------
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
-                block_q, block_k, num_heads):
-    del num_heads
+                block_q, block_k):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -375,34 +462,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:  # q blocks strictly above the diagonal see nothing of this k
-        run = ki * block_k <= qi * block_q + (block_q - 1)
+    def accumulate(cut):
+        q, do, p, ds = _block_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, qi,
+            ki, cut, sm_scale=sm_scale, block_q=block_q, block_k=block_k)
+        dv_acc[:] += _dot(p.astype(do.dtype), do, _TN)     # pT do
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, _TN)      # dsT q
 
-    @pl.when(run)
-    def _accumulate():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        lse_blk = lse_ref[0, :, :1]
-        delta_blk = delta_ref[0, :, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        p = _masked_p(s, lse_blk, causal, qi, ki, block_q, block_k, mask_ref)
-        # dv += pᵀ @ do
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk) * sm_scale
-        # dk += dsᵀ @ q
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_visited_block(accumulate, causal, qi, ki, block_q, block_k)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -410,94 +477,60 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_call(kernel, q_major, outs, q, k, v, do, lse_lanes, delta_lanes,
+              kv_mask, sm_scale, causal, block_q, block_k, num_heads,
+              interpret):
+    """One of the streamed backward kernels over q/k/v/do [BH, S, D] and
+    lse/delta [BH, S, LANES]; `outs` names its outputs' sides ("q" or
+    "k"), each with an accumulator of its block."""
+    BH, S, D = q.shape
+    q_side, k_side, mask_spec = _streamed_specs(
+        D, block_q, block_k, causal, kv_mask, num_heads, q_major)
+    in_specs = [q_side(D), k_side, k_side, q_side(D), q_side(LANES),
+                q_side(LANES)]
+    args = [q, k, v, do, lse_lanes, delta_lanes]
+    if kv_mask is not None:
+        in_specs.append(mask_spec)
+        args.append(kv_mask)
+
+    def kern(*refs):
+        mask_ref = refs[6] if kv_mask is not None else None
+        kernel(*refs[:6], mask_ref, *refs[len(args):], sm_scale=sm_scale,
+               causal=causal, block_q=block_q, block_k=block_k)
+    nq, nk = S // block_q, S // block_k
+    side = {"q": (q_side(D), block_q), "k": (k_side, block_k)}
+    out = pl.pallas_call(
+        kern,
+        grid=(BH, nq, nk) if q_major else (BH, nk, nq),
+        in_specs=in_specs,
+        out_specs=[side[o][0] for o in outs],
+        out_shape=[_out_struct((BH, S, D), q.dtype, q, k, v, do)
+                   for _ in outs],
+        scratch_shapes=[pltpu.VMEM((side[o][1], D), jnp.float32)
+                        for o in outs],
+        interpret=interpret,
+    )(*args)
+    return out
+
+
 def _dq_call(q, k, v, do, lse_lanes, delta_lanes, kv_mask, sm_scale,
              causal, block_q, block_k, num_heads, interpret):
-    """dq for one (q-span × k-span) pairing. lse/delta: [BH, S, LANES].
-    Reused by the ring-attention backward (parallel/ring_attention.py)
-    with per-block lse/delta from the GLOBAL softmax statistics."""
-    BH, S, D = q.shape
-    H = num_heads
-    lm_spec_q = pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0))
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # v
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # do
-        lm_spec_q,                                                  # lse
-        lm_spec_q,                                                  # delta
-    ]
-    dq_args = [q, k, v, do, lse_lanes, delta_lanes]
-    dq_kern = functools.partial(
-        _dq_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_heads=num_heads)
-    if kv_mask is not None:
-        dq_in_specs.append(
-            pl.BlockSpec((1, 8, block_k), lambda b, i, j: (b // H, 0, j)))
-        dq_args.append(kv_mask)
-    else:
-        inner_dq = dq_kern
-
-        def dq_kern(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dq_ref, dq_acc, _inner=inner_dq):
-            return _inner(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          None, dq_ref, dq_acc)
-    return pl.pallas_call(
-        dq_kern,
-        grid=(BH, S // block_q, S // block_k),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((BH, S, D), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(*dq_args)
+    """dq for one (q-span × k-span) pairing: grid (BH, nq, nk), k
+    innermost. lse/delta: [BH, S, LANES]. Reused by the ring-attention
+    backward (parallel/ring_attention.py) with per-block lse/delta from
+    the GLOBAL softmax statistics."""
+    return _bwd_call(_dq_kernel, True, "q", q, k, v, do, lse_lanes,
+                     delta_lanes, kv_mask, sm_scale, causal, block_q,
+                     block_k, num_heads, interpret)[0]
 
 
 def _dkv_call(q, k, v, do, lse_lanes, delta_lanes, kv_mask, sm_scale,
               causal, block_q, block_k, num_heads, interpret):
-    """dk/dv for one (q-span × k-span) pairing; see _dq_call."""
-    BH, S, D = q.shape
-    H = num_heads
-    dkv_in_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),   # v
-        pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),   # do
-        pl.BlockSpec((1, block_q, LANES), lambda b, j, i: (b, i, 0)),  # lse
-        pl.BlockSpec((1, block_q, LANES), lambda b, j, i: (b, i, 0)),  # delta
-    ]
-    dkv_args = [q, k, v, do, lse_lanes, delta_lanes]
-    dkv_kern = functools.partial(
-        _dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_heads=num_heads)
-    if kv_mask is not None:
-        dkv_in_specs.append(
-            pl.BlockSpec((1, 8, block_k), lambda b, j, i: (b // H, 0, j)))
-        dkv_args.append(kv_mask)
-    else:
-        inner_dkv = dkv_kern
-
-        def dkv_kern(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref, dk_acc, dv_acc, _inner=inner_dkv):
-            return _inner(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          None, dk_ref, dv_ref, dk_acc, dv_acc)
-    return pl.pallas_call(
-        dkv_kern,
-        grid=(BH, S // block_k, S // block_q),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            _out_struct((BH, S, D), k.dtype, q, k, v, do),
-            _out_struct((BH, S, D), v.dtype, q, k, v, do),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*dkv_args)
+    """dk/dv for one pairing: grid (BH, nk, nq), q innermost; see
+    `_dq_call`."""
+    return _bwd_call(_dkv_kernel, False, "kk", q, k, v, do, lse_lanes,
+                     delta_lanes, kv_mask, sm_scale, causal, block_q,
+                     block_k, num_heads, interpret)
 
 
 def _flash_bwd(sm_scale, causal, block_q, block_k, num_heads, interpret,
@@ -518,10 +551,6 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, num_heads, interpret,
     dmask = None if kv_mask is None else jnp.zeros_like(kv_mask)
     return dq, dk, dv, dmask
 
-
-# ---------------------------------------------------------------------------
-# custom_vjp plumbing
-# ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_core(q, k, v, kv_mask, sm_scale, causal, block_q, block_k,
@@ -544,6 +573,278 @@ def _flash_core_fwd(q, k, v, kv_mask, sm_scale, causal, block_q, block_k,
 _flash_core.defvjp(_flash_core_fwd, _flash_bwd)
 
 
+# ---------------------------------------------------------------------------
+# Resident form: a lane tile of heads whole in VMEM, one grid step each
+# ---------------------------------------------------------------------------
+# What the trainer's step runs (no key mask, a sequence of 1024). The
+# kernels read q, k, v [B, S, H·D] where the projections wrote them, a
+# block [S, 128] a grid step (B, H·D / 128): ONE head of 128, or TWO of
+# 64 side by side in the lanes (`hp` heads of D = 128 / hp), and write
+# out, dq, dk, dv the same way, so no transpose stands beside them. A
+# head of the pair is told from its neighbour by ZEROS in the other's
+# lanes of ONE operand of each product (q or do, masked once a q block):
+# a product over all 128 lanes then sees that head alone, costs the MXU
+# what a half-filled one of 64 does, and no lane tile is split. The q
+# and k blocks are walked by loops INSIDE the grid step, bounded by the
+# diagonal: a block above it is never visited, one wholly under it is not
+# masked. The forward keeps the scores' rows on sublanes (s = q kT); the
+# backward keeps them on LANES (sT = k qT), so that lse and delta are
+# read as rows [1, block_q] of arrays [B, H, S] — nothing 128 wide in
+# HBM — and computes s, p, dp and ds ONCE a block for dq, dk and dv.
+# The q blocks, the heads of a pair and the cut block are Python's `for`,
+# so at 1024 a kernel's six block bodies are ONE straight line in which
+# the scheduler lays a body's exp and reductions under its neighbour's
+# products: walked by `fori_loop`s (two bodies a kernel whatever S is,
+# half the 1.1 s Mosaic takes to compile each of a step's 48 kernels)
+# the same bodies wait for each other across the loops' edges, forward +
+# backward 680 + 877 us a layer where this takes 354 + 697 (PERF.md
+# section 6, PR 36). The price is that cold compile, and one that grows
+# with S / block_q.
+
+#: bytes of the resident kernels' blocks (q, k, v, out, do and three
+#: outputs, two buffers each, and two float32 accumulators) a head's
+#: sequence may take to stay whole in VMEM: bfloat16 at 2048 takes 10.5 MB
+#: (measured resident 1.85 ms a layer where it streams in 3.06, and 7.0 s
+#: of the chip's host to compile a layer's two kernels cold where the
+#: streamed three take 4.7: PERF.md, PR 36), float32 at 1024 9.4; float32
+#: at 2048 and bfloat16 at 4096 stream
+_RESIDENT_VMEM_BUDGET = 12 << 20
+#: what Mosaic is asked for: the blocks and the loops' temporaries (a
+#: block of scores [512, 512] float32 is 1 MB, and a step holds several)
+_RESIDENT_VMEM_LIMIT = 48 << 20
+#: the resident loops' steps over q and k where the caller names none.
+#: The largest measured: a step's products wait for each other, so its
+#: cost has a fixed part that only a larger step spreads (forward +
+#: backward a layer at [8, 1024, 16, 64]: 3.56 ms at 128 x 128, 1.63 at
+#: 256 x 256, 1.04 at 512 x 512, where the backward runs at 95% of what
+#: its five products cost the MXU; PERF.md, PR 36)
+_RESIDENT_STEPS = (512, 512)
+
+
+#: heads side by side in a lane block of the resident form, by head_dim:
+#: the two the chip measured (PERF.md, PR 36). Narrower heads would pay
+#: each product 128 // D times over the lanes, wider ones take blocks
+#: that nothing has compiled: both stream
+_RESIDENT_LANE_HEADS = {LANES: 1, LANES // 2: 2}
+
+
+def _resident_heads(S: int, H: int, D: int, dtype) -> Optional[int]:
+    """Heads a lane block of the resident form holds, or None where the
+    form does not apply: a head_dim that is neither 128 nor 64, an odd
+    count of 64, or a sequence past `_RESIDENT_VMEM_BUDGET`."""
+    hp = _RESIDENT_LANE_HEADS.get(D)
+    if hp is None or H % hp:
+        return None
+    per_pos = LANES * (16 * jnp.dtype(dtype).itemsize + 8)
+    return hp if S * per_pos <= _RESIDENT_VMEM_BUDGET else None
+
+
+def _walk_k_blocks(step, carry, causal, i, block_q, block_k, S):
+    """`carry` through `step(k0, carry, cut)` for each k block q block
+    `i` sees: a loop over the blocks wholly under the diagonal (all of
+    them where nothing is causal), unmasked, then the ones it cuts, one
+    by one."""
+    clear = seen = S // block_k
+    if causal:
+        clear = (i * block_q + 1) // block_k
+        seen = -(-(i + 1) * block_q // block_k)
+    if clear:
+        carry = jax.lax.fori_loop(
+            0, clear, lambda j, c: step(
+                pl.multiple_of(j * block_k, block_k), c, False), carry)
+    for j in range(clear, seen):
+        carry = step(j * block_k, carry, True)
+    return carry
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
+                         causal, block_q, block_k, hp):
+    S, W = q_ref.shape[1:]
+    fold = _folds_scale(sm_scale, q_ref.dtype)
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1) // (W // hp)
+    for i in range(S // block_q):
+        rows = slice(i * block_q, (i + 1) * block_q)
+        out = None
+        for h in range(hp):
+            mine = None if hp == 1 else lane_head == h
+            q = _scaled(q_ref[0, rows], sm_scale, fold, mine)
+
+            def attend(k0, carry, cut, q=q, i=i):
+                m_prev, l_prev, acc = carry
+                k = k_ref[0, pl.ds(k0, block_k)]
+                v = v_ref[0, pl.ds(k0, block_k)]
+                s = _dot(q, k, _NT)               # [block_q, block_k]
+                if not fold:
+                    s = s * sm_scale
+                if cut:
+                    s = jnp.where(_under_diagonal(i * block_q, k0, s.shape,
+                                                  0), s, NEG_INF)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                return (m_new,
+                        l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                        acc * alpha + _dot(p.astype(v.dtype), v, _NN))
+
+            m, l, acc = _walk_k_blocks(
+                attend, (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                         jnp.zeros((block_q, 1), jnp.float32),
+                         jnp.zeros((block_q, W), jnp.float32)),
+                causal, i, block_q, block_k, S)
+            l = jnp.maximum(l, 1e-30)
+            # acc holds p v of BOTH heads' values; this head's lanes kept
+            out = acc / l if out is None else jnp.where(mine, acc / l, out)
+            # the row statistic as a ROW: a transpose of its lane broadcast
+            lse_ref[0, 0, h:h + 1, rows] = jnp.broadcast_to(
+                m + jnp.log(l), (block_q, LANES)).T[:1]
+        o_ref[0, rows] = out.astype(o_ref.dtype)
+
+
+def _resident_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                         dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                         sm_scale, causal, block_q, block_k, hp):
+    S, W = q_ref.shape[1:]
+    fold = _folds_scale(sm_scale, q_ref.dtype)
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1) // (W // hp)
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
+    D = W // hp
+    for i in range(S // block_q):
+        rows = slice(i * block_q, (i + 1) * block_q)
+        # delta = rowsum(dO ∘ O) a head, wanted as ROWS: the product
+        # turned once a q block, then a head's D sublanes summed
+        do_o = (do_ref[0, rows].astype(jnp.float32)
+                * o_ref[0, rows].astype(jnp.float32)).T   # [W, block_q]
+        dq = None
+        for h in range(hp):
+            mine = None if hp == 1 else lane_head == h
+            # zeros in the other head's lanes: sT and dpT see this head,
+            # and dk, dv get nothing of it in the other's lanes
+            q = _scaled(q_ref[0, rows], sm_scale, fold, mine)
+            do = _scaled(do_ref[0, rows], 1.0, False, mine)
+            lse = lse_ref[0, 0, h:h + 1, rows]            # [1, block_q]
+            delta = jnp.sum(do_o[h * D:(h + 1) * D], axis=0, keepdims=True)
+
+            def accumulate(k0, dq_h, cut, q=q, do=do, lse=lse, delta=delta,
+                           i=i):
+                at = pl.ds(k0, block_k)
+                k = k_ref[0, at]
+                sT = _dot(k, q, _NT)              # [block_k, block_q]
+                if not fold:
+                    sT = sT * sm_scale
+                pT = jnp.exp(sT - lse)
+                if cut:
+                    pT = jnp.where(_under_diagonal(i * block_q, k0,
+                                                   sT.shape, 1), pT, 0.0)
+                dv_acc[at] += _dot(pT.astype(do.dtype), do, _NN)
+                dpT = _dot(v_ref[0, at], do, _NT)
+                dsT = pT * (dpT - delta)
+                if not fold:
+                    dsT = dsT * sm_scale
+                dsT = dsT.astype(q.dtype)
+                dk_acc[at] += _dot(dsT, q, _NN)
+                return dq_h + _dot(dsT, k, _TN)   # [block_q, W]
+
+            dq_h = _walk_k_blocks(
+                accumulate, jnp.zeros((block_q, W), jnp.float32), causal,
+                i, block_q, block_k, S)
+            # dq_h holds dsT k of BOTH heads' keys; this head's lanes kept
+            dq = dq_h if dq is None else jnp.where(mine, dq_h, dq)
+        if fold:
+            dq = dq * sm_scale
+        dq_ref[0, rows] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _resident_specs(S, W, hp):
+    """(a [S, W] block of q/k/v/out and their gradients [B, S, H·D], a
+    block [hp, S] of the row statistics [B, H / hp, hp, S])."""
+    return (pl.BlockSpec((1, S, W), lambda b, g: (b, 0, g)),
+            pl.BlockSpec((1, 1, hp, S), lambda b, g: (b, g, 0, 0)))
+
+
+_RESIDENT_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=_RESIDENT_VMEM_LIMIT)
+
+
+# jitted and inlined, as `_paged_walk` is: the 24 layers of a program call
+# the kernels with the same shapes, and each body is traced once a program
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8), inline=True)
+def _resident_fwd(q, k, v, sm_scale, causal, block_q, block_k, D,
+                  interpret):
+    """q/k/v [B, S, H·D] -> (out [B, S, H·D], lse [B, H / hp, hp, S]):
+    a lane block of `_RESIDENT_LANE_HEADS[D]` heads a grid step."""
+    B, S, HD = q.shape
+    hp, W = _RESIDENT_LANE_HEADS[D], LANES
+    wide, rows = _resident_specs(S, W, hp)
+    return pl.pallas_call(
+        functools.partial(_resident_fwd_kernel, sm_scale=sm_scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          hp=hp),
+        grid=(B, HD // W),
+        in_specs=[wide, wide, wide],
+        out_specs=[wide, rows],
+        out_shape=[_out_struct((B, S, HD), q.dtype, q, k, v),
+                   _out_struct((B, HD // W, hp, S), jnp.float32, q, k, v)],
+        compiler_params=_RESIDENT_PARAMS, interpret=interpret,
+    )(q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11),
+                   inline=True)
+def _resident_bwd(q, k, v, out, do, lse, sm_scale, causal, block_q,
+                  block_k, D, interpret):
+    """(dq, dk, dv) [B, S, H·D] from the forward's out and lse
+    [B, H / hp, hp, S]."""
+    B, S, HD = q.shape
+    hp, W = _RESIDENT_LANE_HEADS[D], LANES
+    wide, rows = _resident_specs(S, W, hp)
+    return pl.pallas_call(
+        functools.partial(_resident_bwd_kernel, sm_scale=sm_scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          hp=hp),
+        grid=(B, HD // W),
+        in_specs=[wide, wide, wide, wide, wide, rows],
+        out_specs=[wide, wide, wide],
+        out_shape=[_out_struct((B, S, HD), x.dtype, q, k, v, do)
+                   for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((S, W), jnp.float32),    # dk
+                        pltpu.VMEM((S, W), jnp.float32)],   # dv
+        compiler_params=_RESIDENT_PARAMS, interpret=interpret,
+    )(q, k, v, out, do, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _resident_core(q, k, v, sm_scale, causal, block_q, block_k, D,
+                   interpret):
+    return _resident_fwd(q, k, v, sm_scale, causal, block_q, block_k, D,
+                         interpret)[0]
+
+
+def _resident_core_fwd(q, k, v, sm_scale, causal, block_q, block_k, D,
+                       interpret):
+    out, lse = _resident_fwd(q, k, v, sm_scale, causal, block_q, block_k,
+                             D, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _resident_core_bwd(sm_scale, causal, block_q, block_k, D, interpret,
+                       res, do):
+    q, k, v, out, lse = res
+    return _resident_bwd(q, k, v, out, do, lse, sm_scale, causal, block_q,
+                         block_k, D, interpret)
+
+
+_resident_core.defvjp(_resident_core_fwd, _resident_core_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The public op
+# ---------------------------------------------------------------------------
+
 def flash_attention(q, k, v, causal: bool = True,
                     mask=None,
                     block_q: Optional[int] = None,
@@ -555,19 +856,41 @@ def flash_attention(q, k, v, causal: bool = True,
     doesn't tile into Mosaic-legal blocks (ViT's S=197); either way the
     choice is reported through `note_traced("attention", ...)`.
 
-    block_q/block_k default to a per-seq-len policy measured on v5e
-    (gpt2-medium train step): 512 tiles up to seq 1024; 1024 tiles from
-    seq 2048 up — the bigger tiles cut grid steps that re-read q/lse and
-    buy +2pp MFU at 2048 and +4.6pp at 4096 (README long-context table).
-    2048-wide q tiles overflow VMEM; don't.
+    Two forms of the same algorithm, chosen from the operands alone and
+    reported through `note_traced("flash", ...)`:
+      resident[heads=N,q=..,k=..]  no key mask, heads that fill lane
+        tiles (128 wide, or an even count of 64), a sequence whose blocks
+        fit `_RESIDENT_VMEM_BUDGET` (2048 in bfloat16, 1024 in float32):
+        the kernels read and write [B, S, H·D] rows, N heads a lane
+        block, loops inside one grid step a block
+      streamed[q=..,k=..]  everything else that tiles: [B·H, S, D]
+        through a grid over (head, q block, k block)
+    block_q/block_k are the steps of either form. Where the caller names
+    none: resident 512 x 512, the largest measured (`_RESIDENT_STEPS`);
+    streamed 512 tiles up to seq 1024 and 1024 tiles from seq 2048 up
+    where they tile it. Measured on a v5e at the training cells' shape,
+    [8, 1024, 16, 64] bfloat16 causal, forward + backward a layer
+    (PERF.md section 6, PR 36): resident 0.35 + 0.70 ms; streamed 0.79 +
+    0.70 + 0.71 (2.52 before its products took bfloat16 operands and
+    its masks the cut blocks only); at [4, 2048, 16, 64] resident 1.85,
+    streamed 1024-tiles 3.06, 512-tiles 3.62. 2048-wide streamed q
+    tiles overflow VMEM; don't.
     """
     B, S, H, D = q.shape
     interpret = _resolve_interpret(interpret)
-    # 1024 tiles only when they tile S exactly — a 512-multiple like 2560
-    # must keep 512 tiles (flash), never fall through to the dense path
-    auto = 1024 if S >= 2048 and S % 1024 == 0 else 512
-    block_q = min(block_q or auto, S)
-    block_k = min(block_k or auto, S)
+    mesh = _kernel_mesh(q)
+    # the heads a device will hold decide the form and its steps
+    ways = 1 if mesh is None else _dividing_axes(mesh, H, _HEAD_AXES)[1]
+    hp = None if mask is not None else _resident_heads(
+        S, H // ways, D, q.dtype)
+    if hp is not None:
+        auto_q, auto_k = _RESIDENT_STEPS
+    else:
+        # 1024 tiles only when they tile S exactly — a 512-multiple like
+        # 2560 must keep 512 tiles (flash), never fall through to dense
+        auto_q = auto_k = 1024 if S >= 2048 and S % 1024 == 0 else 512
+    block_q = min(block_q or auto_q, S)
+    block_k = min(block_k or auto_k, S)
     unaligned = (S % block_q or S % block_k
                  or (not interpret and (block_q % 8 or block_k % 8)))
     if unaligned:
@@ -576,7 +899,6 @@ def flash_attention(q, k, v, causal: bool = True,
         return dense_attention(q, k, v, mask=mask, causal=causal,
                                dtype=q.dtype)
     note_traced("attention", "flash")
-    mesh = _kernel_mesh(q)
     if mesh is not None:
         # each device re-enters with its own rows / heads (inside the
         # manual region _kernel_mesh is None and the kernel below runs)
@@ -587,6 +909,16 @@ def flash_attention(q, k, v, causal: bool = True,
                 block_k=block_k, interpret=interpret),
             mesh, B, H, (q, k, v, mask), (qkv, qkv, qkv, ("rows", None)),
             qkv)
+    sm_scale = 1.0 / (D ** 0.5)
+    if hp is not None and (interpret or not (block_q % LANES
+                                             or block_k % 16)):
+        # (Mosaic: the transposed scores have block_q on their lanes)
+        note_traced("flash", f"resident[heads={hp},q={block_q},k={block_k}]")
+        out = _resident_core(
+            *(x.reshape(B, S, H * D) for x in (q, k, v)), sm_scale, causal,
+            block_q, block_k, D, interpret)
+        return out.reshape(B, S, H, D)
+    note_traced("flash", f"streamed[q={block_q},k={block_k}]")
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
@@ -597,7 +929,6 @@ def flash_attention(q, k, v, causal: bool = True,
         kv_mask = jnp.broadcast_to(
             mask.astype(jnp.float32)[:, None, :], (B, 8, S))
 
-    sm_scale = 1.0 / (D ** 0.5)
     out = _flash_core(to_bh(q), to_bh(k), to_bh(v), kv_mask, sm_scale,
                       causal, block_q, block_k, H, interpret)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)

@@ -655,3 +655,60 @@ def test_falcon_h1_prefill_bucket_fits_beside_what_the_chip_holds(
     assert m.alias_size_in_bytes >= held - 8.8e9
     assert m.temp_size_in_bytes < 2.4e9
     assert held + m.temp_size_in_bytes < 15.6e9
+
+
+@pytest.mark.parametrize("H,D,dtype,form,kernels", [
+    # the training cells' shape: pairs of 64-wide heads, resident
+    (16, 64, jnp.bfloat16, "resident[heads=2,q=512,k=512]", 2),
+    # heads that are a lane tile each
+    (8, 128, jnp.bfloat16, "resident[heads=1,q=512,k=512]", 2),
+    # float32 operands: 9.4 MB of blocks, the most the resident form takes
+    (16, 64, jnp.float32, "resident[heads=2,q=512,k=512]", 2),
+    # gpt2-xl's 25 heads: pairs do not divide them, the streamed form
+    (25, 64, jnp.bfloat16, "streamed[q=512,k=512]", 3),
+])
+def test_flash_attention_and_its_gradient_at_the_training_cells_shape(
+        H, D, dtype, form, kernels, one_chip, quiet_cache):
+    """Attention and its gradient (`jax.vjp`) over [8, 1024, H, D], causal:
+    bfloat16 as `LMTrainer`'s step calls it, and float32. Mosaic takes
+    the kernels. In the resident form they are TWO (forward; one
+    backward for dq, dk and dv), they read q, k, v and write out and
+    the gradients as [B, S, H·D] rows: no copy or transpose of an
+    operand stands beside them where the operands are BORN that way
+    (here: arguments [B, S, H·D], what a 2-D projection writes, seen
+    through the 4-D interface), and no row statistic 128 lanes wide
+    ([B·H, 1024, 128] float32, four times q) exists in the program. The
+    streamed form, which other shapes keep, has its three kernels, its
+    transposes and those arrays.
+
+    (A 4-D array [8, 1024, 16, 64] does NOT lie as [B, S, H·D] on the
+    chip: XLA tiles its two minor dims, (16, 64), and a projection with
+    two feature dims is lowered to a convolution over the heads whose
+    output has the sequence minor. Between either and ANY row-major
+    kernel operand the compiler puts a relayout copy; the model's
+    projections still pay those, PERF.md section 6, PR 36.)"""
+    from mpi_operator_tpu.ops.attention import flash_attention, record_traced
+    B, S = 8, 1024
+    x = jax.ShapeDtypeStruct((B, S, H * D), dtype, sharding=one_chip)
+
+    def attend(q, k, v, do):
+        # the vjp itself: a loss's reduction would bring a copy of its own
+        out, vjp = jax.vjp(lambda *qkv: flash_attention(
+            *(x.reshape(B, S, H, D) for x in qkv), causal=True,
+            interpret=False).reshape(B, S, H * D), q, k, v)
+        return (out,) + vjp(do)
+    with record_traced() as traced:
+        compiled = jax.jit(attend).lower(x, x, x, x).compile()
+    assert traced["flash"] == {form} and traced["attention"] == {"flash"}
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    operand_copies = _copies_of(text, (B, S, H, D), (B, H, S, D),
+                                (B * H, S, D), (B, S, H * D))
+    wide_rows = re.findall(rf"f32\[{B * H},{S},128\]", text)
+    if kernels == 2:
+        assert operand_copies == [] and wide_rows == []
+        # what the program holds beside its operands and results: the
+        # row statistics, 4 bytes a head and position, and `delta`
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    else:
+        assert operand_copies and wide_rows
