@@ -99,6 +99,7 @@ import numpy as np
 from ..models.generate import decode_model
 from ..telemetry import span
 from ..telemetry import events as ev
+from ..telemetry import spans
 from .programs import build_programs, cast_program
 from .scheduler import Request, RequestState, Scheduler
 from .slots import PageAllocator, SlotManager
@@ -476,6 +477,9 @@ class ServingEngine:
             # the step's span carries them and its sync is the one that waits
             self._prefill_queued = (0, 0)
             self._session = None   # open steppable session (start()/finish())
+            # the open spans of each request that came through submit():
+            # [its root, the phase it is in] (telemetry/spans.py, "a request")
+            self._request_spans: Dict[int, list] = {}
             # push-based load reporting (set_heartbeat): (hook, interval)
             self._heartbeat = None
             self._heartbeat_last: Optional[float] = None
@@ -563,6 +567,7 @@ class ServingEngine:
         self._session: Optional[Dict] = None
         self._session_span = None
         self._trace_now = None
+        self._request_spans = {}
         self.occupancy_peak = 0
         self.pages_in_use_peak = 0
         self.spec_proposed = 0
@@ -659,6 +664,27 @@ class ServingEngine:
             self._session_span.abandon(now)
             self._session_span = None
         self._trace_now = None
+
+    def _request_phase(self, rid: int, name: Optional[str], **attrs):
+        """Close request `rid`'s open phase and, at the same instant, open
+        `name` with `attrs` (None: close its root instead: the request
+        is over). Returns the span that closed, for its last attributes;
+        None for a request that did not come through `submit()`."""
+        entry = self._request_spans.get(rid)
+        if entry is None:
+            return None
+        root, phase = entry
+        # (a session clock that is not the wall's, a test's or a replay's,
+        # may have put an arrival, and so the start, ahead of now)
+        now_ns = max(time.perf_counter_ns(), phase.start_ns)
+        phase.end(now_ns)
+        if name is None:
+            del self._request_spans[rid]
+            root.end(now_ns)
+        else:
+            entry[1] = spans.begin(name, parent=root.id, start_ns=now_ns,
+                                   request=rid, **attrs)
+        return phase
 
     # -- the loop ---------------------------------------------------------
 
@@ -943,6 +969,8 @@ class ServingEngine:
                 self.spec_tokens += emit
                 if tel is not None:
                     tel.spec_tokens_per_step.observe(emit)
+                if not st.token_times:
+                    self._request_phase(st.req.id, "request.decode")
                 for j in range(emit):
                     t = int(row_t[j])
                     if tel is not None:
@@ -1019,6 +1047,8 @@ class ServingEngine:
                 if st.done:
                     continue
                 t = int(out_tok[st.slot])
+                if not st.token_times:
+                    self._request_phase(st.req.id, "request.decode")
                 if tel is not None:
                     if st.token_times:
                         tel.tpot_seconds.observe(now - st.token_times[-1])
@@ -1052,6 +1082,14 @@ class ServingEngine:
             if timeout is not None:
                 st.deadline = st.admitted_at + timeout
             self.slots.bind(st)
+            blocked_on = self.scheduler.blocked_on.pop(st.req.id, "none")
+            queued = self._request_phase(
+                st.req.id, "request.prefill", calls=len(st.chunks),
+                cached_tokens=st.cached_tokens)
+            if queued is not None:
+                queued.attrs["blocked_on"] = blocked_on
+                self._request_spans[st.req.id][0].set(
+                    pages_reserved=len(st.owned_pages))
             rt = self._trace(st.req.id)
             if rt is not None:
                 # admission hop ends where the scheduler stamped it; a
@@ -1089,6 +1127,11 @@ class ServingEngine:
         for p in st.owned_pages:
             alloc.release(p)
         st.owned_pages = []
+        entry = self._request_spans.get(st.req.id)
+        if entry is not None:
+            entry[0].set(tokens=len(st.generated),
+                         finish_reason=st.finish_reason)
+            self._request_phase(st.req.id, None)
         if self.events is not None:
             self.events.emit(
                 ev.SLOT_RETIRE, request=st.req.id, slot=st.slot,
@@ -1211,6 +1254,16 @@ class ServingEngine:
                 f"pages but the pool has {alloc.usable} usable "
                 f"(raise num_pages or lower max_new_tokens)")
         self.scheduler.submit(req)
+        # the request enters the span log: its root and its first phase
+        # begin now, or at its arrival where a replayed trace puts that
+        # in the future
+        start_ns = time.perf_counter_ns() + max(0, int(
+            1e9 * (req.arrival - self._session["now_fn"]())))
+        root = spans.begin("request", start_ns=start_ns, request=req.id,
+                           prompt_len=len(req.prompt))
+        self._request_spans[req.id] = [root, spans.begin(
+            "request.queued", parent=root.id, start_ns=start_ns,
+            request=req.id)]
         if self.tracer is not None:
             # open (or, behind a router / on a failover replay, JOIN)
             # this request's trace — the router's queue-wait hop closes
@@ -1221,6 +1274,17 @@ class ServingEngine:
             if rt is not None:
                 rt.begin_hop("serve.admission",
                              max(req.arrival, self._session["now_fn"]()))
+
+    def withdraw(self, req: Request) -> None:
+        """Take a request that still waits for admission back out of the
+        session: the router's drain re-routes it to a survivor. Its
+        records close here, `finish_reason` "withdrawn"."""
+        blocked_on = self.scheduler.withdraw(req)
+        entry = self._request_spans.get(req.id)
+        if entry is not None:
+            entry[1].set(blocked_on=blocked_on)
+            entry[0].set(tokens=0, finish_reason="withdrawn")
+            self._request_phase(req.id, None)
 
     @property
     def active(self) -> bool:
@@ -1247,12 +1311,17 @@ class ServingEngine:
             on_token = sess["on_token"]
             results = sess["results"]
             now = now_fn()
-            with span("serve.schedule"):
+            with span("serve.schedule") as sched:
                 # deadline sweep FIRST: a wedged head-of-queue request frees
                 # its slot before this iteration's admission fills the rows
                 self._sweep_timeouts(now, results)
                 self._note_admissions(
                     self.scheduler.admit(self.slots.free, now, alloc))
+                reserved, filled = self.scheduler.page_counts(
+                    alloc.page_size)
+                sched.set(blocked=self.scheduler.blocked,
+                          waiting=self.scheduler.waiting,
+                          pages_reserved=reserved, pages_filled=filled)
             self.occupancy_peak = max(self.occupancy_peak,
                                       self.slots.occupied)
             self.pages_in_use_peak = max(self.pages_in_use_peak,
@@ -1333,6 +1402,8 @@ class ServingEngine:
             self._session_span = None
         self._trace_now = None
         self._session = None
+        # a request the session leaves unfinished leaves no record
+        self._request_spans = {}
         return sess["results"]
 
     def run(self, requests: Sequence[Request] = (),

@@ -462,10 +462,10 @@ class Router:
         # pull back everything still queued behind the slots: those
         # requests never touched pages, so re-routing them is pure
         # bookkeeping — the same fresh-Request replay failover uses
-        queue = rep.engine.scheduler.queue
-        pulled = [q for q in list(queue) if q.id in rep.inflight]
+        pulled = [q for q in list(rep.engine.scheduler.queue)
+                  if q.id in rep.inflight]
         for q in pulled:
-            queue.remove(q)
+            rep.engine.withdraw(q)
             del rep.inflight[q.id]
             replay = Request(
                 id=q.id, prompt=list(q.prompt),

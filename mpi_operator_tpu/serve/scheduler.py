@@ -214,6 +214,15 @@ class Scheduler:
         # Dies with the scheduler (engine reset() also resets the
         # allocator, so no pins leak).
         self.staged: Dict[int, Tuple[List[int], List[int], List[int]]] = {}
+        # why the last `admit` stopped with arrived requests still queued
+        # ("slot", "pages", "gate"; "none" where nobody waits), how many
+        # it left waiting, and per queued request what its last failed
+        # attempt lacked: the engine writes them into the span log
+        # (`serve.schedule`'s `blocked` and `waiting`, `request.queued`'s
+        # `blocked_on`)
+        self.blocked = "none"
+        self.waiting = 0
+        self.blocked_on: Dict[int, str] = {}
 
     def submit(self, req: Request) -> None:
         p = len(req.prompt)
@@ -234,6 +243,12 @@ class Scheduler:
             self.queue = deque(items)
         else:
             self.queue.append(req)
+
+    def withdraw(self, req: Request) -> str:
+        """Take a queued request back out; returns what its last failed
+        attempt at admission lacked ("none" where none failed)."""
+        self.queue.remove(req)
+        return self.blocked_on.pop(req.id, "none")
 
     def next_arrival(self) -> Optional[float]:
         return self.queue[0].arrival if self.queue else None
@@ -294,14 +309,21 @@ class Scheduler:
         `self.staged`. Two wins: the reservation pins their cached
         prefix chains before decode-side allocations can evict them, and
         the moment a slot frees the head admits instantly — no
-        reservation work on that step's critical path."""
+        reservation work on that step's critical path.
+
+        Why it stopped is left in `self.blocked`, what each request it
+        tried and could not place lacked in `self.blocked_on`, and the
+        arrived requests it leaves queued in `self.waiting`."""
         out = []
+        self.blocked = "none"
         while free_slots and self.queue and self.queue[0].arrival <= now:
             picked = None
+            lacked = "gate"               # unless some request lacked pages
             for idx, req in enumerate(self.queue):
                 if idx >= self.admit_lookahead or req.arrival > now:
                     break
                 if self.gate is not None and not self.gate(req):
+                    self.blocked_on[req.id] = "gate"
                     continue              # backpressured; let others try
                 reserved = self.staged.pop(req.id, None)
                 if reserved is None:
@@ -309,7 +331,9 @@ class Scheduler:
                 if reserved is not None:
                     picked = (idx, req, reserved)
                     break
+                self.blocked_on[req.id] = lacked = "pages"
             if picked is None:
+                self.blocked = lacked
                 break
             idx, req, reserved = picked
             del self.queue[idx]
@@ -332,6 +356,7 @@ class Scheduler:
             for idx, req in enumerate(self.queue):
                 if idx >= self.admit_lookahead or req.arrival > now:
                     break
+                self.blocked = self.blocked_on[req.id] = "slot"
                 if req.id in self.staged:
                     continue
                 if self.gate is not None and not self.gate(req):
@@ -339,6 +364,11 @@ class Scheduler:
                 reserved = self._reserve_pages(req, allocator)
                 if reserved is not None:
                     self.staged[req.id] = reserved
+        self.waiting = 0
+        for req in self.queue:            # sorted by arrival
+            if req.arrival > now:
+                break
+            self.waiting += 1
         return out
 
     def next_prefill(self) -> Optional[RequestState]:
@@ -352,6 +382,23 @@ class Scheduler:
 
     def retire(self, st: RequestState) -> None:
         self.active.remove(st)
+
+    def page_counts(self, page_size: int) -> Tuple[int, int]:
+        """(reserved, filled): the pages that admitted and staged requests
+        hold, and those of them with at least one written position (a
+        request's first ceil(pos / page_size); a staged request's cached
+        chain). One pass over the active rows; a page that two requests
+        share through the prefix cache counts once for each."""
+        reserved = filled = 0
+        for st in self.active:
+            n = len(st.owned_pages)
+            written = -(-st.pos // page_size)
+            reserved += n
+            filled += written if written < n else n
+        for chain, private, _ in self.staged.values():
+            reserved += len(chain) + len(private)
+            filled += len(chain)
+        return reserved, filled
 
     @property
     def idle(self) -> bool:

@@ -26,12 +26,21 @@ A record (`Span`):
   name, attrs attributes may be set until the span closes (`set`)
   start_ns, end_ns   on `time.perf_counter_ns()`
   in_capture  whether a profiler capture was running when it opened
-  thread      `threading.get_ident()` of the thread that opened it
+  thread      `threading.get_ident()` of the thread that opened it; 0 for
+              a span that lives across calls (`begin` ... `Span.end`): it
+              belongs to no thread's stack, nests under no tick, and is
+              in_capture by the moment it ENDED, as JAX's records are
 
 The trees the program records:
 
   a serving tick (serve/engine.py `tick()`; one `serve.tick` a worked tick)
-    serve.schedule     timeouts, admission
+    serve.schedule     timeouts, admission    blocked: why admission
+                       stopped (`none`: nobody waits; `slot`; `pages`: a
+                       free slot and no span of pages for any request
+                       within the lookahead; `gate`), waiting: the
+                       arrived requests it left queued, pages_reserved:
+                       the pages that admitted and staged requests hold,
+                       pages_filled: those of them with a written position
     serve.prefill      one prefill call's dispatch, arrays built
                        state_rows (a model that keeps state a slot): the
                        rows whose chunk started at 0, and so from zeros
@@ -41,8 +50,35 @@ The trees the program records:
     serve.sync         the blocking token fetch   caused_by = the dispatch
     serve.retire       tokens streamed, requests retired
 
+  a request (serve/engine.py; begun in one tick and ended in another, so
+  thread 0; every record carries request=<id>; a phase is recorded when
+  it closes, and the three are contiguous and sum to the root)
+    request            `submit` (its arrival, if later) -> retirement
+                       prompt_len, tokens, finish_reason, pages_reserved
+    request.queued     the same start -> admission     blocked_on: what
+                       held it at its last failed attempt (`slot`,
+                       `pages`, `gate`; `none` where none failed)
+    request.prefill    admission -> its first token on the host
+                       calls: the prefill calls its prompt was planned
+                       into, cached_tokens
+    request.decode     first token -> retirement
+  A request that times out before its first token leaves no
+  `request.decode`; one the router's drain takes back out of the queue
+  (`ServingEngine.withdraw`) closes as `withdrawn`; one still open when
+  the session closes (`finish`) leaves nothing. The disaggregated
+  facade's requests never pass `ServingEngine.submit` and leave none of
+  these.
+
+  anywhere
+    py.gc              a collection of Python's cyclic collector that
+                       took `GC_MIN_NS` or longer, on the thread it ran
+                       on, under whatever span was open    generation,
+                       collected. A shorter one leaves nothing.
+
   An attribute is kept only where something reads it (PERF.md section 3
-  names the reader of each); the counts a tick could carry are
+  names the reader of each: a request's and a phase's are the log lines of
+  `perfbench/readers/request_phase_ms_percentile.py`, a collection's
+  those of `engine_stall_ms.py`); the counts a tick could carry are
   `ServeTelemetry`'s gauges already — and so are the counts a decode step
   of the served model makes for itself: an expert layer's
   `moe_held_picks`, `moe_identity_picks`, `moe_load_max`, summed over the
@@ -93,6 +129,7 @@ The trees the program records:
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import sys
 import threading
@@ -101,6 +138,9 @@ from typing import Any, Dict, List, Optional
 
 #: records kept; the oldest fall out
 LOG_BOUND = 100_000
+
+#: a collection of the cyclic collector shorter than this leaves no record
+GC_MIN_NS = 1_000_000
 
 _LOG: "collections.deque[Span]" = collections.deque(maxlen=LOG_BOUND)
 _ids = itertools.count(1)
@@ -164,6 +204,14 @@ class Span:
     def duration_ns(self) -> int:
         return self.end_ns - self.start_ns
 
+    def end(self, end_ns: Optional[int] = None, **attrs) -> None:
+        """Close a span that `begin` opened, in whatever call and on
+        whatever thread: the record goes into the log now."""
+        self.attrs.update(attrs)
+        self.end_ns = time.perf_counter_ns() if end_ns is None else end_ns
+        self.in_capture = _capturing()
+        _LOG.append(self)
+
     def __enter__(self) -> "Span":
         stack = _stack()
         self.parent = stack[-1].id if stack else None
@@ -198,6 +246,40 @@ class Span:
                 f"{self.duration_ns / 1e6:.3f} ms {self.attrs})")
 
 
+def _capturing() -> bool:
+    return _TraceAnnotation is not None and _TraceAnnotation.is_enabled()
+
+
+def _closed(name: str, attrs: Dict[str, Any], duration_ns: int) -> None:
+    """A region of this thread that ended just now and was not a `with`
+    block (JAX reports a phase when it is over, the collector calls back
+    at both ends): a closed record under whatever span is open here."""
+    rec = Span(name, None, attrs)
+    stack = _stack()
+    rec.parent = stack[-1].id if stack else None
+    rec.thread = threading.get_ident()
+    rec.in_capture = _capturing()
+    rec.end_ns = time.perf_counter_ns()
+    rec.start_ns = rec.end_ns - duration_ns
+    _LOG.append(rec)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """`gc.callbacks` hook: a collection that took `GC_MIN_NS` or longer
+    becomes a `py.gc` record. The young collections, many a tick, cost
+    two clock reads each and leave nothing."""
+    if phase == "start":
+        _open.gc_start_ns = time.perf_counter_ns()
+        return
+    took = time.perf_counter_ns() - getattr(_open, "gc_start_ns", 1 << 62)
+    if took >= GC_MIN_NS:
+        _closed("py.gc", {"generation": info["generation"],
+                          "collected": info["collected"]}, took)
+
+
+gc.callbacks.append(_on_gc)
+
+
 def _on_jax_duration(event: str, duration: float, **kw) -> None:
     """jax.monitoring listener: a phase of JAX's that just ended becomes a
     closed span under whatever program span is open on this thread. A
@@ -214,14 +296,7 @@ def _on_jax_duration(event: str, duration: float, **kw) -> None:
     if name == "jax.compile" and getattr(_open, "cache_hit", False):
         _open.cache_hit = False
         name = "jax.cache_load"
-    rec = Span(name, None, attrs)
-    stack = _stack()
-    rec.parent = stack[-1].id if stack else None
-    rec.thread = threading.get_ident()
-    rec.in_capture = _TraceAnnotation.is_enabled()
-    rec.end_ns = time.perf_counter_ns()
-    rec.start_ns = rec.end_ns - int(duration * 1e9)
-    _LOG.append(rec)
+    _closed(name, attrs, int(duration * 1e9))
 
 
 def _resolve() -> None:
@@ -246,6 +321,20 @@ def span(name: str, caused_by: Optional[int] = None, **attrs) -> Span:
     return Span(name, caused_by, attrs)
 
 
+def begin(name: str, parent: Optional[int] = None,
+          caused_by: Optional[int] = None, start_ns: Optional[int] = None,
+          **attrs) -> Span:
+    """Open a span that outlives the call: a request that waits through
+    many ticks. It is on no thread's stack (thread 0, no annotation), so
+    nothing nests under it by itself: name its `parent` by id. Nothing is
+    recorded until `Span.end`; dropping a span it closed under does not
+    take it along."""
+    rec = Span(name, caused_by, attrs)
+    rec.parent = parent
+    rec.start_ns = time.perf_counter_ns() if start_ns is None else start_ns
+    return rec
+
+
 def records() -> List[Span]:
     """A snapshot of the closed spans, oldest first."""
     return list(_LOG.copy())      # copy() is atomic; iterating _LOG is not
@@ -261,4 +350,5 @@ if sys.modules.get("jax") is not None:
     # may be before the program's first span
     _resolve()
 
-__all__ = ["LOG_BOUND", "Span", "clear", "records", "span"]
+__all__ = ["GC_MIN_NS", "LOG_BOUND", "Span", "begin", "clear", "records",
+           "span"]

@@ -299,8 +299,10 @@ class ServeTelemetry:
             "tpu_worker_tpot_seconds", "inter-token gap per slot")
         self.prefill_seconds = hist(
             "tpu_worker_prefill_seconds",
-            "prefill chunk host DISPATCH time (async enqueue; not the "
-            "call's device time, which the next decode sync absorbs)")
+            "host time of one prefill call's DISPATCH (an asynchronous "
+            "enqueue: array building and the jit call), NOT the call's "
+            "device time, which the next decode step's sync absorbs "
+            "(span serve.sync behind a dispatch with prefill_rows > 0)")
         self.decode_step_seconds = hist(
             "tpu_worker_decode_step_seconds",
             "decode step wall time, dispatch to token sync")

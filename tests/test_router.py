@@ -281,6 +281,9 @@ class _QueueingEngine(_FakeEngine):
         self.submitted.append(req.id)
         self.scheduler.queue.append(req)
 
+    def withdraw(self, req):
+        self.scheduler.queue.remove(req)
+
     @property
     def active(self):
         return bool(self._work or self.scheduler.queue)

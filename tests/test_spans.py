@@ -146,7 +146,14 @@ def test_two_threads_do_not_share_a_stack():
     assert recs["other.outer"].thread != recs["main.outer"].thread
 
 
-def test_many_threads_lose_no_record_and_share_no_id():
+@pytest.fixture
+def no_gc_records(monkeypatch):
+    """For the tests that count the log: a collection that happened to
+    take a millisecond would be one record more."""
+    monkeypatch.setattr(spans, "GC_MIN_NS", 10**15)
+
+
+def test_many_threads_lose_no_record_and_share_no_id(no_gc_records):
     """More threads than cores, a short switch interval: every span of
     every thread is in the log once, and nests under its own thread's."""
     workers, each = 24, 400
@@ -177,7 +184,7 @@ def test_many_threads_lose_no_record_and_share_no_id():
             assert by_id[r.parent].thread == r.thread
 
 
-def test_the_log_is_bounded_and_keeps_the_newest():
+def test_the_log_is_bounded_and_keeps_the_newest(no_gc_records):
     extra = 50
     for i in range(spans.LOG_BOUND + extra):
         with span("s", i=i):
@@ -196,6 +203,110 @@ def test_records_is_a_snapshot():
         pass
     assert [r.name for r in snap] == ["a"]
     assert [r.name for r in spans.records()] == ["a", "b"]
+
+
+# -- spans that live across calls --------------------------------------------
+
+def test_a_begun_and_ended_record_is_on_no_thread_and_keeps_its_links():
+    with span("dispatch") as dispatch:
+        pass
+    root = spans.begin("request", request=7, prompt_len=12)
+    with span("tick.one"):
+        phase = spans.begin("request.queued", parent=root.id,
+                            caused_by=dispatch.id, request=7)
+    assert [r.name for r in spans.records()] == ["dispatch", "tick.one"]
+    with span("tick.two") as two:
+        phase.end(blocked_on="pages")
+        root.set(tokens=3)
+        root.end()
+    recs = {r.name: r for r in spans.records()}
+    assert [r.name for r in spans.records()] == [
+        "dispatch", "tick.one", "request.queued", "request", "tick.two"]
+    queued, req = recs["request.queued"], recs["request"]
+    assert queued.thread == 0 and req.thread == 0
+    assert queued.parent == req.id and req.parent is None
+    assert queued.caused_by == dispatch.id
+    assert queued.attrs == {"request": 7, "blocked_on": "pages"}
+    assert req.attrs == {"request": 7, "prompt_len": 12, "tokens": 3}
+    assert req.start_ns <= queued.start_ns <= queued.end_ns <= req.end_ns
+    assert two.start_ns <= queued.end_ns <= two.end_ns
+    assert queued.in_capture is False and req.id < queued.id < two.id
+
+
+def test_begin_and_end_take_the_callers_instants():
+    rec = spans.begin("request", start_ns=1_000, request=1)
+    rec.end(end_ns=4_500)
+    (got,) = spans.records()
+    assert (got.start_ns, got.end_ns, got.duration_ns) == (1_000, 4_500, 3_500)
+
+
+def test_a_record_that_ended_under_a_dropped_span_stays():
+    """A request may be retired (a timeout) in a tick that then finds
+    nothing to do and is dropped: the tick's own children go, the
+    request's records stay, in order."""
+    req = spans.begin("request", request=1)
+    with span("before"):
+        pass
+    with span("idle.tick") as tick:
+        with span("child"):
+            pass
+        req.end(finish_reason="timeout")
+        with span("child"):
+            pass
+        tick.drop()
+    assert [(r.name, r.thread == 0) for r in spans.records()] == [
+        ("before", False), ("request", True)]
+
+
+def test_a_record_on_no_thread_is_invisible_to_a_threads_nesting():
+    """`self_times` nests one thread's spans by time; a request that
+    lives across the ticks it overlaps takes nothing from them."""
+    from perfbench.readers import _spans
+    req = spans.begin("request", request=1)
+    with span("serve.tick") as tick:
+        with span("serve.sync") as sync:
+            pass
+    req.end()
+    mine = [r for r in spans.records() if r.thread == tick.thread]
+    own, root = _spans.self_times(mine)
+    assert set(own) == {tick.id, sync.id} and root[sync.id] == tick.id
+    assert own[tick.id] == tick.duration_ns - sync.duration_ns
+    # and with it in the pile it is a thread of its own, not a parent
+    own_all, root_all = _spans.self_times(spans.records())
+    assert own_all[tick.id] == own[tick.id] and root_all[tick.id] == tick.id
+    assert own_all[req.id] == req.duration_ns
+
+
+# -- the collector -----------------------------------------------------------
+
+def _collect(monkeypatch, floor_ns):
+    import gc
+    monkeypatch.setattr(spans, "GC_MIN_NS", floor_ns)
+    junk = []
+    for _ in range(2000):
+        a, b = [], []
+        a.append(b)
+        b.append(a)
+        junk.append(a)
+    del junk, a, b
+    with span("serve.tick") as tick:
+        gc.collect()
+    return tick
+
+
+def test_a_long_collection_leaves_py_gc_under_the_open_span(monkeypatch):
+    tick = _collect(monkeypatch, 1)           # every collection is "long"
+    (rec,) = [r for r in by_name("py.gc") if r.parent == tick.id]
+    assert rec.thread == threading.get_ident()
+    assert rec.attrs["generation"] == 2 and rec.attrs["collected"] >= 4000
+    assert tick.start_ns <= rec.start_ns < rec.end_ns <= tick.end_ns
+
+
+def test_a_short_collection_leaves_nothing(monkeypatch):
+    _collect(monkeypatch, 10**12)             # none takes a quarter hour
+    assert by_name("py.gc") == []
+    assert spans.GC_MIN_NS == 10**12 and spans._on_gc in __import__(
+        "gc").callbacks
 
 
 # -- JAX's own phases --------------------------------------------------------
@@ -390,8 +501,12 @@ def test_every_worked_tick_is_a_tree_in_order_and_syncs_name_dispatches(
     # only what a reader consumes is carried (PERF.md section 3)
     assert all(set(d.attrs) == {"prefill_rows", "prefill_bucket"}
                for d in dispatches)
+    assert all(set(r.attrs) == {"blocked", "waiting", "pages_reserved",
+                                "pages_filled"}
+               for r in by_name("serve.schedule"))
     assert not any(r.attrs for name in TICK_ORDER + ["serve.tick"]
-                   if name != "serve.decode_step" for r in by_name(name))
+                   if name not in ("serve.decode_step", "serve.schedule")
+                   for r in by_name(name))
 
     # a prefill call is queued on the device ahead of the next dispatch,
     # and that dispatch's span says so: how many rows' chunks, how wide
@@ -452,6 +567,215 @@ def test_a_verify_step_is_named_by_its_sync_too(toy):
     assert {v.id for v in verifies} <= causes
     assert causes <= {r.id for r in verifies + by_name("serve.decode_step")}
     assert len(tel.host_gap_seconds.values) == len(by_name("serve.sync"))
+
+
+# -- the request in the log --------------------------------------------------
+
+def _small_pool_engine(toy, **kw):
+    """Four slots and seven usable pages of 16: a request of this mix
+    needs 2-4, so pages and not slots hold the queue."""
+    from mpi_operator_tpu.serve import EngineConfig, ServingEngine
+    model, params = toy
+    return ServingEngine(model, params, EngineConfig(
+        slots=4, chunk_buckets=(8, 16), page_size=16, num_pages=8, **kw))
+
+
+def _long_requests(n=6):
+    from mpi_operator_tpu.serve import Request
+    rng = np.random.default_rng(0)
+    return [Request(id=i, prompt=rng.integers(0, 250, 10 + 3 * i).tolist(),
+                    max_new_tokens=20 + i) for i in range(n)]
+
+
+PHASES = ["request.queued", "request.prefill", "request.decode"]
+
+
+@pytest.fixture
+def served(toy):
+    eng = _small_pool_engine(toy)
+    spans.clear()
+    results = eng.run(_long_requests())
+    return eng, results
+
+
+def test_every_finished_request_is_a_root_and_three_phases_that_sum(served):
+    _, results = served
+    roots = {r.attrs["request"]: r for r in by_name("request")}
+    assert sorted(roots) == sorted(results) == list(range(6))
+    for rid, root in roots.items():
+        kids = [r for r in spans.records()
+                if r.parent == root.id and r.name in PHASES]
+        assert [k.name for k in sorted(kids, key=lambda r: r.start_ns)] \
+            == PHASES
+        assert all(k.attrs["request"] == rid and k.thread == 0
+                   for k in kids)
+        queued, prefill, decode = sorted(kids, key=lambda r: r.start_ns)
+        assert queued.start_ns == root.start_ns
+        assert queued.end_ns == prefill.start_ns
+        assert prefill.end_ns == decode.start_ns
+        assert decode.end_ns == root.end_ns
+        assert sum(k.duration_ns for k in kids) == root.duration_ns
+        assert root.thread == 0 and root.parent is None
+
+
+def test_the_roots_and_phases_carry_what_the_readers_take(served):
+    from mpi_operator_tpu.serve.scheduler import plan_chunks
+    _, results = served
+    reqs = {r.id: r for r in _long_requests()}
+    for root in by_name("request"):
+        rid = root.attrs["request"]
+        assert root.attrs == {
+            "request": rid, "prompt_len": len(reqs[rid].prompt),
+            "pages_reserved": (len(reqs[rid].prompt) - 2
+                               + reqs[rid].max_new_tokens) // 16 + 1,
+            "tokens": len(results[rid].tokens), "finish_reason": "length"}
+    for r in by_name("request.prefill"):
+        rid = r.attrs["request"]
+        assert r.attrs == {"request": rid, "cached_tokens": 0, "calls": len(
+            plan_chunks(len(reqs[rid].prompt) - 1, (8, 16)))}
+    # a decode phase carries its request alone: the tokens are the root's
+    assert all(set(r.attrs) == {"request"} for r in by_name("request.decode"))
+
+
+def test_the_requests_that_waited_say_they_waited_for_pages(served):
+    queued = {r.attrs["request"]: r for r in by_name("request.queued")}
+    assert all(set(r.attrs) == {"request", "blocked_on"}
+               for r in queued.values())
+    held = {rid: r.attrs["blocked_on"] for rid, r in queued.items()}
+    # the pool takes the first two (2 + 3 of 7 pages); four slots never
+    # fill, so whoever waits, waits for pages
+    assert held[0] == held[1] == "none"
+    assert {held[i] for i in range(2, 6)} == {"pages"}
+    first_out = min(r.end_ns for r in by_name("request"))
+    for rid in range(2, 6):
+        assert queued[rid].end_ns >= first_out       # not before pages freed
+        assert queued[rid].duration_ns > 100 * queued[0].duration_ns
+
+
+def test_serve_schedule_says_why_admission_stopped_and_counts_pages(served):
+    eng, _ = served
+    sched = by_name("serve.schedule")
+    assert {r.attrs["blocked"] for r in sched} == {"none", "pages"}
+    for r in sched:
+        assert 0 <= r.attrs["pages_filled"] <= r.attrs["pages_reserved"] <= 7
+    assert max(r.attrs["pages_filled"] for r in sched) > 0
+    # while somebody waits for pages something is reserved, and a slot free
+    for r in sched:
+        if r.attrs["blocked"] == "pages":
+            assert r.attrs["pages_reserved"] >= 4
+    # who waits is counted after the tick's admissions: all six are sent
+    # before the first tick, which admits two
+    assert [r.attrs["waiting"] for r in sched][0] == 4
+    assert [r.attrs["waiting"] for r in sched][-1] == 0
+    assert all(r.attrs["waiting"] > 0 for r in sched
+               if r.attrs["blocked"] == "pages")
+    # when the last request has gone nothing is held
+    assert eng.scheduler.page_counts(16) == (0, 0)
+    assert eng._request_spans == {} and eng.scheduler.blocked_on == {}
+
+
+def test_admission_blocked_on_slots_says_slot(toy):
+    from mpi_operator_tpu.serve import EngineConfig, ServingEngine
+    model, params = toy
+    eng = ServingEngine(model, params, EngineConfig(
+        slots=2, chunk_buckets=(8, 16), page_size=16, num_pages=40))
+    spans.clear()
+    eng.run(_long_requests(4))
+    held = [r.attrs["blocked_on"] for r in by_name("request.queued")]
+    assert sorted(held) == ["none", "none", "slot", "slot"]
+    assert {r.attrs["blocked"] for r in by_name("serve.schedule")} == {
+        "none", "slot"}
+
+
+def test_a_gated_request_says_gate(toy):
+    eng = _small_pool_engine(toy)
+    spans.clear()
+    ticks = {"n": 0}
+    eng.scheduler.gate = lambda req: ticks["n"] > 3
+    eng.start()
+    for r in _long_requests(1):
+        eng.submit(r)
+    while eng.active:
+        ticks["n"] += 1
+        eng.tick()
+        if ticks["n"] > 500:
+            break
+    eng.finish()
+    (queued,) = by_name("request.queued")
+    assert queued.attrs["blocked_on"] == "gate"
+
+
+def test_a_withdrawn_request_closes_its_records_and_leaves_nothing(toy):
+    """The router's drain takes a queued request back out: its root and
+    its wait close as `withdrawn`, and neither the engine nor the scheduler
+    keeps anything of it. A request still open at `finish` leaves none."""
+    eng = _small_pool_engine(toy)
+    spans.clear()
+    reqs = _long_requests()
+    eng.start()
+    for r in reqs:
+        eng.submit(r)
+    eng.tick()                                # two admitted, four wait
+    assert eng.scheduler.blocked_on[5] == "pages"
+    eng.withdraw(reqs[5])
+    assert reqs[5] not in eng.scheduler.queue
+    assert 5 not in eng.scheduler.blocked_on and 5 not in eng._request_spans
+    (root,) = by_name("request")
+    (queued,) = by_name("request.queued")[-1:]
+    assert root.attrs == {"request": 5, "prompt_len": len(reqs[5].prompt),
+                          "tokens": 0, "finish_reason": "withdrawn"}
+    assert queued.attrs == {"request": 5, "blocked_on": "pages"}
+    assert queued.parent == root.id
+    assert queued.duration_ns == root.duration_ns
+    assert len(eng._request_spans) == 5
+    eng.finish()                              # five still open
+    assert eng._request_spans == {}
+    assert [r.attrs["request"] for r in by_name("request")] == [5]
+
+
+def test_a_timed_out_request_closes_with_its_finish_reason(toy):
+    from mpi_operator_tpu.serve import Request
+    eng = _small_pool_engine(toy, request_timeout=0.5)
+    spans.clear()
+    clock = {"now": 0.0}
+    eng.start(now_fn=lambda: clock["now"])
+    eng.submit(Request(id=9, prompt=list(range(1, 12)), max_new_tokens=40))
+    for _ in range(6):
+        eng.tick()
+    clock["now"] = 1.0                        # past its deadline
+    while eng.active:
+        eng.tick()
+    results = eng.finish()
+    assert results[9].finish_reason == "timeout"
+    (root,) = by_name("request")
+    assert root.attrs["finish_reason"] == "timeout"
+    assert root.attrs["tokens"] == len(results[9].tokens) > 0
+    kids = sorted((r for r in spans.records() if r.parent == root.id),
+                  key=lambda r: r.start_ns)
+    assert [k.name for k in kids] == PHASES
+    assert sum(k.duration_ns for k in kids) == root.duration_ns
+    assert eng._request_spans == {}
+
+
+def test_a_request_that_times_out_before_its_first_token_has_no_decode(toy):
+    from mpi_operator_tpu.serve import Request
+    eng = _small_pool_engine(toy, request_timeout=0.5)
+    spans.clear()
+    clock = {"now": 0.0}
+    eng.start(now_fn=lambda: clock["now"])
+    eng.submit(Request(id=3, prompt=list(range(1, 60)), max_new_tokens=8))
+    eng.tick()                                # admitted, first chunk only
+    clock["now"] = 1.0
+    while eng.active:
+        eng.tick()
+    eng.finish()
+    (root,) = by_name("request")
+    kids = sorted((r for r in spans.records() if r.parent == root.id),
+                  key=lambda r: r.start_ns)
+    assert [k.name for k in kids] == PHASES[:2]
+    assert root.attrs["finish_reason"] == "timeout"
+    assert root.attrs["tokens"] == 0
+    assert sum(k.duration_ns for k in kids) == root.duration_ns
 
 
 # -- the trainer -------------------------------------------------------------
