@@ -208,6 +208,7 @@ def check_trainer(head, device) -> dict:
              f"{head.get('batch_device_ids')}; expected all of {ids}")
     return {k: head[k] for k in (
         "value", "final_loss", "mfu", "compile_seconds", "step_compiles",
+        "grad_reductions", "grad_reductions_async",
         "step_time_p50_ms", "attention_impl", "device_bytes_in_use")}
 
 

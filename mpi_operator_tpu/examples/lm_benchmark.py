@@ -1125,7 +1125,8 @@ def main(argv=None) -> int:
         # compile time apart from step time (with_run_report fills these)
         for key in ("platform", "device_kind", "device_count",
                     "attention_impl", "compile_seconds", "step_compiles",
-                    "mfu", "step_time_p50_ms", "state_device_ids",
+                    "grad_reductions", "grad_reductions_async", "mfu",
+                    "step_time_p50_ms", "state_device_ids",
                     "batch_device_ids", "device_bytes_in_use"):
             if key in metrics:
                 headline[key] = metrics[key]

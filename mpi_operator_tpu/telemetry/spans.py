@@ -121,7 +121,13 @@ The trees the program records:
     each with JAX's own work under it, by `fun_name`:
     jax.trace, jax.lower, jax.compile (backend compile) or jax.cache_load
     (the same program found in the persistent cache). One of these under
-    a `serve.tick` after warm-up IS the recompile, by name.
+    a `serve.tick` after warm-up IS the recompile, by name. `LMTrainer`'s
+    step compiles at its first call, under no span of the program's; its
+    jax.compile (or jax.cache_load) of `jit(_step_fn)` carries
+                       grad_reductions, grad_reductions_async: the
+                       reductions across chips the program holds and how
+                       many of them the compiler made asynchronous (0 and
+                       0 on one chip; `lm_benchmark`'s record prints them)
 
   input (data/prefetch.py)
     data.next          the consumer's wait on the queue   depth on entry
@@ -340,6 +346,16 @@ def records() -> List[Span]:
     return list(_LOG.copy())      # copy() is atomic; iterating _LOG is not
 
 
+def last(*names: str, **attrs) -> Optional[Span]:
+    """The newest closed record with one of `names` and these attributes:
+    the way to a region that was recorded when it was over (a jax.compile),
+    for what is known about it only after."""
+    for rec in reversed(records()):
+        if rec.name in names and attrs.items() <= rec.attrs.items():
+            return rec
+    return None
+
+
 def clear() -> None:
     """Forget every record (for the tests)."""
     _LOG.clear()
@@ -350,5 +366,5 @@ if sys.modules.get("jax") is not None:
     # may be before the program's first span
     _resolve()
 
-__all__ = ["GC_MIN_NS", "LOG_BOUND", "Span", "begin", "clear", "records",
-           "span"]
+__all__ = ["GC_MIN_NS", "LOG_BOUND", "Span", "begin", "clear", "last",
+           "records", "span"]

@@ -201,6 +201,10 @@ def test_lm_benchmark_headline_names_device_and_traced_attention(
     # the state the step returns matches the state it was first given:
     # one train-step program, not a second compile on the second call
     assert head["step_compiles"] == 1
+    # ... which reduces the gradients over the host's devices, and on the
+    # CPU none of it asynchronously
+    assert head["grad_reductions"] >= 1
+    assert head["grad_reductions_async"] == 0
     assert head["state_device_ids"] == head["batch_device_ids"] \
         == list(range(jax.device_count()))
 

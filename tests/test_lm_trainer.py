@@ -1,19 +1,24 @@
 """LM trainer tests: sharded training convergence + objective math."""
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mpi_operator_tpu.models.transformer import CausalLM, MaskedLM, \
     bert_config, gpt2_config
 from mpi_operator_tpu.parallel import MeshConfig, make_mesh
+from mpi_operator_tpu.telemetry import spans
 from mpi_operator_tpu.train.lm_trainer import (
-    LMTrainer, LMTrainerConfig, lm_loss)
+    LMTrainer, LMTrainerConfig, count_grad_reductions, lm_loss)
 
 
-def _trainer(mesh_cfg, model_cfg_kw=None, **tcfg_kw):
+def _trainer(mesh_cfg, model_cfg_kw=None, devices=None, **tcfg_kw):
     cfg = gpt2_config("test", attention="dense", dtype=jnp.float32,
                       vocab_size=128, max_len=64, **(model_cfg_kw or {}))
-    mesh = make_mesh(mesh_cfg)
+    mesh = make_mesh(mesh_cfg, devices=devices)
     tcfg = LMTrainerConfig(global_batch_size=8, seq_len=32, warmup_steps=2,
                            **tcfg_kw)
     tr = LMTrainer(CausalLM(cfg), mesh, tcfg)
@@ -37,6 +42,117 @@ def test_loss_decreases_dp_fsdp_tp():
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0]
     assert int(state.step) == 5
+
+
+def _two_steps(mesh_kw, **tcfg_kw):
+    """Two steps of the toy model on a mesh of as many host devices as
+    `mesh_kw` asks for: the first step's loss, Adam's first moment after
+    it (the clipped gradient as the optimizer got it, times 1 - b1), the
+    parameters after both, the trainer, and its compile's span."""
+    n = math.prod(mesh_kw.values())
+    tr = _trainer(MeshConfig(**mesh_kw), devices=jax.devices()[:n],
+                  **tcfg_kw)
+    state = tr.init_state(jax.random.PRNGKey(0))
+    toks, tgts = _batch(tr)
+    spans.clear()
+    state, m = tr.train_step(state, toks, tgts)
+    loss = float(m["loss"])
+    mu = jax.tree.map(np.asarray, [
+        x.mu for x in jax.tree.leaves(
+            state.opt_state, is_leaf=lambda x: hasattr(x, "mu"))
+        if hasattr(x, "mu")][0])
+    state, _ = tr.train_step(state, toks, tgts)
+    compiles = [r for r in spans.records() if r.name == "jax.compile"
+                and r.attrs.get("fun_name") == "jit(_step_fn)"]
+    return loss, mu, jax.tree.map(np.asarray, state.params), tr, compiles
+
+
+@functools.lru_cache(maxsize=None)
+def _one_device():
+    return _two_steps({"dp": 1})
+
+
+@pytest.mark.parametrize("mesh_kw,tcfg_kw,options", [
+    ({"dp": 4}, {}, None),
+    ({"fsdp": 4}, {}, None),
+    ({"dp": 2, "fsdp": 2}, {}, None),
+    ({"dp": 4}, {"accum_steps": 2}, None),
+    ({"dp": 4}, {}, {"xla_embed_ir_in_executable": False}),
+], ids=["dp4", "fsdp4", "dp2xfsdp2", "dp4-accum2", "dp4-options"])
+def test_a_data_parallel_step_is_the_one_device_step(
+        mesh_kw, tcfg_kw, options, monkeypatch):
+    """However the batch is split over chips and whatever the compiler is
+    told about the reductions (on the CPU: nothing), the step is the
+    one-device step: the same loss, the same gradient as the optimizer
+    got it (every leaf reduced over every chip before the clip's norm),
+    the same parameters after two updates, within float32 round-off. One
+    compiled program, whose compile's span says how many reductions it
+    holds: none on one device, some on a mesh, none of them asynchronous
+    on the CPU. Handed options (here one the CPU's compiler knows, in
+    place of the TPU's), the step is compiled ONCE all the same: the
+    executable steps, and the jit, which would compile it again, is
+    never called."""
+    monkeypatch.setattr(LMTrainer, "_compiler_options",
+                        lambda self: options)
+    loss, mu, params, tr, compiles = _two_steps(mesh_kw, **tcfg_kw)
+    ref_loss, ref_mu, ref_params, ref_tr, ref_compiles = _one_device()
+    assert abs(loss - ref_loss) < 1e-5
+    norm = lambda t: math.sqrt(sum(    # noqa: E731
+        float(np.sum(np.square(x, dtype=np.float64)))
+        for x in jax.tree.leaves(t)))
+    assert abs(norm(mu) - norm(ref_mu)) < 1e-5 * norm(ref_mu)
+    for a, b in zip(jax.tree.leaves(mu), jax.tree.leaves(ref_mu)):
+        np.testing.assert_allclose(a, b, atol=2e-6)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(ref_params)):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+    assert tr.step_compiles == ref_tr.step_compiles == 1
+    assert tr._step._cache_size() == (1 if options is None else 0)
+    (span,), (ref_span,) = compiles, ref_compiles
+    assert ref_span.attrs == {"fun_name": "jit(_step_fn)",
+                              "grad_reductions": 0,
+                              "grad_reductions_async": 0}
+    assert span.attrs["grad_reductions"] >= 1
+    assert span.attrs["grad_reductions_async"] == 0
+    assert tr.grad_reductions == (span.attrs["grad_reductions"], 0)
+
+
+def test_count_grad_reductions_reads_a_compiled_programs_text():
+    """What counts: an all-reduce or reduce-scatter with an array among
+    its operands, wherever it stands; asynchronous as a start / done pair
+    or inside an `async-collective-start` fusion's computation (the
+    TPU's form, counted once though the fusion that carries its steps
+    holds it again); NOT a scalar's reduction, a done, an all-gather, nor an
+    `all-reduce` that only carries the name of the pair it was merged
+    back from."""
+    text = """HloModule jit__step_fn
+%add (a: f32[], b: f32[]) -> f32[] {
+  ROOT %s = f32[] add(%a, %b)
+}
+%fused_computation.7 (p: bf16[8,8]) -> (bf16[8,8], bf16[8,8], u32[]) {
+  %p = bf16[8,8]{1,0} parameter(0)
+  %all-reduce.5 = bf16[8,8]{1,0} all-reduce(%p), to_apply=%add
+  ROOT %c = (bf16[8,8], bf16[8,8], u32[]) custom-call(%p, %all-reduce.5)
+}
+%async_collective_fusion.3 (p: bf16[8,8], w: bf16[8,8]) -> bf16[8,8] {
+  %all-reduce.6 = bf16[8,8]{1,0} all-reduce(%p), to_apply=%add
+  ROOT %conv = bf16[8,8] convolution(%p, %w)
+}
+%body (x: f32[4]) -> f32[4] {
+  ROOT %reduce-scatter.1 = f32[1]{0} reduce-scatter(%x), dimensions={0}
+}
+ENTRY %main (a: bf16[8,8], b: f32[4]) -> f32[] {
+  %all-reduce.1 = f32[] all-reduce(%loss), to_apply=%add
+  %async-collective-start.2 = (bf16[8,8], bf16[8,8], u32[]) fusion(%a), kind=kCustom, calls=%fused_computation.7
+  %fusion.3 = bf16[8,8] fusion(%async-collective-start.2, %a), kind=kOutput, calls=%async_collective_fusion.3
+  %async-collective-done.2 = bf16[8,8] fusion(%fusion.3), kind=kCustom, calls=%fused_computation.8
+  %all-reduce.9 = (f32[4]{0}, f32[]) all-reduce(%b, %n), to_apply=%add, frontend_attributes={async_collective_name="all-reduce-start.3"}
+  %all-reduce-start.4 = bf16[8,8] all-reduce-start(%a), to_apply=%add
+  %all-reduce-done.4 = bf16[8,8] all-reduce-done(%all-reduce-start.4)
+  %all-gather.1 = f32[16] all-gather(%b), dimensions={0}
+}
+"""
+    assert count_grad_reductions(text) == (4, 2)
+    assert count_grad_reductions("") == (0, 0)
 
 
 def test_moe_variant_trains():

@@ -205,6 +205,23 @@ def test_records_is_a_snapshot():
     assert [r.name for r in spans.records()] == ["a", "b"]
 
 
+def test_last_is_the_newest_record_of_a_name_with_the_attributes():
+    """How what is known only after a region closed gets onto its record
+    (the train step's counts onto its jax.compile)."""
+    for name, fun in (("jax.compile", "jit(f)"), ("jax.compile", "jit(g)"),
+                      ("jax.trace", "g"), ("jax.cache_load", "jit(f)")):
+        with span(name, fun_name=fun):
+            pass
+    a, b, _, d = spans.records()
+    assert spans.last("jax.compile") is b
+    assert spans.last("jax.compile", fun_name="jit(f)") is a
+    assert spans.last("jax.compile", "jax.cache_load", fun_name="jit(f)") is d
+    assert spans.last("jax.lower") is None
+    assert spans.last("jax.trace", fun_name="jit(g)") is None
+    spans.last("jax.trace").set(seen=1)
+    assert spans.records()[2].attrs == {"fun_name": "g", "seen": 1}
+
+
 # -- spans that live across calls --------------------------------------------
 
 def test_a_begun_and_ended_record_is_on_no_thread_and_keeps_its_links():

@@ -9,6 +9,7 @@ One file, the topology described inside a fixture: only the worker that
 runs this file loads the TPU's library.
 """
 import json
+import math
 import os
 import re
 
@@ -712,3 +713,142 @@ def test_flash_attention_and_its_gradient_at_the_training_cells_shape(
         assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
     else:
         assert operand_copies and wide_rows
+
+
+def _described_train_step(topo, monkeypatch, chips, layers=2):
+    """`LMTrainer`'s step at the training cells' widths compiled for
+    `chips` of the described v5e:2x2 on a dp mesh, the lowering told it
+    is for a TPU (`attention="auto"` and the kernels' `interpret` ask
+    `jax.default_backend()`). Returns the compiled step and the
+    `compiler_options` its jit was handed."""
+    from mpi_operator_tpu.models.transformer import (CausalLM,
+                                                     TransformerConfig)
+    from mpi_operator_tpu.parallel import MeshConfig, make_mesh
+    from mpi_operator_tpu.parallel.sharding import activation_rules_scope
+    from mpi_operator_tpu.train import lm_trainer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    handed = []
+    jit = jax.jit
+
+    def spy(fun, **kw):
+        if getattr(fun, "__name__", "") == "_step_fn":
+            handed.append(kw.get("compiler_options"))
+        return jit(fun, **kw)
+    monkeypatch.setattr(lm_trainer.jax, "jit", spy)
+    model = CausalLM(TransformerConfig(
+        vocab_size=50304, max_len=1024, num_layers=layers, num_heads=16,
+        embed_dim=1024, mlp_dim=4096, causal=True, dtype=jnp.bfloat16,
+        attention="auto", remat=False))
+    mesh = make_mesh(MeshConfig(dp=chips), devices=topo.devices[:chips])
+    trainer = lm_trainer.LMTrainer(model, mesh, lm_trainer.LMTrainerConfig(
+        global_batch_size=8 * chips, seq_len=1024))
+    # nothing can be put on a described chip: the state as shapes, which
+    # also leaves the trainer its shardings
+    state = jax.eval_shape(trainer.init_state, jax.random.PRNGKey(0))
+    batch = lambda dt: jax.ShapeDtypeStruct(          # noqa: E731
+        (8 * chips, 1024), dt, sharding=trainer.batch_sharding)
+    with activation_rules_scope(mesh):
+        compiled = trainer.compile_step().lower(
+            state, batch(jnp.int32), batch(jnp.int32),
+            batch(jnp.float32)).compile()
+    (options,) = handed
+    return compiled, options
+
+
+_HLO_ARRAY = re.compile(r"\b(bf16|f32)\[([\d,]*)\]")
+
+
+def _reductions(text):
+    """The all-reduces of a compiled step, in the order of the scheduled
+    entry computation: (asynchronous, [(type, dims) of each operand],
+    line number in the entry) — an `async-collective-start` fusion stands
+    for the all-reduce its computation holds."""
+    bodies, lines, entry = {}, text.splitlines(), None
+    for n, ln in enumerate(lines):
+        if ln.startswith("ENTRY "):
+            entry = n
+        m = re.match(r"^%?([\w.-]+) \(", ln)
+        if m:
+            name = m.group(1)
+        m = re.search(r" = (.*?) all-reduce\(", ln)
+        if m and entry is None:
+            bodies[name] = _HLO_ARRAY.findall(m.group(1))
+    out = []
+    for n, ln in enumerate(lines[entry:]):
+        m = re.match(r"^\s*%?async-collective-start[\w.-]* = .* "
+                     r"calls=%?([\w.-]+)", ln)
+        if m and m.group(1) in bodies:
+            out.append((True, bodies[m.group(1)], n))
+            continue
+        m = re.search(r" = (.*?) all-reduce\(", ln)
+        if m:
+            out.append((False, _HLO_ARRAY.findall(m.group(1)), n))
+    return out, lines[entry:]
+
+
+def test_a_dp4_train_steps_gradient_all_reduces_run_under_weight_gradients(
+        topo, quiet_cache, monkeypatch):
+    """The training cells' step on a dp=4 mesh of the described chips, two
+    layers deep. With `DP_OVERLAP_OPTIONS` (which the trainer hands its
+    jit because the mesh splits the batch over TPUs) every matrix but
+    the last of each of the scheduler's groups is reduced ALONE and
+    asynchronously: an `async-collective-start` fusion, a weight
+    gradient's product that carries the transfer's steps
+    (`async_collective_fusion`), an `async-collective-done`. Without
+    them (the parent's program) the same leaves are reduced as tuples,
+    synchronously, and nothing is asynchronous. What is reduced, and in
+    what type, is the same leaf for leaf: matrices and biases in
+    bfloat16, the type the backward products write, norm scales and
+    biases in float32. No asynchronous pair was merged back (an
+    `all-reduce` that carries `async_collective_name`) where it stands
+    alone with a MiB or more."""
+    from mpi_operator_tpu.train import lm_trainer
+    compiled, options = _described_train_step(topo, monkeypatch, 4)
+    assert options == lm_trainer.DP_OVERLAP_OPTIONS
+    text = compiled.as_text()
+    reds, entry = _reductions(text)
+    leaves = lambda rs: sorted(op for _, ops, _ in rs   # noqa: E731
+                               for op in ops if op[1])
+    is_async = [a for a, ops, _ in reds if any(d for _, d in ops)]
+    total, n_async = lm_trainer.count_grad_reductions(text)
+    assert (total, n_async) == (len(is_async), sum(is_async))
+    # two layers: 12 block matrices, the position table and the tied
+    # table twice (the head's part and the embedding's)
+    assert n_async >= 10 and total - n_async <= 5
+    for asynchronous, ops, at in reds:
+        if asynchronous:
+            assert len(ops) == 1
+            done = next(n for n in range(at, len(entry)) if re.match(
+                r"^\s*%?async-collective-done", entry[n]))
+            assert any("calls=%async_collective_fusion" in ln
+                       for ln in entry[at:done]), entry[at][:200]
+        elif len(ops) > 1:
+            # what is still combined is small: a MiB is the bound
+            assert sum(math.prod(map(int, d.split(","))) * (
+                2 if t == "bf16" else 4) for t, d in ops if d) <= 1 << 20
+    for t, d in leaves(reds):
+        # float32: norm scales and biases [1024] only; all else bfloat16
+        assert t == "bf16" or d == "1024", (t, d)
+    assert ("bf16", "50304,1024") in leaves(reds)
+
+    monkeypatch.setattr(lm_trainer, "DP_OVERLAP_OPTIONS", None)
+    parent, options = _described_train_step(topo, monkeypatch, 4)
+    assert options is None
+    parent_reds, _ = _reductions(parent.as_text())
+    assert leaves(parent_reds) == leaves(reds)
+    assert not any(a for a, _, _ in parent_reds)
+    assert lm_trainer.count_grad_reductions(parent.as_text())[1] == 0
+    assert max(len(ops) for _, ops, _ in parent_reds) > 12
+
+
+def test_a_one_chip_train_step_holds_no_collective_and_takes_no_option(
+        topo, quiet_cache, monkeypatch):
+    from mpi_operator_tpu.train import lm_trainer
+    compiled, options = _described_train_step(topo, monkeypatch, 1)
+    assert options is None
+    text = compiled.as_text()
+    assert lm_trainer.count_grad_reductions(text) == (0, 0)
+    assert not re.search(r" (all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all)[\w-]*\(", text)
+    assert "async-collective" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
