@@ -10,7 +10,9 @@ would call, one child process after another:
            every Pallas kernel the two legs below use, compiled by Mosaic
            and compared with its dense reference at the legs' shapes; and
            the kernels of the other served models at theirs (the latent
-           decode kernel; the paged decode kernel with its lower bound
+           decode kernel at 64 heads and, under DeepSeek-V2's scaled RoPE
+           and a table of 256 pages, at 128, with that model's [64, 128]
+           chunk in row groups; the paged decode kernel with its lower bound
            over a ring and a long table; the state-space scans, a chunk
            against its steps, Mamba-2's through its state-update kernel)
   trainer  python -m mpi_operator_tpu.examples.lm_benchmark --workload gpt2

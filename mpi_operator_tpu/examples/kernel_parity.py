@@ -13,8 +13,12 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   rows [pages, page, KV * 2D])                   `Attention._decode_attend`
   the int8-cache variants of both decode kernels
   mla_paged_decode_attention (absorbed latent   vs `mla_paged_attend`, the
-  attention, LongCat-Flash's published widths)     dense form of
-                                                   `LatentAttention`
+  attention, LongCat-Flash's published widths     dense form of
+  and DeepSeek-V2's: 128 heads, YaRN, a table      `LatentAttention`
+  of 256 pages)
+  mla_paged_attend_rows (a [64, 128] chunk of   vs `LatentAttention` with K
+  128 heads, its absorbed queries built four       and V expanded
+  rows at a time)
   paged_decode_attention with a lower bound     vs a dense masked softmax
   (a window), over a slot's ring of pages and     over the gathered rows
   over a long table, at Phi-4-mini-flash's
@@ -65,6 +69,14 @@ SERVE_SHAPE = dict(slots=8, max_len=256, page_size=64, prefilled=200)
 #: the latent decode kernel at LongCat-Flash's published widths (the
 #: module's defaults): 64 heads share rows of 512 + 64, padded to 640
 MLA_CASE = dict(slots=8, max_len=1280, page_size=64, prefilled=1000)
+#: the same kernel and module at DeepSeek-V2's (`models/deepseek_v2.py`):
+#: 128 heads, no rank factors, YaRN's frequencies and scale, the cell's
+#: table of 256 pages
+MLA_CASE_128 = dict(slots=8, max_len=16384, page_size=64, prefilled=1000,
+                    family="deepseek_v2")
+#: the chunk path at the cell's one prefill shape, which builds its
+#: absorbed queries a group of rows at a time
+MLA_CHUNK_CASE = dict(rows=64, chunk=128, page_size=64)
 #: Phi-4-mini-flash's differential attention as the kernel sees it: 40
 #: query heads over 10 key/value pairs of 128, scores / 8, a window of 512
 #: over a ring of 9 pages a slot, and the same call without a window over
@@ -263,8 +275,18 @@ def decode_case(int8: bool, slots: int, max_len: int, page_size: int,
             "max_rel_err": _rel_err(got, ref)}
 
 
+def _mla_config(family: str, **fields):
+    """The configuration `LatentAttention` runs under: LongCat-Flash's or
+    DeepSeek-V2's, at its published widths."""
+    if family == "deepseek_v2":
+        from ..models.deepseek_v2 import DeepseekV2Config
+        return DeepseekV2Config(**fields)
+    from ..models.longcat import LongcatConfig
+    return LongcatConfig(**fields)
+
+
 def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
-                    **widths) -> Dict[str, object]:
+                    family: str = "longcat", **widths) -> Dict[str, object]:
     """One single-token step through `LatentAttention` (models/longcat.py)
     over a prefilled latent page pool, every row at its own cursor: the
     absorbed Pallas kernel against the module's dense form
@@ -273,17 +295,21 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from ..models.longcat import LatentAttention, LongcatConfig
+    from ..models.longcat import LatentAttention
     from ..ops.attention import record_traced, traced_name
 
     nblk = max_len // page_size
-    cfg = LongcatConfig(max_len=max_len, dtype=jnp.bfloat16, decode=True,
-                        decode_page_size=page_size,
-                        decode_num_pages=slots * nblk + 1, **widths)
+    cfg = _mla_config(family, max_len=max_len, dtype=jnp.bfloat16,
+                      decode=True, decode_page_size=page_size,
+                      decode_num_pages=slots * (-(-prefilled // page_size))
+                      + 1, **widths)
     dense = LatentAttention(dataclasses.replace(cfg, decode_kernel=False))
     kernel = LatentAttention(dataclasses.replace(cfg, decode_kernel=True))
-    ids = np.random.RandomState(0).permutation(slots * nblk) + 1
-    pages = jnp.asarray(ids.reshape(slots, nblk), jnp.int32)
+    # a row's live pages, scattered over the pool; the table's tail is dead
+    live = -(-prefilled // page_size)
+    ids = np.random.RandomState(0).permutation(slots * live) + 1
+    pages = jnp.asarray(np.pad(ids.reshape(slots, live),
+                               ((0, 0), (0, nblk - live))), jnp.int32)
     kp, kx, ks = jax.random.split(jax.random.PRNGKey(2), 3)
     E = cfg.hidden_size
     x_fill = jax.random.normal(kx, (slots, prefilled, E), jnp.bfloat16)
@@ -322,8 +348,49 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
     ref = step(dense)(params, filled["cache"])
     return {"kernel": "mla_paged_decode_attention", "traced": name,
             "shape": {"slots": slots, "max_len": max_len,
-                      "page_size": page_size, **widths},
+                      "page_size": page_size, "family": family,
+                      "heads": cfg.num_heads, **widths},
             "cursors": [int(c) for c in cur],
+            "max_rel_err": _rel_err(got, ref)}
+
+
+def mla_chunk_case(rows: int, chunk: int, page_size: int, **widths
+                   ) -> Dict[str, object]:
+    """One prefill call of `rows` x `chunk` tokens from position 0 through
+    DeepSeek-V2's `LatentAttention` in decode mode — the latent pages
+    written, the absorbed queries built, attended and projected a group
+    of rows at a time (`ops.attention.mla_paged_attend_rows`) — against
+    the same module outside decode mode, K and V expanded: same weights,
+    same input."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..models.longcat import LatentAttention
+    from ..ops.attention import mla_query_rows, mla_row_width
+
+    nblk = chunk // page_size
+    cfg = _mla_config("deepseek_v2", max_len=chunk, dtype=jnp.bfloat16,
+                      **widths)
+    dcfg = dataclasses.replace(cfg, decode=True, decode_page_size=page_size,
+                               decode_num_pages=rows * nblk + 1)
+    group = mla_query_rows(rows, chunk, cfg.num_heads, mla_row_width(
+        cfg.kv_lora_rank, cfg.qk_rope_head_dim), cfg.dtype)
+    if group >= rows:
+        raise AssertionError(f"[{rows}, {chunk}] x {cfg.num_heads} heads "
+                             f"builds its queries whole, not in row groups")
+    kp, kx = jax.random.split(jax.random.PRNGKey(3))
+    x = jax.random.normal(kx, (rows, chunk, cfg.hidden_size), jnp.bfloat16)
+    pos = jnp.broadcast_to(jnp.arange(chunk)[None], (rows, chunk))
+    pages = 1 + jnp.arange(rows * nblk, dtype=jnp.int32).reshape(rows, nblk)
+    plain = LatentAttention(cfg)
+    params = plain.init(kp, x[:1])["params"]
+    got = jax.jit(lambda p: LatentAttention(dcfg).apply(
+        {"params": p}, x, positions=pos, pages=pages,
+        mutable=["cache"])[0])(params)
+    ref = jax.jit(lambda p: plain.apply({"params": p}, x))(params)
+    return {"kernel": "mla_paged_attend_rows",
+            "shape": {"rows": rows, "chunk": chunk, "page_size": page_size,
+                      "heads": cfg.num_heads, "rows_a_group": group},
             "max_rel_err": _rel_err(got, ref)}
 
 
@@ -471,7 +538,8 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
                       serve_shape: Optional[dict] = None,
                       model: Optional[dict] = None,
                       decode_models: Optional[List[dict]] = None,
-                      mla: Optional[dict] = None,
+                      mla: Optional[List[dict]] = None,
+                      mla_chunk: Optional[dict] = None,
                       window: Optional[dict] = None,
                       scan: Optional[dict] = None,
                       ssd: Optional[dict] = None,
@@ -479,7 +547,8 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
     """Every kernel the two legs use, at their shapes; one record each
     with its measured error and `ok`. The decode kernels run once per
     entry of `decode_models` (default: `model` alone); the latent decode
-    kernel where `mla` gives its case, the windowed decode kernel and the
+    kernel where `mla` gives its cases (and the latent chunk path where
+    `mla_chunk` does), the windowed decode kernel and the
     scans where `window`, `scan` and `ssd` give theirs. Off TPU the kernels
     interpret (the tier-1 test runs tiny shapes that way)."""
     train_shape = train_shape or TRAIN_SHAPE
@@ -491,8 +560,10 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
         for case in (contiguous_decode_case, decode_case):
             for int8 in (False, True):
                 records.append(case(int8, **serve_shape, **geometry))
-    if mla:
-        records.append(mla_decode_case(**mla))
+    for case in mla or ():
+        records.append(mla_decode_case(**case))
+    if mla_chunk:
+        records.append(mla_chunk_case(**mla_chunk))
     if window:
         records += window_decode_cases(**window)
     if scan:
@@ -523,7 +594,8 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     device = device_record()
     records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL],
-                                mla=MLA_CASE, window=WINDOW_CASE,
+                                mla=[MLA_CASE, MLA_CASE_128],
+                                mla_chunk=MLA_CHUNK_CASE, window=WINDOW_CASE,
                                 scan=SCAN_CASE, ssd=SSD_CASE)
     for rec in records:
         print(json.dumps({**rec, **device}))

@@ -11,7 +11,12 @@ sublayers between:
     h3 = h2 + A1(norm_a1(h2))
     out = h3 + F1(norm_f1(h3)) + s
 
-MLA: `c_q = RMSNorm(x W_qa)`, `q = c_q W_qb` in heads of `nope ‖ rope`
+MLA (`LatentAttention`, which `models/deepseek_v2.py` serves DeepSeek-V2
+with as well: what differs between the two models it takes from the
+configuration — `mla_scale_q_lora` / `mla_scale_kv_lora`, the factors
+below, LongCat's alone; `sm_scale`; `rope_freqs`, None for plain
+`theta^(-2i/d)` or the frequencies to rotate by, YaRN's there):
+`c_q = RMSNorm(x W_qa)`, `q = c_q W_qb` in heads of `nope ‖ rope`
 columns, times `sqrt(hidden / q_rank)`; `[c_kv ‖ k_pe] = x W_kva`,
 `c_kv = RMSNorm(c_kv) sqrt(hidden / kv_rank)`, `k_pe` one head shared by
 all, RoPE on interleaved pairs of `q_pe` and `k_pe`; `[k_nope ‖ v] =
@@ -22,7 +27,9 @@ position — `c_kv ‖ k_pe`, padded to whole lane tiles
 (`ops.attention.mla_row_width`) — in a page pool `[pages, page, W]`, and
 `W_kvb` is absorbed: `q~ = q_nope W_kvb[K]`, scores `q~.c_kv + q_pe.k_pe`,
 `u = P c_kv`, `v = u W_kvb[V]` (`ops.attention.mla_paged_attend`, and the
-Pallas kernel for single-token steps). The latent cache exists paged
+Pallas kernel for single-token steps; a chunk whose absorbed queries
+would pass a gigabyte builds them a group of rows at a time,
+`ops.attention.mla_paged_attend_rows`). The latent cache exists paged
 only: it is driven by the serving engine's slots and page tables.
 
 The expert layer is `parallel/held_experts.py`: told which experts it
@@ -38,6 +45,7 @@ fetches them with the step's tokens (`STEP_COUNTERS` names them).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -69,6 +77,9 @@ class LongcatConfig:
     zero_expert_num: int = 256      # identity experts, after the real ones
     moe_topk: int = 12
     routed_scaling_factor: float = 6.0
+    #: q and c_kv times sqrt(hidden / rank), as published
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
     rope_theta: float = 1e7
     rms_norm_eps: float = 1e-5
     #: (first, count): the real experts whose weights live here
@@ -87,6 +98,13 @@ class LongcatConfig:
     def router_outputs(self) -> int:
         return self.n_routed_experts + self.zero_expert_num
 
+    @property
+    def sm_scale(self) -> float:
+        return 1.0 / math.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim)
+
+    #: no rope scaling: `rope_interleaved` rotates by theta^(-2i/d)
+    rope_freqs = None
+
 
 def rms_norm(x, scale, eps):
     x32 = x.astype(jnp.float32)
@@ -94,11 +112,14 @@ def rms_norm(x, scale, eps):
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_interleaved(x, positions, theta: float):
+def rope_interleaved(x, positions, theta: float, freqs=None):
     """Rotary embedding on interleaved pairs (x[2i], x[2i+1]) of the last
-    dim; x [B, S, ..., D], positions [B, S]."""
+    dim; x [B, S, ..., D], positions [B, S]. `freqs` [D/2] float32, where
+    given, are the frequencies to rotate by (a scaled RoPE's) in place
+    of theta^(-2i/D)."""
     D = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
     ang = positions.astype(jnp.float32)[..., None] * freqs      # [B, S, D/2]
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[2:])
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -118,7 +139,9 @@ class _Norm(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    config: LongcatConfig
+    #: a LongcatConfig, or another model's with the same fields
+    #: (`deepseek_v2.DeepseekV2Config`)
+    config: Any
 
     @nn.compact
     def __call__(self, x, positions=None, pages=None):
@@ -137,18 +160,22 @@ class LatentAttention(nn.Module):
                if positions is None
                else jnp.broadcast_to(jnp.asarray(positions, jnp.int32),
                                      (B, S)))
-        sm_scale = 1.0 / math.sqrt(dn + dr)
+        sm_scale = cfg.sm_scale
+        rope = functools.partial(rope_interleaved, positions=pos,
+                                 theta=cfg.rope_theta, freqs=cfg.rope_freqs)
 
         with jax.named_scope("mla.project"):
             c_q = _Norm(cfg.rms_norm_eps, name="q_a_norm")(x @ q_a)
-            q = jnp.einsum("bsr,rhd->bshd", c_q, q_b) * jnp.asarray(
-                math.sqrt(E / rq), dt)
+            q = jnp.einsum("bsr,rhd->bshd", c_q, q_b)
+            if cfg.mla_scale_q_lora:
+                q = q * jnp.asarray(math.sqrt(E / rq), dt)
             q_nope, q_pe = q[..., :dn], q[..., dn:]
             kv = x @ kv_a
-            c_kv = _Norm(cfg.rms_norm_eps, name="kv_a_norm")(
-                kv[..., :rkv]) * jnp.asarray(math.sqrt(E / rkv), dt)
-            k_pe = rope_interleaved(kv[..., rkv:], pos, cfg.rope_theta)
-            q_pe = rope_interleaved(q_pe, pos, cfg.rope_theta)
+            c_kv = _Norm(cfg.rms_norm_eps, name="kv_a_norm")(kv[..., :rkv])
+            if cfg.mla_scale_kv_lora:
+                c_kv = c_kv * jnp.asarray(math.sqrt(E / rkv), dt)
+            k_pe = rope(kv[..., rkv:])
+            q_pe = rope(q_pe)
 
         if not cfg.decode:
             with jax.named_scope("mla.attend"):
@@ -170,8 +197,10 @@ class LatentAttention(nn.Module):
         """Write this call's latent rows at `pos` through the page
         tables, then attend with the up-projection absorbed."""
         from ..ops.attention import (mla_paged_attend,
+                                     mla_paged_attend_rows,
                                      mla_paged_decode_attention,
-                                     mla_row_width, note_traced)
+                                     mla_query_rows, mla_row_width,
+                                     note_traced)
         cfg = self.config
         B, S, H, dn = q_nope.shape
         rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -205,6 +234,11 @@ class LatentAttention(nn.Module):
                 flat.reshape(-1)].set(rows.reshape(B * S, W), mode="drop"
                                       ).reshape(NP, ps, W)
         with jax.named_scope("mla.attend"):
+            if mla_query_rows(B, S, H, W, cfg.dtype) < B:
+                note_traced("decode" if S == 1 else "prefill", "dense")
+                return mla_paged_attend_rows(
+                    q_nope, q_pe, kv_b[..., :dn], kv_b[..., dn:], pool.value,
+                    pos, pt, rkv, sm_scale)
             q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, kv_b[..., :dn])
             q = jnp.concatenate(
                 [q_lat, q_pe, jnp.zeros((B, S, H, W - rkv - dr), cfg.dtype)],
@@ -219,15 +253,19 @@ class LatentAttention(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    config: LongcatConfig
+    """`down(silu(gate x) * up x)` of `width` (None: the configuration's
+    `ffn_hidden_size`), traced under the scope `traced_as`."""
+    config: Any
+    width: Optional[int] = None
+    traced_as: str = "ffn"
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        E, F = x.shape[-1], cfg.ffn_hidden_size
+        E, F = x.shape[-1], self.width or cfg.ffn_hidden_size
         def p(name, shape):
             return self.param(name, init, shape).astype(cfg.dtype)
-        with jax.named_scope("ffn"):
+        with jax.named_scope(self.traced_as):
             h = jax.nn.silu(x @ p("gate", (E, F))) * (x @ p("up", (E, F)))
             return h @ p("down", (F, E))
 

@@ -1617,7 +1617,8 @@ def mla_row_width(rank: int, rope: int) -> int:
     return -(-(rank + rope) // LANES) * LANES
 
 
-def mla_paged_attend(q, pool, positions, page_table, rank, sm_scale):
+def mla_paged_attend(q, pool, positions, page_table, rank, sm_scale,
+                     pages_a_turn: int = 1):
     """Absorbed latent attention over the page pool in plain jax: the
     dense form the kernel is compared with, the path of every
     multi-token call (prefill chunks) and of a decode step that asked
@@ -1633,21 +1634,33 @@ def mla_paged_attend(q, pool, positions, page_table, rank, sm_scale):
     Pages are walked in logical order with an online softmax, up to the
     furthest block any live query reaches — a chunk of a 200-token
     prompt walks four pages, not the table's hundred — so neither scores
-    nor gathered rows over the whole logical length ever exist."""
+    nor gathered rows over the whole logical length ever exist. A turn
+    takes `pages_a_turn` pages (a divisor of the table's length): the
+    float32 accumulator passes through memory once a turn, and a turn's
+    scores are that many pages wide."""
     B, S, H, W = q.shape
     NP, ps, _ = pool.shape
     nblk = page_table.shape[1]
+    if nblk % pages_a_turn:
+        raise ValueError(f"a turn of {pages_a_turn} pages does not divide "
+                         f"a table of {nblk}")
+    width = pages_a_turn * ps
     pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B, S))
-    blocks = jnp.max(jnp.where(pos < nblk * ps, pos // ps + 1, 0))
+    blocks = jnp.max(jnp.where(pos < nblk * ps, pos // width + 1, 0))
     pt = jnp.asarray(page_table, jnp.int32)
 
     def attend(q, qpos, pt):
         """q [G, S*H, W], qpos [G, S*H], pt [G, nblk] -> [G, S*H, rank]."""
         def body(j, carry):
             m, l, acc = carry
-            page = pool[pt[:, j]]                             # [G, ps, W]
+            if pages_a_turn == 1:
+                page = pool[pt[:, j]]                         # [G, ps, W]
+            else:
+                page = pool[jax.lax.dynamic_slice_in_dim(
+                    pt, j * pages_a_turn, pages_a_turn, 1)].reshape(
+                    pt.shape[0], width, W)
             s = einsum_f32("bqw,bkw->bqk", q, page) * sm_scale
-            cols = j * ps + jnp.arange(ps, dtype=jnp.int32)
+            cols = j * width + jnp.arange(width, dtype=jnp.int32)
             s = jnp.where(cols[None, None, :] <= qpos[:, :, None], s,
                           NEG_INF)
             m_new = jnp.maximum(m, s.max(-1, keepdims=True))
@@ -1667,9 +1680,9 @@ def mla_paged_attend(q, pool, positions, page_table, rank, sm_scale):
 
     q = q.reshape(B, S * H, W)
     qpos = jnp.repeat(pos, H, axis=1)                         # [B, S*H]
-    # the float32 accumulator is [rows, S*H, rank]: a 128-token chunk of
-    # 64 rows and 64 heads would hold a gigabyte of it, so rows go through
-    # in groups of about `_MLA_QUERY_ROWS` queries
+    # the float32 accumulator is [rows, S*H, rank]: a 128-token chunk of 64
+    # rows holds a gigabyte of it at 64 heads and two at 128, so rows go
+    # through in groups of `_MLA_QUERY_ROWS` queries (8 rows there, 4 here)
     G = max(1, _MLA_QUERY_ROWS // (S * H))
     if B > G and B % G == 0:
         grouped = lambda x: x.reshape((B // G, G) + x.shape[1:])  # noqa: E731
@@ -1689,14 +1702,24 @@ _MLA_QUERY_ROWS = 65536
 _MLA_PAGES_VMEM_BUDGET = 1280 * 1024
 
 
+#: a table of this many pages or more says contexts run to thousands of
+#: positions: its turns take a slot twice as large
+_MLA_LONG_TABLE = 128
+
+
 def mla_pages_per_turn(nblk: int, page_bytes: int) -> int:
     """Pages one turn of the latent decode kernel's loop takes: what fits
     a slot of `_MLA_PAGES_VMEM_BUDGET`, at least one, at most the table.
     More pages a turn give the MXU a wider score block and the loop
     fewer turns; a turn's pages past the row's last live one are fetched
     again from that page, so a wide turn wastes bytes on short contexts
-    (PERF.md, PR 30: eight 80 KB pages at LongCat-Flash's widths)."""
-    return max(1, min(nblk, _MLA_PAGES_VMEM_BUDGET // (2 * page_bytes)))
+    (PERF.md, PR 30: eight 80 KB pages at LongCat-Flash's widths, whose
+    rows start at 64 positions). Under a long table (`_MLA_LONG_TABLE`)
+    that tail is a small share of a row's pages and the slot is doubled
+    (PERF.md, PR 39: sixteen pages a turn take 2.72 ns a cached token and
+    layer at 128 heads where eight take 3.22)."""
+    slot = _MLA_PAGES_VMEM_BUDGET * (2 if nblk >= _MLA_LONG_TABLE else 1)
+    return max(1, min(nblk, slot // (2 * page_bytes)))
 
 
 def _mla_decode_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
@@ -1856,6 +1879,61 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
     )(cur, pt, q, pool)
 
 
+#: cached positions a turn of the row groups' walk scores at once
+_MLA_ROWS_TURN = 512
+
+#: bytes of absorbed queries `[B, S, H, W]` a chunk call may build for all
+#: its rows at once; `q_lat` and `u`, four fifths of that each, stand
+#: beside them. LongCat-Flash's `[64, 128]` chunk of 64 heads builds 671
+#: MB; DeepSeek-V2's of 128 heads would build 1.34 GB + 2 x 1.07 GB
+_MLA_BUILT_QUERY_BYTES = 1 << 30
+
+
+def mla_query_rows(B: int, S: int, H: int, W: int, dtype) -> int:
+    """Rows of a `[B, S]` call whose absorbed queries are built together:
+    all B where `[B, S, H, W]` of them stay under
+    `_MLA_BUILT_QUERY_BYTES`; else what one pass of `mla_paged_attend`
+    takes (`_MLA_QUERY_ROWS` queries), a divisor of B."""
+    if B * S * H * W * jnp.dtype(dtype).itemsize <= _MLA_BUILT_QUERY_BYTES:
+        return B
+    return max(d for d in range(1, B + 1)
+               if B % d == 0 and d <= max(1, _MLA_QUERY_ROWS // (S * H)))
+
+
+def mla_paged_attend_rows(q_nope, q_pe, w_k, w_v, pool, positions,
+                          page_table, rank, sm_scale):
+    """A chunk's latent attention from the queries as projected to the
+    values as projected, a group of `mla_query_rows` rows at a time:
+    absorb (`q_nope` [B, S, H, dn] through `w_k` [rank, H, dn]), pad to
+    the pool's rows beside `q_pe`, `mla_paged_attend`, and the absorbed
+    value projection (`w_v` [rank, H, dv]) — so that the absorbed queries
+    and `u` exist for one group only. Each group walks to the furthest
+    page ITS live queries reach, `_MLA_ROWS_TURN` positions a turn: rows
+    that are no member of a prefill call walk none. Returns
+    [B, S, H, dv]."""
+    B, S, H, _ = q_nope.shape
+    ps, W = pool.shape[1:]
+    nblk = page_table.shape[1]
+    G = mla_query_rows(B, S, H, W, q_nope.dtype)
+    turn = max(d for d in range(1, nblk + 1)
+               if nblk % d == 0 and d <= max(1, _MLA_ROWS_TURN // ps))
+    pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B, S))
+
+    def part(a):
+        q_nope, q_pe, pos, pt = a
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_k)
+        pad = jnp.zeros(q_pe.shape[:-1] + (W - rank - q_pe.shape[-1],),
+                        q_pe.dtype)
+        u = mla_paged_attend(jnp.concatenate([q_lat, q_pe, pad], -1), pool,
+                             pos, pt, rank, sm_scale, pages_a_turn=turn)
+        return jnp.einsum("bshr,rhd->bshd", u, w_v)
+
+    grouped = lambda x: x.reshape((B // G, G) + x.shape[1:])      # noqa: E731
+    out = jax.lax.map(part, (grouped(q_nope), grouped(q_pe), grouped(pos),
+                             grouped(jnp.asarray(page_table, jnp.int32))))
+    return out.reshape((B,) + out.shape[2:])
+
+
 # ---------------------------------------------------------------------------
 # A chunk of queries over the per-head page pool, in plain jax
 # ---------------------------------------------------------------------------
@@ -1944,6 +2022,7 @@ __all__ = ["flash_attention", "decode_attention", "decode_block_k",
            "decode_head_block", "paged_decode_attention",
            "paged_pages_per_turn", "kv_row_width",
            "pack_kv_rows", "paged_attend",
-           "mla_paged_attend", "mla_paged_decode_attention",
+           "mla_paged_attend", "mla_paged_attend_rows", "mla_query_rows",
+           "mla_paged_decode_attention",
            "mla_pages_per_turn", "mla_row_width", "einsum_f32",
            "record_traced", "note_traced", "traced_name"]

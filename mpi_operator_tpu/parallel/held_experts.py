@@ -10,12 +10,20 @@ experts give. This module is that part, with no stand-in for the other
 chips or for the exchange between them: what the absent experts would add
 is left out.
 
-Routing (`route`): softmax over every router output in float32; the
-picks are the top `top_k` of `p + bias` (the score-correction bias moves
-the CHOICE only); a pick's weight is `scale * p`, not renormalised over
-the picks. Outputs at or past `n_real` are identity ("zero-compute")
-experts: `E_i(y) = y`, so their whole contribution is `y` times the sum
-of their weights (`identity_weight`).
+Routing (`route`), shared by the two served models with experts
+(`models/longcat.py`: flat, with a bias and identity experts;
+`models/deepseek_v2.py`: group-limited, no bias, a shared expert beside):
+softmax over every router output in float32; the picks are the top `top_k`
+of `p + bias` (the score-correction bias moves the CHOICE only); a pick's
+weight is `scale * p`, not renormalised over the picks. With `n_group` > 1
+the outputs are `n_group` runs of consecutive experts, a group's score is
+the largest `p` in it, the `topk_group` best groups stay and `p` outside
+them counts as 0 in the choice (`kept_groups`; device-limited routing: an
+expert group is what one chip holds, so a token's picks lie on at most
+`topk_group` chips). Ties, among groups and among picks, go to the lower
+index, as `lax.top_k` breaks them. Outputs at or past `n_real` are
+identity ("zero-compute") experts: `E_i(y) = y`, so their whole
+contribution is `y` times the sum of their weights (`identity_weight`).
 
 Two forms of the held experts' part, equal in what they compute and
 neither dropping a token (`held_experts`):
@@ -54,9 +62,37 @@ from ..ops.attention import einsum_f32
 MASKED_MAX_TOKENS = 256
 
 
-def route(logits, bias, top_k: int, scale: float):
+def kept_groups(p, n_group: int, topk_group: int):
+    """[T, n_group] bool: the `topk_group` groups of consecutive outputs
+    whose largest score in `p` [T, n_out] is highest."""
+    T, n_out = p.shape
+    best = jnp.max(p.reshape(T, n_group, n_out // n_group), axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)               # [T, g]
+    return jnp.any(groups[..., None] == jnp.arange(n_group), axis=1)
+
+
+def route_in_groups(logits, bias, top_k: int, scale: float, n_group: int,
+                    topk_group: int):
+    """`route`'s group-limited form, with the [T, n_group] bool of the
+    groups each row kept."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    choice = p if bias is None else p + bias.astype(jnp.float32)
+    keep = kept_groups(choice, n_group, topk_group)
+    choice = jnp.where(jnp.repeat(keep, p.shape[-1] // n_group, axis=-1),
+                       choice, 0.0)
+    _, idx = jax.lax.top_k(choice, top_k)
+    return idx, scale * jnp.take_along_axis(p, idx, axis=-1), keep
+
+
+def route(logits, bias, top_k: int, scale: float, n_group: int = 1,
+          topk_group: int = 1):
     """([T, k] picked output ids, [T, k] weights) from [T, n_out] router
-    logits (any float type; the softmax runs in float32)."""
+    logits (any float type; the softmax runs in float32). `n_group` 1
+    picks flat over all outputs; above 1 only inside the `topk_group`
+    best groups (`bias` may then be None)."""
+    if n_group > 1:
+        return route_in_groups(logits, bias, top_k, scale, n_group,
+                               topk_group)[:2]
     p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     _, idx = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)
     return idx, scale * jnp.take_along_axis(p, idx, axis=-1)
@@ -139,20 +175,28 @@ def held_experts(y, idx, w, first: int, gate, up, down) -> jax.Array:
     return grouped_experts(y, idx, w, first, gate, up, down)
 
 
-def shortcut_experts(y, router, bias, gate, up, down, *, held: Tuple[int, int],
-                     n_real: int, top_k: int, scale: float):
-    """The whole layer on this chip for y [T, H]: the held experts' part
-    plus the identity experts' (computed here in full; in the deployment
-    the token's home chip does). Returns ([T, H] in y's type, the
-    `pick_counts` of the call)."""
-    first, count = held
-    if gate.shape[0] != count:
+def _router_logits(y, router):
+    return jnp.einsum("th,hn->tn", y.astype(jnp.float32),
+                      router.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _check_held(held, gate):
+    if gate.shape[0] != held[1]:
         raise ValueError(f"holds {gate.shape[0]} experts' weights but was "
                          f"told held={held}")
+
+
+def shortcut_experts(y, router, bias, gate, up, down, *, held: Tuple[int, int],
+                     n_real: int, top_k: int, scale: float):
+    """LongCat-Flash's whole layer on this chip for y [T, H]: the held
+    experts' part plus the identity experts' (computed here in full; in
+    the deployment the token's home chip does). Returns ([T, H] in y's
+    type, the `pick_counts` of the call)."""
+    first, count = held
+    _check_held(held, gate)
     with jax.named_scope("moe.route"):
-        logits = jnp.einsum("th,hn->tn", y.astype(jnp.float32),
-                            router.astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST)
+        logits = _router_logits(y, router)
         idx, w = route(logits, bias, top_k, scale)
         counts = pick_counts(idx, first, count, n_real)
     with jax.named_scope("moe.experts"):
@@ -163,6 +207,34 @@ def shortcut_experts(y, router, bias, gate, up, down, *, held: Tuple[int, int],
     return out.astype(y.dtype), counts
 
 
-__all__ = ["MASKED_MAX_TOKENS", "route", "held_gates", "identity_weight",
-           "pick_counts", "masked_experts", "grouped_experts",
-           "held_experts", "shortcut_experts"]
+def group_limited_experts(y, router, gate, up, down, *,
+                          held: Tuple[int, int], top_k: int, scale: float,
+                          n_group: int, topk_group: int):
+    """The routed part of a layer with group-limited routing, no bias and
+    no identity experts (DeepSeek-V2; the shared expert that every token
+    passes is the model's own and is added there), on this chip for
+    y [T, H]: the same two forms of the held experts' part. Returns
+    ([T, H] float32, (picks on held experts, the largest held expert's
+    load, rows whose kept groups include one that holds a held expert —
+    the rows the deployment's dispatch would send to this chip))."""
+    first, count = held
+    _check_held(held, gate)
+    n_out = router.shape[-1]
+    with jax.named_scope("moe.route"):
+        logits = _router_logits(y, router)
+        idx, w, keep = route_in_groups(logits, None, top_k, scale, n_group,
+                                       topk_group)
+        picks, _, load = pick_counts(idx, first, count, n_out)
+        size = n_out // n_group
+        mine = (jnp.arange(n_group) >= first // size) \
+            & (jnp.arange(n_group) <= (first + count - 1) // size)
+        hits = jnp.sum(jnp.any(keep & mine, axis=-1).astype(jnp.int32))
+    with jax.named_scope("moe.experts"):
+        out = held_experts(y, idx, w, first, gate, up, down)
+    return out, (picks, load, hits)
+
+
+__all__ = ["MASKED_MAX_TOKENS", "route", "route_in_groups", "kept_groups", "held_gates",
+           "identity_weight", "pick_counts", "masked_experts",
+           "grouped_experts", "held_experts", "shortcut_experts",
+           "group_limited_experts"]

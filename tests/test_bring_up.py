@@ -331,6 +331,39 @@ def test_kernel_parity_harness_runs_the_latent_decode_kernel_when_asked():
     assert MLA_CASE["max_len"] % MLA_CASE["page_size"] == 0
 
 
+def test_kernel_parity_harness_runs_deepseek_v2s_latent_paths_when_asked(
+        monkeypatch):
+    """The same kernel under the other model that shares `LatentAttention`
+    (YaRN, no rank factors; a table longer than the rows' live pages),
+    and its chunk path in row groups against K and V expanded."""
+    from mpi_operator_tpu.examples.kernel_parity import (MLA_CASE_128,
+                                                         MLA_CHUNK_CASE,
+                                                         mla_chunk_case,
+                                                         mla_decode_case)
+    from mpi_operator_tpu.ops import attention
+
+    toy = dict(hidden_size=48, num_heads=4, q_lora_rank=12, kv_lora_rank=16,
+               qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8)
+    rec = mla_decode_case(slots=3, max_len=128, page_size=8, prefilled=40,
+                          family="deepseek_v2", **toy)
+    assert rec["traced"] == "pallas_mla_paged[live,pages=16]"
+    assert rec["shape"]["family"] == "deepseek_v2"
+    assert rec["max_rel_err"] <= 2e-2
+    assert MLA_CASE_128["family"] == "deepseek_v2"
+    assert MLA_CASE_128["max_len"] // MLA_CASE_128["page_size"] == 256
+    # the real case takes the row-group path; so does the toy, told to
+    assert attention.mla_query_rows(
+        MLA_CHUNK_CASE["rows"], MLA_CHUNK_CASE["chunk"], 128, 640,
+        "bfloat16") == 4
+    with pytest.raises(AssertionError, match="builds its queries whole"):
+        mla_chunk_case(rows=4, chunk=16, page_size=8, **toy)
+    monkeypatch.setattr(attention, "_MLA_BUILT_QUERY_BYTES", 0)
+    monkeypatch.setattr(attention, "_MLA_QUERY_ROWS", 2 * 16 * 4)
+    rec = mla_chunk_case(rows=4, chunk=16, page_size=8, **toy)
+    assert rec["kernel"] == "mla_paged_attend_rows"
+    assert rec["shape"]["rows_a_group"] == 2 and rec["max_rel_err"] <= 2e-2
+
+
 def test_kernel_parity_harness_runs_the_windowed_kernel_and_the_scan():
     from mpi_operator_tpu.examples.kernel_parity import (scan_case,
                                                          window_decode_cases)

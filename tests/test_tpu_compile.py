@@ -195,6 +195,122 @@ def test_longcat_decode_step_fits_the_chip_beside_its_weights_and_pool(
 
 
 # ---------------------------------------------------------------------------
+# DeepSeek-V2: the same latent kernel and chunk walk at 128 heads
+# ---------------------------------------------------------------------------
+DSV2 = dict(slots=64, pages=11008, page=64, max_len=16384, heads=128)
+
+
+def test_latent_decode_kernel_compiles_at_128_heads_over_a_table_of_256(
+        one_chip, quiet_cache):
+    """DeepSeek-V2's widths: 128 heads on rows of 640, 11 008 pages of 64,
+    a table of 256, under which a turn takes 16 pages. Mosaic takes the
+    kernel as laid out for 64 heads (q [1, 128, 640], float32 scores and
+    probabilities [128, 1024] a turn, acc [128, 512], two slots of 1.3
+    MB); the pool stays where it lies. LongCat-Flash's table of 100
+    keeps 8 pages a turn."""
+    from mpi_operator_tpu.ops.attention import (mla_pages_per_turn,
+                                                mla_paged_decode_attention)
+    S, NP, ps, H = (DSV2[k] for k in ("slots", "pages", "page", "heads"))
+    assert mla_pages_per_turn(DSV2["max_len"] // ps, ps * 640 * 2) == 16
+    assert mla_pages_per_turn(MAX_LEN // PAGE, PAGE * 640 * 2) == 8
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+
+    def step(q, pool, cur, pt, rows, at):
+        pool = pool.reshape(NP * ps, 640).at[at].set(
+            rows, mode="drop").reshape(NP, ps, 640)
+        return pool, mla_paged_decode_attention(q, pool, cur, pt, 512,
+                                                0.11472, interpret=False)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        spec((S, H, 640), jnp.bfloat16), spec((NP, ps, 640), jnp.bfloat16),
+        spec((S,), jnp.int32), spec((S, DSV2["max_len"] // ps), jnp.int32),
+        spec((S, 640), jnp.bfloat16), spec((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (NP, ps, 640), (NP * ps, 640)) == []
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= NP * ps * 640 * 2
+    assert m.temp_size_in_bytes < 64 << 20
+
+
+@pytest.fixture(scope="module")
+def deepseek_v2(one_chip):
+    """(dims, decode-mode model, abstract params and cache on the chip) of
+    `deepseek-v2-1of8` as `serve-deepseekv2-1of8-longdoc` serves it."""
+    from mpi_operator_tpu.models.generate import decode_model
+    from perfbench import weights_deepseekv2 as wd
+    from perfbench.kinds import _serve_deepseekv2
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "deepseek-v2-1of8.json")) as f:
+        dims = wd.Dims.from_config(json.load(f))
+    S, NP, ps, L = (DSV2[k] for k in ("slots", "pages", "page", "max_len"))
+    dmodel = decode_model(
+        _serve_deepseekv2.model_of(dims, jnp.bfloat16, L, True), True,
+        page_size=ps, num_pages=NP)
+    on_chip = lambda tree: jax.tree.map(                        # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: wd.make_params(jax.random.PRNGKey(0), dims, jnp.bfloat16)))
+    z = jnp.zeros((S, 1), jnp.int32)
+    table = jnp.zeros((S, L // ps), jnp.int32)
+    cache = on_chip(jax.eval_shape(
+        lambda p: dmodel.apply({"params": p}, z, positions=z,
+                               with_head=False, mutable=["cache"],
+                               pages=table)[1]["cache"], params))
+    return dims, dmodel, params, cache
+
+
+def test_deepseek_v2_decode_step_fits_the_chip_beside_its_weights_and_pool(
+        one_chip, quiet_cache, monkeypatch, deepseek_v2):
+    """The engine's own `step_paged` over the dense layer and four expert
+    layers: 6.29 GB of weights and a 4.51 GB pool resident, five kernel
+    calls under `mla.attend`, next to no temporaries, no pool-wide copy."""
+    dims, dmodel, params, cache = deepseek_v2
+    S, NP, ps, L = (DSV2[k] for k in ("slots", "pages", "page", "max_len"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _lower_greedy_step(
+        _engine_programs(dmodel, S, ps), params, cache, S, L // ps,
+        one_chip).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert [c.rsplit(".", 1)[0] for c in calls] == ["mla.attend"] * 5
+    assert _copies_of(text, (NP, ps, 640), (NP * ps, 640)) == []
+    assert m.alias_size_in_bytes >= dims.layers * NP * ps * 640 * 2
+    assert 10.7e9 < m.argument_size_in_bytes < 10.9e9
+    assert m.temp_size_in_bytes < 0.5e9
+
+
+def test_deepseek_v2_prefill_bucket_builds_its_queries_in_row_groups(
+        one_chip, quiet_cache, monkeypatch, deepseek_v2):
+    """The engine's own `prefill_paged` at the cell's one bucket ([64,
+    128] tokens, 128 heads): the absorbed queries are built four rows at
+    a time, so the program's temporaries stay under 3 GB beside the 10.8
+    GB the engine holds (built for all 64 rows at once they alone would
+    be 1.34 + 2 x 1.07 GB a layer); no copy of a pool, the cache
+    aliased."""
+    dims, dmodel, params, cache = deepseek_v2
+    S, NP, ps, L = (DSV2[k] for k in ("slots", "pages", "page", "max_len"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
+                                              sharding=one_chip)
+    compiled = _engine_programs(dmodel, S, ps).prefill.lower(
+        params, cache, arg(jnp.int32, S, 128), arg(jnp.int32, S),
+        arg(jnp.int32, S, L // ps)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert "tpu_custom_call" not in text
+    assert _copies_of(text, (NP, ps, 640), (NP * ps, 640)) == []
+    assert m.alias_size_in_bytes >= dims.layers * NP * ps * 640 * 2
+    assert m.temp_size_in_bytes < 3.0e9
+    assert held + m.temp_size_in_bytes < 15.6e9
+
+
+# ---------------------------------------------------------------------------
 # gpt2-xl: the per-head page pool as rows [pages, page, KV * 2D]
 # ---------------------------------------------------------------------------
 XL = dict(slots=64, pages=384, page=64, heads=25, head_dim=64, max_len=1024)
