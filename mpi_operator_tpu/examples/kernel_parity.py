@@ -15,7 +15,8 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   mla_paged_decode_attention (absorbed latent   vs `mla_paged_attend`, the
   attention, LongCat-Flash's published widths     dense form of
   and DeepSeek-V2's: 128 heads, YaRN, a table      `LatentAttention`
-  of 256 pages)
+  of 256 pages, rows of three turns in two
+  chains)
   mla_paged_attend_rows (a [64, 128] chunk of   vs `LatentAttention` with K
   128 heads, its absorbed queries built four       and V expanded
   rows at a time)
@@ -71,8 +72,9 @@ SERVE_SHAPE = dict(slots=8, max_len=256, page_size=64, prefilled=200)
 MLA_CASE = dict(slots=8, max_len=1280, page_size=64, prefilled=1000)
 #: the same kernel and module at DeepSeek-V2's (`models/deepseek_v2.py`):
 #: 128 heads, no rank factors, YaRN's frequencies and scale, the cell's
-#: table of 256 pages
-MLA_CASE_128 = dict(slots=8, max_len=16384, page_size=64, prefilled=1000,
+#: table of 256 pages; contexts of three turns of sixteen pages, so that
+#: cursors stand either side of a turn's edge and a slot is filled twice
+MLA_CASE_128 = dict(slots=8, max_len=16384, page_size=64, prefilled=2500,
                     family="deepseek_v2")
 #: the chunk path at the cell's one prefill shape, which builds its
 #: absorbed queries a group of rows at a time
@@ -344,7 +346,8 @@ def mla_decode_case(slots: int, max_len: int, page_size: int, prefilled: int,
     name = traced_name(traced["decode"]) or ""
     if not name.startswith("pallas_mla_paged[live,pages=") or "+" in name:
         raise AssertionError(f"decode step traced {traced['decode']}, "
-                             f"expected one pallas_mla_paged[live,pages=N]")
+                             f"expected one pallas_mla_paged[live,pages=N] "
+                             f"or [live,pages=N,chains=C]")
     ref = step(dense)(params, filled["cache"])
     return {"kernel": "mla_paged_decode_attention", "traced": name,
             "shape": {"slots": slots, "max_len": max_len,

@@ -93,8 +93,8 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
                     a grid step) | "pallas_paged[hb=N]" (an int8 pool:
                     the grid form; N: kv heads a grid step of the kernel
                     covers, `decode_head_block`) |
-                    "pallas_mla_paged[live,pages=N]" (the latent kernel
-                    that walks a row's live pages, N a turn) | "dense"
+                    "pallas_mla_paged[live,pages=N(,chains=C)]" (the latent
+                    walk, N pages a turn, C chains in flight) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
     A jitted function traces once, so wrap the whole run (first call
     included), not a later window."""
@@ -1710,105 +1710,167 @@ _MLA_LONG_TABLE = 128
 def mla_pages_per_turn(nblk: int, page_bytes: int) -> int:
     """Pages one turn of the latent decode kernel's loop takes: what fits
     a slot of `_MLA_PAGES_VMEM_BUDGET`, at least one, at most the table.
-    More pages a turn give the MXU a wider score block and the loop
-    fewer turns; a turn's pages past the row's last live one are fetched
-    again from that page, so a wide turn wastes bytes on short contexts
-    (PERF.md, PR 30: eight 80 KB pages at LongCat-Flash's widths, whose
-    rows start at 64 positions). Under a long table (`_MLA_LONG_TABLE`)
-    that tail is a small share of a row's pages and the slot is doubled
-    (PERF.md, PR 39: sixteen pages a turn take 2.72 ns a cached token and
-    layer at 128 heads where eight take 3.22)."""
+    More pages a turn give the loop fewer turns, each with its fixed
+    cost (the loop's edge, the MXU filling and draining); a turn's pages
+    past the row's last live one are fetched again from that page and
+    attended under the mask, so a wide turn wastes bytes and products on
+    short contexts (PERF.md, PR 30: eight 80 KB pages at LongCat-Flash's
+    widths, whose rows start at 64 positions). Under a long table
+    (`_MLA_LONG_TABLE`) that tail is a small share of a row's pages and
+    the slot is doubled (PERF.md, PR 39 and PR 40: sixteen pages a turn
+    against eight and thirty-two, for one chain and for two)."""
     slot = _MLA_PAGES_VMEM_BUDGET * (2 if nblk >= _MLA_LONG_TABLE else 1)
     return max(1, min(nblk, slot // (2 * page_bytes)))
 
 
+#: chains a turn is cut into from `_MLA_CHAINS_HEADS` heads on: there a
+#: page's products cost the MXU what its copy costs HBM (a page of 64 rows
+#: of 640 bfloat16 columns: 0.100 us of HBM; 2 x H x (640 + 512) x 64
+#: products: 0.096 us of the MXU at 128 heads, 0.048 at 64)
+_MLA_CHAINS, _MLA_CHAINS_HEADS = 2, 128
+
+
+def mla_chains(H: int, pages: int) -> int:
+    """Chains of score product -> softmax -> p.v product that one turn of
+    the latent decode kernel keeps in flight, from the call's own shapes:
+    `_MLA_CHAINS` of `pages / _MLA_CHAINS` pages each from
+    `_MLA_CHAINS_HEADS` heads on (where the turn's pages divide so), else
+    one. In one chain the MXU stands idle between the score product and
+    p.v while the row maximum crosses the lanes and the first
+    exponentials are taken: a fifth of a turn's instructions at 128
+    heads, where the MXU's work a page is as long as the page's copy and
+    nothing hides it (11% of the kernel's time on the chip: PERF.md, PR
+    40). At 64 heads the copies bound the turn either way, and two chains
+    measure what one does."""
+    if H >= _MLA_CHAINS_HEADS and pages % _MLA_CHAINS == 0:
+        return _MLA_CHAINS
+    return 1
+
+
 def _mla_decode_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
                        sem_ref, slot_ref, acc_ref, m_ref, l_ref, *,
-                       sm_scale, ps, rank, nblk, pages):
+                       sm_scale, ps, rank, nblk, pages, chains):
     """One decode step for one row: grid (B,), rows in order. The pool
     stays in HBM; the row's LIVE pages — logical pages 0 .. min(cursor //
-    ps, nblk - 1), and no others — are walked `pages` at a time, each
-    turn's pages copied into one of two VMEM slots while the other
-    slot's are attended: all H heads share them, scores [H, pages * ps]
-    from the absorbed query against whole rows, p.v against their first
-    `rank` columns, an online softmax across turns. The row's last turn
-    starts the next row's first copies, so only the call's first row
-    waits for a page; `slot_ref` carries which slot they went to.
+    ps, nblk - 1), and no others — are walked `pages` at a time through
+    two VMEM slots: all H heads share a turn's pages, scores against
+    whole rows, p.v against their first `rank` columns, an online softmax
+    in float32 across chains and turns.
+
+    A turn is ONE straight-line block, which the compiler schedules as a
+    whole: a branch or a loop inside it would end the block, and the MXU
+    would stand idle on either side (the loop that started a turn's
+    copies page by page and the loop that waited for them were a quarter
+    of the parent's turn: PERF.md, PR 40). So:
+
+    - the turn waits ONCE, for its whole slot (a wait reads a size);
+    - it is cut into `chains` chains of `pages / chains` pages, each an
+      online-softmax update of its own, and chain c + 1's score product
+      is issued BEFORE chain c's softmax: the MXU multiplies one chain's
+      operands while the vector units take the other's maximum,
+      exponentials and sums (`mla_chains`);
+    - behind a chain's update, its share of the slot is filled again for
+      the turn AFTER THE NEXT (the call's turns in order, across rows):
+      a copy has a whole turn to land, the copy engine never runs dry,
+      and the starts' address arithmetic runs on the scalar unit under
+      the products. The starts are a loop unrolled where it is lowered,
+      so the text holds one descriptor a chain (a descriptor a page cost
+      a serving process 0.3 s of tracing a call: PERF.md, PR 30), and
+      they are unconditional: the call's last two turns fetch the last
+      row's first pages once more, and the last row waits for them
+      before it leaves. Only the call's first row starts copies and then
+      waits for them; `slot_ref` carries a row's first slot to it.
 
     A turn's pages past the last live one are copied from that page
     again and masked: nothing a dead table entry points at is read. A
     cursor past the logical cache (a retiring row's post-EOS step)
-    attends the whole table, as the dense form's clamp does.
-
-    A turn's copies are started, and waited for, in a loop over its
-    pages and not one by one in Python: a kernel with a descriptor a
-    page in its text cost a serving process 0.3 s of tracing a call,
-    sixteen calls a set-up (PERF.md, PR 30)."""
+    attends the whole table, as the dense form's clamp does."""
     b, nb = pl.program_id(0), pl.num_programs(0)
     width = pages * ps
+    share = pages // chains
 
     def last_live(row):
         return jnp.minimum(cur_ref[row] // ps, nblk - 1)
 
-    def copy(page, slot, k):
-        return pltpu.make_async_copy(pool_ref.at[page], buf_ref.at[slot, k],
-                                     sem_ref.at[slot])
+    def turns_of(row):
+        return last_live(row) // pages + 1
 
-    def start(row, turn, slot):
+    def after(row, turn):
+        """The turn behind (row, turn) in the call's order: the last
+        row's last turn is followed by that row's first."""
+        more = turn + 1 < turns_of(row)
+        return (jnp.where(more, row, jnp.minimum(row + 1, nb - 1)),
+                jnp.where(more, turn + 1, 0))
+
+    def start(row, turn, slot, first=0, count=pages, unroll=False):
         last = last_live(row)
 
         def one(k, _):
-            copy(pt_ref[row, jnp.minimum(turn * pages + k, last)], slot,
-                 k).start()
-        jax.lax.fori_loop(0, pages, one, None)
+            pltpu.make_async_copy(
+                pool_ref.at[pt_ref[row, jnp.minimum(turn * pages + k, last)]],
+                buf_ref.at[slot, k], sem_ref.at[slot]).start()
+        jax.lax.fori_loop(first, first + count, one, None, unroll=unroll)
 
-    def wait(slot):
-        def one(k, _):
-            copy(0, slot, k).wait()     # a wait reads the size, not the page
-        jax.lax.fori_loop(0, pages, one, None)
-
-    turns = last_live(b) // pages + 1
-    cur = jnp.minimum(cur_ref[b], nblk * ps - 1)
+    def wait(slot):         # the slot's bytes at once, whatever filled it
+        pltpu.make_async_copy(buf_ref.at[slot], buf_ref.at[slot],
+                              sem_ref.at[slot]).wait()
 
     @pl.when(b == 0)
     def _first_row():
         slot_ref[0] = 0
         start(b, 0, 0)
+        start(*after(b, 0), 1)
 
     first_slot = slot_ref[0]
+    turns = turns_of(b)
+    cur = jnp.minimum(cur_ref[b], nblk * ps - 1)
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
 
     def turn(i, _):
-        slot = (first_slot + i) % 2
-        more = i + 1 < turns
-
-        @pl.when(more | (b + 1 < nb))
-        def _next():    # this row's next turn, or the next row's first
-            start(jnp.where(more, b, jnp.minimum(b + 1, nb - 1)),
-                  jnp.where(more, i + 1, 0), 1 - slot)
-
+        slot = (first_slot + i) & 1
+        refill = after(*after(b, i))
         wait(slot)
-        rows = buf_ref[slot].reshape(width, buf_ref.shape[-1])
-        s = jax.lax.dot_general(
-            q_ref[0], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale    # [H, width]
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(i * width + cols <= cur, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(
-            p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p.astype(rows.dtype), rows[:, :rank],
-            preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new
+
+        def scores(c):
+            rows = buf_ref[slot, c * share:(c + 1) * share].reshape(
+                share * ps, buf_ref.shape[-1])
+            s = jax.lax.dot_general(
+                q_ref[0], rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [H, columns]
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            first = i * width + c * share * ps
+            return jnp.where(first + cols <= cur, s, NEG_INF), rows
+
+        def update(c, s, rows):
+            m_prev = m_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+                p.astype(rows.dtype), rows[:, :rank],
+                preferred_element_type=jnp.float32)
+            m_ref[:, :1] = m_new
+            start(*refill, slot, c * share, share, unroll=True)
+
+        held = scores(0)
+        for c in range(1, chains):
+            ahead = scores(c)
+            update(c - 1, *held)
+            held = ahead
+        update(chains - 1, *held)
 
     jax.lax.fori_loop(0, turns, turn, None)
-    slot_ref[0] = (first_slot + turns) % 2
+    slot_ref[0] = (first_slot + turns) & 1
+
+    @pl.when(b == nb - 1)
+    def _last_row():
+        wait(0)
+        wait(1)
+
     o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
                 ).astype(o_ref.dtype)
 
@@ -1823,7 +1885,9 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
     int32 [B] (row b attends positions <= cursor(b)), page_table int32
     [B, nblk]. Returns u [B, H, rank]. One grid step a row, which walks
     the row's live pages with its own copies (`_mla_decode_kernel`): the
-    time follows the contexts, not the table's length."""
+    time follows the contexts, not the table's length. The pages a turn
+    and the chains a turn keeps in flight follow from the shapes
+    (`mla_pages_per_turn`, `mla_chains`) and are in the traced name."""
     B, H, W = q.shape
     NP, ps, _ = pool.shape
     if pool.shape[2] != W or rank > W:
@@ -1848,7 +1912,23 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
             mesh, B, 1, (q, pool, cur, pt),
             (rows, (None, None, None), ("rows",), ("rows", None)), rows)
     pages = mla_pages_per_turn(nblk, ps * W * pool.dtype.itemsize)
-    note_traced("decode", f"pallas_mla_paged[live,pages={pages}]")
+    chains = mla_chains(H, pages)
+    note_traced("decode", f"pallas_mla_paged[live,pages={pages}"
+                          + (f",chains={chains}]" if chains > 1 else "]"))
+    return _mla_walk(q, pool, cur, pt, rank, sm_scale, interpret, pages,
+                     chains)
+
+
+# jitted, and inlined where it is called, as `_paged_walk` is: a model's
+# layers call the kernel with the same shapes, and its body is traced once
+# a program and not once a layer (a body traced a layer, with a turn's
+# starts unrolled in it, cost DeepSeek-V2's set-up 3.9 s: PERF.md, PR 40)
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8), inline=True)
+def _mla_walk(q, pool, cur, pt, rank, sm_scale, interpret, pages, chains):
+    """`_mla_decode_kernel`'s pallas_call: cursors and page table as scalar
+    prefetch, the pool an HBM operand, two slots of `pages` pages."""
+    B, H, W = q.shape
+    ps, nblk = pool.shape[1], pt.shape[1]
 
     def row_spec(minor):
         return pl.BlockSpec((1, H, minor), lambda b, *pre: (b, 0, 0))
@@ -1869,10 +1949,10 @@ def mla_paged_decode_attention(q, pool, cache_index, page_table, rank: int,
     )
     return pl.pallas_call(
         functools.partial(_mla_decode_kernel, sm_scale=sm_scale, ps=ps,
-                          rank=rank, nblk=nblk, pages=pages),
+                          rank=rank, nblk=nblk, pages=pages, chains=chains),
         grid_spec=grid_spec,
         out_shape=_out_struct((B, H, rank), q.dtype, q, pool),
-        # rows in order: a row's last turn fetches for the next
+        # rows in order: a turn's slot is filled by the turns before it
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -2024,5 +2104,5 @@ __all__ = ["flash_attention", "decode_attention", "decode_block_k",
            "pack_kv_rows", "paged_attend",
            "mla_paged_attend", "mla_paged_attend_rows", "mla_query_rows",
            "mla_paged_decode_attention",
-           "mla_pages_per_turn", "mla_row_width", "einsum_f32",
+           "mla_pages_per_turn", "mla_chains", "mla_row_width", "einsum_f32",
            "record_traced", "note_traced", "traced_name"]

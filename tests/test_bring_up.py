@@ -349,6 +349,17 @@ def test_kernel_parity_harness_runs_deepseek_v2s_latent_paths_when_asked(
     assert rec["traced"] == "pallas_mla_paged[live,pages=16]"
     assert rec["shape"]["family"] == "deepseek_v2"
     assert rec["max_rel_err"] <= 2e-2
+    # at the model's 128 heads a turn goes in two chains, and says so
+    # (the toy's four heads stand in for them, and its pages of 2 KB are
+    # held to sixteen a turn); three turns a row
+    with monkeypatch.context() as mp:
+        mp.setattr(attention, "_MLA_CHAINS_HEADS", 4)
+        mp.setattr(attention, "_MLA_PAGES_VMEM_BUDGET", 16 * 8 * 128 * 2)
+        rec = mla_decode_case(slots=7, max_len=1024, page_size=8,
+                              prefilled=300, family="deepseek_v2", **toy)
+    assert rec["traced"] == "pallas_mla_paged[live,pages=16,chains=2]"
+    assert max(rec["cursors"]) == 2 * 16 * 8 and rec["max_rel_err"] <= 2e-2
+    assert MLA_CASE_128["prefilled"] > 2 * 16 * MLA_CASE_128["page_size"]
     assert MLA_CASE_128["family"] == "deepseek_v2"
     assert MLA_CASE_128["max_len"] // MLA_CASE_128["page_size"] == 256
     # the real case takes the row-group path; so does the toy, told to

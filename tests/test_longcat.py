@@ -15,10 +15,11 @@ import pytest
 
 from mpi_operator_tpu.models.longcat import (LatentAttention, LongcatLM,
                                              rope_interleaved)
-from mpi_operator_tpu.ops.attention import (mla_paged_attend,
+from mpi_operator_tpu.ops.attention import (mla_chains, mla_paged_attend,
                                             mla_paged_decode_attention,
                                             mla_pages_per_turn,
-                                            mla_row_width)
+                                            mla_row_width, record_traced,
+                                            traced_name)
 from mpi_operator_tpu.parallel import held_experts as he
 from mpi_operator_tpu.serve import EngineConfig, Request, ServingEngine
 from mpi_operator_tpu.serve.transfer import PageTransfer
@@ -170,13 +171,18 @@ def _brute_latent(q, pool, cur, pt, R, sm_scale):
                       gathered[..., :R])
 
 
-def _pages_a_turn(monkeypatch, pages, ps=16, W=128):
-    """Size the kernel's page slots for `pages` float32 pages a turn."""
+def _pages_a_turn(monkeypatch, pages, ps=16, W=128, chains=1):
+    """Size the kernel's page slots for `pages` float32 pages a turn, and
+    have the toy's four heads take a turn in `chains` chains (where the
+    turn's pages divide so): the form that 128 heads take."""
     from mpi_operator_tpu.ops import attention
     monkeypatch.setattr(attention, "_MLA_PAGES_VMEM_BUDGET",
                         2 * pages * ps * W * 4)
+    monkeypatch.setattr(attention, "_MLA_CHAINS", chains)
+    monkeypatch.setattr(attention, "_MLA_CHAINS_HEADS", 4)
 
 
+@pytest.mark.parametrize("chains", [1, 2])
 @pytest.mark.parametrize("pages", [1, 2, 3, 100])
 @pytest.mark.parametrize("nblk,cursors", [
     (4, [0, 15, 16, 63]),          # the first page, its end, the table's
@@ -190,8 +196,8 @@ def _pages_a_turn(monkeypatch, pages, ps=16, W=128):
     (13, [15, 16, 31, 32]),
     (13, [63, 64, 127, 128])])
 def test_latent_decode_kernel_interpreted_matches_the_dense_form(
-        monkeypatch, nblk, cursors, pages):
-    _pages_a_turn(monkeypatch, pages)
+        monkeypatch, nblk, cursors, pages, chains):
+    _pages_a_turn(monkeypatch, pages, chains=chains)
     q, pool, pt = _latent_case(nblk)
     cur = jnp.asarray(cursors, jnp.int32)
     got = mla_paged_decode_attention(q, pool, cur, pt, 32, 0.2)
@@ -203,13 +209,14 @@ def test_latent_decode_kernel_interpreted_matches_the_dense_form(
     assert float(jnp.abs(want - brute)[inside].max()) < 1e-5
 
 
-@pytest.mark.parametrize("pages", [1, 2, 100])
+@pytest.mark.parametrize("pages,chains", [(1, 1), (2, 1), (100, 1), (2, 2),
+                                          (4, 4), (100, 2)])
 def test_latent_decode_kernel_walks_a_free_row_beside_live_ones(
-        monkeypatch, pages):
+        monkeypatch, pages, chains):
     """A free slot's table is all trash and its cursor 0: it walks one
-    page (the trash page) and disturbs neither neighbour — the row
+    page (the trash page) and disturbs neither neighbour — the rows
     before it fetched its first page for it."""
-    _pages_a_turn(monkeypatch, pages)
+    _pages_a_turn(monkeypatch, pages, chains=chains)
     q, pool, pt = _latent_case(6)
     pt = pt.at[1].set(0).at[3].set(0)
     cur = jnp.asarray([70, 0, 33, 0], jnp.int32)
@@ -218,13 +225,15 @@ def test_latent_decode_kernel_walks_a_free_row_beside_live_ones(
                          ).max()) < 1e-5
 
 
-@pytest.mark.parametrize("pages", [1, 2, 3, 100])
+@pytest.mark.parametrize("pages,chains", [(1, 1), (2, 1), (3, 1), (100, 1),
+                                          (2, 2), (4, 2), (4, 4)])
 def test_latent_decode_kernel_reads_no_page_past_a_rows_cursor(
-        monkeypatch, pages):
+        monkeypatch, pages, chains):
     """Every table entry past a row's last live page points at a page
     full of NaN: a kernel that fetched one into a turn would carry it
-    into the output through 0 x NaN."""
-    _pages_a_turn(monkeypatch, pages)
+    into the output through 0 x NaN. The call's last two turns fetch
+    once more, unconditionally: the last row's first pages."""
+    _pages_a_turn(monkeypatch, pages, chains=chains)
     nblk, ps = 9, 16
     q, pool, pt = _latent_case(nblk)
     cur = jnp.asarray([0, 16, 47, 143], jnp.int32)
@@ -244,19 +253,66 @@ def test_latent_decode_kernel_sizes_a_turn_from_the_pages_bytes():
     assert mla_pages_per_turn(100, 64 * 640 * 2) == 8
     assert mla_pages_per_turn(3, 64 * 640 * 2) == 3       # a short table
     assert mla_pages_per_turn(100, 1 << 30) == 1          # never none
+    # and cuts a turn into chains from the head count, where it divides
+    assert mla_chains(64, 8) == 1 and mla_chains(64, 16) == 1
+    assert mla_chains(128, 16) == 2 and mla_chains(128, 8) == 2
+    assert mla_chains(128, 3) == 1 and mla_chains(256, 16) == 2
 
 
-def test_latent_decode_kernel_starts_a_turns_copies_in_a_loop():
-    """Two places start copies (the call's first row; the next turn or
-    the next row) and one waits, however many pages a turn takes: a
-    descriptor a page in the kernel's text is paid for in every trace of
-    a program that calls it (PERF.md, PR 30)."""
+@pytest.mark.parametrize("chains,starts,traced_as", [
+    (1, 3, "pallas_mla_paged[live,pages=8]"),
+    (2, 4, "pallas_mla_paged[live,pages=8,chains=2]"),
+    (4, 6, "pallas_mla_paged[live,pages=8,chains=4]")])
+def test_latent_decode_kernel_starts_a_turns_copies_in_a_loop(
+        monkeypatch, chains, starts, traced_as):
+    """However many pages a turn takes, the kernel's text holds two
+    starts for the call's first row (its first two turns) and one a
+    chain (a loop unrolled where it is lowered, not where it is traced),
+    and three waits of a whole slot (a turn's, the last row's two): a
+    descriptor a page in the text is paid for in every trace of a
+    program that calls the kernel (PERF.md, PR 30)."""
+    _pages_a_turn(monkeypatch, 8, chains=chains)
     q, pool, pt = _latent_case(13)
-    text = str(jax.make_jaxpr(
-        lambda *a: mla_paged_decode_attention(*a, 32, 0.2, interpret=False))(
-        q, pool, jnp.zeros(4, jnp.int32), pt))
-    assert mla_pages_per_turn(13, 16 * 128 * 4) > 2
-    assert text.count("dma_start") == 2 and text.count("dma_wait") == 1
+    with record_traced() as traced:
+        text = str(jax.make_jaxpr(
+            lambda *a: mla_paged_decode_attention(*a, 32, 0.2,
+                                                  interpret=False))(
+            q, pool, jnp.zeros(4, jnp.int32), pt))
+    assert traced_name(traced["decode"]) == traced_as
+    assert (text.count("dma_start"), text.count("dma_wait")) == (starts, 3)
+
+
+@pytest.mark.parametrize("H,nblk,traced_as", [
+    (128, 256, "pallas_mla_paged[live,pages=16,chains=2]"),   # DeepSeek-V2
+    (64, 100, "pallas_mla_paged[live,pages=8]")])             # LongCat-Flash
+def test_latent_decode_kernel_at_the_cells_shapes_matches_the_dense_form(
+        H, nblk, traced_as):
+    """The kernel as the two cells' programs hold it (bfloat16 pages of 64
+    rows of 640, rank 512; interpreted) against `mla_paged_attend`: a
+    cursor on a page's first and on its last position, a row of one live
+    page, a row whose last turn is one page long, a row at the table's
+    end and a cursor past the table."""
+    ps, W, R = 64, 640, 512
+    pages = mla_pages_per_turn(nblk, ps * W * 2)
+    turn = pages * ps
+    cursors = [3 * ps, 5 * ps - 1, 7, turn + 5, 2 * turn + ps - 1,
+               nblk * ps - 1, 2 ** 30]
+    B = len(cursors)
+    k = jax.random.split(jax.random.PRNGKey(H), 2)
+    q = jax.random.normal(k[0], (B, H, W), jnp.bfloat16)
+    pool = jax.random.normal(k[1], (nblk + 1, ps, W), jnp.bfloat16)
+    rng = np.random.RandomState(H)      # rows share the pool's pages
+    pt = jnp.asarray(np.stack([rng.permutation(nblk) + 1
+                               for _ in range(B)]), jnp.int32)
+    cur = jnp.asarray(cursors, jnp.int32)
+    with record_traced() as traced:
+        got = mla_paged_decode_attention(q, pool, cur, pt, R, 0.1)
+    assert traced_name(traced["decode"]) == traced_as
+    want = mla_paged_attend(q[:, None], pool,
+                            jnp.minimum(cur, nblk * ps - 1)[:, None], pt, R,
+                            0.1)[:, 0]
+    err = jnp.abs(got.astype(F32) - want.astype(F32)).max(axis=(1, 2))
+    assert float(err.max()) <= 2e-2 * float(jnp.abs(want.astype(F32)).max()), err
 
 
 def test_dense_form_walks_rows_in_groups_and_skips_rows_past_the_cache():

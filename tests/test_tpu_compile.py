@@ -200,19 +200,55 @@ def test_longcat_decode_step_fits_the_chip_beside_its_weights_and_pool(
 DSV2 = dict(slots=64, pages=11008, page=64, max_len=16384, heads=128)
 
 
+def _latent_vmem(H, pages, chains, ps=64, W=640, rank=512, itemsize=2):
+    """Bytes of the latent decode kernel's VMEM as its shapes give them,
+    counted as if nothing were reused: two slots of `pages` pages; q and
+    u, double-buffered by the grid; of every chain in flight float32
+    scores and probabilities [H, pages / chains * ps] and the
+    probabilities once more in the pool's type; accumulator and
+    statistics. That Mosaic compiles the call under its scoped limit is
+    the proof; this is the number."""
+    slots = 2 * pages * ps * W * itemsize
+    q_u = 2 * H * (W + rank) * itemsize
+    scores = chains * H * (pages // chains) * ps * (4 + 4 + itemsize)
+    acc = H * rank * 4 + 2 * H * 128 * 4
+    return slots + q_u + scores + acc
+
+
+@pytest.mark.parametrize("forced,traced_as", [
+    (None, "pallas_mla_paged[live,pages=16,chains=2]"),
+    ((16, 1), "pallas_mla_paged[live,pages=16]"),
+    ((8, 2), "pallas_mla_paged[live,pages=8,chains=2]"),
+    ((32, 2), "pallas_mla_paged[live,pages=32,chains=2]"),
+    ((32, 4), "pallas_mla_paged[live,pages=32,chains=4]")])
 def test_latent_decode_kernel_compiles_at_128_heads_over_a_table_of_256(
-        one_chip, quiet_cache):
+        one_chip, quiet_cache, monkeypatch, forced, traced_as):
     """DeepSeek-V2's widths: 128 heads on rows of 640, 11 008 pages of 64,
-    a table of 256, under which a turn takes 16 pages. Mosaic takes the
-    kernel as laid out for 64 heads (q [1, 128, 640], float32 scores and
-    probabilities [128, 1024] a turn, acc [128, 512], two slots of 1.3
-    MB); the pool stays where it lies. LongCat-Flash's table of 100
-    keeps 8 pages a turn."""
-    from mpi_operator_tpu.ops.attention import (mla_pages_per_turn,
-                                                mla_paged_decode_attention)
+    a table of 256, under which a turn takes 16 pages, and at 128 heads
+    takes them in two chains of eight (q [1, 128, 640], float32 scores and
+    probabilities [128, 512] a chain, acc [128, 512], two slots of 1.3
+    MB: 4.7 MB of the 16 Mosaic gives a kernel); the pool stays where it
+    lies. LongCat-Flash's table of 100 keeps 8 pages a turn and its 64
+    heads one chain. The forms the microbenchmark forces beside the
+    kernel's own (`scripts/mla_decode_microbench.py`: the parent's one
+    chain, other turns, four chains) compile too, the widest at 8.4 MB."""
+    from mpi_operator_tpu.ops import attention
+    from mpi_operator_tpu.ops.attention import (mla_chains,
+                                                mla_pages_per_turn,
+                                                mla_paged_decode_attention,
+                                                record_traced)
     S, NP, ps, H = (DSV2[k] for k in ("slots", "pages", "page", "heads"))
     assert mla_pages_per_turn(DSV2["max_len"] // ps, ps * 640 * 2) == 16
     assert mla_pages_per_turn(MAX_LEN // PAGE, PAGE * 640 * 2) == 8
+    assert mla_chains(H, 16) == 2 and mla_chains(64, 8) == 1
+    pages, chains = forced or (16, 2)
+    if forced:
+        monkeypatch.setattr(attention, "_MLA_LONG_TABLE", 1 << 30)
+        monkeypatch.setattr(attention, "_MLA_PAGES_VMEM_BUDGET",
+                            2 * pages * ps * 640 * 2)
+        monkeypatch.setattr(attention, "_MLA_CHAINS", chains)
+        monkeypatch.setattr(attention, "_MLA_CHAINS_HEADS", 1)
+    assert _latent_vmem(H, pages, chains) < SCOPED_VMEM
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
                                                   sharding=one_chip)
 
@@ -221,10 +257,13 @@ def test_latent_decode_kernel_compiles_at_128_heads_over_a_table_of_256(
             rows, mode="drop").reshape(NP, ps, 640)
         return pool, mla_paged_decode_attention(q, pool, cur, pt, 512,
                                                 0.11472, interpret=False)
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        spec((S, H, 640), jnp.bfloat16), spec((NP, ps, 640), jnp.bfloat16),
-        spec((S,), jnp.int32), spec((S, DSV2["max_len"] // ps), jnp.int32),
-        spec((S, 640), jnp.bfloat16), spec((S,), jnp.int32)).compile()
+    with record_traced() as traced:
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            spec((S, H, 640), jnp.bfloat16),
+            spec((NP, ps, 640), jnp.bfloat16), spec((S,), jnp.int32),
+            spec((S, DSV2["max_len"] // ps), jnp.int32),
+            spec((S, 640), jnp.bfloat16), spec((S,), jnp.int32)).compile()
+    assert traced["decode"] == {traced_as}
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert _copies_of(text, (NP, ps, 640), (NP * ps, 640)) == []
