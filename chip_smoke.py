@@ -14,7 +14,9 @@ would call, one child process after another:
            and a table of 256 pages, at 128, with that model's [64, 128]
            chunk in row groups; the paged decode kernel with its lower bound
            over a ring and a long table; the state-space scans, a chunk
-           against its steps, Mamba-2's through its state-update kernel)
+           against its steps, Mamba-2's through its state-update kernel at
+           Falcon-H1's head of 128 channels and at Granite-4.0-H's of 64,
+           two heads a lane tile: `ssd_traced` names the form each took)
   trainer  python -m mpi_operator_tpu.examples.lm_benchmark --workload gpt2
            --size medium --seq-len 512 (global batch 16 over all visible
            chips), started the way the operator starts a gang: a worker
@@ -69,6 +71,8 @@ SLOTS, REQUESTS = 8, 16
 LM = "mpi_operator_tpu.examples.lm_benchmark"
 SERVE = "mpi_operator_tpu.examples.serve_benchmark"
 KERNELS = "mpi_operator_tpu.examples.kernel_parity"
+SSD_FORMS = ["pallas_ssd_update[P-minor,heads=2]",
+             "pallas_ssd_update[P-minor]"]
 
 
 class LegFailed(Exception):
@@ -181,8 +185,14 @@ def check_kernels(head, device) -> dict:
     _require(head.get("platform") == "tpu",
              f"platform {head.get('platform')!r}, not 'tpu'")
     _require(head.get("ok") is True, f"kernel parity failed: {head}")
+    # the state update ran in both of its forms: a head a lane tile
+    # (Falcon-H1's 128 channels) and two heads a tile (Granite's 64)
+    _require(head.get("ssd_traced") == SSD_FORMS,
+             f"state update traced {head.get('ssd_traced')!r}, "
+             f"not {SSD_FORMS}")
     return {"kernels": head["kernels"],
             "decode_traced": head["decode_traced"],
+            "ssd_traced": head["ssd_traced"],
             "worst_max_rel_err": head["worst_max_rel_err"],
             "tol": head["tol"]}
 

@@ -27,7 +27,8 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   selective_scan over a chunk (`ops/ssm.py`)    vs its own steps one by one
   ssd_chunk_scan over a chunk, the matmul form   vs the steps of
   (Falcon-H1-34B's 32 heads of 128 over 256        `ssd_state_update`, the
-  states)                                          Pallas kernel, one by one
+  states, a head a lane tile; Granite-4.0-H's      Pallas kernel, one by one;
+  128 heads of 64 over 128, two heads a tile)      the record names the form
 
 The paged decode cases go through the `Attention` module itself — one
 set of weights, one prefilled pool, the single-token step run once with
@@ -90,6 +91,9 @@ SCAN_CASE = dict(rows=8, chunk=64, channels=5120, states=16)
 #: Mamba-2's chunk and step at Falcon-H1-34B's widths
 SSD_CASE = dict(rows=8, chunk=128, heads=32, head_dim=128, groups=2,
                 states=256)
+#: and at Granite-4.0-H-Small's: heads of 64 channels, two to a lane tile
+SSD_CASE_64 = dict(rows=8, chunk=128, heads=128, head_dim=64, groups=1,
+                   states=128)
 
 
 def _rel_err(got, ref) -> float:
@@ -508,11 +512,13 @@ def ssd_case(rows: int, chunk: int, heads: int, head_dim: int, groups: int,
              states: int) -> Dict[str, object]:
     """`ssd_chunk_scan` over a chunk with a state carried in, against the
     steps of `ssd_state_update` (on the chip: the Pallas kernel, its state
-    donated) one position at a time."""
+    donated) one position at a time; the state held as `ssd_state_shape`
+    has it, and the record's `ssd_traced` the form the update took."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.ssm import ssd_chunk_scan, ssd_state_update
+    from ..ops.attention import record_traced, traced_name
+    from ..ops.ssm import ssd_chunk_scan, ssd_state_shape, ssd_state_update
 
     ks = jax.random.split(jax.random.PRNGKey(6), 6)
     x = jax.random.normal(ks[0], (rows, chunk, heads, head_dim))
@@ -521,18 +527,21 @@ def ssd_case(rows: int, chunk: int, heads: int, head_dim: int, groups: int,
     B, C = (jax.random.normal(k, (rows, chunk, groups, states))
             for k in ks[3:5])
     D = jnp.ones((heads,))
-    s0 = jax.random.normal(ks[5], (rows, heads, states, head_dim))
+    s0 = jax.random.normal(ks[5], ssd_state_shape(rows, heads, head_dim,
+                                                  groups, states))
     y, last = jax.jit(ssd_chunk_scan)(x, dt, A, B, C, D, s0)
     step = jax.jit(ssd_state_update, donate_argnums=(6,))
-    _assert_mosaic(step, x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D, s0)
-    s, ys = s0 + 0.0, []
-    for t in range(chunk):
-        y_t, s = step(x[:, t], dt[:, t], A, B[:, t], C[:, t], D, s)
-        ys.append(y_t)
+    with record_traced() as traced:
+        _assert_mosaic(step, x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D, s0)
+        s, ys = s0 + 0.0, []
+        for t in range(chunk):
+            y_t, s = step(x[:, t], dt[:, t], A, B[:, t], C[:, t], D, s)
+            ys.append(y_t)
     return {"kernel": "ssd_chunk_scan_vs_state_update_steps",
             "shape": {"rows": rows, "chunk": chunk, "heads": heads,
                       "head_dim": head_dim, "groups": groups,
                       "states": states},
+            "ssd_traced": traced_name(traced["ssd"]),
             "max_rel_err": max(_rel_err(y, jnp.stack(ys, 1)),
                                _rel_err(last, s))}
 
@@ -545,7 +554,7 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
                       mla_chunk: Optional[dict] = None,
                       window: Optional[dict] = None,
                       scan: Optional[dict] = None,
-                      ssd: Optional[dict] = None,
+                      ssd: Optional[List[dict]] = None,
                       tol: float = BF16_TOL) -> List[Dict[str, object]]:
     """Every kernel the two legs use, at their shapes; one record each
     with its measured error and `ok`. The decode kernels run once per
@@ -571,8 +580,8 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
         records += window_decode_cases(**window)
     if scan:
         records.append(scan_case(**scan))
-    if ssd:
-        records.append(ssd_case(**ssd))
+    for case in ssd or ():
+        records.append(ssd_case(**case))
     for rec in records:
         rec["tol"] = tol
         rec["ok"] = bool(rec["max_rel_err"] <= tol)
@@ -599,7 +608,8 @@ def main(argv=None) -> int:
     records = run_kernel_parity(decode_models=[GPT2_MEDIUM, GPT2_XL],
                                 mla=[MLA_CASE, MLA_CASE_128],
                                 mla_chunk=MLA_CHUNK_CASE, window=WINDOW_CASE,
-                                scan=SCAN_CASE, ssd=SSD_CASE)
+                                scan=SCAN_CASE,
+                                ssd=[SSD_CASE, SSD_CASE_64])
     for rec in records:
         print(json.dumps({**rec, **device}))
     ok = all(rec["ok"] for rec in records)
@@ -610,6 +620,8 @@ def main(argv=None) -> int:
                       "tol": BF16_TOL,
                       "decode_traced": sorted({r["traced"] for r in records
                                                if r.get("traced")}),
+                      "ssd_traced": sorted({r["ssd_traced"] for r in records
+                                            if r.get("ssd_traced")}),
                       **device,
                       "compile_cache_dir": cache_dir}))
     return 0 if ok else 1

@@ -36,7 +36,8 @@ contract):
     scatter, read by `paged_decode_attention` in a decode step and by
     `paged_attend` in a chunk;
   - `ssm [slots, heads, N, P]` float32, the recurrent state, states major
-    and a head's channels minor (`ops/ssm.py`): 4 MB a slot and layer at
+    and a head's channels minor (`ops/ssm.py::ssd_state_shape`, which puts
+    heads of under 128 channels side by side): 4 MB a slot and layer at
     the 34B model's widths, what 2 048 cached tokens cost. A decode step
     updates it where it lies (`ssd_state_update`), a chunk passes it
     through `ssd_chunk_scan` once;
@@ -62,7 +63,8 @@ import jax.numpy as jnp
 from ..ops.attention import (einsum_f32, kv_row_width, note_traced,
                              pack_kv_rows, paged_attend,
                              paged_decode_attention)
-from ..ops.ssm import causal_conv, ssd_chunk_scan, ssd_state_update
+from ..ops.ssm import (causal_conv, ssd_chunk_scan, ssd_state_shape,
+                       ssd_state_update)
 from .longcat import _Norm as RMSNorm
 from .phi4flash import _by_rows
 from .transformer import _head_matmul, rope
@@ -162,7 +164,10 @@ class MLP(nn.Module):
 
 class Mamba2(nn.Module):
     """The Mamba-2 mixer of one layer, without `ssm_out_multiplier`."""
-    config: FalconH1Config
+    #: a FalconH1Config, or another model's with the same `mamba_*`
+    #: fields, `ssm_in_multiplier` and `ssm_multipliers` (all 1 where it
+    #: has none: `granite_hybrid.GraniteHybridConfig`)
+    config: Any
 
     @nn.compact
     def __call__(self, u, positions=None):
@@ -182,20 +187,22 @@ class Mamba2(nn.Module):
         D = p("D", (Hm,), nn.initializers.ones).astype(f32)
         norm_scale = p("norm", (Dm,), nn.initializers.ones)
         w_out = p("out_proj", (Dm, E)).astype(dt)
-        # ssm_multipliers, a column of the projection each
-        mup = jnp.concatenate([
-            jnp.full((n,), m, f32) for n, m in zip(
-                (Dm, Dm, K * N, K * N, Hm), cfg.ssm_multipliers)])
+        # ssm_multipliers, a column of the projection each (None: all 1)
+        mup = None if all(m == 1 for m in cfg.ssm_multipliers) else \
+            jnp.concatenate([
+                jnp.full((n,), m, f32) for n, m in zip(
+                    (Dm, Dm, K * N, K * N, Hm), cfg.ssm_multipliers)])
+        held = ssd_state_shape(B, Hm, P, K, N)
 
         if cfg.decode:
             pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B, S))
-            ssm = self.variable("cache", "ssm", jnp.zeros, (B, Hm, N, P), f32)
+            ssm = self.variable("cache", "ssm", jnp.zeros, held, f32)
             conv = self.variable("cache", "conv", jnp.zeros,
                                  (B, W - 1, Dc), dt)
             state, tail = ssm.value, conv.value
         else:
             pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-            state = jnp.zeros((B, Hm, N, P), f32)
+            state = jnp.zeros(held, f32)
             tail = jnp.zeros((B, W - 1, Dc), dt)
 
         def mix(u, pos, state, tail):
@@ -205,8 +212,10 @@ class Mamba2(nn.Module):
             fresh = pos[:, 0] == 0
             tail = jnp.where(fresh[:, None, None], jnp.zeros((), dt), tail)
             with jax.named_scope("ssd.project"):
-                proj = (einsum_f32("gse,ec->gsc", u * cfg.ssm_in_multiplier,
-                                   w_in) * mup).astype(dt)
+                if cfg.ssm_in_multiplier != 1:
+                    u = u * cfg.ssm_in_multiplier
+                proj = einsum_f32("gse,ec->gsc", u, w_in)
+                proj = (proj if mup is None else proj * mup).astype(dt)
                 z, xbc = proj[..., :Dm], proj[..., Dm:Dm + Dc]
                 step = jax.nn.softplus(proj[..., Dm + Dc:].astype(f32) + b_dt)
                 # dt 0 holds the state over a junk position
@@ -247,6 +256,53 @@ class Mamba2(nn.Module):
         return out
 
 
+def grouped_query_attend(mod, scope: str, q, k, v, pos, pages, sm_scale):
+    """Causal grouped-query attention of q [B, S, H, D] over k, v
+    [B, S, KV, D], for the attention module `mod` (its `config`, its
+    cache): the whole sequence in one call with nothing kept, or, in a
+    decode model, the keys and values written into the module's own pool
+    `cached_kv` [pages, page, width] at `pos` through the page table
+    `pages` and read back from it (the Pallas walk for one position a row,
+    `paged_attend` for a chunk). `scope` names the device scopes
+    (`<scope>.cache_write`, `<scope>.attend`). Returns [B, S, H * D]."""
+    cfg = mod.config
+    B, S, H, D = q.shape
+    KV, dt = k.shape[2], cfg.dtype
+    if not cfg.decode:
+        # the whole sequence in one call, nothing kept
+        with jax.named_scope(scope + ".attend"):
+            q5 = q.reshape(B, S, KV, H // KV, D)
+            s = einsum_f32("bskqd,btkd->bkqst", q5, k) * sm_scale
+            s = jnp.where(pos[:, None, None, None, :]
+                          <= pos[:, None, None, :, None], s, -1e30)
+            a = einsum_f32("bkqst,btkd->bskqd",
+                           jax.nn.softmax(s, -1).astype(dt), v)
+            return a.astype(dt).reshape(B, S, H * D)
+    ps, NP, L = cfg.decode_page_size, cfg.decode_num_pages, cfg.max_len
+    nblk = L // ps
+    width = kv_row_width(KV, D)
+    pt = jnp.broadcast_to(jnp.asarray(pages, jnp.int32), (B, nblk))
+    ckv = mod.variable("cache", "cached_kv", jnp.zeros, (NP, ps, width), dt)
+    with jax.named_scope(scope + ".cache_write"):
+        phys = jnp.take_along_axis(
+            pt, jnp.minimum(pos // ps, nblk - 1), axis=1)
+        # a junk position gets an index past the pool: scatters drop
+        # out-of-bounds updates
+        flat = jnp.where(pos < L, phys * ps + pos % ps, NP * ps)
+        ckv.value = ckv.value.reshape(NP * ps, width).at[
+            flat.reshape(-1)].set(
+                pack_kv_rows(k, v).reshape(B * S, width),
+                mode="drop").reshape(NP, ps, width)
+    with jax.named_scope(scope + ".attend"):
+        if S == 1 and cfg.decode_kernel:
+            a = paged_decode_attention(q[:, 0], ckv.value, pos[:, 0], pt,
+                                       sm_scale=sm_scale)[:, None]
+        else:
+            note_traced("decode" if S == 1 else "prefill", "dense")
+            a = paged_attend(q, ckv.value, pos, pt, sm_scale)
+        return a.reshape(B, S, H * D)
+
+
 class Attention(nn.Module):
     """The attention heads of one layer, without
     `attention_out_multiplier`; their input comes already multiplied by
@@ -274,41 +330,8 @@ class Attention(nn.Module):
                      .reshape(B, S, KV, D), pos, cfg.rope_theta)
             v = qkv[..., (H + KV) * D:].reshape(B, S, KV, D)
 
-        if not cfg.decode:
-            # the whole sequence in one call, nothing kept
-            with jax.named_scope("h1attn.attend"):
-                q5 = q.reshape(B, S, KV, H // KV, D)
-                s = einsum_f32("bskqd,btkd->bkqst", q5, k) * sm_scale
-                s = jnp.where(pos[:, None, None, None, :]
-                              <= pos[:, None, None, :, None], s, -1e30)
-                a = einsum_f32("bkqst,btkd->bskqd",
-                               jax.nn.softmax(s, -1).astype(dt), v)
-                a = a.astype(dt).reshape(B, S, H * D)
-        else:
-            ps, NP, L = cfg.decode_page_size, cfg.decode_num_pages, cfg.max_len
-            nblk = L // ps
-            width = kv_row_width(KV, D)
-            pt = jnp.broadcast_to(jnp.asarray(pages, jnp.int32), (B, nblk))
-            ckv = self.variable("cache", "cached_kv", jnp.zeros,
-                                (NP, ps, width), dt)
-            with jax.named_scope("h1attn.cache_write"):
-                phys = jnp.take_along_axis(
-                    pt, jnp.minimum(pos // ps, nblk - 1), axis=1)
-                # a junk position gets an index past the pool: scatters
-                # drop out-of-bounds updates
-                flat = jnp.where(pos < L, phys * ps + pos % ps, NP * ps)
-                ckv.value = ckv.value.reshape(NP * ps, width).at[
-                    flat.reshape(-1)].set(
-                        pack_kv_rows(k, v).reshape(B * S, width),
-                        mode="drop").reshape(NP, ps, width)
-            with jax.named_scope("h1attn.attend"):
-                if S == 1 and cfg.decode_kernel:
-                    a = paged_decode_attention(q[:, 0], ckv.value, pos[:, 0],
-                                               pt, sm_scale=sm_scale)[:, None]
-                else:
-                    note_traced("decode" if S == 1 else "prefill", "dense")
-                    a = paged_attend(q, ckv.value, pos, pt, sm_scale)
-                a = a.reshape(B, S, H * D)
+        a = grouped_query_attend(self, "h1attn", q, k, v, pos, pages,
+                                 sm_scale)
         with jax.named_scope("h1attn.out"):
             return a @ w_o
 

@@ -96,10 +96,10 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
                     "pallas_mla_paged[live,pages=N(,chains=C)]" (the latent
                     walk, N pages a turn, C chains in flight) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
-    A jitted function traces once, so wrap the whole run (first call
-    included), not a later window."""
-    rec: Dict[str, Set[str]] = {"attention": set(), "flash": set(),
-                                "decode": set(), "prefill": set()}
+      "ssd"       — ops/ssm.py's state update: `ssd_update_form` | "dense"
+    Traced once a jit: wrap the whole run, first call included."""
+    rec: Dict[str, Set[str]] = {k: set() for k in (
+        "attention", "flash", "decode", "prefill", "ssd")}
     token = _TRACED.set(rec)
     try:
         yield rec

@@ -33,6 +33,20 @@ precision), and a decode step through ONE Pallas kernel on the TPU
 (`ssd_state_update`: grid (rows, head blocks), the state aliased in and
 out, each `[256, 128]` tile read once and written once).
 
+A head of FEWER channels than the 128 lanes of a tile (Granite-4.0-H's
+128 heads of 64 over 128 states, one group) would, held `[N, 64]`, fill
+half of each lane tile: twice its bytes on the chip and every vector half
+empty, the very thing the layout avoids for Mamba-1. There the state is
+held `[H / n, N, n P]`, the `n = 128 / P` neighbouring heads of a group
+side by side on a tile's lanes (`ssd_heads_per_tile`, from the shapes
+alone): x, dt x, the decay and y of the n heads are still one row of 128
+lanes, B and C the same columns, and the kernel below is the same kernel
+over H / n tiles a row. The published layout (`[H, P, N]`, the states
+minor, the C sum along the lanes) was measured beside it and lost
+(PERF.md, PR 42). `ssd_state_shape` is the shape a caller holds;
+`ssd_chunk_scan` and `ssd_state_update` read the layout off the state
+they are handed.
+
 In both, a position whose step (`delta`, `dt`) is 0 leaves the state
 exactly as it was (exp(0) = 1, and + 0), which is how a caller holds the
 state over pad tokens and over rows that are no member of a call.
@@ -48,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.compat import out_struct as _out_struct
+from .attention import note_traced
 
 #: positions a chunk of `ssd_chunk_scan` takes (`mamba_chunk_size`)
 SSD_CHUNK = 128
@@ -55,6 +70,47 @@ SSD_CHUNK = 128
 #: [256, 128] float32 are 1 MB in and 1 MB out, twice for the pipeline's
 #: two buffers, a quarter of what Mosaic lets a kernel take of VMEM
 _SSD_HEAD_BLOCK = 8
+#: lanes of a float32 tile
+_LANES = 128
+
+
+def ssd_heads_per_tile(heads: int, head_dim: int, groups: int) -> int:
+    """Heads of one group that share a tile of the held state: 1 where a
+    head's `head_dim` channels fill the 128 lanes (or do not divide
+    them, or a group's heads the count), else 128 // head_dim."""
+    n = _LANES // head_dim if head_dim < _LANES else 1
+    if n < 2 or n * head_dim != _LANES or (heads // groups) % n:
+        return 1
+    return n
+
+
+def ssd_state_shape(rows: int, heads: int, head_dim: int, groups: int,
+                    states: int):
+    """The shape the recurrent state of `rows` rows is held in:
+    `[rows, H / n, N, n P]`, n = `ssd_heads_per_tile` (`[rows, H, N, P]`
+    where a head fills a tile)."""
+    n = ssd_heads_per_tile(heads, head_dim, groups)
+    return (rows, heads // n, states, n * head_dim)
+
+
+def _heads_of(state, H: int):
+    """[G, H / n, N, n P] as held -> [G, H, N, P]."""
+    G, T, N, W = state.shape
+    if T == H:
+        return state
+    n = H // T
+    return state.reshape(G, T, N, n, W // n).swapaxes(2, 3).reshape(
+        G, H, N, W // n)
+
+
+def _tiles_of(state, T: int):
+    """[G, H, N, P] -> [G, T, N, (H / T) P] as held."""
+    G, H, N, P = state.shape
+    if T == H:
+        return state
+    n = H // T
+    return state.reshape(G, T, n, N, P).swapaxes(2, 3).reshape(
+        G, T, N, n * P)
 
 
 def selective_scan(x, delta, A, B, C, D, state):
@@ -130,11 +186,14 @@ def ssd_chunk_scan(x, dt, A, B, C, D, state, chunk: int = SSD_CHUNK):
     """The SSD recurrence over T positions, `chunk` at a time in the
     matmul form (arXiv:2405.21060, section 6): x [G, T, H, P]; dt
     [G, T, H] (after its softplus; 0 at a junk position); A, D [H]; B, C
-    [G, T, K, N], K groups of H // K heads; state [G, H, N, P] -> (y
-    [G, T, H, P], the state after position T-1), all float32. A chunk
-    costs the state one pass, not one a position. T past a chunk and no
-    multiple of it is padded with positions of dt 0."""
+    [G, T, K, N], K groups of H // K heads; state as `ssd_state_shape`
+    has it -> (y [G, T, H, P], the state after position T-1, held as it
+    came), all float32. A chunk costs the state one pass, not one a
+    position. T past a chunk and no multiple of it is padded with
+    positions of dt 0."""
     G, T, H, P = x.shape
+    tiles = state.shape[1]
+    state = _heads_of(state, H)
     L = min(T, chunk)
     pad = -T % L
     xs = (x, dt, B, C)
@@ -152,13 +211,14 @@ def ssd_chunk_scan(x, dt, A, B, C, D, state, chunk: int = SSD_CHUNK):
             jnp.moveaxis(a.reshape((G, n, L) + a.shape[2:]), 1, 0)
             for a in xs))
         y = jnp.moveaxis(y, 0, 1).reshape(G, n * L, H, P)[:, :T]
-    return y + D[:, None] * x, state
+    return y + D[:, None] * x, _tiles_of(state, tiles)
 
 
 def _ssd_update_kernel(fresh_ref, x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
                        s_ref, y_ref, o_ref, *, hb):
-    """One position for one (row, block of `hb` heads of one group):
-    each head's [N, P] tile comes in once and goes out once. B and C
+    """One position for one (row, block of `hb` tiles of one group): each
+    [N, P] tile (a head, or the heads that share its lanes: P is then
+    their channels side by side) comes in once and goes out once. B and C
     arrive as rows [1, N] and are turned into columns constant along the
     lanes (a row broadcast down the sublanes, transposed), once for the
     block; a head's decay, dt x and y are rows of P lanes. A row that
@@ -197,57 +257,76 @@ def _ssd_update_kernel(fresh_ref, x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref,
 def _ssd_update_call(x, dt, A, B, C, D, state, fresh, interpret):
     G, H, P = x.shape
     K, N = B.shape[1:]
-    hb = min(_SSD_HEAD_BLOCK, H // K)
-    if (H // K) % hb:
-        raise ValueError(f"a group's {H // K} heads are no multiple of the "
+    # tiles a row and lanes a tile: a head each where P fills the lanes,
+    # else H // T neighbouring heads side by side (`ssd_state_shape`)
+    T, W = state.shape[1], state.shape[3]
+    hb = min(_SSD_HEAD_BLOCK, T // K)
+    if (T // K) % hb:
+        raise ValueError(f"a group's {T // K} tiles are no multiple of the "
                          f"{hb} a grid step takes")
-    lanes = lambda a: jnp.broadcast_to(a[..., None], a.shape + (P,))  # noqa
-    row = pl.BlockSpec((1, hb, P), lambda r, j, *_: (r, j, 0))
-    head = pl.BlockSpec((hb, P), lambda r, j, *_: (j, 0))
+    lanes = lambda a: jnp.broadcast_to(  # noqa: E731
+        a[..., None], a.shape + (P,)).reshape(a.shape[:-1] + (T, W))
+    row = pl.BlockSpec((1, hb, W), lambda r, j, *_: (r, j, 0))
+    head = pl.BlockSpec((hb, W), lambda r, j, *_: (j, 0))
     group = pl.BlockSpec((1, 1, 1, N),
-                         lambda r, j, *_: (r, j * hb // (H // K), 0, 0))
-    tile = pl.BlockSpec((1, hb, N, P), lambda r, j, *_: (r, j, 0, 0))
+                         lambda r, j, *_: (r, j * hb // (T // K), 0, 0))
+    tile = pl.BlockSpec((1, hb, N, W), lambda r, j, *_: (r, j, 0, 0))
     y, state = pl.pallas_call(
         functools.partial(_ssd_update_kernel, hb=hb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(G, H // hb),
+            num_scalar_prefetch=1, grid=(G, T // hb),
             in_specs=[row, row, head, head, group, group, tile],
             out_specs=[row, tile]),
-        out_shape=[_out_struct((G, H, P), jnp.float32, x, state),
+        out_shape=[_out_struct((G, T, W), jnp.float32, x, state),
                    _out_struct(state.shape, jnp.float32, x, state)],
         # operand 7 (the prefetched flags are operand 0) is the state
         input_output_aliases={7: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(fresh.astype(jnp.int32), x, lanes(dt), lanes(A), lanes(D),
-      B[:, :, None], C[:, :, None], state)
-    return y, state
+    )(fresh.astype(jnp.int32), x.reshape(G, T, W), lanes(dt), lanes(A),
+      lanes(D), B[:, :, None], C[:, :, None], state)
+    return y.reshape(G, H, P), state
+
+
+def ssd_update_form(x, state) -> str:
+    """The name the state-update kernel is traced under for x [G, H, P]
+    and a state as held: `pallas_ssd_update[P-minor]` (a head a tile) or
+    `pallas_ssd_update[P-minor,heads=n]` (n heads side by side on a
+    tile's lanes)."""
+    n = x.shape[1] // state.shape[1]
+    return "pallas_ssd_update[P-minor" + (f",heads={n}]" if n > 1 else "]")
 
 
 def ssd_state_update(x, dt, A, B, C, D, state, fresh=None,
                      interpret: Optional[bool] = None):
     """`ssd_chunk_scan` for a chunk of one, a decode step: x [G, H, P];
-    dt [G, H]; A, D [H]; B, C [G, K, N]; state [G, H, N, P]; `fresh` [G]
-    bool, rows that start from zeros whatever their state holds -> (y
-    [G, H, P], state), float32 throughout. On the TPU one Pallas kernel
-    (`_ssd_update_kernel`) whose state operand is its state result, so a
-    donated state is updated where it lies; plain `jax.numpy` elsewhere
-    (`interpret=True`: the kernel, interpreted, for the tests)."""
+    dt [G, H]; A, D [H]; B, C [G, K, N]; state as `ssd_state_shape` has
+    it; `fresh` [G] bool, rows that start from zeros whatever their state
+    holds -> (y [G, H, P], state), float32 throughout. On the TPU one
+    Pallas kernel (`_ssd_update_kernel`) whose state operand is its state
+    result, so a donated state is updated where it lies; plain
+    `jax.numpy` elsewhere (`interpret=True`: the kernel, interpreted, for
+    the tests). Which ran is noted for `attention.record_traced` under
+    "ssd" (`ssd_update_form`, or "dense")."""
     if fresh is None:
         fresh = jnp.zeros(x.shape[:1], bool)
     if interpret is None and jax.default_backend() != "tpu":
+        note_traced("ssd", "dense")
         G, H, P = x.shape
         K, N = B.shape[1:]
-        s5 = jnp.where(fresh[:, None, None, None], 0.0, state).reshape(
-            G, K, H // K, N, P)
+        tiles = state.shape[1]
+        s5 = jnp.where(fresh[:, None, None, None], 0.0,
+                       _heads_of(state, H)).reshape(G, K, H // K, N, P)
         s5 = jnp.exp(dt * A).reshape(G, K, H // K, 1, 1) * s5 \
             + B[:, :, None, :, None] * (dt[..., None] * x).reshape(
                 G, K, H // K, 1, P)
         y = jnp.sum(s5 * C[:, :, None, :, None], axis=3).reshape(G, H, P)
-        return y + D[:, None] * x, s5.reshape(G, H, N, P)
+        return y + D[:, None] * x, _tiles_of(s5.reshape(G, H, N, P), tiles)
+    note_traced("ssd", ssd_update_form(x, state))
     return _ssd_update_call(x, dt, A, B, C, D, state, fresh, bool(interpret))
 
 
 __all__ = ["selective_scan", "causal_conv", "ssd_chunk_scan",
-           "ssd_state_update", "SSD_CHUNK"]
+           "ssd_state_update", "ssd_state_shape", "ssd_heads_per_tile",
+           "ssd_update_form", "SSD_CHUNK"]

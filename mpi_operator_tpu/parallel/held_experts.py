@@ -10,9 +10,15 @@ experts give. This module is that part, with no stand-in for the other
 chips or for the exchange between them: what the absent experts would add
 is left out.
 
-Routing (`route`), shared by the two served models with experts
+Routing (`route`), shared by the served models with experts
 (`models/longcat.py`: flat, with a bias and identity experts;
-`models/deepseek_v2.py`: group-limited, no bias, a shared expert beside):
+`models/deepseek_v2.py`: group-limited, no bias, a shared expert beside;
+`models/granite_hybrid.py`: flat, no bias, normalised over its picks, a
+shared expert beside). What the weights are normalised OVER is the
+model's to state (`over`): "all", the first two models' rule, or "picks"
+(Granite's `GraniteMoeHybridTopKGating`): the picks are the `top_k`
+largest LOGITS and their weights `scale` times a softmax over those
+`top_k` logits alone, so a row's weights sum to `scale`. Under "all":
 softmax over every router output in float32; the picks are the top `top_k`
 of `p + bias` (the score-correction bias moves the CHOICE only); a pick's
 weight is `scale * p`, not renormalised over the picks. With `n_group` > 1
@@ -85,11 +91,22 @@ def route_in_groups(logits, bias, top_k: int, scale: float, n_group: int,
 
 
 def route(logits, bias, top_k: int, scale: float, n_group: int = 1,
-          topk_group: int = 1):
+          topk_group: int = 1, over: str = "all"):
     """([T, k] picked output ids, [T, k] weights) from [T, n_out] router
     logits (any float type; the softmax runs in float32). `n_group` 1
     picks flat over all outputs; above 1 only inside the `topk_group`
-    best groups (`bias` may then be None)."""
+    best groups (`bias` may then be None). `over` "picks": the `top_k`
+    largest logits, weighted by a softmax over those alone (flat, no
+    bias)."""
+    if over == "picks":
+        if n_group > 1 or bias is not None:
+            raise ValueError("a gate normalised over its picks is flat and "
+                             "has no bias")
+        top, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
+        return idx, scale * jax.nn.softmax(top, axis=-1)
+    if over != "all":
+        raise ValueError(f"over={over!r}: weights are normalised over "
+                         f"'all' outputs or over the 'picks'")
     if n_group > 1:
         return route_in_groups(logits, bias, top_k, scale, n_group,
                                topk_group)[:2]
@@ -234,7 +251,27 @@ def group_limited_experts(y, router, gate, up, down, *,
     return out, (picks, load, hits)
 
 
+def flat_experts(y, router, gate, up, down, *, held: Tuple[int, int],
+                 top_k: int):
+    """The routed part of a layer whose gate is flat and normalised over
+    its picks (`route(over="picks")`), with no bias and no identity
+    experts (Granite-4.0-H; the shared expert that every token passes is
+    the model's own and is added there), on this chip for y [T, H]: the
+    same two forms of the held experts' part.
+    Returns ([T, H] float32, (picks on held experts, the largest held
+    expert's load))."""
+    first, count = held
+    _check_held(held, gate)
+    with jax.named_scope("moe.route"):
+        idx, w = route(_router_logits(y, router), None, top_k, 1.0,
+                       over="picks")
+        picks, _, load = pick_counts(idx, first, count, router.shape[-1])
+    with jax.named_scope("moe.experts"):
+        out = held_experts(y, idx, w, first, gate, up, down)
+    return out, (picks, load)
+
+
 __all__ = ["MASKED_MAX_TOKENS", "route", "route_in_groups", "kept_groups", "held_gates",
            "identity_weight", "pick_counts", "masked_experts",
            "grouped_experts", "held_experts", "shortcut_experts",
-           "group_limited_experts"]
+           "group_limited_experts", "flat_experts"]

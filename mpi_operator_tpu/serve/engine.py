@@ -58,7 +58,11 @@ model the way a frontend needs it served:
   `EngineConfig.async_decode=False` drains each step before the next
   dispatch — same compiled program (compile_counts is mode-blind),
   token-identical at temperature 0, the A/B baseline the serving bench
-  measures against.
+  measures against. `EngineConfig.async_depth` keeps more than one step
+  dispatched behind the sync (step N+depth goes out before step N's
+  tokens come in): the same tokens, `depth` steps of work queued on the
+  device against a host that stands still, an EOS seen `depth` steps
+  late.
 
 - **Speculative decoding** (`EngineConfig.speculative`). Decode is one
   memory-bound HBM sweep per token; speculation turns k sequential
@@ -87,6 +91,7 @@ decode-kernel paths, async and sync.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import time
@@ -151,7 +156,18 @@ class EngineConfig:
     ≤2 bucketed widths from {2, draft_k+1}. Greedy rows are token-exact
     vs the plain engine; sampling rows never speculate (their next
     token is a draw, not an argmax, so lookahead has nothing to verify
-    against) and run plain decode in the same batch."""
+    against) and run plain decode in the same batch.
+
+    `async_depth` (with `async_decode`; 1 = the double-buffered loop) is
+    how many decode steps stay dispatched and unfetched behind the one
+    being synced: step N+depth is dispatched before step N's tokens are
+    fetched. Every step's inputs are on the device already (the token
+    chain, the cursors the host advances at dispatch), so the tokens are
+    the same at any depth; what it buys is work queued on the device for
+    `depth` steps' time, through which a host that stands still (a paused
+    VM, a long collection) starves nothing; what it costs is that a token
+    reaches its client, and an EOS frees its row, `depth - 1` steps
+    later. Speculation drains the queue before every verify step."""
     slots: int = 8
     chunk_buckets: Tuple[int, ...] = (32, 128, 512)
     decode_kernel: Optional[bool] = None
@@ -173,6 +189,7 @@ class EngineConfig:
     # delete its buffers); anything not yet in the served type is cast as
     # always.
     own_params: bool = False
+    async_depth: int = 1
 
 
 @dataclasses.dataclass
@@ -362,6 +379,10 @@ class ServingEngine:
                 if b > mcfg.max_len:
                     raise ValueError(f"chunk bucket {b} exceeds "
                                      f"max_len={mcfg.max_len}")
+            if cfg.async_depth < 1:
+                raise ValueError(f"async_depth={cfg.async_depth}: at least "
+                                 f"one step stays dispatched (async_decode="
+                                 f"False fetches every step at once)")
             if cfg.speculative not in (None, "ngram", "draft"):
                 raise ValueError(f"speculative={cfg.speculative!r}: expected "
                                  f"None, 'ngram' or 'draft'")
@@ -1200,7 +1221,7 @@ class ServingEngine:
         if now_fn is None:
             t0 = time.perf_counter()
             now_fn = lambda: time.perf_counter() - t0   # noqa: E731
-        self._session = {"results": {}, "pending": None,
+        self._session = {"results": {}, "pending": collections.deque(),
                          "on_token": on_token, "now_fn": now_fn}
         self._trace_now = now_fn
         if self.tracer is not None:
@@ -1291,7 +1312,7 @@ class ServingEngine:
         """True while the open session still has work in flight."""
         return (self._session is not None
                 and not (self.scheduler.idle
-                         and self._session["pending"] is None))
+                         and not self._session["pending"]))
 
     def tick(self) -> bool:
         """One iteration of the admit → prefill → decode loop. Returns
@@ -1334,10 +1355,14 @@ class ServingEngine:
             # heartbeat AFTER admission: the published queue depth is what
             # is still waiting behind the slots, not this instant's intake
             self._maybe_heartbeat(now)
+            # dispatched steps whose tokens are not fetched yet, oldest
+            # first
+            pending = sess["pending"]
+            sync = lambda: self._sync_decode_step(             # noqa: E731
+                pending.popleft(), now_fn, on_token, results)
             # nothing resident yet and the next arrival is in the future:
             # nothing to advance — report it instead of spinning
-            pending = sess["pending"]
-            if self.slots.occupied == 0 and pending is None:
+            if self.slots.occupied == 0 and not pending:
                 nxt = self.scheduler.next_arrival()
                 if nxt is not None and nxt > now_fn():
                     tick_span.drop()
@@ -1350,32 +1375,46 @@ class ServingEngine:
                     and self.scheduler.decoding()):
                 # drafting reads host-known history, and acceptance
                 # decides the next step's inputs — drain the in-flight
-                # step first (speculative steps are synchronous; the
+                # steps first (speculative steps are synchronous; the
                 # multi-token payoff replaces the dispatch overlap)
-                if pending is not None:
-                    self._sync_decode_step(pending, now_fn, on_token,
-                                           results)
-                    pending = None
+                while pending:
+                    sync()
                 planned = self._plan_drafts()
             if planned:
                 self._spec_step(planned, now_fn, on_token, results)
-                new_pending = None
+                dispatched = None
             else:
                 # no row drafted this step (novel text, sampling rows,
                 # exhausted budgets): plain decode, async overlap intact
-                new_pending = (self._dispatch_decode_step()
-                               if self.scheduler.decoding() else None)
-            if pending is not None:
-                self._sync_decode_step(pending, now_fn, on_token, results)
-                pending = None
-            if self.config.async_decode:
-                pending = new_pending
-            elif new_pending is not None:
-                # sync mode: same compiled step, fetched immediately
-                self._sync_decode_step(new_pending, now_fn, on_token,
-                                       results)
-            sess["pending"] = pending
+                dispatched = (self._dispatch_decode_step()
+                              if self.scheduler.decoding() else None)
+            # fetch the oldest step AFTER the new one went out, once more
+            # than `async_depth` are out (sync mode is depth 0: the same
+            # compiled step, fetched immediately); a tick that dispatched
+            # nothing drains the queue by a step
+            if dispatched is not None:
+                pending.append(dispatched)
+            elif pending:
+                sync()
+            depth = self.config.async_depth if self.config.async_decode \
+                else 0
+            while len(pending) > depth:
+                sync()
             return True
+
+    def drain(self) -> None:
+        """Fetch the tokens of every dispatched step now, oldest first.
+        The session stays open; the next tick() dispatches onto a device
+        with nothing queued. For a caller that wants the work it has
+        submitted so far DONE before it goes on (a benchmark's set-up
+        before its window opens: at `async_depth` n the device is up to
+        n steps, and the prefill calls dispatched with them, behind)."""
+        sess = self._session
+        if sess is None:
+            raise RuntimeError("drain() outside a session (call start())")
+        while sess["pending"]:
+            self._sync_decode_step(sess["pending"].popleft(), sess["now_fn"],
+                                   sess["on_token"], sess["results"])
 
     def session_results(self) -> Dict[int, RequestResult]:
         """The open session's retired results so far (live view) — the

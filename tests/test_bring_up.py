@@ -392,13 +392,59 @@ def test_kernel_parity_harness_runs_the_windowed_kernel_and_the_scan():
     assert rec["max_rel_err"] <= 1e-5
 
 
-def test_kernel_parity_harness_runs_the_ssd_chunk_against_its_steps():
-    from mpi_operator_tpu.examples.kernel_parity import SSD_CASE, ssd_case
+@pytest.mark.parametrize("name,shape,form", [
+    ("a_head_a_tile", dict(rows=2, chunk=12, heads=4, head_dim=8, groups=2,
+                           states=16), "dense"),
+    ("two_heads_a_tile", dict(rows=2, chunk=12, heads=4, head_dim=64,
+                              groups=1, states=16), "dense"),
+])
+def test_kernel_parity_harness_runs_the_ssd_chunk_against_its_steps(
+        name, shape, form):
+    """The SSD case at a head that fills a lane tile alone (Falcon-H1's
+    form) and at heads of 64 channels, two to a tile (Granite-4.0-H's);
+    off the chip the steps are plain `jax.numpy` and say so."""
+    from mpi_operator_tpu.examples.kernel_parity import (
+        SSD_CASE, SSD_CASE_64, ssd_case)
+    from mpi_operator_tpu.ops.ssm import ssd_state_shape
 
-    rec = ssd_case(rows=2, chunk=12, heads=4, head_dim=8, groups=2, states=16)
+    rec = ssd_case(**shape)
     assert rec["kernel"] == "ssd_chunk_scan_vs_state_update_steps"
     assert rec["max_rel_err"] <= 1e-5
+    assert rec["ssd_traced"] == form
     assert SSD_CASE["heads"] * SSD_CASE["head_dim"] == 4096
+    assert SSD_CASE_64["heads"] * SSD_CASE_64["head_dim"] == 8192
+    held = {k: v for k, v in shape.items() if k not in ("rows", "chunk")}
+    tiles = ssd_state_shape(1, held["heads"], held["head_dim"],
+                            held["groups"], held["states"])[1]
+    assert tiles == (2 if name == "two_heads_a_tile" else 4)
+
+
+def test_the_state_update_names_the_form_it_takes_at_each_models_shape():
+    """What `chip_smoke.py`'s kernels leg requires of `ssd_traced`, from
+    the shapes alone: Falcon-H1's head a tile, Granite's two heads."""
+    import importlib.util
+    import os
+
+    import jax.numpy as jnp
+
+    from mpi_operator_tpu.examples.kernel_parity import SSD_CASE, SSD_CASE_64
+    from mpi_operator_tpu.ops.ssm import ssd_state_shape, ssd_update_form
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    forms = []
+    for case in (SSD_CASE, SSD_CASE_64):
+        shape = ssd_state_shape(1, case["heads"], case["head_dim"],
+                                case["groups"], case["states"])
+        assert shape[-1] == 128 and shape[1] * shape[3] == 128 * 64 \
+            or case is SSD_CASE
+        forms.append(ssd_update_form(
+            jnp.zeros((1, case["heads"], case["head_dim"])),
+            jnp.zeros(shape)))
+    assert sorted(forms) == smoke.SSD_FORMS
 
 
 # -- kernels on a multi-device mesh -------------------------------------------

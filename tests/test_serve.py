@@ -376,6 +376,71 @@ def test_engine_async_matches_sync_token_exact(decode_kernel):
         assert a[req.id].finish_reason == b[req.id].finish_reason
 
 
+@pytest.mark.parametrize("depth", [2, 4, 8])
+def test_engine_async_depth_is_token_exact(depth):
+    """`async_depth` steps dispatched and unfetched: the same greedy
+    tokens, EOS cuts (which now cost up to `depth` junk steps, all
+    discarded) and finish reasons as the double-buffered loop, through the
+    same compiled programs; six requests over four slots, so rows are
+    admitted onto slots whose last occupant still has junk steps out. (A
+    sampled row draws by the step's number, which admission order moves:
+    its tokens are another fair draw, not the same one.)"""
+    model, params, engine = _setup()
+    rs = np.random.RandomState(11)
+    probe = Request(99, list(rs.randint(0, 64, (6,))), max_new_tokens=8)
+    eos = _oracle(model, params, probe)[2]     # a token greedy WILL emit
+    engine.reset()
+    reqs = [Request(i, list(rs.randint(0, 64, (3 + i,))), max_new_tokens=8,
+                    eos_id=eos)
+            for i in range(6)]
+    assert engine.config.async_depth == 1      # the default
+    a = engine.run(reqs)
+    counts = engine.compile_counts()
+    engine.config.async_depth = depth
+    engine.reset()
+    b = engine.run(reqs)
+    assert engine.compile_counts() == counts
+    assert any(r.finish_reason == "eos" for r in a.values())
+    for req in reqs:
+        assert a[req.id].tokens == b[req.id].tokens == \
+            _oracle(model, params, req), f"request {req.id} diverged"
+        assert a[req.id].finish_reason == b[req.id].finish_reason
+    assert engine.slots.occupied == 0
+
+
+def test_engine_drain_fetches_what_is_out_and_changes_no_token():
+    """`drain()` mid-session: nothing dispatched stays unfetched, the
+    session goes on, and every token is what the undrained loop serves."""
+    model, params, engine = _setup(async_depth=4)
+    rs = np.random.RandomState(17)
+    reqs = [Request(i, list(rs.randint(0, 64, (3 + i,))), max_new_tokens=9)
+            for i in range(5)]
+    want = engine.run(reqs)
+    engine.reset()
+    with pytest.raises(RuntimeError, match="outside a session"):
+        engine.drain()
+    engine.start()
+    for r in reqs:
+        engine.submit(r)
+    for _ in range(6):
+        engine.tick()
+    assert engine._session["pending"]
+    engine.drain()
+    assert not engine._session["pending"]
+    while engine.active:
+        engine.tick()
+    got = engine.finish()
+    for r in reqs:
+        assert got[r.id].tokens == want[r.id].tokens
+
+
+def test_engine_refuses_a_depth_under_one():
+    model, params, _ = _setup()
+    with pytest.raises(ValueError, match="async_depth"):
+        ServingEngine(model, params, EngineConfig(
+            slots=4, chunk_buckets=(4, 8), page_size=8, async_depth=0))
+
+
 def test_engine_async_compile_pins_and_sampled_replay():
     """Async mode holds the same no-recompile contract as sync, across
     run -> reset -> run with mixed greedy+sampled traffic; and a reset

@@ -691,32 +691,44 @@ H1 = dict(slots=96, pages=5312, page=64, max_len=4096, ssm_heads=32,
           d_state=256, ssm_head_dim=128, groups=2)
 
 
+#: Granite-4.0-H-Small's mixer in its cell: heads of 64 channels, under a
+#: lane tile, held two to a tile
+G4 = dict(slots=48, ssm_heads=128, d_state=128, ssm_head_dim=64, groups=1)
+
+
+@pytest.mark.parametrize("name,shape,held", [
+    ("falcon_h1", H1, (96, 32, 256, 128)),
+    ("granite_4_0_h", G4, (48, 64, 128, 128)),
+])
 def test_ssd_state_update_kernel_updates_the_donated_state_where_it_lies(
-        one_chip, quiet_cache):
-    """One layer's state update at Falcon-H1-34B's widths, 96 rows of 32
-    tiles [256, 128] float32: Mosaic takes the kernel (8 heads a grid
-    step, the transposes that turn B and C into columns) under its
-    scoped VMEM limit; the 403 MB of state are the call's operand and
-    its result, aliased, and nothing of that size is copied or made
+        one_chip, quiet_cache, name, shape, held):
+    """One layer's state update at Falcon-H1-34B's widths (96 rows of 32
+    tiles [256, 128] float32, a head a tile) and at Granite-4.0-H-Small's
+    (48 rows of 64 tiles [128, 128], two heads of 64 channels a tile):
+    Mosaic takes the kernel (8 tiles a grid step, the transposes that
+    turn B and C into columns) under its scoped VMEM limit; a row's
+    4 194 304 B of state are the call's operand and its result, aliased,
+    no lane of a tile empty, and nothing of that size is copied or made
     beside them."""
-    from mpi_operator_tpu.ops.ssm import ssd_state_update
-    S, H, N, P, K = (H1["slots"], H1["ssm_heads"], H1["d_state"],
-                     H1["ssm_head_dim"], H1["groups"])
+    from mpi_operator_tpu.ops.ssm import ssd_state_shape, ssd_state_update
+    S, H, N, P, K = (shape["slots"], shape["ssm_heads"], shape["d_state"],
+                     shape["ssm_head_dim"], shape["groups"])
+    assert ssd_state_shape(S, H, P, K, N) == held
     spec = lambda *shape: jax.ShapeDtypeStruct(             # noqa: E731
         shape, jnp.float32, sharding=one_chip)
     compiled = jax.jit(
         lambda *a: ssd_state_update(*a[:-1], fresh=a[-1], interpret=False),
         donate_argnums=(6,)).lower(
             spec(S, H, P), spec(S, H), spec(H), spec(S, K, N), spec(S, K, N),
-            spec(H), spec(S, H, N, P),
+            spec(H), spec(*held),
             jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
         ).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     state = S * H * N * P * 4
     assert text.count("tpu_custom_call") == 1
-    assert _copies_of(text, (S, H, N, P)) == []
-    assert m.alias_size_in_bytes >= state == 96 * 4194304
+    assert _copies_of(text, held) == []
+    assert m.alias_size_in_bytes >= state == S * 4194304
     assert m.temp_size_in_bytes < 16 << 20
 
 
