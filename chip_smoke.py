@@ -16,7 +16,9 @@ would call, one child process after another:
            over a ring and a long table; the state-space scans, a chunk
            against its steps, Mamba-2's through its state-update kernel at
            Falcon-H1's head of 128 channels and at Granite-4.0-H's of 64,
-           two heads a lane tile: `ssd_traced` names the form each took)
+           two heads a lane tile: `ssd_traced` names the form each took;
+           the training step's head and loss in one pass at the cells'
+           shape: `head_loss_traced` names the kernel's form)
   trainer  python -m mpi_operator_tpu.examples.lm_benchmark --workload gpt2
            --size medium --seq-len 512 (global batch 16 over all visible
            chips), started the way the operator starts a gang: a worker
@@ -190,9 +192,14 @@ def check_kernels(head, device) -> dict:
     _require(head.get("ssd_traced") == SSD_FORMS,
              f"state update traced {head.get('ssd_traced')!r}, "
              f"not {SSD_FORMS}")
+    # the head and its loss ran as the one kernel, not as the scan
+    _require(str(head.get("head_loss_traced")).startswith("pallas_xent["),
+             f"head and loss traced {head.get('head_loss_traced')!r}, "
+             f"not the kernel")
     return {"kernels": head["kernels"],
             "decode_traced": head["decode_traced"],
             "ssd_traced": head["ssd_traced"],
+            "head_loss_traced": head["head_loss_traced"],
             "worst_max_rel_err": head["worst_max_rel_err"],
             "tol": head["tol"]}
 
@@ -202,6 +209,9 @@ def check_trainer(head, device) -> dict:
     _require(head.get("attention_impl") == "flash",
              f"train step traced attention "
              f"{head.get('attention_impl')!r}, not the flash kernel")
+    _require(str(head.get("head_loss_impl")).startswith("pallas_xent["),
+             f"train step traced its head and loss as "
+             f"{head.get('head_loss_impl')!r}, not the kernel")
     loss = head.get("final_loss")
     # random weights, random tokens: the loss starts at ln(vocab) = 10.8
     # and a dozen warm-up-rate steps move it little; it must be a finite
@@ -221,7 +231,8 @@ def check_trainer(head, device) -> dict:
     return {k: head[k] for k in (
         "value", "final_loss", "mfu", "compile_seconds", "step_compiles",
         "grad_reductions", "grad_reductions_async",
-        "step_time_p50_ms", "attention_impl", "device_bytes_in_use")}
+        "step_time_p50_ms", "attention_impl", "head_loss_impl",
+        "device_bytes_in_use")}
 
 
 def check_server(head, device) -> dict:

@@ -29,6 +29,10 @@ GPT-2-XL's 25 heads (an odd count, the benchmark's serving shape):
   (Falcon-H1-34B's 32 heads of 128 over 256        `ssd_state_update`, the
   states, a head a lane tile; Granite-4.0-H's      Pallas kernel, one by one;
   128 heads of 64 over 128, two heads a tile)      the record names the form
+  tied_head_xent (`ops/xent.py`: the training    vs `lm_loss` over the whole
+  step's head and loss in one pass, value, dh       logits of `_head_matmul`
+  and dtable at the cells' 8 x 1024 tokens of
+  1024 against 50 304 rows)
 
 The paged decode cases go through the `Attention` module itself — one
 set of weights, one prefilled pool, the single-token step run once with
@@ -94,6 +98,8 @@ SSD_CASE = dict(rows=8, chunk=128, heads=32, head_dim=128, groups=2,
 #: and at Granite-4.0-H-Small's: heads of 64 channels, two to a lane tile
 SSD_CASE_64 = dict(rows=8, chunk=128, heads=128, head_dim=64, groups=1,
                    states=128)
+#: the training cells' head: 8 x 1024 tokens a chip against GPT-2's table
+HEAD_LOSS_CASE = dict(batch=8, seq=1024, embed=1024, vocab=50304)
 
 
 def _rel_err(got, ref) -> float:
@@ -546,6 +552,51 @@ def ssd_case(rows: int, chunk: int, heads: int, head_dim: int, groups: int,
                                _rel_err(last, s))}
 
 
+def head_loss_case(batch: int, seq: int, embed: int, vocab: int
+                   ) -> Dict[str, object]:
+    """`tied_head_xent`'s loss, accuracy, dh and dtable against `lm_loss`
+    and the arg-max over the whole float32 logits; the record's
+    `head_loss_traced` names the form it took (on the chip the kernel)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..models.transformer import _head_matmul
+    from ..ops.attention import record_traced, traced_name
+    from ..ops.xent import tied_head_xent
+    from ..train.lm_trainer import lm_loss
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    h = jax.random.normal(ks[0], (batch, seq, embed), jnp.bfloat16)
+    # steep enough that a share of the labels ARE their row's arg-max
+    table = (4.0 / embed ** 0.5) * jax.random.normal(ks[1], (vocab, embed))
+    y = jnp.argmax(_head_matmul(h, table.astype(h.dtype)), -1)
+    y = jnp.where(jax.random.bernoulli(ks[2], 0.5, y.shape), y,
+                  (y + 1) % vocab)
+
+    def dense(h, table):
+        z = _head_matmul(h, table.astype(h.dtype))
+        return lm_loss(z, y), jnp.mean(jnp.argmax(z, -1) == y)
+
+    def one_pass(h, table):
+        return tied_head_xent(h, table, y)
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1), has_aux=True))
+
+    with record_traced() as traced:
+        _assert_mosaic(both(one_pass), h, table)
+        (loss, acc), grads = both(one_pass)(h, table)
+    (ref_loss, ref_acc), ref_grads = both(dense)(h, table)
+    errs = [abs(float(loss) - float(ref_loss)) / abs(float(ref_loss)),
+            abs(float(acc) - float(ref_acc))]
+    errs += [_rel_err(g, r) for g, r in zip(grads, ref_grads)]
+    return {"kernel": "tied_head_xent_vs_logits",
+            "shape": {"batch": batch, "seq": seq, "embed": embed,
+                      "vocab": vocab},
+            "head_loss_traced": traced_name(traced["head_loss"]),
+            "accuracy": float(acc), "max_rel_err": max(errs)}
+
+
 def run_kernel_parity(train_shape: Optional[dict] = None,
                       serve_shape: Optional[dict] = None,
                       model: Optional[dict] = None,
@@ -555,13 +606,15 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
                       window: Optional[dict] = None,
                       scan: Optional[dict] = None,
                       ssd: Optional[List[dict]] = None,
+                      head_loss: Optional[dict] = None,
                       tol: float = BF16_TOL) -> List[Dict[str, object]]:
     """Every kernel the two legs use, at their shapes; one record each
     with its measured error and `ok`. The decode kernels run once per
     entry of `decode_models` (default: `model` alone); the latent decode
     kernel where `mla` gives its cases (and the latent chunk path where
     `mla_chunk` does), the windowed decode kernel and the
-    scans where `window`, `scan` and `ssd` give theirs. Off TPU the kernels
+    scans where `window`, `scan` and `ssd` give theirs, the training
+    step's head and loss where `head_loss` does. Off TPU the kernels
     interpret (the tier-1 test runs tiny shapes that way)."""
     train_shape = train_shape or TRAIN_SHAPE
     serve_shape = serve_shape or SERVE_SHAPE
@@ -582,6 +635,8 @@ def run_kernel_parity(train_shape: Optional[dict] = None,
         records.append(scan_case(**scan))
     for case in ssd or ():
         records.append(ssd_case(**case))
+    if head_loss:
+        records.append(head_loss_case(**head_loss))
     for rec in records:
         rec["tol"] = tol
         rec["ok"] = bool(rec["max_rel_err"] <= tol)
@@ -609,7 +664,8 @@ def main(argv=None) -> int:
                                 mla=[MLA_CASE, MLA_CASE_128],
                                 mla_chunk=MLA_CHUNK_CASE, window=WINDOW_CASE,
                                 scan=SCAN_CASE,
-                                ssd=[SSD_CASE, SSD_CASE_64])
+                                ssd=[SSD_CASE, SSD_CASE_64],
+                                head_loss=HEAD_LOSS_CASE)
     for rec in records:
         print(json.dumps({**rec, **device}))
     ok = all(rec["ok"] for rec in records)
@@ -622,6 +678,9 @@ def main(argv=None) -> int:
                                                if r.get("traced")}),
                       "ssd_traced": sorted({r["ssd_traced"] for r in records
                                             if r.get("ssd_traced")}),
+                      "head_loss_traced": next(
+                          (r["head_loss_traced"] for r in records
+                           if r.get("head_loss_traced")), None),
                       **device,
                       "compile_cache_dir": cache_dir}))
     return 0 if ok else 1

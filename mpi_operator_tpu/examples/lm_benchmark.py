@@ -1124,8 +1124,8 @@ def main(argv=None) -> int:
         # reports it, the attention implementation the step traced, and
         # compile time apart from step time (with_run_report fills these)
         for key in ("platform", "device_kind", "device_count",
-                    "attention_impl", "compile_seconds", "step_compiles",
-                    "grad_reductions", "grad_reductions_async", "mfu",
+                    "attention_impl", "head_loss_impl", "compile_seconds",
+                    "step_compiles", "grad_reductions", "grad_reductions_async", "mfu",
                     "step_time_p50_ms", "state_device_ids",
                     "batch_device_ids", "device_bytes_in_use"):
             if key in metrics:

@@ -78,8 +78,8 @@ _TRACED: contextvars.ContextVar = contextvars.ContextVar(
 
 @contextlib.contextmanager
 def record_traced() -> Iterator[Dict[str, Set[str]]]:
-    """Collect the attention implementations traced while the block runs.
-
+    """Collect the implementations traced while the block runs: traced
+    once a jit, so wrap the whole run, first call included.
     Yields a dict the dispatch sites fill at TRACE time:
       "attention" — full-sequence attention: "flash" | "dense" | "ring"
       "flash"     — the form of the flash kernels traced, with the heads
@@ -97,9 +97,9 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
                     walk, N pages a turn, C chains in flight) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
       "ssd"       — ops/ssm.py's state update: `ssd_update_form` | "dense"
-    Traced once a jit: wrap the whole run, first call included."""
+      "head_loss" — ops/xent.py's head and loss in one pass: `Form.name`"""
     rec: Dict[str, Set[str]] = {k: set() for k in (
-        "attention", "flash", "decode", "prefill", "ssd")}
+        "attention", "flash", "decode", "prefill", "ssd", "head_loss")}
     token = _TRACED.set(rec)
     try:
         yield rec

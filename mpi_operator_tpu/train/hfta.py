@@ -305,14 +305,9 @@ class HFTATrainer:
 
     def _fused_step_fn(self, state, hp, tokens, targets, mask):
         def forward(params, t, y, m):
-            (loss, logits), grads = jax.value_and_grad(
+            (loss, acc), grads = jax.value_and_grad(
                 self._lm._loss_fn, has_aux=True)(params, t, y, m)
-            if logits is None:                       # fused-xent path
-                acc = jnp.full((), jnp.nan, jnp.float32)
-            else:
-                acc = (jnp.sum((jnp.argmax(logits, -1) == y) * m)
-                       / jnp.maximum(m.sum(), 1))
-            return loss, acc, grads
+            return loss, acc.astype(jnp.float32), grads
 
         loss, acc, grads = self._map_replicas(forward)(
             state.params, tokens, targets, mask)

@@ -78,7 +78,10 @@ class LMTrainerConfig:
     moe_aux_weight: float = 0.01
     masked_lm: bool = False        # BERT-style objective over masked slots
     # chunked tied-head xent (fused_lm_loss): the full [B*S, vocab] logits
-    # never hit HBM; causal models only (BERT's MLM head has extra layers)
+    # never hit HBM; causal models only (BERT's MLM head has extra layers).
+    # A MEMORY option: on a v5e it costs the head 27.2 ms where the default
+    # (LMTrainer._one_pass_head: one pass over the vocabulary, which keeps
+    # the bfloat16 cotangent, 0.82 GB at 8 x 1024 tokens) takes 15.0
     fused_xent: bool = False
     # gradient accumulation: split each global batch into `accum_steps`
     # microbatches, lax.scan the fwd+bwd over them, apply ONE optimizer
@@ -460,35 +463,62 @@ class LMTrainer:
         return (mcfg is not None and getattr(mcfg, "tp_overlap", False)
                 and dict(self.mesh.shape).get("tp", 1) > 1)
 
+    def _one_pass_head(self):
+        """Whether the tied head and its loss run as ONE pass over the
+        vocabulary (`ops/xent.py::tied_head_xent`: no logits in HBM, and
+        an accuracy all the same), read off what the trainer can see: a
+        causal model (BERT's masked objective has another head), a table
+        no model axis splits and a sequence no axis splits (tp = sp = 1
+        on the mesh; a tp ring keeps `tp_overlap_lm_loss`), bfloat16
+        compute at widths the kernel tiles (`xent.covers`; float32 keeps
+        the logits). `fused_xent` still selects `fused_lm_loss`: the chip
+        timed it 12 ms slower, and it keeps 0.45 GB less between the
+        passes (PERF.md section 6, PR 43): a memory option."""
+        from ..ops.xent import covers
+        mcfg = getattr(self.model, "config", None)
+        axes = dict(self.mesh.shape)
+        return (mcfg is not None and mcfg.causal
+                and not self.config.masked_lm
+                and axes.get("tp", 1) == 1 and axes.get("sp", 1) == 1
+                and covers(mcfg.embed_dim, mcfg.vocab_size, mcfg.dtype))
+
     def _loss_fn(self, params, tokens, targets, mask, denom=None,
                  aux_scale=1.0, include_aux=True):
-        """`denom`/`aux_scale` support exact gradient accumulation: with
-        denom = the FULL-batch mask count and aux_scale = 1/accum_steps,
-        the SUM of microbatch gradients equals the full-batch gradient by
-        linearity — masked objectives included (each microbatch's own
-        mask.sum() would weight tokens unevenly)."""
-        if self._use_fused():
+        """-> (loss, accuracy). `denom`/`aux_scale` support exact gradient
+        accumulation: with denom = the FULL-batch mask count and
+        aux_scale = 1/accum_steps, the SUM of microbatch gradients equals
+        the full-batch gradient by linearity — masked objectives included
+        (each microbatch's own mask.sum() would weight tokens unevenly)."""
+        fused = self._use_fused()
+        if fused or self._one_pass_head():
             h, interm = self.model.apply(
                 {"params": params}, tokens, with_head=False,
                 mutable=["intermediates"])
-            if self._use_overlap_loss():
+            table = params["wte"]["embedding"]
+            # the chunked paths never materialize logits; accuracy is a
+            # diagnostic, not worth a second vocab projection
+            acc = jnp.full((), jnp.nan)
+            if not fused:
+                from ..ops.xent import tied_head_xent
+                loss, acc = tied_head_xent(h, table, targets, mask,
+                                           denom=denom)
+            elif self._use_overlap_loss():
                 ring = getattr(self.model.config, "tp_ring", "uni")
-                loss = tp_overlap_lm_loss(h, params["wte"]["embedding"],
-                                          targets, mask, self.mesh,
-                                          denom=denom, ring=ring)
+                loss = tp_overlap_lm_loss(h, table, targets, mask,
+                                          self.mesh, denom=denom, ring=ring)
             else:
-                loss = fused_lm_loss(h, params["wte"]["embedding"], targets,
-                                     mask, denom=denom)
-            logits = None
+                loss = fused_lm_loss(h, table, targets, mask, denom=denom)
         else:
             logits, interm = self.model.apply(
                 {"params": params}, tokens, mutable=["intermediates"])
             loss = lm_loss(logits, targets, mask, denom=denom)
+            acc = jnp.sum((jnp.argmax(logits, -1) == targets) * mask) \
+                / jnp.maximum(mask.sum(), 1)
         aux = jax.tree.leaves(interm.get("intermediates", {}))
         if aux and include_aux:
             loss = loss + aux_scale * self.config.moe_aux_weight * sum(
                 jnp.asarray(a).mean() for a in aux)
-        return loss, logits
+        return loss, acc
 
     def _step_fn(self, state: LMTrainState, tokens, targets, mask):
         A = self.config.accum_steps
@@ -518,22 +548,15 @@ class LMTrainer:
                  mask.reshape(A, B // A, *mask.shape[1:])))
             state = self._guarded(state, state.apply_gradients(grad_sum),
                                   loss_sum, grad_sum)
-            # accuracy would need the per-microbatch logits kept alive —
-            # defeats the memory point of accumulating
+            # a microbatch's accuracy is over its own mask count; the
+            # step's would weight them: not kept
             return state, {"loss": loss_sum,
                            "accuracy": jnp.full((), jnp.nan),
                            "nonfinite_streak": state.nonfinite_streak}
-        (loss, logits), grads = jax.value_and_grad(
+        (loss, acc), grads = jax.value_and_grad(
             self._loss_fn, has_aux=True)(state.params, tokens, targets, mask)
         state = self._guarded(state, state.apply_gradients(grads), loss,
                               grads)
-        if logits is None:
-            # fused path never materializes logits; accuracy is a
-            # diagnostic, not worth a second vocab projection
-            acc = jnp.full((), jnp.nan)
-        else:
-            acc = jnp.sum((jnp.argmax(logits, -1) == targets) * mask) \
-                / jnp.maximum(mask.sum(), 1)
         return state, {"loss": loss, "accuracy": acc,
                        "nonfinite_streak": state.nonfinite_streak}
 

@@ -419,6 +419,23 @@ def test_kernel_parity_harness_runs_the_ssd_chunk_against_its_steps(
     assert tiles == (2 if name == "two_heads_a_tile" else 4)
 
 
+def test_kernel_parity_harness_runs_the_head_and_its_loss_when_asked():
+    """The training step's head and loss in one pass joins the registry:
+    off the chip it runs as the scan and says so, at the cells' shape the
+    kernel's steps divide the tokens."""
+    from mpi_operator_tpu.examples.kernel_parity import (HEAD_LOSS_CASE,
+                                                         head_loss_case)
+    from mpi_operator_tpu.ops import xent
+
+    rec = head_loss_case(batch=2, seq=16, embed=128, vocab=640)
+    assert rec["kernel"] == "tied_head_xent_vs_logits"
+    assert rec["head_loss_traced"] == "xla_chunked[chunks=8,products=3]"
+    assert rec["max_rel_err"] <= 1e-2 and 0.2 < rec["accuracy"] < 0.8
+    c = HEAD_LOSS_CASE
+    assert xent.covers(c["embed"], c["vocab"], "bfloat16")
+    assert c["batch"] * c["seq"] % xent._KEPT_ROWS == 0
+
+
 def test_the_state_update_names_the_form_it_takes_at_each_models_shape():
     """What `chip_smoke.py`'s kernels leg requires of `ssd_traced`, from
     the shapes alone: Falcon-H1's head a tile, Granite's two heads."""

@@ -409,33 +409,48 @@ def test_grad_accumulation_batch_validation():
                                   accum_steps=2))   # 12 % (2*8) != 0
 
 
-def test_fused_xent_matches_unfused_step():
-    """fused_lm_loss must be numerically identical to the logits path —
-    same loss and same params after one step (chunked scan + checkpoint
-    changes memory behavior, never values)."""
-    import numpy as np
+@pytest.mark.parametrize("dtype,form,atol", [
+    # `fused_xent` selects fused_lm_loss, the scan under jax.checkpoint
+    (jnp.float32, "fused_lm_loss", 2e-5),
+    # bfloat16 compute without the flag: the head and its loss run in
+    # one pass (ops/xent.py; off the TPU its scan form)
+    (jnp.bfloat16, "one_pass", 2e-3),
+])
+def test_fused_xent_matches_unfused_step(dtype, form, atol, monkeypatch):
+    """Each form that keeps the logits out of HBM must be numerically
+    the logits path — same loss and same params after one step (a
+    chunked scan changes memory behavior, never values)."""
     import optax
-    from flax.core import meta
 
-    from mpi_operator_tpu.models.transformer import CausalLM, gpt2_config
-    from mpi_operator_tpu.parallel import MeshConfig, make_mesh
-    from mpi_operator_tpu.train import LMTrainer, LMTrainerConfig
+    from mpi_operator_tpu.ops.attention import record_traced
 
-    cfg = gpt2_config("test", attention="dense", dtype=jnp.float32,
+    cfg = gpt2_config("test", attention="dense", dtype=dtype,
                       vocab_size=256, max_len=32)
     toks = jax.random.randint(jax.random.PRNGKey(5), (8, 17), 0, 256)
     toks, tgts = toks[:, :-1], toks[:, 1:]
     mesh = make_mesh(MeshConfig(dp=8))
-    outs = {}
+    outs, traced = {}, {}
     for fused in (False, True):
-        t = LMTrainer(CausalLM(cfg), mesh,
-                      LMTrainerConfig(global_batch_size=8, seq_len=16,
-                                      fused_xent=fused),
-                      tx=optax.sgd(0.1))
+        if not fused:
+            # the reference: the logits path, in either type
+            monkeypatch.setattr(LMTrainer, "_one_pass_head",
+                                lambda self: False)
+        t = LMTrainer(CausalLM(cfg), mesh, LMTrainerConfig(
+            global_batch_size=8, seq_len=16,
+            fused_xent=fused and form == "fused_lm_loss"),
+            tx=optax.sgd(0.1))
         s = t.init_state(jax.random.PRNGKey(0))
-        s, m = t.train_step(s, toks, tgts)
-        outs[fused] = (float(m["loss"]), s.params)
+        with record_traced() as rec:
+            s, m = t.train_step(s, toks, tgts)
+        monkeypatch.undo()
+        outs[fused] = (float(m["loss"]), s.params, float(m["accuracy"]))
+        traced[fused] = rec["head_loss"]
+    assert traced[False] == set()
+    assert (traced[True] == {"xla_chunked[chunks=8,products=3]"}) \
+        == (form == "one_pass")
     assert abs(outs[True][0] - outs[False][0]) < 1e-5
+    # the one pass keeps the step's accuracy; fused_lm_loss never had one
+    assert (outs[True][2] == outs[False][2]) == (form == "one_pass")
     for a, b in zip(jax.tree.leaves(outs[True][1]),
                     jax.tree.leaves(outs[False][1])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=atol)

@@ -1018,4 +1018,72 @@ def test_a_one_chip_train_step_holds_no_collective_and_takes_no_option(
     assert not re.search(r" (all-reduce|all-gather|reduce-scatter|"
                          r"collective-permute|all-to-all)[\w-]*\(", text)
     assert "async-collective" not in text
-    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    # two layers' flash kernels (forward, backward) and the head's one
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+
+
+def test_the_train_step_scores_its_head_and_its_loss_in_one_pass(
+        topo, quiet_cache, monkeypatch):
+    """The training cells' step (8 x 1024 tokens a chip, GPT-2-medium's
+    widths, two layers deep) takes `ops/xent.py`'s one-kernel form, and
+    says so; its program holds no array of float32 logits, whole or as
+    rows, on one chip or on the dp=4 mesh (where the kernel runs under
+    `shard_map` on a chip's rows), and what it keeps beside its operands
+    falls by half of what those logits took (the other half is the
+    bfloat16 cotangent, which the `dtable` product reads)."""
+    from mpi_operator_tpu.ops.attention import record_traced
+    from mpi_operator_tpu.train import lm_trainer
+    logits = re.compile(r"f32\[(8,1024|8192|32,1024|32768),50304\]")
+    temp = {}
+    for chips in (1, 4):
+        with record_traced() as traced:
+            compiled, _ = _described_train_step(topo, monkeypatch, chips)
+        assert traced["head_loss"] == {
+            "pallas_xent[rows=256,vocab_tile=2048,products=3]"}
+        assert not logits.search(compiled.as_text())
+        temp[chips] = compiled.memory_analysis().temp_size_in_bytes
+    monkeypatch.setattr(lm_trainer.LMTrainer, "_one_pass_head",
+                        lambda self: False)
+    with record_traced() as traced:
+        parent, _ = _described_train_step(topo, monkeypatch, 1)
+    assert traced["head_loss"] == set()
+    assert logits.search(parent.as_text())
+    assert parent.memory_analysis().temp_size_in_bytes - temp[1] \
+        > 0.45 * 8 * 1024 * 50304 * 4
+
+
+@pytest.mark.parametrize("tokens,vocab", [
+    (8192, 50304),      # the cells' head
+    (4096, 50257),      # GPT-2's unpadded table: another cut of the tile
+])
+def test_head_loss_kernel_fits_the_vmem_it_asks_for(tokens, vocab,
+                                                    one_chip, quiet_cache):
+    """`tied_head_xent` and both its gradients for `tokens` rows of 1024
+    against `vocab`: Mosaic takes the kernel with the 51.5 MB of kept
+    logits (it refuses one that passes the limit it was given), the
+    blocks and scratch `kernel_vmem_bytes` counts are under that limit,
+    and the limit under what a v5e has; the cotangent is the one large
+    array between the kernel and the `dtable` product."""
+    from mpi_operator_tpu.ops import xent
+    t = xent.tiling(tokens, vocab, xent._KEPT_ROWS)
+    need = xent.kernel_vmem_bytes(t, 1024, 2, True)
+    limit = xent._vmem_limit(t, 1024, 2, True)
+    assert need < limit <= xent.VMEM_CEILING < 128 << 20
+    assert need > 4 * t.rows * vocab
+    shape = lambda sh, dt: jax.ShapeDtypeStruct(   # noqa: E731
+        sh, dt, sharding=one_chip)
+
+    def grads(h, table, y):
+        return jax.value_and_grad(
+            lambda h, table: xent.tied_head_xent(
+                h, table, y, scan=False, interpret=False),
+            argnums=(0, 1), has_aux=True)(h, table)
+    compiled = jax.jit(grads).lower(
+        shape((1, tokens, 1024), jnp.bfloat16),
+        shape((vocab, 1024), jnp.bfloat16),
+        shape((1, tokens), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(rf"f32\[(1,)?{tokens},{vocab}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.25 * 2 * tokens * vocab
