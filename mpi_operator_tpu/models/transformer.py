@@ -176,10 +176,10 @@ def _dense(features, name, logical_axes, dtype):
 class _ProjParams(nn.Module):
     """Parameter container producing the SAME tree (names, shapes, init
     fns, logical axes) as the nn.Dense/DenseGeneral it stands in for,
-    without running the matmul. The tp_overlap path consumes the kernels
-    explicitly inside shard_map (ring collective-matmuls,
-    parallel/collectives.py), so parameters trained on either path load
-    directly on the other."""
+    without running the matmul: Attention runs its own products on the
+    kernels (`_rows_dense`; under tp_overlap the ring collective-matmuls
+    of parallel/collectives.py inside shard_map), so parameters trained
+    on either path, or before PR 44, load directly on the other."""
     kernel_shape: tuple
     bias_shape: tuple
     kernel_axes: tuple
@@ -197,6 +197,15 @@ class _ProjParams(nn.Module):
                                          self.bias_axes),
             self.bias_shape, jnp.float32)
         return k, b
+
+
+def _rows_dense(x, kernel, bias, dtype, kernel_dim=0):
+    """`x [.., K]` times a 2-D `kernel` over the kernel's dim `kernel_dim`,
+    plus `bias`, by `nn.Dense`'s dtype rule: operands and bias cast to
+    `dtype`, the product in `dtype` (the MXU accumulates in float32)."""
+    x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias, dtype=dtype)
+    return jax.lax.dot_general(
+        x, kernel, (((x.ndim - 1,), (kernel_dim,)), ((), ()))) + bias
 
 
 def tp_overlap_ring(cfg: "TransformerConfig", mesh, seq_len: int) -> int:
@@ -300,19 +309,32 @@ class Attention(nn.Module):
                 f"divisors or disable tp_overlap")
 
         def proj(heads, name):
-            return nn.DenseGeneral(
-                axis=-1, dtype=cfg.dtype, features=(heads, D), name=name,
-                kernel_init=nn.with_logical_partitioning(
-                    kernel_init, ("embed", "heads", "kv")),
-                bias_init=nn.with_logical_partitioning(
-                    nn.initializers.zeros, ("heads", "kv")),
-            )
+            # ONE product over the merged heads·D dim, so the activation
+            # is written as [B, S, heads·D] rows, the form the flash
+            # kernels read; [B, S, heads, D] below is a view of it. (A
+            # DenseGeneral with two feature dims is lowered to a
+            # convolution over the heads that writes the sequence minor,
+            # and a relayout copy then stands before every kernel
+            # operand.) The tree is DenseGeneral's: kernel
+            # [E, heads, D], bias [heads, D]; B and S are not merged, an
+            # sp mesh shards S.
+            w, b = _ProjParams((E, heads, D), (heads, D),
+                               ("embed", "heads", "kv"), ("heads", "kv"),
+                               name=name)()
+            # The kernel enters as [heads·D, E], the product contracting
+            # its minor dim: the order a TPU keeps an [E, heads, 64]
+            # master in (E minor), so the weight gradient is born, and on
+            # a dp mesh reduced, as the master lies; as [E, heads·D] it
+            # is relaid before the update on a mesh, and on one chip the
+            # step measured 0.3 ms slower (PERF.md section 6, PR 44).
+            return _rows_dense(
+                x, w.transpose(1, 2, 0).reshape(heads * D, E),
+                b.reshape(heads * D), cfg.dtype,
+                kernel_dim=1).reshape(B, S, heads, D)
         if ring:
             q, k, v = self._overlap_qkv(x, mesh, ring)
         else:
-            q = proj(H, "query")(x)
-            k = proj(KV, "key")(x)
-            v = proj(KV, "value")(x)
+            q, k, v = proj(H, "query"), proj(KV, "key"), proj(KV, "value")
 
         if cfg.pos_embedding == "rope" and not cfg.decode:
             pos = jnp.arange(S) if positions is None else positions
@@ -333,14 +355,12 @@ class Attention(nn.Module):
 
         if ring:
             return self._overlap_out(out, mesh, ring)
-        out = nn.DenseGeneral(
-            features=E, axis=(-2, -1), dtype=cfg.dtype, name="out",
-            kernel_init=nn.with_logical_partitioning(
-                kernel_init, ("heads", "kv", "embed")),
-            bias_init=nn.with_logical_partitioning(
-                nn.initializers.zeros, ("embed",)),
-        )(out)
-        return out
+        # the heads' outputs read as the rows the kernels wrote: one
+        # contracted dim of H·D
+        w, b = _ProjParams((H, D, E), (E,), ("heads", "kv", "embed"),
+                           ("embed",), name="out")()
+        return _rows_dense(out.reshape(B, S, H * D), w.reshape(H * D, E), b,
+                           cfg.dtype)
 
     def _overlap_qkv(self, x, mesh, tp):
         """Fused qkv as ONE ring allgather_matmul: the three column-parallel

@@ -902,13 +902,13 @@ def flash_attention(q, k, v, causal: bool = True,
     if mesh is not None:
         # each device re-enters with its own rows / heads (inside the
         # manual region _kernel_mesh is None and the kernel below runs)
-        qkv = ("rows", None, "heads", None)
+        qkv = ("rows", None, "heads")   # [B, S, H·D]: 4-D is laid S-minor
         return _per_device(
             lambda q, k, v, mask: flash_attention(
-                q, k, v, causal=causal, mask=mask, block_q=block_q,
-                block_k=block_k, interpret=interpret),
-            mesh, B, H, (q, k, v, mask), (qkv, qkv, qkv, ("rows", None)),
-            qkv)
+                *(x.reshape(*x.shape[:2], -1, D) for x in (q, k, v)), causal,
+                mask, block_q, block_k, interpret).reshape(q.shape),
+            mesh, B, H, (*(x.reshape(B, S, H * D) for x in (q, k, v)), mask),
+            (qkv, qkv, qkv, ("rows", None)), qkv).reshape(B, S, H, D)
     sm_scale = 1.0 / (D ** 0.5)
     if hp is not None and (interpret or not (block_q % LANES
                                              or block_k % 16)):

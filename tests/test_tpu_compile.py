@@ -853,8 +853,10 @@ def test_flash_attention_and_its_gradient_at_the_training_cells_shape(
     chip: XLA tiles its two minor dims, (16, 64), and a projection with
     two feature dims is lowered to a convolution over the heads whose
     output has the sequence minor. Between either and ANY row-major
-    kernel operand the compiler puts a relayout copy; the model's
-    projections still pay those, PERF.md section 6, PR 36.)"""
+    kernel operand the compiler puts a relayout copy. The model's
+    projections paid eight of those a layer until PR 44 made each ONE
+    product over a merged H·D dim:
+    `test_the_train_step_keeps_its_attention_activations_as_rows`.)"""
     from mpi_operator_tpu.ops.attention import flash_attention, record_traced
     B, S = 8, 1024
     x = jax.ShapeDtypeStruct((B, S, H * D), dtype, sharding=one_chip)
@@ -922,6 +924,71 @@ def _described_train_step(topo, monkeypatch, chips, layers=2):
     return compiled, options
 
 
+_PLAIN_STEPS = {}
+
+
+def _plain_train_step(topo, chips):
+    """`_described_train_step` of the trainer as it stands, compiled once a
+    module for each `chips` (four tests read the same two programs):
+    (compiled, compiler options, what `record_traced` heard)."""
+    from mpi_operator_tpu.ops.attention import record_traced
+    if chips not in _PLAIN_STEPS:
+        with pytest.MonkeyPatch.context() as patch, \
+                record_traced() as traced:
+            _PLAIN_STEPS[chips] = _described_train_step(
+                topo, patch, chips) + (traced,)
+    return _PLAIN_STEPS[chips]
+
+
+def _entry(text):
+    """The scheduled entry computation of a compiled program: what stands
+    there is an operation of its own, not part of a fusion."""
+    lines = text.splitlines()
+    return "\n".join(lines[next(n for n, ln in enumerate(lines)
+                                if ln.startswith("ENTRY ")):])
+
+
+def _writes(text, op, dtype, *shapes):
+    """How many `op` instructions of `text` write a `dtype` array of one of
+    `shapes`."""
+    dims = "|".join(",".join(map(str, sh)) for sh in shapes)
+    return len(re.findall(rf"= {dtype}\[({dims})\][^ ]* {op}\(", text))
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_train_step_keeps_its_attention_activations_as_rows(
+        chips, topo, quiet_cache):
+    """The training cells' step, two layers deep, on one chip and on the
+    dp=4 mesh: between `Attention`'s projections and the flash kernels no
+    activation is copied into another layout (the parent's program held
+    16 `copy` of `bf16[8,1024,1024]`, eight a layer: q, k, v and `out`'s
+    input, `do`, `dq`, `dk`, `dv`), anywhere in the program, inside a
+    fusion or out; on the mesh the `shard_map` boundary carries the same
+    rows. What came instead, pinned at the count the hand-in has: each
+    projection's float32 master is cast to bfloat16 ONCE by an operation
+    of its own (4 a layer; the parent's convolutions took the cast in,
+    once forward and once for `dx`), and no weight or weight gradient is
+    relaid: q, k and v enter their product as `[H·D, E]`, the order the
+    chip keeps an `[E, 16, 64]` master in, so their gradients are born
+    and, on the mesh, reduced as the master lies (`[E, H·D]` products
+    brought 3 `copy` of `f32[1024,1024]` a layer there). On the mesh the
+    reduced gradient of `out` is cast back to float32 alone, 1 a layer."""
+    text = _plain_train_step(topo, chips)[0].as_text()
+    assert _copies_of(text, (8, 1024, 1024), (8, 1024, 16, 64),
+                      (8, 16, 1024, 64), (128, 1024, 64)) == []
+    assert "bf16[8,1024,16,64]" not in text      # the 4-D shape is a view
+    entry = _entry(text)
+    qkv, out, flat = (1024, 16, 64), (16, 64, 1024), (1024, 1024)
+    assert _copies_of(entry, qkv, out, flat) == []
+    assert _writes(entry, "convert", "bf16", qkv) == 6
+    assert _writes(entry, "convert", "bf16", out) == 2
+    assert _writes(entry, "convert", "f32", qkv, out, flat) == (
+        2 if chips == 4 else 0)
+    if chips == 4:
+        # the weight gradients cross the chips as the masters lie
+        assert _writes(text, "all-reduce", "bf16", qkv) >= 1
+
+
 _HLO_ARRAY = re.compile(r"\b(bf16|f32)\[([\d,]*)\]")
 
 
@@ -970,7 +1037,7 @@ def test_a_dp4_train_steps_gradient_all_reduces_run_under_weight_gradients(
     `all-reduce` that carries `async_collective_name`) where it stands
     alone with a MiB or more."""
     from mpi_operator_tpu.train import lm_trainer
-    compiled, options = _described_train_step(topo, monkeypatch, 4)
+    compiled, options, _ = _plain_train_step(topo, 4)
     assert options == lm_trainer.DP_OVERLAP_OPTIONS
     text = compiled.as_text()
     reds, entry = _reductions(text)
@@ -1009,9 +1076,9 @@ def test_a_dp4_train_steps_gradient_all_reduces_run_under_weight_gradients(
 
 
 def test_a_one_chip_train_step_holds_no_collective_and_takes_no_option(
-        topo, quiet_cache, monkeypatch):
+        topo, quiet_cache):
     from mpi_operator_tpu.train import lm_trainer
-    compiled, options = _described_train_step(topo, monkeypatch, 1)
+    compiled, options, _ = _plain_train_step(topo, 1)
     assert options is None
     text = compiled.as_text()
     assert lm_trainer.count_grad_reductions(text) == (0, 0)
@@ -1036,8 +1103,7 @@ def test_the_train_step_scores_its_head_and_its_loss_in_one_pass(
     logits = re.compile(r"f32\[(8,1024|8192|32,1024|32768),50304\]")
     temp = {}
     for chips in (1, 4):
-        with record_traced() as traced:
-            compiled, _ = _described_train_step(topo, monkeypatch, chips)
+        compiled, _, traced = _plain_train_step(topo, chips)
         assert traced["head_loss"] == {
             "pallas_xent[rows=256,vocab_tile=2048,products=3]"}
         assert not logits.search(compiled.as_text())
