@@ -22,7 +22,7 @@ Metrics per (devices n, payload):
               allreduce should not grow as the ring grows)
 
 On one real chip the harness degenerates to the n=1 floor (reduction is a
-local copy); the CPU-virtual 8-device mesh (tests, --smoke) exercises the
+local copy); the CPU-virtual 8-device mesh of the tests exercises the
 full curve shape today.
 """
 from __future__ import annotations
@@ -99,18 +99,16 @@ def run_allreduce_benchmark(
 
 
 def main(argv=None) -> int:
-    parser = argparse.ArgumentParser(prog="allreduce-bench")
-    parser.add_argument("--payload-mb", type=float, nargs="+",
-                        default=[1.0, 16.0, 64.0])
-    parser.add_argument("--iters", type=int, default=10)
-    parser.add_argument("--devices", type=int, nargs="+", default=None)
-    args = parser.parse_args(argv)
+    argparse.ArgumentParser(
+        prog="allreduce-bench",
+        description="time the sharded allreduce at 1, 16 and 64 MB over "
+                    "every power-of-two count of the visible devices; "
+                    "one JSON line").parse_args(argv)
     from ..utils.compile_cache import enable_compile_cache
     from ._report import device_record
     enable_compile_cache()
     result = run_allreduce_benchmark(
-        payload_mb=args.payload_mb, device_counts=args.devices,
-        iters=args.iters, log=lambda s: print(s, file=sys.stderr))
+        log=lambda s: print(s, file=sys.stderr))
     print(json.dumps({**result, **device_record()}))
     return 0
 

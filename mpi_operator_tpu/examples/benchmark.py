@@ -33,7 +33,6 @@ def run_benchmark(
     image_size: int = 224,
     dtype_name: str = "bfloat16",
     num_slices: int = 1,
-    learning_rate: float = 0.1,
     stem: str = "conv7",
     data_dir: Optional[str] = None,
     profile_dir: Optional[str] = None,
@@ -41,8 +40,7 @@ def run_benchmark(
     ckpt_every: int = 0,
     log: Callable[[str], None] = print,
 ) -> Tuple[object, Dict[str, float]]:
-    """Shared wiring for every benchmark surface (bench.py, the container
-    entrypoint, tests): mesh over all visible devices, synthetic or on-disk
+    """Shared wiring for the container entrypoint and the tests: mesh over all visible devices, synthetic or on-disk
     data (`data_dir` — npy shards, data/imagefolder.py), DP train loop.
     Returns (final_state, metrics)."""
     import jax
@@ -61,8 +59,7 @@ def run_benchmark(
     model = create_model(model_name, num_classes=1000, dtype=dtype,
                          stem=stem)
     cfg = TrainerConfig(global_batch_size=global_batch,
-                        image_size=image_size, num_classes=1000,
-                        learning_rate=learning_rate)
+                        image_size=image_size, num_classes=1000)
     trainer = Trainer(model, mesh, cfg)
     state = trainer.init_state(jax.random.PRNGKey(0))
     if data_dir is not None:
@@ -115,7 +112,6 @@ def main(argv=None) -> int:
     parser.add_argument("--ckpt-every", type=int, default=0,
                         help="async checkpoint every N steps into "
                              "--train-dir (0 = final only)")
-    parser.add_argument("--learning-rate", type=float, default=0.1)
     parser.add_argument("--stem", default="s2d", choices=["s2d", "conv7"],
                         help="s2d (default): 4x4 space-to-depth stem — "
                              "feeds the MXU's input lanes (measured +4.7%% "
@@ -163,7 +159,6 @@ def main(argv=None) -> int:
             image_size=args.image_size,
             dtype_name=args.dtype,
             num_slices=info.num_slices,
-            learning_rate=args.learning_rate,
             stem=args.stem,
             data_dir=args.data_dir,
             profile_dir=args.profile_dir,

@@ -14,7 +14,7 @@ out of process, on CPU hosts:
             then SIGTERM'd again
   resize    back to the original size
   phase 3   4 devices, batch 2/device — resharding restore again, runs
-            to --stop-at-step and exits 0
+            to STOP_AT_STEP and exits 0
 
 The global batch is constant (4x2 = 2x4 = 8) and the token stream is
 step-keyed, so every phase consumes exactly the batches the
@@ -26,7 +26,7 @@ resize_ledger/goodput_ledger the live controller renders, reporting the
 across both resizes.
 
     python -m mpi_operator_tpu.examples.elastic_benchmark \
-        --out-dir /tmp/elastic [--no-oracle]
+        --out-dir /tmp/elastic
 
 Prints one JSON line; exit 0 iff every gate held. ``--out-dir`` keeps
 timeline.jsonl / federated.prom / per-phase logs for postmortem use.
@@ -47,6 +47,9 @@ from typing import Dict, List, Optional, Tuple
 #: (devices, batch_per_device) per phase — the product (global batch) is
 #: invariant, which is what makes the loss curves comparable at all
 PHASE_SHAPES: Tuple[Tuple[int, int], ...] = ((4, 2), (2, 4), (4, 2))
+#: global steps the two SIGTERMs land on, and the step every run ends at
+RESIZE_AT, STOP_AT_STEP = (5, 10), 14
+SEQ_LEN = 16
 
 
 def _phase_env(devices: int, port: int, fault: Optional[str],
@@ -109,11 +112,7 @@ def _headline(log_path: str) -> Dict:
 
 
 def run_elastic_benchmark(out_dir: Optional[str] = None,
-                          stop_at_step: int = 14,
-                          resize_at: Tuple[int, int] = (5, 10),
-                          port: int = 8479, seq_len: int = 16,
-                          oracle: bool = True,
-                          log=print) -> Dict:
+                          port: int = 8479, log=print) -> Dict:
     from ..telemetry import EventLog, read_events, events as tev
     from ..telemetry.collector import (goodput_ledger, ledger_lines,
                                        merge_timeline, resize_ledger,
@@ -141,8 +140,8 @@ def run_elastic_benchmark(out_dir: Optional[str] = None,
                       workers=PHASE_SHAPES[0][0])
             plan = [
                 # (shape, fault step, expected rc)
-                (PHASE_SHAPES[0], resize_at[0], 215),
-                (PHASE_SHAPES[1], resize_at[1], 215),
+                (PHASE_SHAPES[0], RESIZE_AT[0], 215),
+                (PHASE_SHAPES[1], RESIZE_AT[1], 215),
                 (PHASE_SHAPES[2], None, 0),
             ]
             for idx, ((devices, bpd), fault_step, want_rc) in enumerate(plan):
@@ -152,9 +151,9 @@ def run_elastic_benchmark(out_dir: Optional[str] = None,
                 log(f"elastic: phase {idx} — {devices} device(s) x "
                     f"batch {bpd}"
                     + (f", SIGTERM at step {fault_step}" if fault else
-                       f", run to step {stop_at_step}"))
+                       f", run to step {STOP_AT_STEP}"))
                 rc, wall = _run_phase(train_dir, devices, bpd, port,
-                                      stop_at_step, seq_len, log_path,
+                                      STOP_AT_STEP, SEQ_LEN, log_path,
                                       fault=fault, reshard=idx > 0)
                 result["phases"].append({"devices": devices,
                                          "batch_per_device": bpd,
@@ -171,7 +170,7 @@ def run_elastic_benchmark(out_dir: Optional[str] = None,
                     clog.emit(tev.GANG_RESIZE, job=job, workers=nxt[0],
                               tpus=nxt[0] * 2)
             else:
-                clog.emit(tev.JOB_SUCCEEDED, job=job, step=stop_at_step)
+                clog.emit(tev.JOB_SUCCEEDED, job=job, step=STOP_AT_STEP)
 
         headline = _headline(os.path.join(out_dir, "phase2.log"))
         result["final_loss"] = headline.get("final_loss")
@@ -219,16 +218,16 @@ def run_elastic_benchmark(out_dir: Optional[str] = None,
             if ledger["goodput"] <= 0:
                 fail("zero federated goodput across the resizes")
 
-        if oracle and result["ok"]:
+        if result["ok"]:
             # the straight-through control: same seed, same step-keyed
             # stream, same topology as phases 1/3, never interrupted
             log(f"elastic: oracle — {PHASE_SHAPES[0][0]} device(s) "
-                f"straight to step {stop_at_step}")
+                f"straight to step {STOP_AT_STEP}")
             oracle_dir = os.path.join(out_dir, "oracle_ckpt")
             olog = os.path.join(out_dir, "oracle.log")
             rc, _wall = _run_phase(oracle_dir, PHASE_SHAPES[0][0],
-                                   PHASE_SHAPES[0][1], port, stop_at_step,
-                                   seq_len, olog, fault=None,
+                                   PHASE_SHAPES[0][1], port, STOP_AT_STEP,
+                                   SEQ_LEN, olog, fault=None,
                                    reshard=False)
             if rc != 0:
                 fail(f"oracle run exited {rc}")
@@ -264,24 +263,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                         help="keep artifacts (timeline.jsonl, "
                              "federated.prom, phase logs) here; default "
                              "is a temp dir removed on exit")
-    parser.add_argument("--stop-at-step", type=int, default=14)
-    parser.add_argument("--resize-at", default="5,10",
-                        help="global steps the two SIGTERMs land on")
-    parser.add_argument("--seq-len", type=int, default=16)
     parser.add_argument("--port", type=int, default=8479,
                         help="coordinator port for the phase subprocesses")
-    parser.add_argument("--no-oracle", action="store_true",
-                        help="skip the straight-through control run")
     args = parser.parse_args(argv)
-    resize_at = tuple(int(x) for x in args.resize_at.split(","))
-    if len(resize_at) != 2 or not (0 < resize_at[0] < resize_at[1]
-                                   < args.stop_at_step):
-        raise SystemExit(f"--resize-at must be two ascending steps below "
-                         f"--stop-at-step, got {args.resize_at!r}")
     result = run_elastic_benchmark(
-        out_dir=args.out_dir, stop_at_step=args.stop_at_step,
-        resize_at=resize_at, port=args.port, seq_len=args.seq_len,
-        oracle=not args.no_oracle,
+        out_dir=args.out_dir, port=args.port,
         log=lambda s: print(s, file=sys.stderr))
     print(json.dumps(result))
     return 0 if result["ok"] else 1

@@ -33,19 +33,18 @@ from ._report import device_ids, with_run_report
 MLM_MASK_RATE = 0.15
 
 
-def _worker_telemetry(metrics_port, event_log, train_dir, events, log):
+def _worker_telemetry(metrics_port, train_dir, events, log):
     """The run's WorkerTelemetry: a /metrics server when --metrics-port
-    is given (0 = ephemeral, for tests), an event log at --event-log or
-    defaulting to <train_dir>/events.jsonl when a train dir exists (so
-    resilience runs record their drains with zero extra flags). `events`
-    borrows an already-open log — ownership stays with the caller.
-    Returns (telemetry, owns_events)."""
+    is given (0 = ephemeral, for tests), an event log at
+    <train_dir>/events.jsonl when a train dir exists (so resilience runs
+    record their drains with zero extra flags). `events` borrows an
+    already-open log (main opens --event-log before distributed init) —
+    ownership stays with the caller. Returns (telemetry, owns_events)."""
     from ..telemetry import EventLog, WorkerTelemetry
 
     owns = events is None
     if events is None:
-        path = event_log or (os.path.join(train_dir, "events.jsonl")
-                             if train_dir else None)
+        path = os.path.join(train_dir, "events.jsonl") if train_dir else None
         events = EventLog(path) if path else None
     if events is not None and os.environ.get("TPU_PACK_GROUP"):
         # packed jobs share one worker process (and one event file);
@@ -79,12 +78,8 @@ def run_lm_benchmark(
     remat: bool = False,
     remat_policy: str = "none",
     moe_experts: int = 0,
-    moe_dropless: bool = False,
     ep: int = 1,
-    num_layers: Optional[int] = None,
     fused_xent: bool = False,
-    flash_block_q: Optional[int] = None,
-    flash_block_k: Optional[int] = None,
     tp_overlap: bool = False,
     tp_ring: str = "uni",
     accum_steps: int = 1,
@@ -96,13 +91,10 @@ def run_lm_benchmark(
     divergence_k: int = 3,
     stop_check_every: Optional[int] = None,
     stop_at_step: Optional[int] = None,
-    lr_schedule: str = "linear",
-    decay_steps: int = 10_000,
     lr: Optional[float] = None,
     lr_warmup_steps: Optional[int] = None,
     profile_dir: Optional[str] = None,
     metrics_port: Optional[int] = None,
-    event_log: Optional[str] = None,
     events=None,
     log: Callable[[str], None] = print,
 ) -> Tuple[object, Dict[str, float]]:
@@ -128,9 +120,6 @@ def run_lm_benchmark(
     n = jax.device_count()
     if ep > 1 and not moe_experts:
         raise ValueError("--ep needs --moe-experts (nothing to shard)")
-    if moe_dropless and not moe_experts:
-        raise ValueError("--moe-dropless needs --moe-experts (no MoE is "
-                         "built without it)")
     if moe_experts and moe_experts % ep:
         # the sharding rules silently REPLICATE a non-divisible expert dim
         # (parallel/sharding._divisible_spec), which would mislabel a
@@ -163,16 +152,7 @@ def run_lm_benchmark(
         # expert-parallel MoE: every other block's FFN becomes a top-2
         # mixture routed over the ep axis (parallel/moe.py); the trainer
         # folds the load-balancing aux loss in automatically
-        overrides = dict(num_experts=moe_experts,
-                         moe_dropless=moe_dropless)
-    if flash_block_q:
-        overrides["flash_block_q"] = flash_block_q
-    if flash_block_k:
-        overrides["flash_block_k"] = flash_block_k
-    if num_layers:
-        # depth override: scaling studies + tiny pp×moe configs (the
-        # "test" presets are 2 layers, which can't tile moe_every×pp)
-        overrides["num_layers"] = num_layers
+        overrides = dict(num_experts=moe_experts)
     if tp_overlap:
         # ring collective-matmul projections + vocab-parallel overlapped
         # loss (parallel/collectives.py): only meaningful with a tp ring
@@ -206,11 +186,9 @@ def run_lm_benchmark(
         opt_overrides["warmup_steps"] = lr_warmup_steps
     tcfg = LMTrainerConfig(global_batch_size=global_batch, seq_len=seq_len,
                            masked_lm=masked, fused_xent=fused_xent,
-                           accum_steps=accum_steps,
-                           lr_schedule=lr_schedule, decay_steps=decay_steps,
-                           **opt_overrides)
-    wtel, owns_events = _worker_telemetry(metrics_port, event_log,
-                                          train_dir, events, log)
+                           accum_steps=accum_steps, **opt_overrides)
+    wtel, owns_events = _worker_telemetry(metrics_port, train_dir,
+                                          events, log)
     if pp > 1:
         # GPipe over the pp axis: stage-sliced CausalLM — or MaskedLM
         # (bert): the mask stream rides the relays and the last stage
@@ -554,14 +532,10 @@ def run_hfta_benchmark(
     k: int = 8,
     learning_rates=None,
     seeds=None,
-    num_layers: Optional[int] = None,
     train_dir: Optional[str] = None,
-    lr_schedule: str = "linear",
-    decay_steps: int = 10_000,
     lr: Optional[float] = None,
     lr_warmup_steps: Optional[int] = None,
     metrics_port: Optional[int] = None,
-    event_log: Optional[str] = None,
     events=None,
     log: Callable[[str], None] = print,
 ) -> Tuple[object, Dict[str, float]]:
@@ -593,9 +567,7 @@ def run_hfta_benchmark(
     dtype = jnp.bfloat16 if dtype_name == "bfloat16" else jnp.float32
 
     name = f"{workload}-{size}" if size else workload
-    overrides = {"num_layers": num_layers} if num_layers else {}
-    model = create_lm(name, dtype=dtype, max_len=max(seq_len, 32),
-                      **overrides)
+    model = create_lm(name, dtype=dtype, max_len=max(seq_len, 32))
     vocab = model.config.vocab_size
 
     global_batch = batch_per_device * n        # PER-REPLICA batch
@@ -605,7 +577,6 @@ def run_hfta_benchmark(
     if lr_warmup_steps is not None:
         opt_overrides["warmup_steps"] = lr_warmup_steps
     tcfg = LMTrainerConfig(global_batch_size=global_batch, seq_len=seq_len,
-                           lr_schedule=lr_schedule, decay_steps=decay_steps,
                            **opt_overrides)
     hp = HFTAHyperparams.sweep(k, tcfg, learning_rates=learning_rates,
                                seeds=seeds)
@@ -613,8 +584,8 @@ def run_hfta_benchmark(
     log(f"hfta: fusing K={k} × {name} replicas, "
         f"lrs={list(hp.learning_rates)} seeds={list(hp.seeds)}")
 
-    wtel, owns_events = _worker_telemetry(metrics_port, event_log,
-                                          train_dir, events, log)
+    wtel, owns_events = _worker_telemetry(metrics_port, train_dir,
+                                          events, log)
     try:
         state = trainer.init_state()
         state = maybe_resume(train_dir, state, log)
@@ -650,125 +621,9 @@ def run_hfta_benchmark(
 
 
 @with_run_report
-def run_generate_benchmark(
-    size: Optional[str] = None,
-    batch: int = 8,
-    prompt_len: int = 128,
-    new_tokens: int = 128,
-    # one generate() call is ~0.2-0.4 s of device work at these shapes;
-    # 8 of them put the window's one closing host read and the first
-    # call's dispatch well under 1% of it
-    num_iters: int = 8,
-    dtype_name: str = "bfloat16",
-    temperature: float = 0.0,
-    family: str = "gpt2",
-    kv_cache_dtype: Optional[str] = None,
-    decode_kernel: Optional[bool] = None,
-    log: Callable[[str], None] = print,
-) -> Dict[str, float]:
-    """Inference benchmark: KV-cache autoregressive decode throughput
-    (models/generate.py). Reports end-to-end NEW tokens/sec (prefill
-    amortized in) for the gpt2 AND llama families (llama's GQA cache is
-    num_heads/num_kv_heads× smaller, the decode-bandwidth win) — the
-    inference half the reference has no analogue for. kv_cache_dtype=
-    "int8" halves the cache bytes again (quantized storage).
-    decode_kernel: None = auto (the Pallas decode fast path on TPU, the
-    dense oracle elsewhere); True/False forces one side — the knob the
-    bench ladder uses to keep kernel-vs-dense an A/B on the same leg.
-    The returned `decode_impl` is what the decode step actually traced."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from ..models import create_lm, generate
-    from ..parallel.sharding import shard_init
-    from ..parallel import MeshConfig, make_mesh
-
-    dtype = jnp.bfloat16 if dtype_name == "bfloat16" else jnp.float32
-    if decode_kernel is None:
-        # auto: the Pallas fast path wherever it compiles to Mosaic; CPU
-        # runs keep the dense oracle (interpret-mode pallas inside the
-        # decode scan is a simulation, not a measurement)
-        decode_kernel = jax.default_backend() == "tpu"
-    name = f"{family}-{size}" if size else family
-    model = create_lm(name, dtype=dtype,
-                      kv_cache_dtype=kv_cache_dtype,
-                      decode_kernel=decode_kernel,
-                      max_len=max(prompt_len + new_tokens, 32))
-    mesh = make_mesh(MeshConfig(dp=jax.device_count()))
-    variables, _ = shard_init(
-        model, mesh, jax.random.PRNGKey(0),
-        jnp.zeros((1, prompt_len), jnp.int32))
-    params = variables["params"]
-    # inference params in inference precision, cast ONCE up front: decode
-    # re-reads every parameter each step, and f32 masters inside the
-    # decode program get streamed+converted per step by XLA (sunk
-    # converts — models/generate.py note), doubling the bytes the loop
-    # reads. Measured on v5e: bf16 masters are 2.2x decode throughput.
-    if dtype == jnp.bfloat16:
-        params = jax.jit(lambda p: jax.tree.map(
-            lambda x: x.astype(dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating) else x, p))(params)
-        jax.block_until_ready(params)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (batch, prompt_len),
-                                0, model.config.vocab_size)
-
-    rng = jax.random.PRNGKey(2)
-    c0 = time.perf_counter()
-    out = generate(model, params, prompt, new_tokens,
-                   temperature=temperature, rng=rng)       # compiles
-    # a host read of the last token waits for the whole program: the
-    # timed window below starts with compile and warm-up finished
-    int(out.tokens[0, -1])
-    compile_seconds = time.perf_counter() - c0
-    t0 = time.perf_counter()
-    for i in range(num_iters):
-        out = generate(model, params, prompt, new_tokens,
-                       temperature=temperature,
-                       rng=jax.random.fold_in(rng, i))
-    int(out.tokens[0, -1])                 # waits for the last call
-    dt = time.perf_counter() - t0
-    tps = batch * new_tokens * num_iters / dt
-
-    # MBU roofline (VERDICT r03 weak #3): decode at small batch is
-    # HBM-bandwidth-bound — every step re-reads all params (amortized
-    # over the batch) plus each row's KV cache at its current length.
-    # Report achieved bytes/s over the chip's peak next to the raw
-    # throughput so "fast" is judged against the roofline, not a vacuum.
-    from ..utils import flops as _flops
-    cfg = model.config
-    kv_elem_bytes, kv_scale_bytes = (
-        (1.0, 4.0) if kv_cache_dtype == "int8" else (2.0, 0.0))
-    bytes_per_step = _flops.decode_bytes_per_step(
-        num_params=_flops.param_count(params),
-        num_layers=cfg.num_layers,
-        num_kv_heads=cfg.num_kv_heads or cfg.num_heads,
-        head_dim=cfg.head_dim,
-        batch=batch,
-        avg_len=prompt_len + (new_tokens + 1) / 2.0,
-        param_bytes=2 if dtype_name == "bfloat16" else 4,
-        kv_cache_bytes=kv_elem_bytes, kv_scale_bytes=kv_scale_bytes)
-    mbu_val = _flops.mbu(bytes_per_step, steps_per_sec=tps / batch)
-    log(f"generate {name}{' kv=int8' if kv_cache_dtype == 'int8' else ''}"
-        f"{' kernel' if decode_kernel else ''}: "
-        f"batch={batch} prompt={prompt_len} "
-        f"new={new_tokens}: {tps:.0f} new tokens/sec"
-        + (f"  MBU {mbu_val:.1%}" if mbu_val is not None else ""))
-    return {"decode_tokens_per_sec": tps,
-            "tokens_per_iter": batch * new_tokens,
-            "mbu": mbu_val,
-            "decode_kernel": bool(decode_kernel),
-            "decode_bytes_per_step": bytes_per_step,
-            "compile_seconds": compile_seconds,
-            "wall_seconds": dt}
-
-
-@with_run_report
 def run_vit_benchmark(
     size: str = "b16",
     batch_per_device: int = 32,
-    image_size: int = 224,
     num_steps: int = 50,
     warmup_steps: int = 5,
     dtype_name: str = "bfloat16",
@@ -781,7 +636,6 @@ def run_vit_benchmark(
     divergence_k: int = 3,
     stop_check_every: Optional[int] = None,
     metrics_port: Optional[int] = None,
-    event_log: Optional[str] = None,
     events=None,
     log: Callable[[str], None] = print,
 ) -> Tuple[object, Dict[str, float]]:
@@ -803,14 +657,13 @@ def run_vit_benchmark(
     global_batch = batch_per_device * n
 
     model = create_vit(f"vit-{size}", num_classes=1000, dtype=dtype)
-    cfg = TrainerConfig(global_batch_size=global_batch,
-                        image_size=image_size, num_classes=1000)
+    cfg = TrainerConfig(global_batch_size=global_batch, num_classes=1000)
     trainer = Trainer(model, mesh, cfg)
     state = trainer.init_state(jax.random.PRNGKey(0))
     from ..train.checkpoint import (maybe_resume, maybe_save,
                                         wait_for_checkpoints)
-    wtel, owns_events = _worker_telemetry(metrics_port, event_log,
-                                          train_dir, events, log)
+    wtel, owns_events = _worker_telemetry(metrics_port, train_dir,
+                                          events, log)
     resilience = ResilienceContext(
         ResilienceConfig.from_env(train_dir=train_dir,
                                   divergence_k=divergence_k,
@@ -824,11 +677,11 @@ def run_vit_benchmark(
         if data_dir is not None:
             from ..data.imagefolder import NpyImageDataset
             dataset = NpyImageDataset(
-                data_dir, global_batch, image_size=image_size, dtype=dtype,
+                data_dir, global_batch, dtype=dtype,
                 sharding=batch_sharding(mesh))
         else:
             dataset = SyntheticImageDataset(
-                global_batch, image_size=image_size, num_classes=1000,
+                global_batch, num_classes=1000,
                 dtype=dtype, sharding=batch_sharding(mesh))
         from ..train.checkpoint import periodic_saver
         try:
@@ -863,7 +716,6 @@ def main(argv=None) -> int:
                              "vit: b16|l16 (defaults = BASELINE configs)")
     parser.add_argument("--batch-per-device", type=int, default=None)
     parser.add_argument("--seq-len", type=int, default=512)
-    parser.add_argument("--image-size", type=int, default=224)
     parser.add_argument("--num-steps", type=int, default=50)
     parser.add_argument("--warmup-steps", type=int, default=5)
     parser.add_argument("--eval-steps", type=int, default=0,
@@ -889,27 +741,12 @@ def main(argv=None) -> int:
     parser.add_argument("--moe-experts", type=int, default=0,
                         help="replace every other FFN with an N-expert "
                              "top-2 MoE (expert-parallel over ep)")
-    parser.add_argument("--moe-dropless", action="store_true",
-                        help="dropless MoE: every expert runs every token "
-                             "(num_experts× FFN FLOPs, zero dropped "
-                             "tokens); default is capacity dispatch with "
-                             "the drop rate sown as an intermediate")
     parser.add_argument("--ep", type=int, default=1,
                         help="expert-parallel degree (shards MoE experts)")
-    parser.add_argument("--num-layers", type=int, default=0,
-                        help="override the preset's layer count (scaling "
-                             "studies; tiny pp×moe configs)")
     parser.add_argument("--accum-steps", type=int, default=1,
                         help="gradient accumulation: microbatches per "
                              "optimizer step (activation memory / N, "
                              "numerically identical update)")
-    parser.add_argument("--flash-block-q", type=int, default=0,
-                        help="flash-attention q tile (0 = kernel auto "
-                             "policy: 512, or 1024 when seq >= 2048 "
-                             "divides 1024); sweep per seq-len")
-    parser.add_argument("--flash-block-k", type=int, default=0,
-                        help="flash-attention k tile (0 = kernel auto "
-                             "policy, see --flash-block-q)")
     parser.add_argument("--tp-overlap", action="store_true",
                         help="ring collective-matmul TP projections + "
                              "overlapped vocab-parallel loss (needs "
@@ -977,11 +814,6 @@ def main(argv=None) -> int:
                              "running --num-steps past the resume point "
                              "— a preempted+restarted run ends at the "
                              "same step the original was aiming for")
-    parser.add_argument("--lr-schedule", default="linear",
-                        choices=["linear", "cosine"],
-                        help="warmup-linear (constant after warmup) or "
-                             "warmup-cosine decaying over --decay-steps")
-    parser.add_argument("--decay-steps", type=int, default=10_000)
     parser.add_argument("--lr", type=float, default=None,
                         help="peak learning rate (default: trainer's "
                              "2.5e-4)")
@@ -1038,7 +870,7 @@ def main(argv=None) -> int:
             _state, metrics = run_vit_benchmark(
                 size=args.size or "b16",
                 batch_per_device=args.batch_per_device or 32,
-                image_size=args.image_size, num_steps=args.num_steps,
+                num_steps=args.num_steps,
                 warmup_steps=args.warmup_steps, dtype_name=args.dtype,
                 num_slices=info.num_slices, data_dir=args.data_dir,
                 train_dir=args.train_dir, ckpt_every=args.ckpt_every,
@@ -1062,10 +894,7 @@ def main(argv=None) -> int:
                 if args.hfta_lrs else None,
                 seeds=[int(x) for x in args.hfta_seeds.split(",")]
                 if args.hfta_seeds else None,
-                num_layers=args.num_layers or None,
-                train_dir=args.train_dir,
-                lr_schedule=args.lr_schedule,
-                decay_steps=args.decay_steps, lr=args.lr,
+                train_dir=args.train_dir, lr=args.lr,
                 lr_warmup_steps=args.lr_warmup_steps,
                 metrics_port=args.metrics_port, events=events,
                 log=log)
@@ -1083,12 +912,8 @@ def main(argv=None) -> int:
                 tp=args.tp, pp=args.pp,
                 pp_schedule=args.pp_schedule,
                 pp_interleave=args.pp_interleave, sp=args.sp,
-                moe_experts=args.moe_experts,
-                moe_dropless=args.moe_dropless,
-                ep=args.ep, num_layers=args.num_layers or None,
+                moe_experts=args.moe_experts, ep=args.ep,
                 fused_xent=args.fused_xent,
-                flash_block_q=args.flash_block_q or None,
-                flash_block_k=args.flash_block_k or None,
                 tp_overlap=args.tp_overlap,
                 tp_ring=args.tp_ring,
                 accum_steps=args.accum_steps,
@@ -1103,8 +928,6 @@ def main(argv=None) -> int:
                 divergence_k=args.divergence_k,
                 stop_check_every=args.stop_check_every,
                 stop_at_step=args.stop_at_step,
-                lr_schedule=args.lr_schedule,
-                decay_steps=args.decay_steps,
                 lr=args.lr,
                 lr_warmup_steps=args.lr_warmup_steps,
                 profile_dir=args.profile_dir,

@@ -5,7 +5,7 @@ drains, emergency checkpoints, divergence rollbacks, init retries, and
 slot admissions are rare, discrete, and individually precious — exactly
 the records a post-mortem needs after the process is already dead.
 
-The record discipline is bench.py's mid-kill-survivable one: each event
+The record discipline is the mid-kill-survivable one: each event
 is a single JSON line written, flushed, AND os.fsync'd before emit()
 returns. A SIGKILL between two emits loses nothing; a SIGKILL in the
 middle of a write can at worst truncate the LAST line. `read_events`

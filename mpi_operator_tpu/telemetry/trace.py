@@ -433,8 +433,8 @@ def hop_percentiles(spans: Iterable[Dict[str, Any]],
                     ps: Tuple[int, ...] = (50, 99)
                     ) -> Dict[str, float]:
     """{"<hop>_p50_ms": ..., "<hop>_p99_ms": ...} across all request
-    hops — the per-hop breakdown bench.py folds into its serving-leg
-    JSONL records."""
+    hops — the per-hop breakdown serve_benchmark folds into its
+    records' `*_hop_*` fields."""
     by_hop: Dict[str, List[float]] = {}
     for s in hop_spans(spans):
         by_hop.setdefault(hop_name(s), []).append(s.get("seconds", 0.0))

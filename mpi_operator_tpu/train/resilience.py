@@ -97,7 +97,7 @@ class PreemptionListener:
     """Installs SIGTERM/SIGUSR1 handlers that set a flag; `requested`
     reads it. Previous handlers are chained (called after ours) and
     restored on uninstall, so harnesses with their own SIGTERM
-    bookkeeping (bench.py's summary flush) keep working. Signal handlers
+    bookkeeping (a summary flush, say) keep working. Signal handlers
     only install from the main thread — construct this there."""
 
     SIGNALS = (signal.SIGTERM, signal.SIGUSR1)

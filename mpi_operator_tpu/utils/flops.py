@@ -30,7 +30,7 @@ from typing import Optional, Tuple
 # ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s). Only "TPU v5 lite" has been
 # read off hardware by this repo (chip_smoke.py, PERF.md); a kind that is
 # not listed is an error, never a default — a guessed peak makes every
-# MFU/MBU computed from it wrong without saying so.
+# MFU computed from it wrong without saying so.
 _PEAKS = {
     "TPU v5 lite": (197e12, 819e9),      # v5e
     "TPU v5e": (197e12, 819e9),
@@ -66,41 +66,6 @@ def device_peak_flops(device=None) -> Optional[float]:
     """Peak bf16 FLOP/s for one device; None off-TPU."""
     peaks = device_peaks(device)
     return peaks[0] if peaks else None
-
-
-def device_hbm_bandwidth(device=None) -> Optional[float]:
-    """Peak HBM bytes/s for one device; None off-TPU."""
-    peaks = device_peaks(device)
-    return peaks[1] if peaks else None
-
-
-def decode_bytes_per_step(num_params: int, num_layers: int,
-                          num_kv_heads: int, head_dim: int,
-                          batch: int, avg_len: float,
-                          param_bytes: int = 2,
-                          kv_cache_bytes: float = 2.0,
-                          kv_scale_bytes: float = 0.0) -> float:
-    """HBM bytes one autoregressive decode step must read — the roofline
-    numerator for MBU (model bandwidth utilization). Decode at small batch
-    is bandwidth-bound: every step re-reads the full parameter set once
-    (amortized over the whole batch) plus each sequence's KV cache at its
-    current length. `kv_cache_bytes` is per cached element (2 bf16, 1
-    int8); `kv_scale_bytes` covers quantization scales per (position,
-    head) pair per k/v tensor (4 for one f32 scale)."""
-    params = num_params * param_bytes
-    kv_per_pos = 2 * num_layers * num_kv_heads * (
-        head_dim * kv_cache_bytes + kv_scale_bytes)
-    return params + batch * avg_len * kv_per_pos
-
-
-def mbu(bytes_per_step: float, steps_per_sec: float,
-        device=None) -> Optional[float]:
-    """Achieved fraction of peak HBM bandwidth (single device). None
-    off-TPU."""
-    bw = device_hbm_bandwidth(device)
-    if not bw or not bytes_per_step:
-        return None
-    return bytes_per_step * steps_per_sec / bw
 
 
 def compiled_flops(compiled) -> Optional[float]:
@@ -193,8 +158,7 @@ def throughput_stats(flops_per_step: Optional[float], steps_per_sec: float,
     }
 
 
-__all__ = ["device_peaks", "device_peak_flops", "device_hbm_bandwidth",
-           "compiled_flops",
+__all__ = ["device_peaks", "device_peak_flops", "compiled_flops",
            "resnet_train_flops_per_image",
            "transformer_train_flops_per_token", "param_count", "mfu",
-           "mbu", "decode_bytes_per_step", "throughput_stats"]
+           "throughput_stats"]

@@ -1,9 +1,8 @@
 """Force the JAX host (CPU) platform with N virtual devices.
 
-Tests and the multi-chip dryrun need N devices on a machine with none;
-XLA's host platform can present N virtual ones. Used by tests/conftest.py,
-`bench.py --smoke` and __graft_entry__.dryrun_multichip so the callers
-cannot drift.
+Tests need N devices on a machine with none; XLA's host platform can
+present N virtual ones. Used by tests/conftest.py and by ad-hoc scripts
+that rehearse a mesh on the CPU, so the callers cannot drift.
 
 Must be called before the jax backend initializes (importing jax is fine;
 creating an array is not).

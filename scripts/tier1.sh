@@ -1,8 +1,10 @@
 #!/usr/bin/env bash
-# The blessed tier-1 gate — the ROADMAP.md "Tier-1 verify" command,
+# The blessed tier-1 gate — the command the driver runs after every PR
+# (`commands` of its record of the last run, /root/TESTS_LAST_RUN.json),
 # verbatim. CI and local builders invoke THIS script so there is exactly
-# one definition of "the tests pass"; if the command needs to change,
-# change it in ROADMAP.md and mirror it here in the same commit.
+# one definition of "the tests pass": the driver's. (ROADMAP.md's
+# "Tier-1 verify" line is an older, serial statement of it that sessions
+# are told to leave as it is; see the budget-history note under it.)
 #
 # Semantics worth knowing before editing:
 #   - JAX_PLATFORMS=cpu + tests/conftest.py give 8 virtual CPU devices
@@ -12,12 +14,14 @@
 #     the virtual-device mesh satisfies it, and so do the `serving` and
 #     `hfta` markers (run `pytest -m hfta` to gate the fused-trainer
 #     surface alone).
-#   - timeout -k 10 3000: the whole suite must land in 50 min (870,
-#     then 1140, 1320, 1500, 1860, 2400 until 2026-08-08 — see the budget
-#     history note in ROADMAP.md).
-#   - DOTS_PASSED counts progress dots from the captured log so the
-#     driver can read a pass-count even when pytest's summary line is
-#     cut off by the timeout.
+#   - timeout -k 10 1470 with -p xdist -n 6 --dist loadfile: six worker
+#     processes, a test FILE to a worker, the whole suite inside 24.5 min
+#     (1088.6 s at PR 44; serially it is some 6 000 test-seconds — see the
+#     budget history note in ROADMAP.md).
+#   - DOTS_PASSED is read from the junit file (--junitxml), or from the
+#     progress dots of the captured log when a cut run wrote none, so the
+#     driver has a pass-count even when pytest's summary line is cut off
+#     by the timeout; WORKERS_DOWN counts xdist workers that died.
 #
 #   ./scripts/tier1.sh --resilience additionally runs the OUT-OF-PROCESS
 #   preemption smoke below (real SIGTERM, real exit codes, real resume —
@@ -677,4 +681,4 @@ if [ "${1:-}" = "--sched" ]; then
   exit 0
 fi
 
-set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 3000 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --durations=15 --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c); exit $rc
+set -o pipefail; rm -rf /tmp/_t1.log /tmp/_t1.xml; timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null | head -n 1 | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}'); echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)}; echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null); exit $rc

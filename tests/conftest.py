@@ -37,6 +37,19 @@ def _reset_checkpoint_saved_state():
         mod.reset_saved_state()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _empty_span_log():
+    """Empty telemetry.spans' process-global log (bound: 100 000 records)
+    when a test module starts: an xdist worker runs several files in one
+    process, every engine tick appends, and a traced run that finds the
+    log full raises LogWrapped. Module scope, so a file whose module
+    fixture runs an engine once keeps its records for all its tests."""
+    mod = sys.modules.get("mpi_operator_tpu.telemetry.spans")
+    if mod is not None:
+        mod.clear()
+    yield
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

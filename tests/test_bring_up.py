@@ -96,7 +96,7 @@ def test_peaks_table_v5e_unknown_tpu_and_cpu():
 
     v5e = _Dev("tpu", "TPU v5 lite")
     assert flops.device_peak_flops(v5e) == 197e12
-    assert flops.device_hbm_bandwidth(v5e) == 819e9
+    assert flops.device_peaks(v5e) == (197e12, 819e9)
     with pytest.raises(ValueError, match="TPU v9 mega"):
         flops.device_peaks(_Dev("tpu", "TPU v9 mega"))
     # a substring of a known kind is not that kind
