@@ -489,6 +489,13 @@ class StatusServer:
         self._served_done.wait(timeout=linger)
 
     def close(self) -> None:
+        # shutdown first: it wakes the serving thread out of accept(), and
+        # a listening socket closed under a blocked accept() stays bound
+        # for as long as the process lives
+        try:
+            self._sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._sock.close()
         except OSError:

@@ -97,9 +97,11 @@ def record_traced() -> Iterator[Dict[str, Set[str]]]:
                     walk, N pages a turn, C chains in flight) | "dense"
       "prefill"   — multi-token KV-cache calls (always "dense" today)
       "ssd"       — ops/ssm.py's state update: `ssd_update_form` | "dense"
+      "gdn"       — ops/gated_delta.py's state update: `gdn_update_form`
+                    | "dense"
       "head_loss" — ops/xent.py's head and loss in one pass: `Form.name`"""
     rec: Dict[str, Set[str]] = {k: set() for k in (
-        "attention", "flash", "decode", "prefill", "ssd", "head_loss")}
+        "attention", "flash", "decode", "prefill", "ssd", "gdn", "head_loss")}
     token = _TRACED.set(rec)
     try:
         yield rec

@@ -50,6 +50,22 @@ they are handed.
 In both, a position whose step (`delta`, `dt`) is 0 leaves the state
 exactly as it was (exp(0) = 1, and + 0), which is how a caller holds the
 state over pad tokens and over rows that are no member of a call.
+
+A THIRD recurrence lives in the file beside this one, `ops/gated_delta.py`
+(Gated DeltaNet, arXiv:2412.06464; Qwen3-Next), under the same conventions
+(the state carried in and handed back, float32, the minor dimension the
+one that fills the lanes, a step of zeros holds the state exactly):
+
+    S_t = exp(g_t) S_{t-1} + k_t (beta_t (v_t - exp(g_t) S_{t-1}^T k_t))^T
+    o_t = S_t^T q_t
+
+Neither form here can serve it: the write above, `B (dt x)^T`, does not
+depend on S, so a chunk is a sum of decayed outer products and the SSD
+kernel passes a tile once with ONE reduction over it (the C sum); the
+delta rule's write reads the decayed tile along k_t before it writes, so
+a chunk needs a triangular solve for the writes, and a step needs the
+read-out before the write and the output after it: two reductions while
+the tile is in VMEM.
 """
 from __future__ import annotations
 

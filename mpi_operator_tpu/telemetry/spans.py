@@ -113,6 +113,22 @@ The trees the program records:
                      pool of keys and values
     gmu              a gated memory unit
     mlp              the gated MLP of a phi4flash layer
+    gdn.project      a delta-rule mixer's [q | k | v | z] and [b | a]
+                     projections, beta and the log decay
+    gdn.conv         its causal conv over the slot's tail, the silu and
+                     the L2 norm of q and k
+    gdn.update       one position of the gated delta rule over the slot's
+                     state (the kernel, or plain jax.numpy off the TPU);
+                     gdn.chunk in a multi-token call, the WY form
+    gdn.norm         the per-head norm and the silu(z) gate
+    gdn.out          the output projection
+    q3attn.project   a gated attention layer's q & gate, k, v, the q and k
+                     norms and the partial rotary
+    q3attn.cache_write  its rows into the layer's own page pool
+    q3attn.attend    the walk of a row's pages (heads of 256)
+    q3attn.out       the sigmoid gate on the heads' output, W_o
+    moe.shared       a shared expert beside the held ones (and, where the
+                     model has one, its own sigmoid gate)
 
   set-up
     serve.engine_init > serve.cast_params, serve.init_cache

@@ -50,6 +50,23 @@ def _empty_span_log():
     yield
 
 
+@pytest.fixture
+def status_port():
+    """One user at a time, across xdist workers, of the status port that
+    rank 0 of `lm_benchmark` binds (`bootstrap.STATUS_PORT`, 8477): two
+    files run such a process, `--dist loadfile` runs files side by side,
+    and the second to bind dies with `Address already in use` (tier-1 of
+    PR 46, twice, once more files had moved the schedule). A lock on a
+    file under the run's temporary directory, released when it closes."""
+    import fcntl
+    import tempfile
+    path = os.path.join(tempfile.gettempdir(),
+                        "mpi_operator_tpu_status_port.lock")
+    with open(path, "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        yield
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

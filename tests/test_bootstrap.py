@@ -165,6 +165,20 @@ def test_launcher_wait_loss_then_recovery():
         server2.close()
 
 
+def test_a_closed_status_server_frees_its_port_though_nobody_polls_it():
+    """close() under a serving thread that sits in accept(): the port is
+    free for the next server of the same process at once (the in-process
+    `lm_benchmark.main` of tests/test_bring_up.py left 8477 bound for the
+    rest of its xdist worker's life, and a gang of this file then died
+    with `Address already in use`)."""
+    from mpi_operator_tpu.bootstrap.bootstrap import StatusServer
+    server = StatusServer(port=0)
+    server.close()
+    server._thread.join(timeout=5)
+    assert not server._thread.is_alive()
+    StatusServer(port=server.port).close()
+
+
 def test_launcher_wait_loss_then_timeout_returns_lost_exit():
     """LOST → RESTARTING → fresh startup window expires → LAUNCHER_LOST_EXIT
     (not BootstrapError: contact was established, so this is infra loss)."""
@@ -356,7 +370,7 @@ sys.exit(lm_benchmark.main(sys.argv[2:]))
 '''
 
 
-def test_resize_and_resume_e2e(tmp_path):
+def test_resize_and_resume_e2e(tmp_path, status_port):
     """The resize contract end-to-end with REAL processes (the way the
     rendezvous e2e proves bootstrap): a 2-process gang boots from the
     controller-MATERIALIZED worker env, trains the shipped lm_benchmark
@@ -469,7 +483,7 @@ def test_resize_and_resume_e2e(tmp_path):
     assert losses2[0] < 11.0, (losses1, losses2)   # sane, not diverged
 
 
-def test_elastic_shrink_and_resume_e2e(tmp_path):
+def test_elastic_shrink_and_resume_e2e(tmp_path, status_port):
     """The ELASTIC path end-to-end with REAL processes (VERDICT r04 next
     #6 — shrink was controller-tested only): a 2-process elastic gang
     boots from the controller-materialized env, trains the shipped CLI

@@ -166,7 +166,7 @@ def _last_json(text):
 
 
 def test_lm_benchmark_headline_names_device_and_traced_attention(
-        monkeypatch, capsys):
+        monkeypatch, capsys, status_port):
     from mpi_operator_tpu.bootstrap.bootstrap import poll_status
     from mpi_operator_tpu.examples import lm_benchmark
 

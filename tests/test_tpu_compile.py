@@ -1153,3 +1153,181 @@ def test_head_loss_kernel_fits_the_vmem_it_asks_for(tokens, vocab,
     assert not re.search(rf"f32\[(1,)?{tokens},{vocab}\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 1.25 * 2 * tokens * vocab
+
+
+# ---------------------------------------------------------------------------
+# Qwen3-Next as one chip of the 4 that share a layer: heads of 256 over two
+# layers' own pools, six delta-rule states of 2 MB a slot, 96 slots
+# ---------------------------------------------------------------------------
+Q3 = dict(slots=96, pages=15360, page=64, max_len=16384, heads=16,
+          kv_heads=2, head_dim=256, key_heads=16, value_heads=32)
+
+
+def test_walking_kernel_and_chunk_walk_at_heads_of_256_eight_queries_a_group(
+        one_chip, quiet_cache):
+    """One attention layer's cache write and kernel call at Qwen3-Next's
+    widths: rows of 2 x (256 + 256) = 1024 columns (2 048 B a position),
+    a head's K two lane tiles, eight queries a group, a table of 256
+    pages. Mosaic takes the walking kernel in the K-lane form (16 pages
+    of 128 KB a turn, two slots, both heads a grid step: nothing here
+    was refused where the 576-wide latent pool above is); the pool is
+    aliased through write and read. `paged_attend`, the chunk path, takes
+    the same shape at the cell's bucket."""
+    from mpi_operator_tpu.ops.attention import (kv_row_width, paged_attend,
+                                                paged_decode_attention,
+                                                record_traced, traced_name)
+    S, NP, ps, H, KV, D = (Q3[k] for k in ("slots", "pages", "page", "heads",
+                                           "kv_heads", "head_dim"))
+    nblk = Q3["max_len"] // ps
+    W = kv_row_width(KV, D)
+    assert (W, nblk, H // KV) == (1024, 256, 8)
+    pages, vmem = _walk_vmem(nblk, ps, KV, D, H // KV)
+    assert pages == 16 and vmem < SCOPED_VMEM, (pages, vmem)
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
+                                                  sharding=one_chip)
+
+    def layer(q, pool, cur, pt, rows, at):
+        pool = pool.reshape(NP * ps, W).at[at].set(
+            rows, mode="drop").reshape(NP, ps, W)
+        return pool, paged_decode_attention(q, pool, cur, pt,
+                                            interpret=False)
+    with record_traced() as traced:
+        compiled = jax.jit(layer, donate_argnums=(1,)).lower(
+            spec((S, H, D), jnp.bfloat16), spec((NP, ps, W), jnp.bfloat16),
+            spec((S,), jnp.int32), spec((S, nblk), jnp.int32),
+            spec((S, W), jnp.bfloat16), spec((S,), jnp.int32)).compile()
+    assert traced_name(traced["decode"]) == "pallas_paged[live,pages=16,hb=2]"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (NP, ps, W), (NP * ps, W)) == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= NP * ps * W * 2
+    chunk = jax.jit(paged_attend).lower(
+        spec((S, 128, H, D), jnp.bfloat16), spec((NP, ps, W), jnp.bfloat16),
+        spec((S, 128), jnp.int32), spec((S, nblk), jnp.int32)).compile()
+    assert "tpu_custom_call" not in chunk.as_text()
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_gdn_state_update_kernel_updates_the_donated_state_where_it_lies(
+        one_chip, quiet_cache):
+    """One layer's delta-rule step at Qwen3-Next's widths (96 rows of 32
+    tiles [128, 128] float32 on 16 key heads): Mosaic takes the kernel (16
+    value heads a grid step, ONE transpose a block that turns their eight
+    key heads' k and q into columns, a column spread along the lanes a
+    head) under its scoped VMEM limit; a row's 2 097 152 B of state are
+    the call's operand and its result, aliased, and nothing of that size
+    is copied or made beside them."""
+    from mpi_operator_tpu.ops.gated_delta import gated_delta_state_update
+    S, Hk, Hv = Q3["slots"], Q3["key_heads"], Q3["value_heads"]
+    held = (S, Hv, 128, 128)
+    spec = lambda *shape: jax.ShapeDtypeStruct(             # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: gated_delta_state_update(*a[:-1], fresh=a[-1],
+                                            interpret=False),
+        donate_argnums=(5,)).lower(
+            spec(S, Hk, 128), spec(S, Hk, 128), spec(S, Hv, 128),
+            spec(S, Hv), spec(S, Hv), spec(*held),
+            jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+        ).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1
+    assert _copies_of(text, held) == []
+    assert m.alias_size_in_bytes >= S * 2097152
+    assert m.temp_size_in_bytes < 16 << 20
+
+
+@pytest.fixture(scope="module")
+def qwen3_next(one_chip):
+    """The decode model, parameter shapes and cache shapes of
+    `perfbench/configs/qwen3-next-80b-a3b-1of4.json` as the benchmark's
+    engine builds them (96 slots, two pools of 15360 pages of 64,
+    contexts to 16384)."""
+    from mpi_operator_tpu.models.generate import decode_model
+    from perfbench import weights_qwen3next as weights
+    from perfbench.kinds import _serve_qwen3next
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "qwen3-next-80b-a3b-1of4.json")) as f:
+        dims = weights.Dims.from_config(json.load(f))
+    model = _serve_qwen3next.model_of(dims, jnp.bfloat16, Q3["max_len"], True)
+    dmodel = decode_model(model, True, page_size=Q3["page"],
+                          num_pages=Q3["pages"])
+    on_chip = lambda tree: jax.tree.map(                        # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: weights.make_params(jax.random.PRNGKey(0), dims,
+                                    jnp.bfloat16)))
+    z = jnp.zeros((Q3["slots"], 1), jnp.int32)
+    table = jnp.zeros((Q3["slots"], Q3["max_len"] // Q3["page"]), jnp.int32)
+    cache = on_chip(jax.eval_shape(
+        lambda p: dmodel.apply({"params": p}, z, positions=z,
+                               with_head=False, mutable=["cache"],
+                               pages=table)[1]["cache"], params))
+    return dims, dmodel, params, cache
+
+
+def _q3_big_copies(text):
+    S, NP, ps = Q3["slots"], Q3["pages"], Q3["page"]
+    return _copies_of(text, (NP, ps, 1024), (NP * ps, 1024),
+                      (S, Q3["value_heads"], 128, 128))
+
+
+def test_qwen3_next_decode_step_passes_each_state_and_pool_through_once(
+        one_chip, quiet_cache, monkeypatch, qwen3_next):
+    """The engine's own `step_paged` over the eight layers: eight Mosaic
+    calls (a state update in each delta-rule layer, a walk of its own
+    pool in each attention layer, each under its scope's name), no copy
+    of a pool [15360, 64, 1024] or of a state [96, 32, 128, 128], all 5.2
+    GB of them aliased through the step, and 7.33 GB of weights, the cache
+    and 55 MB of temporaries fit the chip."""
+    dims, dmodel, params, cache = qwen3_next
+    S, ps, NP = Q3["slots"], Q3["page"], Q3["pages"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _lower_greedy_step(
+        _engine_programs(dmodel, S, ps), params, cache, S,
+        Q3["max_len"] // ps, one_chip).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    calls = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    assert sorted(c.rsplit(".", 1)[0] for c in calls) == (
+        ["gdn.update"] * 6 + ["q3attn.attend"] * 2)
+    assert _q3_big_copies(text) == []
+    held = 2 * NP * ps * 1024 * 2 + S * dims.slot_state_bytes()
+    assert dims.slot_state_bytes() == 12877824
+    assert m.alias_size_in_bytes >= held
+    assert 12.5e9 < m.argument_size_in_bytes < 12.8e9
+    assert m.temp_size_in_bytes < 0.25e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+
+
+def test_qwen3_next_prefill_bucket_fits_beside_what_the_chip_holds(
+        one_chip, quiet_cache, monkeypatch, qwen3_next):
+    """The engine's own `prefill_paged` at the cell's one bucket ([96, 128]
+    tokens: the delta rule in two chunks of 64, rows in groups of 16; 12
+    288 tokens through the grouped experts): no kernel, no copy of a
+    pool, the cache aliased, and the program's temporaries beside the
+    12.6 GB the engine holds stay under the chip's 16 GB. (The compiler
+    does turn each layer's state into another layout and back round the
+    chunk form, 0.4 GB a layer: PERF.md section 7.)"""
+    dims, dmodel, params, cache = qwen3_next
+    S, ps = Q3["slots"], Q3["page"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
+                                              sharding=one_chip)
+    compiled = _engine_programs(dmodel, S, ps).prefill.lower(
+        params, cache, arg(jnp.int32, S, 128), arg(jnp.int32, S),
+        arg(jnp.int32, S, Q3["max_len"] // ps),
+        arg(jnp.int32, S)).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((params, cache)))
+    assert "tpu_custom_call" not in text
+    assert _copies_of(text, (Q3["pages"], ps, 1024),
+                      (Q3["pages"] * ps, 1024)) == []
+    assert m.alias_size_in_bytes >= held - 7.4e9
+    assert m.temp_size_in_bytes < 2.0e9
+    assert held + m.temp_size_in_bytes < 15.0e9
