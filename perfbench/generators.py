@@ -143,3 +143,19 @@ def closed_loop(spec: Dict, seed: int, vocab: int):
         if r.max_new_tokens > lo:
             r.max_new_tokens = int(round(lo + u * (r.max_new_tokens - lo)))
     return reqs[:clients], reqs[clients:]
+
+
+def lap_request(backlog: List[GenRequest], k: int, seed: int,
+                vocab: int) -> GenRequest:
+    """The k-th request (from 0) a closed loop sends once its backlog is
+    spent: the LENGTHS of backlog entry `k mod len(backlog)`, so a lap
+    sends the backlog's lengths again in the backlog's order, wherever the
+    mix's `placement` put them; an id that goes on counting past the
+    backlog's last; token ids drawn anew from a stream of (seed, k) alone,
+    so no prompt is sent twice (a prefix cache would hit) and the ids of
+    the first wave and the backlog do not move."""
+    src = backlog[k % len(backlog)]
+    rng = np.random.default_rng([int(seed), 15, int(k)])
+    return GenRequest(backlog[-1].id + 1 + k,
+                      rng.integers(0, vocab, len(src.prompt)).tolist(),
+                      src.max_new_tokens, 0.0)

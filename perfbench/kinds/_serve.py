@@ -1,7 +1,8 @@
 """What the two kinds of serving traffic share: building the engine around
 the benchmark's seeded weights, warming the shapes the mix can hit, the
-instrumented tick, and the comparison of served tokens with the plain
-reference.
+instrumented tick, the clients of a closed loop (`Clients`, the one place
+where a closed-loop kind's requests are sent), and the comparison of
+served tokens with the plain reference.
 
 The engine is driven through `ServingEngine.start/submit/tick/finish` and
 read through what it offers to any caller: its `telemetry=` hook (the
@@ -16,7 +17,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from perfbench import harness, weights
+from perfbench import generators, harness, weights
 from perfbench.harness import Check, log, span
 
 
@@ -182,6 +183,96 @@ class Engine:
     def free(self) -> None:
         """Give the device back before the reference runs."""
         harness.delete_arrays((self.engine.params, self.engine.cache))
+
+
+class Clients:
+    """The callers of a closed loop, for every closed-loop kind: each sends
+    its next request when its last completes, so the engine is kept as
+    full as admission allows. They send the mix's first wave, then its
+    backlog in order, and past the backlog's end the backlog's LENGTHS
+    again under ids that continue the count and token ids drawn anew
+    (`generators.lap_request`): the loop never runs dry, at whatever rate
+    the program serves, and what it sends while the backlog lasts does not
+    depend on that. A lap's lengths are lengths `Engine.warm` has seen.
+
+    Creating it starts the engine's session and submits the first wave.
+    `prompts` holds every prompt sent, by request id, for the comparison
+    after the window; `token_at` the host's clock at each fetched token;
+    `answered_at_tick` the count of ticks at each completion answered."""
+
+    def __init__(self, eng: Engine, first, backlog, seed: int):
+        from mpi_operator_tpu.serve import Request
+        if not backlog:
+            raise ValueError("a closed loop needs a backlog: past its end "
+                             "the clients send its lengths again")
+        self._request = Request
+        self.eng, self.engine = eng, eng.engine
+        self.backlog, self.seed = backlog, int(seed)
+        self.vocab = eng.dims.vocab_real
+        self.prompts = {r.id: r.prompt for r in first + backlog}
+        self.token_at: List[float] = []
+        self.answered_at_tick: List[int] = []
+        self.sent = self.answered = 0
+        self.base = time.perf_counter()
+        self.engine.start(on_token=lambda req, tok: self.token_at.append(
+            time.perf_counter()), now_fn=self.now)
+        for r in first:
+            self._submit(r, 0.0)
+
+    def now(self) -> float:
+        return time.perf_counter() - self.base
+
+    @property
+    def lapped(self) -> int:
+        """How many requests came from past the backlog's end."""
+        return max(0, self.answered - len(self.backlog))
+
+    def _submit(self, r, arrival: float) -> None:
+        self.engine.submit(self._request(
+            id=r.id, prompt=r.prompt, max_new_tokens=r.max_new_tokens,
+            arrival=arrival))
+        self.sent += 1
+
+    def answer_completions(self) -> None:
+        """Each client whose request completed sends its next one now."""
+        done = len(self.engine.session_results())
+        while self.answered < done:
+            k = self.answered - len(self.backlog)
+            if k < 0:
+                r = self.backlog[self.answered]
+            else:
+                r = generators.lap_request(self.backlog, k, self.seed,
+                                           self.vocab)
+                self.prompts[r.id] = r.prompt
+            self._submit(r, self.now())
+            self.answered += 1
+            self.answered_at_tick.append(len(self.eng.tick_at))
+
+    def first_wave(self, limit_s: float) -> None:
+        """Tick until nobody prefills and admission is at rest."""
+        engine = self.engine
+        while True:
+            occupied = engine.slots.occupied
+            self.eng.tick()
+            self.answer_completions()
+            if engine.scheduler.next_prefill() is None \
+                    and engine.slots.occupied == occupied:
+                return
+            if time.perf_counter() - self.base > limit_s:
+                raise RuntimeError("the first wave did not come to rest "
+                                   f"within {limit_s} s")
+
+    def close(self, t_open: float, t_close: float):
+        """(tokens fetched in [t_open, t_close), the session's results,
+        how many of them did not end by length, what to log of them)."""
+        results = dict(self.engine.session_results())
+        tokens = sum(1 for x in self.token_at if t_open <= x < t_close)
+        failed = sum(1 for r in results.values()
+                     if r.finish_reason != "length")
+        return tokens, results, failed, (
+            f"{len(results)} requests finished of {self.sent} sent "
+            f"({failed} not by length; {self.lapped} from past the end of "
+            f"the backlog's {len(self.backlog)})")
 
 
 def pick_sample(results: Dict[int, object], prompts: Dict[int, List[int]],

@@ -23,7 +23,7 @@ import time
 
 from perfbench import harness
 from perfbench.harness import log
-from perfbench.kinds import _serve_deepseekv2
+from perfbench.kinds import _serve, _serve_deepseekv2
 from perfbench.readers import scope_device_share
 
 
@@ -34,8 +34,6 @@ def run(ctx) -> harness.Outcome:
 def run_loop(ctx, serve) -> harness.Outcome:
     """The closed loop around `serve.Engine`, `serve.deep_closed_loop`,
     `serve.collector_at_rest` and `serve.check_served`."""
-    from mpi_operator_tpu.serve import Request
-
     t = ctx.traffic
     compiles = harness.CompileCounter()
     phases = harness.Phases()
@@ -49,44 +47,8 @@ def run_loop(ctx, serve) -> harness.Outcome:
     counts = eng.warm([len(r.prompt) for r in backlog], eng.dims.vocab_real)
     phases.mark(f"compile or load of {counts}")
 
-    prompts = {r.id: r.prompt for r in first + backlog}
-    token_at = []
-    base = time.perf_counter()
-    now = lambda: time.perf_counter() - base  # noqa: E731
-    engine.start(on_token=lambda req, tok: token_at.append(
-        time.perf_counter()), now_fn=now)
-    for r in first:
-        engine.submit(Request(id=r.id, prompt=r.prompt,
-                              max_new_tokens=r.max_new_tokens, arrival=0.0))
-    sent = {"n": len(first), "answered": 0}
-    retired_at = []         # the tick count at each completion
-
-    def answer_completions():
-        """Each client whose request completed sends its next one now."""
-        done = len(engine.session_results())
-        while sent["answered"] < done:
-            if not backlog:
-                raise RuntimeError("the traffic's backlog ran out: raise "
-                                   "`backlog` in the traffic file")
-            r = backlog.pop(0)
-            engine.submit(Request(id=r.id, prompt=r.prompt,
-                                  max_new_tokens=r.max_new_tokens,
-                                  arrival=now()))
-            sent["answered"] += 1
-            sent["n"] += 1
-            retired_at.append(len(eng.tick_at))
-
-    # the first wave: tick until nobody prefills and admission is at rest
-    while True:
-        occupied = engine.slots.occupied
-        eng.tick()
-        answer_completions()
-        if engine.scheduler.next_prefill() is None \
-                and engine.slots.occupied == occupied:
-            break
-        if time.perf_counter() - base > float(t["first_wave_limit_s"]):
-            raise RuntimeError("the first wave did not come to rest within "
-                               f"{t['first_wave_limit_s']} s")
+    clients = _serve.Clients(eng, first, backlog, ctx.seed)
+    clients.first_wave(float(t["first_wave_limit_s"]))
     with serve.collector_at_rest():
         phases.mark("first wave")
         opened_at = len(eng.tick_at)
@@ -103,17 +65,14 @@ def run_loop(ctx, serve) -> harness.Outcome:
             while time.perf_counter() - t_open < ctx.seconds:
                 tracer.poll(time.perf_counter() - t_open)
                 eng.tick()
-                answer_completions()
+                clients.answer_completions()
             t_close = time.perf_counter()
             tracer.stop()
     if compiles.count:
         raise RuntimeError(f"{compiles.count} program(s) compiled inside "
                            f"the measured window")
-    results = dict(engine.session_results())
     window = t_close - t_open
-    tokens = sum(1 for x in token_at if t_open <= x < t_close)
-    finished = [r for r in results.values() if r.finish_reason == "length"]
-    failed = len(results) - len(finished)
+    tokens, results, failed, served = clients.close(t_open, t_close)
     peak = harness.memory_peak_bytes(ctx.devices)
     counters = eng.window_counters(t_open, t_close)
     counters.update(eng.traced_counters(tracer, counters))
@@ -128,11 +87,10 @@ def run_loop(ctx, serve) -> harness.Outcome:
         f"{1e3 * sum(slow):.1f} ms together "
         f"({[round(1e3 * s) for s in slow][:12]})")
     log(f"window {window:.3f} s: {tokens} tokens fetched in "
-        f"{counters.get('serve.ticks', 0):.0f} ticks, {len(results)} "
-        f"requests finished of {sent['n']} sent ({failed} not by length); "
-        f"peak {peak} bytes; completions at ticks "
-        f"{[n - opened_at for n in retired_at if n > opened_at]} of the "
-        f"window; counters "
+        f"{counters.get('serve.ticks', 0):.0f} ticks, {served}; peak {peak} "
+        f"bytes; completions at ticks "
+        f"{[n - opened_at for n in clients.answered_at_tick if n > opened_at]}"
+        f" of the window; counters "
         f"{ {k: round(v, 3) for k, v in counters.items()} }")
 
     shapes = eng.shapes()
@@ -147,9 +105,9 @@ def run_loop(ctx, serve) -> harness.Outcome:
         shapes=shapes, trace=tracer.summary(ctx.keep_trace),
         peaks=harness.peaks_of(ctx.devices))
     eng.free()
-    checks = serve.check_served(ctx, eng, results, prompts)
+    checks = serve.check_served(ctx, eng, results, clients.prompts)
     return harness.Outcome(
         end_to_end={"serve_tokens_per_s": tokens / window,
                     "setup_s": setup_s},
         evidence=ev, correct=harness.judge(checks) and failed == 0,
-        attempted=sent["n"], failed=failed, memory_peak_bytes=peak)
+        attempted=clients.sent, failed=failed, memory_peak_bytes=peak)

@@ -22,13 +22,11 @@ import time
 
 from perfbench import harness
 from perfbench.harness import log
-from perfbench.kinds import _serve_falconh1
+from perfbench.kinds import _serve, _serve_falconh1
 from perfbench.readers import scope_device_share
 
 
 def run(ctx) -> harness.Outcome:
-    from mpi_operator_tpu.serve import Request
-
     t = ctx.traffic
     compiles = harness.CompileCounter()
     phases = harness.Phases()
@@ -43,42 +41,8 @@ def run(ctx) -> harness.Outcome:
     counts = eng.warm([len(r.prompt) for r in backlog], eng.dims.vocab_real)
     phases.mark(f"compile or load of {counts}")
 
-    prompts = {r.id: r.prompt for r in first + backlog}
-    token_at = []
-    base = time.perf_counter()
-    now = lambda: time.perf_counter() - base  # noqa: E731
-    engine.start(on_token=lambda req, tok: token_at.append(
-        time.perf_counter()), now_fn=now)
-    for r in first:
-        engine.submit(Request(id=r.id, prompt=r.prompt,
-                              max_new_tokens=r.max_new_tokens, arrival=0.0))
-    sent = {"n": len(first), "answered": 0}
-
-    def answer_completions():
-        """Each client whose request completed sends its next one now."""
-        done = len(engine.session_results())
-        while sent["answered"] < done:
-            if not backlog:
-                raise RuntimeError("the traffic's backlog ran out: raise "
-                                   "`backlog` in the traffic file")
-            r = backlog.pop(0)
-            engine.submit(Request(id=r.id, prompt=r.prompt,
-                                  max_new_tokens=r.max_new_tokens,
-                                  arrival=now()))
-            sent["answered"] += 1
-            sent["n"] += 1
-
-    # the first wave: tick until nobody prefills and admission is at rest
-    while True:
-        occupied = engine.slots.occupied
-        eng.tick()
-        answer_completions()
-        if engine.scheduler.next_prefill() is None \
-                and engine.slots.occupied == occupied:
-            break
-        if time.perf_counter() - base > float(t["first_wave_limit_s"]):
-            raise RuntimeError("the first wave did not come to rest within "
-                               f"{t['first_wave_limit_s']} s")
+    clients = _serve.Clients(eng, first, backlog, ctx.seed)
+    clients.first_wave(float(t["first_wave_limit_s"]))
     with _serve_falconh1.collector_at_rest():
         phases.mark("first wave")
         t_open = time.perf_counter()
@@ -95,24 +59,20 @@ def run(ctx) -> harness.Outcome:
             while time.perf_counter() - t_open < ctx.seconds:
                 tracer.poll(time.perf_counter() - t_open)
                 eng.tick()
-                answer_completions()
+                clients.answer_completions()
             t_close = time.perf_counter()
             tracer.stop()
     if compiles.count:
         raise RuntimeError(f"{compiles.count} program(s) compiled inside "
                            f"the measured window")
-    results = dict(engine.session_results())
     window = t_close - t_open
-    tokens = sum(1 for x in token_at if t_open <= x < t_close)
-    finished = [r for r in results.values() if r.finish_reason == "length"]
-    failed = len(results) - len(finished)
+    tokens, results, failed, served = clients.close(t_open, t_close)
     peak = harness.memory_peak_bytes(ctx.devices)
     counters = eng.window_counters(t_open, t_close)
     counters.update(eng.traced_counters(tracer, counters))
     log(f"window {window:.3f} s: {tokens} tokens fetched in "
-        f"{counters.get('serve.ticks', 0):.0f} ticks, {len(results)} "
-        f"requests finished of {sent['n']} sent ({failed} not by length); "
-        f"peak {peak} bytes")
+        f"{counters.get('serve.ticks', 0):.0f} ticks, {served}; peak {peak} "
+        f"bytes")
 
     shapes = eng.shapes()
     if tracer.dir is not None:
@@ -126,9 +86,9 @@ def run(ctx) -> harness.Outcome:
         shapes=shapes, trace=tracer.summary(ctx.keep_trace),
         peaks=harness.peaks_of(ctx.devices))
     eng.free()
-    checks = _serve_falconh1.check_served(ctx, eng, results, prompts)
+    checks = _serve_falconh1.check_served(ctx, eng, results, clients.prompts)
     return harness.Outcome(
         end_to_end={"serve_tokens_per_s": tokens / window,
                     "setup_s": setup_s},
         evidence=ev, correct=harness.judge(checks) and failed == 0,
-        attempted=sent["n"], failed=failed, memory_peak_bytes=peak)
+        attempted=clients.sent, failed=failed, memory_peak_bytes=peak)

@@ -20,6 +20,18 @@ TINY_ENGINE = {"slots": 8, "page_size": 16, "num_pages": 40,
 RENAME = {"train-gpt2m-1chip": ["tiny-train", "tiny-train4"],
           "serve-gpt2xl-decode-heavy": ["tiny-closed"]}
 HOST_LOOP = "engine host loop (serve/engine.py)"
+#: the per-layer metrics every serving cell joins, and the six that read
+#: the whole window from the span log (PR 37), by name: a cell's test
+#: finds its lists by these, never by how many or where they stand
+GENERIC = {"slot_occupancy_pct", "host_blocked_ms_p50", "decode_step_ms_p50",
+           "decode_device_ms_p50", "prefill_rows_per_call",
+           "prefill_tick_share_pct", "engine_host_work_ms_p50",
+           "engine_dispatch_ms_p50", "prefill_stall_share_pct",
+           "host_caused_idle_pct", "setup_trace_lower_s",
+           "setup_compile_or_load_s"}
+WINDOW_SIX = {"queue_wait_ms_p50", "admit_to_first_token_ms_p50",
+              "admission_blocked_on_pages_pct", "pages_reserved_unfilled_pct",
+              "prefill_stall_window_share_pct", "engine_stall_ms_per_window"}
 SCHEDULER = "scheduler and slots (serve/scheduler.py, serve/slots.py)"
 
 

@@ -9,7 +9,8 @@ import types
 import numpy as np
 import pytest
 
-from _perfbench_tiny import REPO, _dump, _load, make_root
+from _perfbench_tiny import (GENERIC, REPO, WINDOW_SIX, _dump, _load,
+                             make_root)
 from perfbench import run
 from perfbench import weights_falconh1 as weights
 from perfbench.kinds import _serve_falconh1
@@ -34,26 +35,34 @@ def toy_config():
     return cfg
 
 
-@pytest.fixture(scope="module")
-def root(tmp_path_factory):
-    root = make_root(tmp_path_factory.mktemp("perfbench_falconh1"))
-    _dump(toy_config(), root, "extra", "configs", "falconh1-tiny.json")
+def toy_length(median, lo, hi):
+    return {"dist": "lognormal", "median": median, "sigma": 0.4, "min": lo,
+            "max": hi}
+
+
+def toy_traffic(**over):
     t = _load(REPO, "perfbench", "traffic", "longform-steady-closed.json")
     t["engine"].update(slots=8, page_size=4, num_pages=200,
                        chunk_buckets=[8], decode_kernel=False)
-    length = lambda median, lo, hi: {"dist": "lognormal",     # noqa: E731
-                                     "median": median, "sigma": 0.4,
-                                     "min": lo, "max": hi}
     t.update(clients=8, backlog=400, max_total=96,
-             first_wave={"context": length(20, 8, 40),
-                         "remaining": length(16, 4, 40)},
-             prompt=length(6, 3, 8), output=length(30, 16, 60),
+             first_wave={"context": toy_length(20, 8, 40),
+                         "remaining": toy_length(16, 4, 40)},
+             prompt=toy_length(6, 3, 8), output=toy_length(30, 16, 60),
              trace_start_s=0.1, trace_seconds=0.3, check_requests=6,
              # bfloat16 program against the float32 reference at toy
              # widths; the altered-token test below reads 1 and more
              limits={"served_logit_gap_widest": 0.05,
                      "served_logprob_gap_widest": 0.05})
-    _dump(t, root, "extra", "traffic", "tiny-longform-closed.json")
+    t.update(over)
+    return t
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    root = make_root(tmp_path_factory.mktemp("perfbench_falconh1"))
+    _dump(toy_config(), root, "extra", "configs", "falconh1-tiny.json")
+    _dump(toy_traffic(), root, "extra", "traffic",
+          "tiny-longform-closed.json")
     bench = _load(root, "BENCHMARK.json")
     real = _load(REPO, "BENCHMARK.json")
     bench["configs"].append({"name": "falconh1-tiny", "source": "none",
@@ -100,11 +109,13 @@ def test_the_cell_is_in_the_benchmark_with_the_issues_parameters():
     own = [x for x in m.data["per_layer"] if x.get("workloads") == [REAL]]
     assert tuple(x["name"] for x in own) == OWN
     assert all(x["moves"] == "serve_tokens_per_s" for x in own)
-    # the cell joins the generic serving and set-up metrics and what a
-    # slot holds, and not the roofline whose event pattern is gpt2-xl's
+    # the cell joins the generic serving and set-up metrics, the six that
+    # read the whole window from the span log (PR 47: sixteen admissions a
+    # traced window) and what a slot holds, and not the roofline whose
+    # event pattern is gpt2-xl's
     lists = {x["name"] for x in m.data["per_layer"]
              if REAL in x.get("workloads", []) and x not in own}
-    assert len(lists) == 13 and "slot_state_bytes_per_row" in lists
+    assert GENERIC | WINDOW_SIX | {"slot_state_bytes_per_row"} <= lists
     assert "paged_decode_roofline" not in lists
     assert REAL in next(x for x in m.data["end_to_end"]
                         if x["name"] == "serve_tokens_per_s")["workloads"]

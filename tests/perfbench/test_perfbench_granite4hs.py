@@ -9,7 +9,7 @@ import types
 import numpy as np
 import pytest
 
-from _perfbench_tiny import REPO, _dump, _load, make_root
+from _perfbench_tiny import REPO, WINDOW_SIX, _dump, _load, make_root
 from perfbench import run
 from perfbench import weights_granite4hs as weights
 from perfbench.kinds import _serve_granite4hs
@@ -31,9 +31,6 @@ GENERIC = {"slot_occupancy_pct", "host_blocked_ms_p50", "decode_step_ms_p50",
            "engine_dispatch_ms_p50", "prefill_stall_share_pct",
            "host_caused_idle_pct", "setup_trace_lower_s",
            "setup_compile_or_load_s", "slot_state_bytes_per_row"}
-WINDOW_SIX = {"queue_wait_ms_p50", "admit_to_first_token_ms_p50",
-              "admission_blocked_on_pages_pct", "pages_reserved_unfilled_pct",
-              "prefill_stall_window_share_pct", "engine_stall_ms_per_window"}
 TOY = dict(vocab_size=128, hidden_size=64, intermediate_size=32,
            shared_intermediate_size=48, num_hidden_layers=3, n_layer=3,
            layer_types=["mamba", "attention", "mamba"],

@@ -83,8 +83,10 @@ def test_the_cell_is_in_the_benchmark_with_the_issues_parameters():
     dp4 = m.cell("train-gpt2m-dp4")
     assert (dp4["config"], dp4["traffic"], dp4["chips"]) == (
         "gpt2-medium", "pretrain-seq1024-dp4", 4)
-    assert sum(c["chips"] == 4 for c in m.data["workloads"]) == 1
-    assert len(m.data["workloads"]) == 4
+    # the four-chip cells stay within the quarter the contract allows,
+    # however many cells later PRs have added
+    cells = m.data["workloads"]
+    assert 1 <= sum(c["chips"] == 4 for c in cells) <= max(1, len(cells) // 4)
 
 
 def test_the_configuration_keeps_every_published_width():

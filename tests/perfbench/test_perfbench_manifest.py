@@ -136,12 +136,27 @@ def test_files_under_paths_are_named_from_allowed_characters(bench):
                 assert PATH.match(rel), rel
 
 
+#: a key that names a width; `num_hidden_layers` holds "hidden" and is the
+#: published key for DEPTH, which every cut configuration rightly lists
+WIDTH = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|n_embd|"
+                   r"n_inner|head_dim|n_head$")
+
+
 def test_reduced_names_no_width(bench):
-    width = re.compile(r"(_dim|_rank)$|hidden|intermediate|n_embd|n_inner|"
-                       r"head_dim|n_head$")
     for c in bench["configs"]:
         assert len(c["reduced"]) <= 16
-        assert not [k for k in c["reduced"] if width.search(k)]
+        assert not [k for k in c["reduced"] if WIDTH.search(k)]
+
+
+@pytest.mark.parametrize("key,is_width", [
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("head_dim", True),
+    ("qk_rope_head_dim", True), ("kv_lora_rank", True), ("n_embd", True),
+    ("n_inner", True), ("n_head", True), ("num_hidden_layers", False),
+    ("num_layers", False), ("n_layer", False), ("n_routed_experts", False),
+    ("vocab_size", False), ("layer_types", False)])
+def test_the_width_pattern_tells_a_width_from_depth(key, is_width):
+    assert bool(WIDTH.search(key)) is is_width
 
 
 def test_full_check_fits_the_time_allowed(bench):

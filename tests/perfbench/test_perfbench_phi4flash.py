@@ -7,7 +7,7 @@ import json
 import numpy as np
 import pytest
 
-from _perfbench_tiny import REPO, _dump, _load, make_root
+from _perfbench_tiny import GENERIC, REPO, _dump, _load, make_root
 from perfbench import run
 from perfbench import weights_phi4flash as weights
 from perfbench.kinds import _serve_phi4flash
@@ -87,15 +87,21 @@ def test_the_cell_is_in_the_benchmark_with_the_issues_parameters():
                            "sigma": 0.35, "min": 4096, "max": 14336}
     assert (t["check_requests"], t["trace_start_s"], t["trace_seconds"]) == (
         6, 5.0, 8.0)
-    assert sum(c["chips"] == 4 for c in m.data["workloads"]) == 1
-    own = [x for x in m.data["per_layer"] if x.get("workloads") == [REAL]]
-    assert tuple(x["name"] for x in own) == OWN
+    cells = m.data["workloads"]
+    assert sum(c["chips"] == 4 for c in cells) <= max(1, len(cells) // 4)
+    by_name = {x["name"]: x for x in m.data["per_layer"]}
+    own = [by_name[n] for n in OWN]
+    assert all(REAL in x["workloads"] for x in own)
     assert all(x["moves"] == "serve_tokens_per_s" for x in own)
+    # its five device metrics are its own; what a slot holds beside its
+    # pages is read in every cell whose slots hold state, Phi's among them
+    assert all(x["workloads"] == [REAL] for x in own
+               if x["name"] != "slot_state_bytes_per_row")
     # the cell joins the generic serving and set-up metrics, and not the
     # roofline whose count (every layer reads every page) is not its own
-    lists = {x["name"]: x["workloads"] for x in m.data["per_layer"]
-             if REAL in x.get("workloads", []) and x not in own}
-    assert len(lists) == 12 and "paged_decode_roofline" not in lists
+    lists = {n for n, x in by_name.items()
+             if REAL in x.get("workloads", []) and n not in OWN}
+    assert GENERIC <= lists and "paged_decode_roofline" not in lists
 
 
 def test_the_configuration_holds_every_published_key_and_cuts_nothing():

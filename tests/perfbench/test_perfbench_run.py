@@ -153,12 +153,18 @@ def test_control_of_the_serving_cell_is_not_correct(root):
                           config=m.config(cell["config"]), traffic=traffic,
                           seed=6, seconds=0.0, trace=False,
                           devices=jax.devices()[:1])
-    eng = serve.Engine(ctx)
-    eng.warm([16], eng.dims.vocab_real)
-    results, prompts = control_serve.serve_window(ctx, eng, 1.5)
-    sample = serve.pick_sample(results, prompts, ctx.seed, 4)
-    g = serve.served_gaps(eng.dims, eng.dtype, eng.key, sample, prompts,
-                          "fp8")
+    # 1.5 s serve a hundred tokens and more on a free CPU; where the
+    # machine is busy (six test workers, other sandboxes) and they do not,
+    # the window is made again on a fresh engine, longer
+    for seconds in (1.5, 6.0, 24.0):
+        eng = serve.Engine(ctx)
+        eng.warm([16], eng.dims.vocab_real)
+        results, prompts = control_serve.serve_window(ctx, eng, seconds)
+        sample = serve.pick_sample(results, prompts, ctx.seed, 4)
+        g = serve.served_gaps(eng.dims, eng.dtype, eng.key, sample, prompts,
+                              "fp8")
+        if g["served_tokens"] > 40:
+            break
     assert g["served_tokens"] > 40
     lim = traffic["limits"]
     assert g["served_logit_gap"] <= lim["served_logit_gap_widest"]
