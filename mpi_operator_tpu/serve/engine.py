@@ -32,9 +32,12 @@ model the way a frontend needs it served:
   bucketed to ≤3 compiled shapes (scheduler.plan_chunks), one call per
   engine loop iteration, interleaved with decode steps — a long prompt
   cannot stall in-flight decodes, and ragged prompt lengths stop forcing
-  per-shape recompiles. One fixed-shape [slots, C] program per bucket
-  advances every waiting slot whose next chunk shares the bucket —
-  deeper queues amortize the same widths.
+  per-shape recompiles. Every waiting slot whose next chunk shares the
+  bucket advances in the same tick, each in a call of its own row
+  (programs.NARROW_ROWS, programs.prefill_calls): the prompt that
+  replaces a retired request runs one row's work, not `slots` rows' with
+  all but one of them empty, and one fixed-shape [1, C] program per
+  bucket serves a first wave as well.
 - **Prefix caching** (`EngineConfig.prefix_cache`). Fully-prefilled
   PROMPT pages are published into a refcounted prefix cache (chained
   keys — exact token equality back to position 0), so a request sharing
@@ -105,7 +108,7 @@ from ..models.generate import decode_model
 from ..telemetry import span
 from ..telemetry import events as ev
 from ..telemetry import spans
-from .programs import build_programs, cast_program
+from .programs import build_programs, cast_program, prefill_calls
 from .scheduler import Request, RequestState, Scheduler
 from .slots import PageAllocator, SlotManager
 from .transfer import PageTransfer
@@ -724,46 +727,46 @@ class ServingEngine:
 
     def _run_prefill_batched(self, lead: RequestState) -> None:
         """Prefill: advance EVERY waiting slot whose next chunk shares
-        the lead's bucket in one [S, C] program — deeper queues amortize
-        the same ≤3 compiled widths instead of serializing one chunk per
-        loop iteration. Rows that are no member of the call run
-        zero tokens at `max_len`: a position past the logical cache, whose
-        writes the page scatter drops (as a verify step's padded tail),
-        and which a model that walks only the pages its queries reach
-        (the latent cache) does not walk for."""
+        the lead's bucket in this tick — deeper queues amortize the same
+        ≤3 compiled widths instead of serializing one chunk per loop
+        iteration. The operands are as wide as the tick's members,
+        `[m, C]`; `programs.prefill_calls` cuts them into calls of
+        `NARROW_ROWS` rows with the slots they belong to, and the span
+        carries that `width`. No row that is no member is computed."""
         size = lead.chunks[0][1]
         with span("serve.prefill") as sp:
             batch = [st for st in self.scheduler.active
                      if st.prefilling and st.chunks[0][1] == size]
-            toks = np.zeros((self.config.slots, size), np.int32)
-            starts = np.full((self.config.slots,),
-                             self.model_config.max_len, np.int32)
-            lengths = np.zeros((self.config.slots,), np.int32)
+            toks = np.zeros((len(batch), size), np.int32)
+            starts = np.zeros((len(batch),), np.int32)
+            lengths = np.zeros((len(batch),), np.int32)
             done = []
-            for st in batch:
+            for i, st in enumerate(batch):
                 w, _ = st.chunks.pop(0)
                 p1 = len(st.req.prompt) - 1
-                window = list(st.req.prompt[w:min(w + size, p1)])
-                lengths[st.slot] = len(window)
-                window += [0] * (size - len(window))
-                toks[st.slot] = window
-                starts[st.slot] = w
+                window = st.req.prompt[w:min(w + size, p1)]
+                lengths[i] = len(window)
+                toks[i, :len(window)] = window
+                starts[i] = w
                 done.append((st, w, p1))
             # a model that keeps state a slot is told where each row's
             # real tokens end (its pads then sit at a junk position), and
             # starts a row whose chunk begins at 0 from zeros
-            extra = ()
             if self._slot_state:
-                extra = (jnp.asarray(lengths),)
                 fresh = sum(1 for _, w, _ in done if w == 0)
                 sp.set(state_rows=fresh)
                 if self.telemetry is not None:
                     self.telemetry.slot_state_starts.inc(fresh)
             t0 = time.perf_counter()
-            self.cache = self._prefill(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(starts), jnp.asarray(self._page_table_array()),
-                *extra)
+            for ops in prefill_calls(
+                    [st.slot for st in batch], toks, starts,
+                    np.asarray([st.page_table for st in batch], np.int32),
+                    lengths if self._slot_state else None,
+                    self.config.slots, self.model_config.max_len):
+                sp.set(width=len(ops[0]))
+                self.cache = self._prefill(self.params, self.cache, *ops)
+                if self.telemetry is not None:
+                    self.telemetry.prefill_calls.inc()
         self._note_prefill_queued(len(batch), size)
         if self.telemetry is not None:
             # async dispatch: host wall time, not device time — the next

@@ -6,24 +6,45 @@ jits the four programs every tick dispatches (`init_cache`,
 `prefill_paged`, `step_paged`, `verify_paged` — the names a device trace
 shows them under) over a decode-mode model's `apply`. The host loop in
 engine.py owns everything else: which rows a call carries, cursors, page
-tables, retirement. Narrowing prefill, or serving another model family,
-is an edit here and nowhere in the loop.
+tables, retirement. How wide a prefill call is, or serving another model
+family, is an edit here and nowhere in the loop.
+
+A prefill call is as wide as its members, not as the slots:
+`prefill_paged` takes the rows that HAVE a chunk and the slots they
+belong to, `NARROW_ROWS` of them a call (`prefill_calls` cuts a tick's
+members into such calls), so a prompt that replaces a retired request
+runs one row's work.
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Tuple
+from typing import Callable, Iterator, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..models.generate import cast_params
 
 
+#: rows of a prefill call. One: every served model's chunk path takes a
+#: single row (`mla_query_rows` groups only what passes a GiB,
+#: `_ssd_chunk` and the delta rule's chunk form carry the rows as a batch
+#: dim), and tests/test_tpu_compile.py compiles each cell's buckets at it.
+#: A kernel whose row block forces more raises THIS, for every model:
+#: `prefill_calls` pads a tick's last call with rows that scatter nowhere.
+#: There is no `[slots, bucket]` form beside it: measured on the chip (PR
+#: 48, PERF.md section 3), a first wave served a row a call builds as fast
+#: as one served `slots` rows a call or faster in six cells of seven and
+#: 8% slower in the seventh, and a second form is a second program to
+#: trace, compile and keep for every bucket.
+NARROW_ROWS = 1
+
+
 class Programs(NamedTuple):
     init_cache: Callable      # (params) -> cache
-    prefill: Callable         # (params, cache, tokens, starts, pages
-    #                            [, lengths]) -> cache
+    prefill: Callable         # (params, cache, rows, tokens, starts,
+    #                            pages[, lengths]) -> cache
     step: Callable            # (params, cache, prev_tok, host_toks,
     #                            use_prev, positions, rng, temperature,
     #                            top_k, top_p, pages, mode)
@@ -32,6 +53,30 @@ class Programs(NamedTuple):
     step_counters: Tuple[str, ...]   # the model's STEP_COUNTERS
     donates_cache: bool
     slot_state: Tuple[str, ...]      # the model's SLOT_STATE
+
+
+def prefill_calls(slots: Sequence[int], tokens, starts, pages, lengths,
+                  n_slots: int, max_len: int) -> Iterator[tuple]:
+    """The operands of the prefill calls that carry a tick's members:
+    `NARROW_ROWS` members a call from their `[m, ...]` operands (numpy),
+    as `(rows, tokens, starts, pages[, lengths])` on the device, what
+    `prefill_paged` takes after the weights and the cache (`lengths`
+    None: a model without `SLOT_STATE`, whose program takes none). A last
+    call short of members is padded with rows of zero tokens at `max_len`
+    whose slot is `n_slots`: it does not exist, so the row's state
+    scatters nowhere."""
+    members = (np.asarray(slots, np.int32), tokens, starts, pages)
+    if lengths is not None:
+        members += (lengths,)
+    for i in range(0, len(slots), NARROW_ROWS):
+        part = [x[i:i + NARROW_ROWS] for x in members]
+        short = NARROW_ROWS - len(part[0])
+        if short:
+            fill = (n_slots, 0, max_len, 0, 0)
+            part = [np.concatenate([x, np.full((short,) + x.shape[1:], v,
+                                               x.dtype)])
+                    for x, v in zip(part, fill)]
+        yield tuple(jnp.asarray(x) for x in part)
 
 
 def cast_program(dtype):
@@ -75,13 +120,15 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
       names any, the contract widens, and the engine keeps its side of
       it:
         * a row's real positions in a call are consecutive and come
-          FIRST; after them, and in every row that is no member of the
-          call (prefill) or consumes no token (a decode step: rows
+          FIRST; after them, and in every row that is a pad of the call
+          (prefill) or consumes no token (a decode step: rows
           mid-prefill, free rows), positions are `max_len`. Over such a
           position the model leaves the row's slot leaves EXACTLY as they
           were: a pad token never enters a recurrence, and a row
           mid-prefill survives the decode steps between its chunks.
-          `prefill` takes the rows' real `lengths` for that;
+          `prefill` takes the rows' real `lengths` for that, and hands
+          the model the slot leaves of the call's rows alone (a model
+          declares them at the call's batch, whatever it is);
         * no position is computed twice: chunk plans do not overlap
           (`scheduler.plan_chunks(overlap=False)`);
         * a call whose first position is 0 starts its row from zeros, so
@@ -131,25 +178,41 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
                                 pages=jnp.zeros((S, nblk), jnp.int32))
         return vars_["cache"]
 
-    def prefill_paged(params, cache, tokens, starts, pages, lengths=None):
-        # BATCHED chunk over the page pool: [S, C] tokens, one row
-        # per slot, writes routed through the page tables — the pool
-        # is shared so there is no row to slice out, and every
-        # waiting slot whose next chunk shares this bucket advances
-        # in the same program. Non-member rows carry zero tokens at
-        # max_len, past the logical cache: the page scatter drops
-        # their writes (transformer.py, longcat.py). `lengths` (a model
-        # with SLOT_STATE) puts a row's pads there too.
+    def slot_leaves(fn, *caches):
+        # `fn` over the leaves that lead with `slots`, the pooled leaves
+        # (which have no row to pick) as the first cache has them
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x, *more: fn(x, *more) if getattr(
+                path[-1], "key", None) in slot_state else x, *caches)
+
+    def prefill_paged(params, cache, rows, tokens, starts, pages,
+                      lengths=None):
+        # A chunk over the page pool for the [R, C] rows that HAVE one,
+        # `rows` [R] naming the slot each belongs to (a prompt that
+        # replaces a retired request is ONE row, not `slots`). Writes are
+        # routed through the rows' page tables — the pool is shared, so
+        # it goes in as it is; the SLOT_STATE leaves go in as the rows of
+        # the slots named, and come back into those rows. A position at
+        # max_len is past the logical cache: the page scatter drops its
+        # write (transformer.py, longcat.py), and `lengths` (a model with
+        # SLOT_STATE) puts a row's pads there. A pad ROW sits there whole
+        # and names slot S, which does not exist: it reads the last
+        # slot's leaves, leaves them as they were and is dropped by the
+        # scatter.
         positions = starts[:, None] + jnp.arange(tokens.shape[1])[None]
         if lengths is not None:
             positions = jnp.where(
                 jnp.arange(tokens.shape[1])[None] < lengths[:, None],
                 positions, dmodel.config.max_len)
+        sub = slot_leaves(lambda x: jnp.take(x, rows, axis=0, mode="clip"),
+                          cache)
         _, vars_ = dmodel.apply(
-            {"params": params, "cache": cache}, tokens,
+            {"params": params, "cache": sub}, tokens,
             positions=positions, with_head=False, mutable=["cache"],
             pages=pages, **cache_only)
-        return vars_["cache"]
+        return slot_leaves(
+            lambda new, old: old.at[rows].set(new, mode="drop"),
+            vars_["cache"], cache)
 
     def step_paged(params, cache, prev_tok, host_toks, use_prev,
                    positions, rng, temperature, top_k, top_p, pages,
@@ -229,4 +292,5 @@ def build_programs(dmodel, cfg, tok_sharding, sample) -> Programs:
         slot_state=slot_state)
 
 
-__all__ = ["Programs", "build_programs", "cast_program"]
+__all__ = ["NARROW_ROWS", "Programs", "build_programs", "cast_program",
+           "prefill_calls"]

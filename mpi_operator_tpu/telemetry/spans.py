@@ -42,8 +42,11 @@ The trees the program records:
                        the pages that admitted and staged requests hold,
                        pages_filled: those of them with a written position
     serve.prefill      one prefill call's dispatch, arrays built
-                       state_rows (a model that keeps state a slot): the
-                       rows whose chunk started at 0, and so from zeros
+                       width: the rows of the program it ran
+                       (programs.NARROW_ROWS: a call a member, not
+                       `slots` rows); state_rows (a model that keeps
+                       state a slot): the rows whose chunk started at 0,
+                       and so from zeros
     serve.decode_step  one decode dispatch (serve.verify_step when
                        speculating)      prefill_rows, prefill_bucket: the
                        prefill calls queued ahead of it on the device

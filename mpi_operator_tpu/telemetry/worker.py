@@ -55,6 +55,9 @@ Serve series (ServingEngine):
   slots                   gauge     — configured slot count
   step_compiles           gauge     — decode-step compile count
   prefill_compiles        gauge     — prefill compile count
+  prefill_calls_total     counter   — prefill programs dispatched (a
+                                      call carries programs.NARROW_ROWS
+                                      rows: a tick with m members, m)
   requests_total          counter   — requests retired
   tokens_total            counter   — new tokens emitted
   kv_pages_total          gauge     — usable KV pages (the pool minus
@@ -326,6 +329,10 @@ class ServeTelemetry:
         self.prefill_compiles = reg.gauge(
             "tpu_worker_prefill_compiles", "prefill compile count",
             labels=labels)
+        self.prefill_calls = reg.counter(
+            "tpu_worker_prefill_calls_total",
+            "prefill programs dispatched: a call carries the member rows "
+            "alone, so a tick with m members dispatches m", labels=labels)
         self.requests_total = reg.counter(
             "tpu_worker_requests_total", "requests retired",
             labels=labels)

@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """A digest of every serving cell's programs as they lower for a TPU,
 without one: for each serving cell of `BENCHMARK.json` the engine's own
-`step_paged` and `prefill_paged` (each bucket) over the cell's model at
-the cell's sizes, lowered for a described v5e (nothing compiles, nothing
-runs), the text hashed. Two checkouts whose lines agree hand the compiler
+`step_paged` and `prefill_paged` (each bucket, at a call's width: since
+PR 48 `programs.NARROW_ROWS` rows, `slots` in a checkout from before)
+over the cell's model at the cell's sizes, lowered for a described v5e
+(nothing compiles, nothing runs), the text hashed. Two checkouts whose lines agree hand the compiler
 the same programs:
 
     JAX_PLATFORMS=cpu python scripts/serving_programs_digest.py > a.jsonl
@@ -43,7 +44,9 @@ def without_locations(text: str) -> str:
     return KERNEL.sub(plain, text)
 
 
-def programs_of(root, cell, one_chip):
+def programs_of(root, cell, one_chip, want=lambda kind: True):
+    """(name, the lowered program) of `cell`'s `step` and `prefill[bucket]`,
+    those whose kind (`step`, `prefill`) `want` takes."""
     import jax
     import jax.numpy as jnp
     from mpi_operator_tpu.models.generate import decode_model
@@ -90,14 +93,22 @@ def programs_of(root, cell, one_chip):
     arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
                                               sharding=one_chip)
     i32, f32 = arg(jnp.int32, S), arg(jnp.float32, S)
-    yield "step", progs.step.lower(
-        params, cache, i32, i32, arg(jnp.bool_, S), i32, arg(jnp.uint32, 2),
-        f32, i32, f32, arg(jnp.int32, S, nblk), "greedy")
-    lengths = (arg(jnp.int32, S),) if progs.slot_state else ()
+    if want("step"):
+        yield "step", progs.step.lower(
+            params, cache, i32, i32, arg(jnp.bool_, S), i32,
+            arg(jnp.uint32, 2), f32, i32, f32, arg(jnp.int32, S, nblk),
+            "greedy")
+    if not want("prefill"):
+        return
+    from mpi_operator_tpu.serve import programs
+    # a call's rows, and before them the slots they belong to: since PR 48
+    R = getattr(programs, "NARROW_ROWS", None)
+    rows, slots_of = (S, ()) if R is None else (R, (arg(jnp.int32, R),))
+    lengths = (arg(jnp.int32, rows),) if progs.slot_state else ()
     for bucket in e["chunk_buckets"]:
         yield f"prefill[{bucket}]", progs.prefill.lower(
-            params, cache, arg(jnp.int32, S, int(bucket)), i32,
-            arg(jnp.int32, S, nblk), *lengths)
+            params, cache, *slots_of, arg(jnp.int32, rows, int(bucket)),
+            arg(jnp.int32, rows), arg(jnp.int32, rows, nblk), *lengths)
 
 
 def main(argv=None) -> int:

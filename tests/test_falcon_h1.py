@@ -23,6 +23,7 @@ from mpi_operator_tpu.serve import (DecodeEngine, EngineConfig, PrefillEngine,
                                     Request, ServingEngine)
 from perfbench import weights_falconh1 as W
 from perfbench.reference import falcon_h1 as ref
+from prefill_forms import member_rows_alone_leave_what_all_rows_leave
 
 TOL = 2e-5
 PUBLISHED = FalconH1Config()
@@ -186,15 +187,20 @@ def test_junk_rows_and_pad_tokens_leave_slot_state_exactly_as_it_was(params):
         assert np.array_equal(before[name][1:], after[name][1:]), name
         assert not np.array_equal(before[name][0], after[name][0]), name
     toks = jnp.ones((S, 8), jnp.int32)
-    padded = eng._prefill(eng.params, cache, toks, i32(12, L, L), pages,
-                          i32(3, 0, 0))
-    exact = eng._prefill(eng.params, cache, toks.at[0, 3:].set(77),
-                         i32(12, L, L), pages, i32(3, 0, 0))
+    padded = eng._prefill(eng.params, cache, i32(0, 1, 2), toks,
+                          i32(12, L, L), pages, i32(3, 0, 0))
+    exact = eng._prefill(eng.params, cache, i32(0, 1, 2),
+                         toks.at[0, 3:].set(77), i32(12, L, L), pages,
+                         i32(3, 0, 0))
     padded, exact = _slot_leaves(padded), _slot_leaves(exact)
     for name in after:
         assert np.array_equal(after[name][1:], padded[name][1:]), name
         # whatever the pad tokens are, they change nothing
         assert np.array_equal(padded[name], exact[name]), name
+    # and a call of the member row alone (beside a pad row that names no
+    # slot) leaves what a call of every slot's row leaves, leaf for leaf
+    assert member_rows_alone_leave_what_all_rows_leave(
+        eng, cache, pages) == len(before)
 
 
 def test_every_layer_holds_a_pool_a_state_and_a_tail(params):
@@ -226,7 +232,7 @@ def test_the_published_sizes_give_the_issues_bytes_a_slot():
 def _prefill_text(eng):
     S, nblk = eng.config.slots, eng._nblk
     z = lambda *s: jnp.zeros(s, jnp.int32)                   # noqa: E731
-    return eng._prefill.lower(eng.params, eng.cache, z(S, 8), z(S),
+    return eng._prefill.lower(eng.params, eng.cache, z(S), z(S, 8), z(S),
                               z(S, nblk), z(S)).as_text(debug_info=True)
 
 

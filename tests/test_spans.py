@@ -521,8 +521,12 @@ def test_every_worked_tick_is_a_tree_in_order_and_syncs_name_dispatches(
     assert all(set(r.attrs) == {"blocked", "waiting", "pages_reserved",
                                 "pages_filled"}
                for r in by_name("serve.schedule"))
+    # (`width`, the rows of the program a prefill call ran, waits for its
+    # reader: PR 48 put it there for the next benchmark PR)
+    assert all(r.attrs == {"width": 1} for r in by_name("serve.prefill"))
     assert not any(r.attrs for name in TICK_ORDER + ["serve.tick"]
-                   if name not in ("serve.decode_step", "serve.schedule")
+                   if name not in ("serve.decode_step", "serve.schedule",
+                                   "serve.prefill")
                    for r in by_name(name))
 
     # a prefill call is queued on the device ahead of the next dispatch,

@@ -324,20 +324,20 @@ def test_deepseek_v2_decode_step_fits_the_chip_beside_its_weights_and_pool(
 
 def test_deepseek_v2_prefill_bucket_builds_its_queries_in_row_groups(
         one_chip, quiet_cache, monkeypatch, deepseek_v2):
-    """The engine's own `prefill_paged` at the cell's one bucket ([64,
-    128] tokens, 128 heads): the absorbed queries are built four rows at
-    a time, so the program's temporaries stay under 3 GB beside the 10.8
-    GB the engine holds (built for all 64 rows at once they alone would
-    be 1.34 + 2 x 1.07 GB a layer); no copy of a pool, the cache
-    aliased."""
+    """The engine's own `prefill_paged` at the cell's one bucket, as wide
+    as since PR 48 ([1, 128] tokens, 128 heads, where until then the 64
+    rows' absorbed queries were built four rows at a time: they alone
+    would have been 1.34 + 2 x 1.07 GB a layer): the program's
+    temporaries beside the 10.8 GB the engine holds; no copy of a pool,
+    the cache aliased."""
     dims, dmodel, params, cache = deepseek_v2
     S, NP, ps, L = (DSV2[k] for k in ("slots", "pages", "page", "max_len"))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
                                               sharding=one_chip)
     compiled = _engine_programs(dmodel, S, ps).prefill.lower(
-        params, cache, arg(jnp.int32, S, 128), arg(jnp.int32, S),
-        arg(jnp.int32, S, L // ps)).compile()
+        params, cache, arg(jnp.int32, 1), arg(jnp.int32, 1, 128),
+        arg(jnp.int32, 1), arg(jnp.int32, 1, L // ps)).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize
@@ -489,7 +489,8 @@ def test_gpt2_xl_decode_step_keeps_its_pool_in_place(
 
 def test_gpt2_xl_prefill_bucket_keeps_its_pool_in_place(
         one_chip, quiet_cache, monkeypatch, gpt2_xl):
-    """The engine's own `prefill_paged` at one bucket ([64, 128] tokens):
+    """The engine's own `prefill_paged` at one bucket ([1, 128] tokens, a
+    call's width since PR 48):
     the chunk's rows go into the pool by the same flat scatter and the
     dense path gathers each row's pages from it — no copy of the pool,
     the pools aliased."""
@@ -499,8 +500,8 @@ def test_gpt2_xl_prefill_bucket_keeps_its_pool_in_place(
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,        # noqa: E731
                                           sharding=one_chip)
     compiled = _engine_programs(dmodel, S, XL["page"]).prefill.lower(
-        params, cache, i32(S, C), i32(S),
-        i32(S, XL["max_len"] // XL["page"])).compile()
+        params, cache, i32(1), i32(1, C), i32(1),
+        i32(1, XL["max_len"] // XL["page"])).compile()
     m = compiled.memory_analysis()
     assert _xl_pool_copies(compiled.as_text()) == []
     assert m.alias_size_in_bytes >= dims.layers * XL["pages"] * XL["page"] \
@@ -800,7 +801,8 @@ def test_falcon_h1_decode_step_passes_each_state_and_pool_through_once(
 def test_falcon_h1_prefill_bucket_fits_beside_what_the_chip_holds(
         one_chip, quiet_cache, monkeypatch, falcon_h1):
     """The engine's own `prefill_paged` at the cell's one bucket
-    ([96, 128] tokens through the chunked scan, rows in groups of 32):
+    ([1, 128] tokens through the chunked scan, a call's width since PR
+    48; the slot leaves gathered at the row and scattered back):
     no copy of a pool, the cache aliased, and the program's temporaries
     beside the 13.2 GB the engine holds (of which this program is not
     handed the head) stay under the chip's 16 GB."""
@@ -810,9 +812,9 @@ def test_falcon_h1_prefill_bucket_fits_beside_what_the_chip_holds(
     arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
                                               sharding=one_chip)
     compiled = _engine_programs(dmodel, S, ps).prefill.lower(
-        params, cache, arg(jnp.int32, S, 128), arg(jnp.int32, S),
-        arg(jnp.int32, S, H1["max_len"] // ps),
-        arg(jnp.int32, S)).compile()
+        params, cache, arg(jnp.int32, 1), arg(jnp.int32, 1, 128),
+        arg(jnp.int32, 1), arg(jnp.int32, 1, H1["max_len"] // ps),
+        arg(jnp.int32, 1)).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize
@@ -1305,9 +1307,9 @@ def test_qwen3_next_decode_step_passes_each_state_and_pool_through_once(
 
 def test_qwen3_next_prefill_bucket_fits_beside_what_the_chip_holds(
         one_chip, quiet_cache, monkeypatch, qwen3_next):
-    """The engine's own `prefill_paged` at the cell's one bucket ([96, 128]
-    tokens: the delta rule in two chunks of 64, rows in groups of 16; 12
-    288 tokens through the grouped experts): no kernel, no copy of a
+    """The engine's own `prefill_paged` at the cell's one bucket ([1, 128]
+    tokens, a call's width since PR 48: the delta rule in two chunks of
+    64; 128 tokens through the grouped experts): no kernel, no copy of a
     pool, the cache aliased, and the program's temporaries beside the
     12.6 GB the engine holds stay under the chip's 16 GB. (The compiler
     does turn each layer's state into another layout and back round the
@@ -1318,9 +1320,9 @@ def test_qwen3_next_prefill_bucket_fits_beside_what_the_chip_holds(
     arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt,           # noqa: E731
                                               sharding=one_chip)
     compiled = _engine_programs(dmodel, S, ps).prefill.lower(
-        params, cache, arg(jnp.int32, S, 128), arg(jnp.int32, S),
-        arg(jnp.int32, S, Q3["max_len"] // ps),
-        arg(jnp.int32, S)).compile()
+        params, cache, arg(jnp.int32, 1), arg(jnp.int32, 1, 128),
+        arg(jnp.int32, 1), arg(jnp.int32, 1, Q3["max_len"] // ps),
+        arg(jnp.int32, 1)).compile()
     text = compiled.as_text()
     m = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize
@@ -1331,3 +1333,64 @@ def test_qwen3_next_prefill_bucket_fits_beside_what_the_chip_holds(
     assert m.alias_size_in_bytes >= held - 7.4e9
     assert m.temp_size_in_bytes < 2.0e9
     assert held + m.temp_size_in_bytes < 15.0e9
+
+
+# ---------------------------------------------------------------------------
+# Every serving cell's prefill buckets at a call's width (PR 48): a prompt
+# runs `programs.NARROW_ROWS` rows, not `slots`
+# ---------------------------------------------------------------------------
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    SERVING_CELLS = {c["name"]: c for c in json.load(_f)["workloads"]
+                     if c["name"].startswith("serve")}
+
+#: temporaries of the `[slots, bucket]` program each bucket ran until PR 48,
+#: compiled for the same described chip by this file's tests at the parent
+#: commit (the other buckets' were never compiled here)
+WIDE_TEMP_UNTIL_PR48 = {
+    ("serve-deepseekv2-1of8-longdoc", 128): 1108537856,
+    ("serve-falconh1-4of72-longform", 128): 2073662976,
+    ("serve-gpt2xl-decode-heavy", 128): 1861819904,
+    ("serve-qwen3next-1of4-sessions-wide", 128): 1425910272,
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVING_CELLS))
+def test_each_prefill_bucket_compiles_at_one_row_at_the_cells_sizes(
+        cell, one_chip, quiet_cache, monkeypatch):
+    """`prefill_paged` over the cell's own model, pool and slot state
+    (built as `scripts/serving_programs_digest.py` builds every cell's
+    programs) compiles at `NARROW_ROWS` = 1 row for each of the cell's
+    buckets: no chunk path needs a row block, the cache stays aliased
+    through the slot leaves' gather and scatter, and the temporaries are a
+    small part of what the `[slots, bucket]` program took, logged beside
+    them."""
+    import importlib.util
+    from mpi_operator_tpu.serve.programs import NARROW_ROWS
+    spec = importlib.util.spec_from_file_location(
+        "serving_programs_digest",
+        os.path.join(REPO, "scripts", "serving_programs_digest.py"))
+    digest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(digest)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert NARROW_ROWS == 1
+    seen = []
+    for name, lowered in digest.programs_of(
+            REPO, SERVING_CELLS[cell], one_chip,
+            want=lambda kind: kind == "prefill"):
+        bucket = int(name[name.index("[") + 1:-1])
+        m = lowered.compile().memory_analysis()
+        wide = WIDE_TEMP_UNTIL_PR48.get((cell, bucket))
+        print(f"{cell} [{NARROW_ROWS}, {bucket}]: temporaries "
+              f"{m.temp_size_in_bytes} B; at [slots, {bucket}] until PR 48 "
+              f"{wide if wide is not None else 'not compiled here'}")
+        # the pools and the slot leaves come back in the buffers they
+        # came in: all that is not aliased is weights
+        assert m.alias_size_in_bytes > 2.9e9
+        assert m.temp_size_in_bytes < 0.3e9
+        assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.5e9
+        if wide is not None:
+            assert m.temp_size_in_bytes < wide / 4
+        seen.append(bucket)
+    with open(os.path.join(REPO, "perfbench", "traffic",
+                           SERVING_CELLS[cell]["traffic"] + ".json")) as f:
+        assert seen == json.load(f)["engine"]["chunk_buckets"]
