@@ -1268,16 +1268,41 @@ def paged_pages_per_turn(nblk: int, page_bytes: int, ps: int,
     half of what fits up to all of it, the count that leaves least of
     the last turn empty when a row has live all it can — the table's
     `nblk` pages, or the pages a `window` touches — and the larger on a
-    tie: a turn's pages past the row's last live one are fetched again
-    from that page, so a ring of 9 pages walks 3 + 3 + 3 (5 + 5 cost 8%
-    more) and gpt2-xl's table of 16 walks 4 a turn (8 a turn cost a row
-    of 1-4 pages 70% more; PERF.md, PR 32). Wide turns pay the turn's own
-    work (a fifth of a microsecond) once for several pages; from 4 a turn
-    the copies are what a page costs, at nine tenths of the HBM peak. A
-    function of shapes and dtype alone."""
+    tie: a ring of 9 pages walks 3 + 3 + 3 and gpt2-xl's table of 16
+    walks 4 a turn. Until PR 49 the rule's reason was that a turn's
+    empty tail was copied all the same; a turn now copies its live pages
+    alone, and the counts stand because the chip still prefers them: rows
+    drawn as the cells hold them take 272.9 us a call at gpt2-xl's 4 a turn
+    against 283.5 / 275.0 / 287.6 at 2 / 5 / 8, 592.3 at Falcon-H1's 16
+    against 607.6 at 8, 1 680.0 at Qwen3-Next's 16 against 1 695.2 at 8
+    (PERF.md, PR 49). Wide turns pay the turn's own work (a fifth of a
+    microsecond) once for several pages; from 4 a turn the copies are
+    what a FULL turn costs, at nine tenths of the HBM peak. A function
+    of shapes and dtype alone."""
     live = nblk if window is None else min(nblk, (window + ps - 2) // ps + 1)
     cap = max(1, min(live, _KV_VMEM_BUDGET // (2 * page_bytes)))
     return min(range(-(-cap // 2), cap + 1), key=lambda n: (-live % n, -n))
+
+
+def _live_span(cursor, ps: int, nblk: int, window: Optional[int]):
+    """The first and last LIVE logical page of a row at `cursor` on a
+    table of `nblk` pages of `ps`: last = min(cursor // ps, nblk - 1), a
+    cursor past the logical cache attending the whole table; first = 0,
+    or with `window` the page that holds cursor - window + 1."""
+    last = jnp.minimum(cursor // ps, nblk - 1)
+    if window is None:
+        return 0, last
+    return jnp.minimum(jnp.maximum(cursor - window + 1, 0) // ps, last), last
+
+
+def _turn_pages(first, last, turn, pages: int):
+    """Turn `turn` of a walk of logical pages first .. last, `pages` a
+    turn: its first page and how many of its pages are live, 1 .. `pages`
+    in each of the walk's (last - first) // pages + 1 turns. What
+    `_paged_walk_kernel` copies in a turn and what it waits for: the one
+    place that says so, for both."""
+    page0 = first + turn * pages
+    return page0, jnp.minimum(pages, last - page0 + 1)
 
 
 def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
@@ -1306,25 +1331,32 @@ def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
     padded with zeros over the V lanes and the output is read from them
     (splitting a lane tile is a relayout: 18% slower, PERF.md PR 28).
 
-    A turn's pages past `last` are copied from that page again and
-    masked: nothing a dead table entry points at is read. A cursor past
-    the logical cache (a retiring row's post-EOS step) attends the whole
-    table (inside its `window`, if any), as the grid form's clamp does.
+    A turn does work for its LIVE pages only (`_turn_pages`: the same
+    count where its copies are started, by this grid step or by the one
+    before it, and where they are waited for): nothing a dead table entry
+    points at is read, and a row's last turn copies no page twice. A FULL
+    turn is attended as one straight block `pages` pages wide; a short one
+    at the narrowest of the widths 1, 2, 4, .. pages that holds its live
+    ones, so a free row's one page costs one page's copy and one page's
+    products (PERF.md, PR 49). What lies in a slot past a turn's live
+    pages is whatever was there: the mask over positions keeps it out of
+    the scores, and so that 0 x nan cannot reach p . V both slots are
+    zeroed once a call, before its first copy; after that a slot holds
+    zeros or rows some turn copied. A cursor past the logical cache (a
+    retiring row's post-EOS step) attends the whole table (inside its
+    `window`, if any), as the grid form's clamp does.
 
     A turn's copies are started, and waited for, in a loop over its
     pages and not one by one in Python (`_mla_decode_kernel`)."""
     b, h = pl.program_id(0), pl.program_id(1)
     nb, nh = pl.num_programs(0), pl.num_programs(1)
-    width = pages * ps
     cols = buf_ref.shape[-1]                    # hb * 2D
+    # what a turn is attended at: 1, 2, 4, .. pages, and `pages`
+    widths = sorted({min(1 << k, pages)
+                     for k in range(pages.bit_length() + 1)})
 
     def span(row):
-        """The row's first and last live logical page."""
-        last = jnp.minimum(cur_ref[row] // ps, nblk - 1)
-        if window is None:
-            return 0, last
-        return jnp.minimum(
-            jnp.maximum(cur_ref[row] - window + 1, 0) // ps, last), last
+        return _live_span(cur_ref[row], ps, nblk, window)
 
     def copy(page, head_block, slot, k):
         src = pool_ref.at[page]
@@ -1335,17 +1367,16 @@ def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
                                      sem_ref.at[slot])
 
     def start(row, head_block, turn, slot):
-        first, last = span(row)
+        page0, n = _turn_pages(*span(row), turn, pages)
 
         def one(k, _):
-            copy(pt_ref[row, jnp.minimum(first + turn * pages + k, last)],
-                 head_block, slot, k).start()
-        jax.lax.fori_loop(0, pages, one, None)
+            copy(pt_ref[row, page0 + k], head_block, slot, k).start()
+        jax.lax.fori_loop(0, n, one, None)
 
-    def wait(slot):
+    def wait(slot, n):
         def one(k, _):
             copy(0, 0, slot, k).wait()  # a wait reads the size, not the page
-        jax.lax.fori_loop(0, pages, one, None)
+        jax.lax.fori_loop(0, n, one, None)
 
     first, last = span(b)
     turns = (last - first) // pages + 1
@@ -1356,6 +1387,9 @@ def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
 
     @pl.when(step == 0)
     def _first_step():
+        # a slot's dead part is masked out of the scores, and 0 x nan is
+        # nan in p . V: what no copy ever wrote must be finite
+        buf_ref[:] = jnp.zeros_like(buf_ref)
         slot_ref[0] = 0
         start(b, h, 0, 0)
 
@@ -1364,18 +1398,10 @@ def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
 
-    def turn(i, _):
-        slot = (first_slot + i) % 2
-        more = i + 1 < turns
-
-        @pl.when(more | (step + 1 < nb * nh))
-        def _next():    # this step's next turn, or the next step's first
-            start(jnp.where(more, b, nxt // nh), jnp.where(more, h, nxt % nh),
-                  jnp.where(more, i + 1, 0), 1 - slot)
-
-        wait(slot)
+    def attend(rows, page0):
+        """The online softmax's update over `rows` [n * ps, cols], the
+        rows of logical pages page0, page0 + 1, .."""
         q = q_ref[0]                                      # [hb, G, D or 2D]
-        rows = buf_ref[slot].reshape(width, cols)
         # the heads' lane-aligned column blocks, stacked: whole vregs
         # under another index (`_decode_kernel`)
         if k_lanes:
@@ -1385,9 +1411,8 @@ def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
             k = v = jnp.stack(jnp.split(rows, hb, axis=1))
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * sm_scale  # [hb, G, width]
-        pos = (first + i * pages) * ps \
-            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            preferred_element_type=jnp.float32) * sm_scale  # [hb, G, n * ps]
+        pos = page0 * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         seen = pos <= cur
         if window is not None:
             seen &= pos > cur_raw - window
@@ -1402,6 +1427,23 @@ def _paged_walk_kernel(cur_ref, pt_ref, q_ref, pool_ref, o_ref, buf_ref,
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_ref[:, :, :1] = m_new
+
+    def turn(i, _):
+        slot = (first_slot + i) % 2
+        more = i + 1 < turns
+
+        @pl.when(more | (step + 1 < nb * nh))
+        def _next():    # this step's next turn, or the next step's first
+            start(jnp.where(more, b, nxt // nh), jnp.where(more, h, nxt % nh),
+                  jnp.where(more, i + 1, 0), 1 - slot)
+
+        page0, n = _turn_pages(first, last, i, pages)
+        wait(slot, n)
+
+        for lo, w in zip([0] + widths, widths):   # the narrowest that holds n
+            @pl.when((lo < n) & (n <= w))
+            def _at(w=w):
+                attend(buf_ref[slot, :w].reshape(w * ps, cols), page0)
 
     jax.lax.fori_loop(0, turns, turn, None)
     slot_ref[0] = (first_slot + turns) % 2
@@ -1509,12 +1551,14 @@ def paged_decode_attention(q, pages, cache_index, page_table,
 
     An unquantised pool is WALKED (`_paged_walk_kernel`): grid
     (B, KV // hb), the pool an HBM operand, a row's live pages copied
-    `paged_pages_per_turn` a turn into two VMEM slots. Nothing is spent
-    on the table's dead entries, so a call's time follows the contexts
-    and not the table's length, and a turn's work is paid once for
-    several pages: the grid form's step a (row, page) cost 0.105 us dead
-    or live and a live page 0.61 us more, of which the copy is 0.40
-    (PERF.md, PR 32).
+    `paged_pages_per_turn` a turn into two VMEM slots, a row's last turn
+    its live pages alone (PR 49). Nothing is spent on the table's dead
+    entries nor on a turn's, so a call's time follows the contexts and
+    not the table's length, and a turn's work is paid once for several
+    pages: the grid form's step a (row, page) cost 0.105 us dead or live
+    and a live page 0.61 us more, of which the copy is 0.40 (PERF.md,
+    PR 32); a gpt2-xl row of one live page costs 1.8 us where a turn of
+    four copies cost 3.0 (PERF.md, PR 49).
 
     An int8 pool (`k_scale` given) stays on the grid form, the
     contiguous kernel's body with another index map (`_decode_call`,

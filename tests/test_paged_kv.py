@@ -458,7 +458,68 @@ _WALK_CURSORS = {
     # rows of one call at unrelated depths: a row's last turn fetches the
     # first pages of a row that is nowhere near it
     "unrelated-depths": (95, 0, 40, 8, 77, 3),
+    # a turn copies its live pages alone (PR 49): what the dead part of a
+    # slot holds is whatever was there. Rows of one live page straight
+    # after rows that filled the table, three turns through both slots:
+    # stale rows in the dead part of either
+    "one-page-after-a-full-table": (95, 3, 200, 0, 95, 7),
+    # free rows sit at cursor 0 on the trash page, between decoding rows
+    "free-rows-between": (40, 0, 0, 77, 0, 20, 0, 0),
+    # the call's first turns land in slots nobody wrote, and the interpreter
+    # starts scratch memory as nan (`test_the_interpreter_...` below): one
+    # live page, then three, which are attended four wide
+    "first-rows-short-in-unwritten-slots": (5, 20, 95, 40),
+    # turns of 1, 2 and 3 live pages, first and after a full one
+    "short-turns-by-live-pages": (7, 15, 23, 39, 47, 55, 87),
 }
+
+
+def test_the_interpreter_starts_scratch_memory_as_nan():
+    """What makes "first-rows-short-in-unwritten-slots" a test of the
+    kernel's own zeroing: a slot's part that no copy wrote reads nan
+    here, and 0 x nan in p . V would reach the output."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(x_ref, o_ref, scratch):
+        o_ref[:] = x_ref[:] + scratch[:]
+    out = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((8, 128), jnp.float32)],
+        interpret=True)(jnp.zeros((8, 128), jnp.float32))
+    assert np.isnan(np.asarray(out)).all()
+
+
+def _turns_by_hand(cur, ps, nblk, pages, window):
+    """A row's turns as lists of live logical pages, by enumeration."""
+    last = min(cur // ps, nblk - 1)
+    first = 0 if window is None else min(max(cur - window + 1, 0) // ps, last)
+    live = list(range(first, last + 1))
+    return [live[i:i + pages] for i in range(0, len(live), pages)]
+
+
+@pytest.mark.parametrize("window", [None, 20], ids=["whole", "window20"])
+@pytest.mark.parametrize("cur", [
+    0, 7, 8,                     # the first page and the second
+    23, 24, 31, 32, 39, 40,      # a turn's edge -1, 0, +1 page
+    63, 64, 71, 72,              # the second edge
+    87, 88, 94, 95,              # the table's last page: a full last turn
+    96, 101, 200,                # past the table
+])
+def test_turn_pages_counts_the_live_pages_of_a_turn(cur, window):
+    """`_turn_pages` over `_live_span`, what a turn of the walk copies
+    AND what it waits for: pages of 8, a table of 12, 4 a turn. Every
+    turn of the walk starts where the last one ended, holds 1 .. 4 live
+    pages, and the turns together are the live span exactly."""
+    ps, nblk, pages = 8, 12, 4
+    want = _turns_by_hand(cur, ps, nblk, pages, window)
+    first, last = attention._live_span(cur, ps, nblk, window)
+    assert (int(first), int(last)) == (want[0][0], want[-1][-1])
+    assert (int(last) - int(first)) // pages + 1 == len(want)
+    for turn, live in enumerate(want):
+        page0, n = attention._turn_pages(first, last, turn, pages)
+        assert (int(page0), int(n)) == (live[0], len(live)), (turn, live)
+        assert 1 <= int(n) <= pages
 
 
 @pytest.mark.parametrize("cursors", list(_WALK_CURSORS.values()),
@@ -489,7 +550,12 @@ def test_walk_at_any_pages_a_turn(H, KV, D, pages):
     (27, 28, 35, 36),    # a window that starts mid-page, and on a page's edge
     (95, 96, 110, 112),  # the table's end and past it, the window still on it
     (95, 2, 50, 21),     # unrelated depths
-], ids=["short", "window-edge", "mid-page", "past-table", "unrelated"])
+    # a window of three live pages (16-23 .. 32-39 under cursor 39): a
+    # short LAST turn at 2 a turn, a short only turn at 4, after rows whose
+    # window fills both slots
+    (95, 39, 47, 0, 31),
+], ids=["short", "window-edge", "mid-page", "past-table", "unrelated",
+        "short-last-turn"])
 @pytest.mark.parametrize("H,KV,D,pages", [
     (5, 5, 64, None), (8, 2, 128, None), (8, 2, 128, 1), (6, 3, 64, 2)],
     ids=["mha64", "pairs128", "pairs128-1", "gqa64-2"])

@@ -617,6 +617,10 @@ def test_walking_kernel_compiles_by_head_blocks(one_chip, quiet_cache):
     from mpi_operator_tpu.ops.attention import (paged_decode_attention,
                                                 record_traced, traced_name)
     S, NP, H, KV, D, ps, nblk = 8, 64, 64, 64, 128, 128, 16
+    pages, vmem = _walk_vmem(nblk, ps, KV // 2, D, H // KV)  # 32 heads a step
+    assert pages == 1 and vmem < SCOPED_VMEM, (
+        f"{pages} page a turn of 32 heads takes {vmem} bytes of VMEM, slots "
+        f"and a turn's temporaries; Mosaic's scoped limit is {SCOPED_VMEM}")
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,   # noqa: E731
                                                   sharding=one_chip)
     with record_traced() as traced:
